@@ -927,9 +927,9 @@ class FrameworkConfig:
     prefetch_depth: int | None = None
     num_devices: int = 0  # 0 = all visible devices
     bucket_multiple: int = 64  # sequence lengths padded up to a multiple of this
-    # Pallas flash-attention kernels. None = auto: enabled on TPU, where they
-    # measure 2-3.5x faster than the XLA attention at 4k context (bench.py's
-    # pallas_speedup_4k); shapes the kernel can't tile fall back per-call
+    # Pallas flash-attention kernels. None = auto: enabled on TPU (their
+    # speed against the XLA attention on the chip: not measured, PERF.md);
+    # shapes the kernel can't tile fall back per-call
     # (models/llama.py checks pallas_attention.supports() at trace time).
     use_pallas: bool | None = None
     # Tensor parallelism for the streaming scorer: shard every streamed
@@ -1265,12 +1265,11 @@ class FrameworkConfig:
         the producer thread contends with XLA:CPU compute for cores)."""
         if self.prefetch_depth is not None:
             return self.prefetch_depth
-        try:
-            import jax
+        import jax
 
-            return 2 if jax.devices()[0].platform != "cpu" else 0
-        except Exception:
-            return 0
+        # A backend that fails to come up propagates: "no prefetch" must
+        # never be the silent reading of a chip that did not start.
+        return 2 if jax.devices()[0].platform != "cpu" else 0
 
     def decode_resident_enabled(
         self, model_cfg, n_weight_chips: int = 1, device=None
@@ -1292,10 +1291,7 @@ class FrameworkConfig:
             weight_bytes_per_chip,
         )
 
-        try:
-            hbm_gb = chip_hbm_gb(device)
-        except Exception:
-            return False
+        hbm_gb = chip_hbm_gb(device)  # None on the CPU; raises on an unknown TPU
         if not hbm_gb:
             return False
         per_chip = weight_bytes_per_chip(model_cfg, self.dtype, n_weight_chips)
@@ -1303,16 +1299,14 @@ class FrameworkConfig:
 
     def pallas_enabled(self) -> bool:
         """Resolve the tri-state ``use_pallas``: explicit value, or auto —
-        on iff the default backend's devices are real TPUs (the kernels are
-        2-3.5x faster there; in interpret mode they'd only be slower)."""
+        on iff the default backend's devices are real TPUs (elsewhere the
+        kernels would run in interpret mode, which is only slower)."""
         if self.use_pallas is not None:
             return self.use_pallas
-        try:
-            import jax
+        import jax
 
-            return jax.devices()[0].platform == "tpu"
-        except Exception:
-            return False
+        # No except: a backend error is the caller's error, not "no kernels".
+        return jax.devices()[0].platform == "tpu"
 
 
 def _parse_tenant_map(spec: str, what: str) -> dict[str, float]:

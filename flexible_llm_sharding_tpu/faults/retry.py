@@ -46,7 +46,7 @@ class RetryPolicy:
     from the ``io_retry_*`` config fields).
 
     ``retryable`` defaults to the transient family: ``OSError`` (which is
-    ``IOError`` — NFS/FUSE blips, truncated reads, wedged tunnels surface
+    ``IOError`` — NFS/FUSE blips and truncated reads surface
     here) and ``TimeoutError``. Everything else — shape mismatches, key
     errors, a corrupt checkpoint's ValueError — fails fast on the first
     attempt: retrying a deterministic bug just triples its latency.

@@ -157,14 +157,14 @@ class PressureMonitor:
 
     @staticmethod
     def _default_hbm_free_frac() -> float | None:
-        try:
-            from flexible_llm_sharding_tpu.utils.metrics import (
-                device_memory_stats,
-            )
+        from flexible_llm_sharding_tpu.utils.metrics import (
+            device_memory_stats,
+        )
 
-            stats = device_memory_stats()
-        except Exception:  # flscheck: disable=EXC-TAXONOMY: an HBM probe failure (backend down, tunnel flake) reads as UNKNOWN — the signal never trips on missing evidence
-            return None
+        # {} on the CPU (no allocator stats: unknown, the signal never
+        # trips); on a TPU a failed query raises rather than reading as
+        # unknown.
+        stats = device_memory_stats()
         limit = stats.get("bytes_limit")
         if not limit:
             return None

@@ -235,24 +235,20 @@ def auto_pin_budget_bytes(device=None) -> int:
 
     Free = the allocator's ``bytes_limit - bytes_in_use`` when the device
     reports memory stats, else the device-kind HBM table (assumed empty).
-    Unknown HBM (the CPU backend, unrecognized kinds) resolves to 0 (off)
-    — the budget is only ever spent where it is real."""
-    try:
-        from flexible_llm_sharding_tpu.utils.metrics import (
-            chip_hbm_gb,
-            device_memory_stats,
-        )
+    The CPU backend has neither and resolves to 0 (off) — the budget is
+    only ever spent where it is real. On a TPU a failed stats query or an
+    unknown device kind raises (utils/metrics.py); it is never read as
+    "off"."""
+    from flexible_llm_sharding_tpu.utils.metrics import (
+        chip_hbm_gb,
+        device_memory_stats,
+    )
 
-        stats = device_memory_stats(device)
-    except Exception:  # flscheck: disable=EXC-TAXONOMY: auto budget resolves to off (0) on ANY probe failure — backends raise anything from ImportError to RuntimeError here
-        return 0
+    stats = device_memory_stats(device)
     limit = stats.get("bytes_limit")
     in_use = stats.get("bytes_in_use", 0.0)
     if not limit:
-        try:
-            hbm = chip_hbm_gb(device)
-        except Exception:  # flscheck: disable=EXC-TAXONOMY: unknown-HBM probes degrade to off, never fail the caller
-            hbm = None
+        hbm = chip_hbm_gb(device)
         if not hbm:
             return 0
         limit = hbm * 1e9

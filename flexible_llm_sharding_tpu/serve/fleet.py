@@ -407,14 +407,28 @@ class ReplicaFleet:
 
     # -- replica lifecycle -------------------------------------------------
 
-    def _mk_replica(self, start: bool = True) -> _Replica:
+    def _mk_replica(self, start: bool = True, device=None) -> _Replica:
         """Build one engine slot (outside the fleet lock: construction
-        reads config.json and builds a weight source)."""
+        reads config.json and builds a weight source). One replica per
+        device: unless the caller pinned the whole fleet to one ``device``,
+        a new replica goes to the local device that holds the fewest (the
+        first N land on N distinct chips); a recycled slot passes its
+        predecessor's ``device`` and stays where it was."""
+        with self._lock:
+            idx = self._next_idx
+            self._next_idx += 1
+            if device is None:
+                device = self._device
+            if device is None:
+                import jax
+
+                held = [r.engine.device for r in self._replicas]
+                device = min(jax.local_devices(), key=held.count)
         engine = ServeEngine(
             self.cfg,
             self._engine_cfg,
             tokenizer=self._tokenizer,
-            device=self._device,
+            device=device,
             start=False,
             # No bare process-wide 'serve'/... mirrors: with N replicas
             # last-wins would expose one arbitrary replica as THE process
@@ -427,9 +441,6 @@ class ReplicaFleet:
             # the same log, so per-replica segment sequences never fork.
             wal=self._wal,
         )
-        with self._lock:
-            idx = self._next_idx
-            self._next_idx += 1
         rep = _Replica(idx, engine, stagger=self._stagger)
         if self._injector is not None or self._stagger is not None:
             engine.fleet_hook = (
@@ -572,7 +583,7 @@ class ReplicaFleet:
                 if rep in self._replicas:
                     self._replicas.remove(rep)
                 return
-        new = self._mk_replica(start=self._started)
+        new = self._mk_replica(start=self._started, device=rep.engine.device)
         with self._lock:
             # Re-check under the lock: shutdown() may have closed the
             # fleet while the fresh engine was being built — appending it
@@ -1113,7 +1124,11 @@ class ReplicaFleet:
         with self._lock:
             replicas = list(self._replicas)
         out["replicas"] = {
-            str(rep.idx): {"state": rep.state, **rep.engine.stats()}
+            str(rep.idx): {
+                "state": rep.state,
+                "device": str(rep.engine.device),
+                **rep.engine.stats(),
+            }
             for rep in replicas
         }
         return out

@@ -132,6 +132,12 @@ def _load_lib() -> ctypes.CDLL | None:
         return _lib
 
 
+def native_loaded() -> bool:
+    """Whether the C++ library built (on first use) and loaded; False means
+    every entry point here runs its pure-Python equivalent."""
+    return _load_lib() is not None
+
+
 class FilePrefetcher:
     """Warms files into the OS page cache ahead of the loader's reads.
 

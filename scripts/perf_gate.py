@@ -5,7 +5,7 @@ phases on every PR and fail on regression beyond the recorded spread.
 bench.py has rich phases but ran ad hoc — a host-path regression (a copy
 sneaking onto the zero-copy stream, the shard cache silently missing, the
 pin tier streaming pinned bytes anyway) could land unnoticed until the
-next hardware window. This gate runs the phases that are meaningful on a
+next chip run. This gate runs the phases that are meaningful on a
 CPU-only runner:
 
 - ``host_stream_*_warm_gbps``  (bench_host_stream, warm legs only — cold

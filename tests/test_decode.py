@@ -468,11 +468,12 @@ def test_decode_resident_auto_gate(tiny_cfg):
     kinds (the CPU backend) resolve to off."""
     from flexible_llm_sharding_tpu.config import LlamaConfig
 
-    class FakeDev:
+    class FakeDev:  # the v5e as chip_smoke.py saw it (PR 21)
+        platform = "tpu"
         device_kind = "TPU v5 lite"
 
         def memory_stats(self):
-            return None
+            return {"bytes_limit": 16909336064, "bytes_in_use": 0}
 
     fw = FrameworkConfig(dtype="bfloat16")
     assert fw.decode_resident_enabled(tiny_cfg, 1, FakeDev())
@@ -642,11 +643,12 @@ def test_decode_kv_on_device_gate(tiny_cfg, model):
     slots = N_GEN - 1
     assert not gen._kv_fits_on_chip(toks, blocks, slots)  # unknown HBM
 
-    class FakeDev:
+    class FakeDev:  # the v5e as chip_smoke.py saw it (PR 21)
+        platform = "tpu"
         device_kind = "TPU v5 lite"
 
         def memory_stats(self):
-            return None
+            return {"bytes_limit": 16909336064, "bytes_in_use": 0}
 
     gen._probe_dev = FakeDev()
     assert gen._kv_fits_on_chip(toks, blocks, slots)

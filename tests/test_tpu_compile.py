@@ -1,0 +1,241 @@
+"""AOT compiles for a DESCRIBED TPU v5e (2x2), no chip attached.
+
+The chip's compiler is installed in the sandbox and compiles for a topology
+that is described, not attached (on-chip-measurement guide, section 2). These
+tests guard what interpret-mode tests cannot see: a kernel the compiler
+refuses (VMEM, tiling alignment), a step that no longer carries its kernel
+(``tpu_custom_call`` absent — the lowering silently took the interpreter or
+the XLA fallback), a program over the chip's memory. A compile that passes is
+not a chip run; ``chip_smoke.py`` is.
+
+Rules this file keeps (same guide): the topology is described inside a
+module-scoped fixture that skips when it cannot be, never at import, never in
+``skipif``/``parametrize``; shardings and shapes are built in fixtures or in
+the test; everything compiles in the test's own process (the worker that
+loaded libtpu keeps its lock); ONE file, so one xdist worker owns the library.
+The persistent compile cache is off for the whole suite (tests/conftest.py):
+an entry compiled for a described device cannot be read back without a chip.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from flexible_llm_sharding_tpu.config import LlamaConfig
+from flexible_llm_sharding_tpu.models import llama
+from flexible_llm_sharding_tpu.ops import pallas_attention as pa
+from flexible_llm_sharding_tpu.runtime import decode, executor
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe means skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp_mesh(topo):
+    return Mesh(np.array(topo.devices), ("tp",))
+
+
+@pytest.fixture
+def lowering_sees_tpu(monkeypatch):
+    """ops/pallas_attention.py picks interpret mode from
+    ``jax.default_backend()``, which is the CPU here; on the chip it is the
+    TPU. Steer it in the test, not through an option of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(sharding, shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = fn.lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+# (n_q, n_kv, qk head dim, v head dim): Llama-3-8B, Llama-3-70B, MLA
+# (DeepSeek-V2/V3, Moonlight: qk 128+64 rope, v 128).
+WIDTHS = {
+    "llama3_8b": (32, 8, 128, 128),
+    "llama3_70b": (64, 8, 128, 128),
+    "mla": (16, 16, 192, 128),
+}
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("length", [512, 4096])
+def test_causal_kernel_compiles(one_chip, widths, length):
+    n_q, n_kv, hd, dv = WIDTHS[widths]
+    s = functools.partial(_sds, one_chip)
+    fn = functools.partial(pa.flash_causal_attention, interpret=False)
+    _, text = _compile(
+        jax.jit(fn),
+        s((length, n_q, hd)), s((length, n_kv, hd)), s((length, n_kv, dv)),
+        s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_prefix_shared_kernel_compiles(one_chip, widths):
+    n_q, n_kv, hd, dv = WIDTHS[widths]
+    s = functools.partial(_sds, one_chip)
+    lp, ns, ls = 4096, 4, 64
+    fn = functools.partial(pa.flash_prefix_shared_attention, interpret=False)
+    _, text = _compile(
+        jax.jit(fn),
+        s((ns, ls, n_q, hd)), s((lp, n_kv, hd)), s((lp, n_kv, dv)),
+        s((ns, ls, n_kv, hd)), s((ns, ls, n_kv, dv)), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("widths", ["llama3_8b", "llama3_70b"])
+def test_decode_kernel_compiles(one_chip, widths):
+    # MLA decodes through the XLA op (models/llama.py: kv_lora_rank), so the
+    # decode kernel has no MLA shape to guard.
+    n_q, n_kv, hd, _ = WIDTHS[widths]
+    s = functools.partial(_sds, one_chip)
+    lp, ns, ls, t = 4096, 4, 64, 64
+    fn = functools.partial(pa.flash_decode_attention, interpret=False)
+    _, text = _compile(
+        jax.jit(fn),
+        s((ns, 1, n_q, hd)), s((lp, n_kv, hd)), s((lp, n_kv, hd)),
+        s((ns, ls, n_kv, hd)), s((ns, ls, n_kv, hd)),
+        s((ns, t, n_kv, hd)), s((ns, t, n_kv, hd)),
+        s((), jnp.int32), s((ns,), jnp.int32), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+# --- whole steps at Llama-3-8B widths (chip_smoke.py's shapes) -------------
+
+LLAMA3_8B = LlamaConfig(
+    vocab_size=128256,
+    hidden_size=4096,
+    intermediate_size=14336,
+    num_hidden_layers=8,
+    num_attention_heads=32,
+    num_key_value_heads=8,
+    rope_theta=500000.0,
+    max_position_embeddings=8192,
+)
+K_LAYERS, B, LP, S, LS, T = 2, 8, 320, 4, 64, 8
+
+
+def _layer_shapes():
+    return jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), LLAMA3_8B, dtype=BF16)
+    )["layers"][0]
+
+
+def _segment(sharding):
+    """Shapes of K_LAYERS stacked decoder layers on one placement."""
+    stacked = jax.tree.map(
+        lambda x: _sds(sharding, (K_LAYERS, *x.shape), x.dtype), _layer_shapes()
+    )
+    return {"layers": stacked, "sliding": None, "rope": None}
+
+
+def test_decoder_block_carries_kernels(one_chip, lowering_sees_tpu):
+    """The scoring step as the executor jits it, use_pallas on: both flash
+    kernels must be IN the compiled program. Without the steer the lowering
+    sees the CPU, emits the interpreter's loops and compiles just as
+    happily with zero custom calls — the failure this test exists for."""
+    s = functools.partial(_sds, one_chip)
+    compiled, text = _compile(
+        executor._decoder_block,
+        LLAMA3_8B, _segment(one_chip),
+        s((B, LP, 4096)), s((B, S, LS, 4096)), s((B,), jnp.int32), True,
+    )
+    assert text.count("tpu_custom_call") >= 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _kv_shapes(s, n_kv, hd=128):
+    return {
+        "kp": s((K_LAYERS, B, LP, n_kv, hd)), "vp": s((K_LAYERS, B, LP, n_kv, hd)),
+        "ks": s((K_LAYERS, B, S, LS, n_kv, hd)), "vs": s((K_LAYERS, B, S, LS, n_kv, hd)),
+        "kg": s((K_LAYERS, B, S, T, n_kv, hd)), "vg": s((K_LAYERS, B, S, T, n_kv, hd)),
+    }
+
+
+def test_kv_decode_step_carries_kernel(one_chip, lowering_sees_tpu):
+    s = functools.partial(_sds, one_chip)
+    _, text = _compile(
+        decode._decode_decoders,
+        LLAMA3_8B, True, None, _segment(one_chip), _kv_shapes(s, 8),
+        s((B, S, 1, 4096)), s((B,), jnp.int32), s((B, S), jnp.int32),
+        s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_prefill_step_carries_kernels(one_chip, lowering_sees_tpu):
+    s = functools.partial(_sds, one_chip)
+    _, text = _compile(
+        decode._prefill_decoders,
+        LLAMA3_8B, True, None, _segment(one_chip),
+        s((B, LP, 4096)), s((B, S, LS, 4096)), s((B,), jnp.int32),
+    )
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_tp4_shard_map_kernel_compiles(tp_mesh, lowering_sees_tpu):
+    """The prefix-shared kernel per head-shard under a 4-chip tp mesh
+    (models/llama.py _flash_tp_prefix_shared): pallas_call has no GSPMD rule,
+    so this shard_map is what tensor parallelism runs."""
+    rep = NamedSharding(tp_mesh, P())
+    s = functools.partial(_sds, rep)
+    fn = jax.jit(
+        lambda *a: llama._flash_tp_prefix_shared(tp_mesh, *a, None, {})
+    )
+    _, text = _compile(
+        fn,
+        s((S, LS, 32, 128)), s((LP, 8, 128)), s((LP, 8, 128)),
+        s((S, LS, 8, 128)), s((S, LS, 8, 128)), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_tp4_decoder_block_carries_kernels(tp_mesh, lowering_sees_tpu):
+    """The scoring step under --tensor_parallel 4: weights sharded by the
+    placement's own specs, activations replicated, kernels inside."""
+    from flexible_llm_sharding_tpu.parallel.sharding import layer_specs
+
+    stacked = jax.tree.map(
+        lambda x, spec: _sds(
+            NamedSharding(tp_mesh, P(None, *spec)), (K_LAYERS, *x.shape), x.dtype
+        ),
+        _layer_shapes(), layer_specs("tp", LLAMA3_8B),
+    )
+    rep = NamedSharding(tp_mesh, P())
+    s = functools.partial(_sds, rep)
+    compiled, text = _compile(
+        executor._decoder_block,
+        LLAMA3_8B, {"layers": stacked, "sliding": None, "rope": None},
+        s((B, LP, 4096)), s((B, S, LS, 4096)), s((B,), jnp.int32), True, tp_mesh,
+    )
+    assert text.count("tpu_custom_call") >= 2
+    assert "all-reduce" in text  # the row-parallel products' ICI reduction
