@@ -86,6 +86,7 @@ def _lin(x: jax.Array, params: Params, w: str, b: str) -> jax.Array:
     return y
 
 
+@jax.named_scope("qkv")
 def _qkv(attn: Params, cfg: LlamaConfig, x: jax.Array):
     """x: [..., L, D] -> q [..., L, n_q, hd], k/v [..., L, n_kv, hd]."""
     hd = cfg.head_dim
@@ -105,6 +106,7 @@ def _out_proj(attn: Params, o: jax.Array) -> jax.Array:
     return _lin(o.reshape(*o.shape[:-2], -1), attn, "wo", "bo")
 
 
+@jax.named_scope("mla_qkv")
 def _qkv_mla(attn: Params, cfg: LlamaConfig, x: jax.Array, positions, total_len=None):
     """Multi-head latent attention q/k/v assembly (DeepSeek-V2/V3,
     DeepseekV3Attention): queries optionally LoRA'd (q_a -> norm -> q_b),
@@ -181,6 +183,7 @@ _ACT = {
 assert set(_ACT) == set(SUPPORTED_ACTIVATIONS)  # config validates against this
 
 
+@jax.named_scope("mlp")
 def _dense_mlp(mlp: Params, x: jax.Array, act) -> jax.Array:
     h = act(_lin(x, mlp, "gate", "bgate")) * _lin(x, mlp, "up", "bup")
     return _lin(h, mlp, "down", "bdown")
@@ -205,27 +208,29 @@ def _moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     MoE at all (dense Llama only, SURVEY.md §2.2 'EP: absent').
     """
     e, k = cfg.num_local_experts, cfg.num_experts_per_tok
-    logits = _mm(x, mlp["router"])  # [..., L, E], model dtype (HF gate dtype)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, k)  # sorted desc, like torch.topk
-    if cfg.moe_norm_topk_prob:  # Mixtral always; Qwen3-MoE per norm_topk_prob
-        top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
-    # Scatter the k renormalised weights back onto the E axis.
-    combine = jnp.sum(
-        jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_vals[..., None], axis=-2
-    ).astype(x.dtype)  # [..., L, E]
-    h = _ACT[cfg.hidden_act](
-        jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
-    ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
-    # Fold the combine weights in BEFORE the down projection (scalar per
-    # token-expert, so algebraically identical to HF's weight-after-w2) and
-    # hard-zero non-selected experts with `where`: a plain `h * 0` would turn
-    # an fp16 overflow (inf) in an expert the router never picked into NaN —
-    # a failure HF can't have, since it never computes unselected experts.
-    # This also avoids materialising a [..., L, E, D] per-expert output.
-    c = combine[..., None]  # [..., L, E, 1]
-    h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
-    return jnp.einsum("...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION)
+    with jax.named_scope("moe_router"):
+        logits = _mm(x, mlp["router"])  # [..., L, E], model dtype (HF gate dtype)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        top_vals, top_idx = jax.lax.top_k(probs, k)  # sorted desc, like torch.topk
+        if cfg.moe_norm_topk_prob:  # Mixtral always; Qwen3-MoE per norm_topk_prob
+            top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+        # Scatter the k renormalised weights back onto the E axis.
+        combine = jnp.sum(
+            jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_vals[..., None], axis=-2
+        ).astype(x.dtype)  # [..., L, E]
+    with jax.named_scope("moe_experts"):
+        h = _ACT[cfg.hidden_act](
+            jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
+        ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
+        # Fold the combine weights in BEFORE the down projection (scalar per
+        # token-expert, so algebraically identical to HF's weight-after-w2) and
+        # hard-zero non-selected experts with `where`: a plain `h * 0` would turn
+        # an fp16 overflow (inf) in an expert the router never picked into NaN —
+        # a failure HF can't have, since it never computes unselected experts.
+        # This also avoids materialising a [..., L, E, D] per-expert output.
+        c = combine[..., None]  # [..., L, E, 1]
+        h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
+        return jnp.einsum("...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION)
 
 
 def _llama4_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
@@ -237,24 +242,27 @@ def _llama4_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     inputs are hard-zeroed so they can't overflow."""
     e, k = cfg.num_local_experts, cfg.num_experts_per_tok
     act = _ACT[cfg.hidden_act]
-    logits = _mm(x, mlp["router"])  # [..., L, E]
-    top_vals, top_idx = jax.lax.top_k(logits.astype(jnp.float32), k)
-    c = jnp.sum(
-        jax.nn.one_hot(top_idx, e, dtype=jnp.float32)
-        * jax.nn.sigmoid(top_vals)[..., None],
-        axis=-2,
-    ).astype(x.dtype)  # [..., L, E]
-    xin = x[..., None, :] * c[..., None]  # [..., L, E, D]
-    xin = jnp.where(c[..., None] != 0, xin, jnp.zeros_like(xin))
-    h = act(
-        jnp.einsum("...led,edf->...lef", xin, mlp["gate"].astype(x.dtype), precision=_PRECISION)
-    ) * jnp.einsum("...led,edf->...lef", xin, mlp["up"].astype(x.dtype), precision=_PRECISION)
-    routed = jnp.einsum(
-        "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
-    )  # contracts e AND f: sums the experts
-    shared = _mm(
-        act(_mm(x, mlp["shared_gate"])) * _mm(x, mlp["shared_up"]), mlp["shared_down"]
-    )
+    with jax.named_scope("moe_router"):
+        logits = _mm(x, mlp["router"])  # [..., L, E]
+        top_vals, top_idx = jax.lax.top_k(logits.astype(jnp.float32), k)
+        c = jnp.sum(
+            jax.nn.one_hot(top_idx, e, dtype=jnp.float32)
+            * jax.nn.sigmoid(top_vals)[..., None],
+            axis=-2,
+        ).astype(x.dtype)  # [..., L, E]
+    with jax.named_scope("moe_experts"):
+        xin = x[..., None, :] * c[..., None]  # [..., L, E, D]
+        xin = jnp.where(c[..., None] != 0, xin, jnp.zeros_like(xin))
+        h = act(
+            jnp.einsum("...led,edf->...lef", xin, mlp["gate"].astype(x.dtype), precision=_PRECISION)
+        ) * jnp.einsum("...led,edf->...lef", xin, mlp["up"].astype(x.dtype), precision=_PRECISION)
+        routed = jnp.einsum(
+            "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
+        )  # contracts e AND f: sums the experts
+    with jax.named_scope("moe_shared_experts"):
+        shared = _mm(
+            act(_mm(x, mlp["shared_gate"])) * _mm(x, mlp["shared_up"]), mlp["shared_down"]
+        )
     return shared + routed
 
 
@@ -269,47 +277,50 @@ def _deepseek_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     compute-all stacked-einsum layout as the Mixtral path."""
     e, k = cfg.num_local_experts, cfg.num_experts_per_tok
     g = cfg.moe_n_group
-    logits = jnp.einsum(
-        "...ld,de->...le",
-        x.astype(jnp.float32),
-        mlp["router"].astype(jnp.float32),
-        precision=_PRECISION,
-    )  # HF routes in float32 end to end
-    scores = jax.nn.sigmoid(logits)  # [..., L, E]
-    choice = scores + mlp["correction_bias"].astype(jnp.float32)
-    if g > 1:
-        grouped = choice.reshape(*choice.shape[:-1], g, e // g)
-        top2, _ = jax.lax.top_k(grouped, 2)
-        group_scores = top2.sum(axis=-1)  # [..., L, G]
-        _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
-        gmask = jnp.sum(
-            jax.nn.one_hot(gidx, g, dtype=choice.dtype), axis=-2
-        )  # [..., L, G]
-        choice = jnp.where(
-            jnp.repeat(gmask, e // g, axis=-1) > 0, choice, 0.0
-        )
-    _, top_idx = jax.lax.top_k(choice, k)
-    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
-    if cfg.moe_norm_topk_prob:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
-    top_w = top_w * cfg.moe_routed_scaling_factor
-    combine = jnp.sum(
-        jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_w[..., None],
-        axis=-2,
-    ).astype(x.dtype)  # [..., L, E]
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum(
+            "...ld,de->...le",
+            x.astype(jnp.float32),
+            mlp["router"].astype(jnp.float32),
+            precision=_PRECISION,
+        )  # HF routes in float32 end to end
+        scores = jax.nn.sigmoid(logits)  # [..., L, E]
+        choice = scores + mlp["correction_bias"].astype(jnp.float32)
+        if g > 1:
+            grouped = choice.reshape(*choice.shape[:-1], g, e // g)
+            top2, _ = jax.lax.top_k(grouped, 2)
+            group_scores = top2.sum(axis=-1)  # [..., L, G]
+            _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
+            gmask = jnp.sum(
+                jax.nn.one_hot(gidx, g, dtype=choice.dtype), axis=-2
+            )  # [..., L, G]
+            choice = jnp.where(
+                jnp.repeat(gmask, e // g, axis=-1) > 0, choice, 0.0
+            )
+        _, top_idx = jax.lax.top_k(choice, k)
+        top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if cfg.moe_norm_topk_prob:
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w * cfg.moe_routed_scaling_factor
+        combine = jnp.sum(
+            jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_w[..., None],
+            axis=-2,
+        ).astype(x.dtype)  # [..., L, E]
     act = _ACT[cfg.hidden_act]
-    h = act(
-        jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
-    ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
-    c = combine[..., None]
-    h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
-    routed = jnp.einsum(
-        "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
-    )
-    shared = _mm(
-        act(_mm(x, mlp["shared_gate"])) * _mm(x, mlp["shared_up"]),
-        mlp["shared_down"],
-    )
+    with jax.named_scope("moe_experts"):
+        h = act(
+            jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
+        ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
+        c = combine[..., None]
+        h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
+        routed = jnp.einsum(
+            "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
+        )
+    with jax.named_scope("moe_shared_experts"):
+        shared = _mm(
+            act(_mm(x, mlp["shared_gate"])) * _mm(x, mlp["shared_up"]),
+            mlp["shared_down"],
+        )
     return routed + shared
 
 
@@ -666,26 +677,28 @@ def prefix_suffix_layer(
             chunk=chunk,
             softcap=cfg.attn_logit_softcap,
         )
-        if tp_mesh is not None:
+    with jax.named_scope("attention"):
+        if flash and tp_mesh is not None:
             attn_out = _flash_tp_causal(
                 tp_mesh, q, k, v, prefix_len, sliding, flash_kw
             )
-        else:
+        elif flash:
             attn_out = pallas_attention.flash_causal_attention(
                 q, k, v, prefix_len, local_on=sliding, **flash_kw
             )
-    else:
-        if sliding is None:
-            mask = causal_mask(lp, lp, window=window, chunk=chunk)
-        else:  # traced per-layer toggle: local mask iff this layer is local
-            mask = jnp.where(
-                sliding,
-                causal_mask(lp, lp, window=window, chunk=chunk),
-                causal_mask(lp, lp),
+        else:
+            if sliding is None:
+                mask = causal_mask(lp, lp, window=window, chunk=chunk)
+            else:  # traced per-layer toggle: local mask iff this layer is local
+                mask = jnp.where(
+                    sliding,
+                    causal_mask(lp, lp, window=window, chunk=chunk),
+                    causal_mask(lp, lp),
+                )
+            attn_out = attention(
+                q, k, v, mask, scale=cfg.attn_scale,
+                softcap=cfg.attn_logit_softcap,
             )
-        attn_out = attention(
-            q, k, v, mask, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap
-        )
     prefix_mid = _residual_attn(params, cfg, prefix_h, attn_out)
     prefix_out = _residual_mlp(params, cfg, prefix_mid)
 
@@ -697,29 +710,29 @@ def prefix_suffix_layer(
         params, cfg, hs, pos_s, rope_sliding, rope_on, total_len
     )
 
-    if flash:
-        if tp_mesh is not None:
+    with jax.named_scope("attention"):
+        if flash and tp_mesh is not None:
             attn_s = _flash_tp_prefix_shared(
                 tp_mesh, qs, k, v, ks, vs, prefix_len, sliding, flash_kw
             )
-        else:
+        elif flash:
             attn_s = pallas_attention.flash_prefix_shared_attention(
                 qs, k, v, ks, vs, prefix_len, local_on=sliding, **flash_kw
             )
-    else:
-        attn_s = prefix_shared_attention(
-            qs,
-            k,
-            v,
-            ks,
-            vs,
-            prefix_len,
-            scale=cfg.attn_scale,
-            window=window,
-            softcap=cfg.attn_logit_softcap,
-            sliding=sliding,
-            chunk=chunk,
-        )
+        else:
+            attn_s = prefix_shared_attention(
+                qs,
+                k,
+                v,
+                ks,
+                vs,
+                prefix_len,
+                scale=cfg.attn_scale,
+                window=window,
+                softcap=cfg.attn_logit_softcap,
+                sliding=sliding,
+                chunk=chunk,
+            )
     suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s)
     suffix_out = _residual_mlp(params, cfg, suffix_mid)
     if return_kv:
@@ -786,35 +799,36 @@ def suffix_only_layer(
         params, cfg, hs, pos_s, rope_sliding, rope_on, total_len
     )
 
-    if flash:
-        flash_kw = dict(
-            scale=cfg.attn_scale,
-            window=window,
-            chunk=chunk,
-            softcap=cfg.attn_logit_softcap,
-        )
-        if tp_mesh is not None:
-            attn_s = _flash_tp_prefix_shared(
-                tp_mesh, qs, kp, vp, ks, vs, prefix_len, sliding, flash_kw
+    with jax.named_scope("attention"):
+        if flash:
+            flash_kw = dict(
+                scale=cfg.attn_scale,
+                window=window,
+                chunk=chunk,
+                softcap=cfg.attn_logit_softcap,
             )
+            if tp_mesh is not None:
+                attn_s = _flash_tp_prefix_shared(
+                    tp_mesh, qs, kp, vp, ks, vs, prefix_len, sliding, flash_kw
+                )
+            else:
+                attn_s = pallas_attention.flash_prefix_shared_attention(
+                    qs, kp, vp, ks, vs, prefix_len, local_on=sliding, **flash_kw
+                )
         else:
-            attn_s = pallas_attention.flash_prefix_shared_attention(
-                qs, kp, vp, ks, vs, prefix_len, local_on=sliding, **flash_kw
+            attn_s = prefix_shared_attention(
+                qs,
+                kp,
+                vp,
+                ks,
+                vs,
+                prefix_len,
+                scale=cfg.attn_scale,
+                window=window,
+                softcap=cfg.attn_logit_softcap,
+                sliding=sliding,
+                chunk=chunk,
             )
-    else:
-        attn_s = prefix_shared_attention(
-            qs,
-            kp,
-            vp,
-            ks,
-            vs,
-            prefix_len,
-            scale=cfg.attn_scale,
-            window=window,
-            softcap=cfg.attn_logit_softcap,
-            sliding=sliding,
-            chunk=chunk,
-        )
     suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s)
     suffix_out = _residual_mlp(params, cfg, suffix_mid)
     return suffix_out, {"ks": ks, "vs": vs}
@@ -883,25 +897,41 @@ def decode_step_layer(
 
     window, chunk, sliding = _effective_window(cfg, sliding)
     tp_size = tp_mesh.shape["tp"] if tp_mesh is not None else 1
-    if use_pallas and not cfg.kv_lora_rank and kq == 1 and base.ndim == 0 and pallas_attention.supports_decode(
-        cfg.num_attention_heads // tp_size,
-        cfg.num_key_value_heads // tp_size,
-        cfg.head_dim,
-    ):
-        flash_kw = dict(
-            scale=cfg.attn_scale,
-            window=window,
-            softcap=cfg.attn_logit_softcap,
-            chunk=chunk,
-        )
-        if tp_mesh is not None:
-            attn_out = _flash_tp_decode(
-                tp_mesh, q, kv["kp"], kv["vp"], kv["ks"], kv["vs"],
-                kv["kg"], kv["vg"], prefix_len, suffix_eos, t, sliding,
-                flash_kw,
+    with jax.named_scope("attention"):
+        if use_pallas and not cfg.kv_lora_rank and kq == 1 and base.ndim == 0 and pallas_attention.supports_decode(
+            cfg.num_attention_heads // tp_size,
+            cfg.num_key_value_heads // tp_size,
+            cfg.head_dim,
+        ):
+            flash_kw = dict(
+                scale=cfg.attn_scale,
+                window=window,
+                softcap=cfg.attn_logit_softcap,
+                chunk=chunk,
             )
+            if tp_mesh is not None:
+                attn_out = _flash_tp_decode(
+                    tp_mesh, q, kv["kp"], kv["vp"], kv["ks"], kv["vs"],
+                    kv["kg"], kv["vg"], prefix_len, suffix_eos, t, sliding,
+                    flash_kw,
+                )
+            else:
+                attn_out = pallas_attention.flash_decode_attention(
+                    q,
+                    kv["kp"],
+                    kv["vp"],
+                    kv["ks"],
+                    kv["vs"],
+                    kv["kg"],
+                    kv["vg"],
+                    prefix_len,
+                    suffix_eos,
+                    t,
+                    local_on=sliding,
+                    **flash_kw,
+                )
         else:
-            attn_out = pallas_attention.flash_decode_attention(
+            attn_out = decode_attention(
                 q,
                 kv["kp"],
                 kv["vp"],
@@ -912,27 +942,12 @@ def decode_step_layer(
                 prefix_len,
                 suffix_eos,
                 t,
-                local_on=sliding,
-                **flash_kw,
+                scale=cfg.attn_scale,
+                window=window,
+                softcap=cfg.attn_logit_softcap,
+                sliding=sliding,
+                chunk=chunk,
             )
-    else:
-        attn_out = decode_attention(
-            q,
-            kv["kp"],
-            kv["vp"],
-            kv["ks"],
-            kv["vs"],
-            kv["kg"],
-            kv["vg"],
-            prefix_len,
-            suffix_eos,
-            t,
-            scale=cfg.attn_scale,
-            window=window,
-            softcap=cfg.attn_logit_softcap,
-            sliding=sliding,
-            chunk=chunk,
-        )
     mid = _residual_attn(params, cfg, x, attn_out)
     return _residual_mlp(params, cfg, mid), kv
 

@@ -39,6 +39,18 @@ import weakref
 
 _PROM_BAD = re.compile(r"[^a-zA-Z0-9_]")
 
+# One-line definitions by "<source>_<key>", emitted as ``# HELP`` lines:
+# process-wide, because the process registry and every engine's own expose
+# the same sources under the same names.
+_HELP: dict[str, str] = {}
+
+
+def describe(source: str, texts: dict[str, str]) -> None:
+    """Give the gauges ``<prefix>_<source>_<key>`` a ``# HELP`` line each:
+    for the ones an operator would misread by name alone."""
+    for key, text in texts.items():
+        _HELP[f"{source}_{key}"] = " ".join(text.split())
+
 
 def source_snapshot(source) -> dict:
     """Normalize a registered source to a dict: call it if callable, else
@@ -114,6 +126,9 @@ class MetricsRegistry:
             if not isinstance(value, (int, float)):
                 return
             metric = _PROM_BAD.sub("_", name)
+            text = _HELP.get(name[len(prefix) + 1:])
+            if text:
+                lines.append(f"# HELP {metric} {text}")
             lines.append(f"# TYPE {metric} gauge")
             lines.append(f"{metric} {value}")
 

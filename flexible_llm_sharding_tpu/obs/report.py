@@ -4,9 +4,13 @@ previously eyeballed off stats lines and Perfetto screenshots.
 Input is a trace written by ``obs.trace`` (Chrome trace-event JSON or
 JSONL — both are auto-detected). Output:
 
-- **link utilization**: fraction of the trace wall the weight stream was
-  busy (merged union of ``shard_load`` + ``device_put`` span intervals
-  over the wall) — how hard the binding constraint is being driven.
+- **link utilization**: fraction of the trace wall in which a weight
+  upload was in flight (merged union of the ``upload`` span intervals,
+  each from its dispatch to where the completion thread saw the bytes
+  arrive, over the wall) — how hard the binding constraint is being
+  driven. ``upload_dispatch`` is the ``device_put`` CALL, which returns at
+  the enqueue, and host builds (``shard_load``) move nothing over the
+  link: neither counts.
 - **overlap efficiency**: ``1 - source_wait / shard_produce`` — the
   fraction of weight-produce time hidden under compute, the same
   definition bench.py derives from executor stats, now computable from
@@ -28,11 +32,12 @@ import json
 import os
 import sys
 
-# Span names whose intervals constitute "the stream is busy" for link
-# utilization. shard_produce is their parent (it additionally covers
-# residency waits), so it is excluded from the union to avoid double
-# counting; overlap efficiency uses it as the produce denominator.
-STREAM_SPAN_NAMES = ("shard_load", "device_put")
+from flexible_llm_sharding_tpu.utils.intervals import union_seconds
+
+# The span whose intervals are "the link carries bytes": one per streamed
+# shard, dispatch -> arrival. shard_produce (the producer's whole per-shard
+# wall) is overlap efficiency's produce denominator.
+UPLOAD_SPAN = "upload"
 PRODUCE_SPAN = "shard_produce"
 WAIT_SPAN = "source_wait"
 
@@ -163,22 +168,6 @@ def load_trace(path: str) -> list[dict]:
     return out
 
 
-def _union_seconds(intervals: list[tuple[float, float]]) -> float:
-    """Total covered seconds of possibly-overlapping [start, end) spans."""
-    if not intervals:
-        return 0.0
-    intervals.sort()
-    total = 0.0
-    cur_s, cur_e = intervals[0]
-    for s, e in intervals[1:]:
-        if s > cur_e:
-            total += cur_e - cur_s
-            cur_s, cur_e = s, e
-        else:
-            cur_e = max(cur_e, e)
-    return total + (cur_e - cur_s)
-
-
 def _quantiles(samples: list[float]) -> dict[str, float]:
     if not samples:
         return {"count": 0}
@@ -225,11 +214,11 @@ def analyze(events: list[dict]) -> dict:
         d["total_s"] = round(d["total_s"], 6)
         d["mean_s"] = round(d["total_s"] / d["count"], 6)
 
-    stream_busy = _union_seconds(
+    stream_busy = union_seconds(
         [
             (s["ts_s"], s["ts_s"] + s["dur_s"])
             for s in spans
-            if s["name"] in STREAM_SPAN_NAMES
+            if s["name"] == UPLOAD_SPAN
         ]
     )
     produce_s = by_name.get(PRODUCE_SPAN, {}).get("total_s", 0.0)
@@ -307,9 +296,19 @@ def format_report(report: dict) -> str:
         f"trace: {report.get('events', 0)} events, "
         f"{report.get('spans', 0)} spans over "
         f"{report.get('wall_s', 0.0):.3f}s wall",
-        f"link utilization: {report.get('link_utilization', 0.0):.1%} "
-        f"(stream busy {report.get('stream_busy_s', 0.0):.3f}s)",
     ]
+    if report.get("spans_by_name", {}).get(UPLOAD_SPAN):
+        lines.append(
+            f"link utilization: {report.get('link_utilization', 0.0):.1%} "
+            f"(uploads in flight {report.get('stream_busy_s', 0.0):.3f}s)"
+        )
+    else:
+        # A serve engine's cycling source, DP's broadcast source and a
+        # trace from before the upload span existed time no upload.
+        lines.append(
+            f"link utilization: not timed (no `{UPLOAD_SPAN}` spans in "
+            "this trace)"
+        )
     if "overlap_efficiency" in report:
         lines.append(
             f"compute/stream overlap efficiency: "

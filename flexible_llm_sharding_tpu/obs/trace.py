@@ -12,9 +12,10 @@ by ``sweep_id`` / ``shard_idx`` / ``wave_id`` / ``request_id``.
 Design constraints, in order:
 
 1. **Zero-cost when disabled.** Every emit goes through a module-level
-   helper that reads one bool and returns a shared no-op; no allocation,
-   no lock, no timestamp is taken on the disabled path. Tracing must be
-   safe to leave compiled into every hot loop.
+   helper that reads one bool and the profiler's flag and returns a
+   shared no-op; no allocation, no lock, no timestamp is taken on the
+   disabled path. Tracing must be safe to leave compiled into every hot
+   loop.
 2. **Bounded.** Spans land in a ring of ``capacity`` records; overflow
    drops the OLDEST spans and counts them (``trace_drops`` in
    ``stats()``), so a long-running server keeps the newest window and
@@ -22,6 +23,14 @@ Design constraints, in order:
 3. **Machine-readable.** ``write()`` exports Chrome trace-event JSON
    (load it at https://ui.perfetto.dev) or JSONL (one span per line, for
    ``cli trace-report`` and ad-hoc jq), chosen by file extension.
+
+4. **On the device's clock when a profiler runs.** A span also enters a
+   ``jax.profiler.TraceAnnotation`` named ``fls.<name>`` carrying the
+   span's attributes whenever a profiler session is active
+   (``--profile_dir``, a benchmark's traced window), whether or not the
+   ring is enabled, so the program's phases land in the ``.xplane.pb``
+   beside the device's ops. The sweep's span is a
+   ``StepTraceAnnotation`` whose ``step_num`` is the ``sweep_id``.
 
 The process-wide singleton is ``TRACER``; the CLIs enable it from
 ``--trace`` via ``ensure_configured(cfg)`` and export via
@@ -37,6 +46,14 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+# Prefix of the program's spans in a profiler trace (the benchmark's own
+# are ``bench.``): readers select by it.
+ANNOTATION_PREFIX = "fls."
+# One read of the profiler's flag: true while any session records.
+profiler_active = TraceAnnotation.is_enabled
+
 # Correlation-id wells. A sweep id is unique per process (offline: one
 # executor call's full pass over the shards; serving: one engine sweep),
 # so spans from interleaved subsystems stitch back into one timeline.
@@ -48,9 +65,9 @@ def new_sweep_id() -> int:
 
 
 class _NullSpan:
-    """Shared no-op context manager returned by every emit while tracing
-    is disabled — the whole disabled-path cost is one attribute read and
-    one bool test in ``span()``."""
+    """Shared no-op context manager returned by every emit while the ring
+    is off and no profiler records — the whole disabled-path cost is one
+    bool test and one read of the profiler's flag in ``span()``."""
 
     __slots__ = ()
 
@@ -65,26 +82,54 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live timed span; records itself into the tracer ring on exit."""
+    """One live timed span: enters the profiler annotation ``fls.<name>``
+    while a session records, and on exit records itself into the tracer
+    ring when that is enabled. ``t0``/``dur_s`` stay readable after the
+    exit, so a caller that keeps an account reads the same clock pair the
+    trace got."""
 
-    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "step", "t0", "dur_s",
+                 "_ann", "_dropped")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict,
+                 step: int | None = None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.attrs = attrs
+        self.step = step
+        self.dur_s = 0.0
+        self._ann = None
+        self._dropped = False
+
+    def drop(self) -> None:
+        """Keep this span out of the ring (a wait that turned out to
+        belong to a resume-skipped shard); its timing stays readable."""
+        self._dropped = True
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        if profiler_active():
+            name = ANNOTATION_PREFIX + self.name
+            if self.step is None:
+                self._ann = TraceAnnotation(name, **self.attrs)
+            else:
+                self._ann = StepTraceAnnotation(
+                    name, step_num=self.step, **self.attrs
+                )
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        t1 = time.perf_counter()
-        self._tracer._append(
-            (self.name, self.cat, self._t0, t1 - self._t0,
-             threading.get_ident(), self.attrs)
-        )
+        self.dur_s = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self._tracer.enabled and not self._dropped:
+            self._tracer._append(
+                (self.name, self.cat, self.t0, self.dur_s,
+                 threading.get_ident(), self.attrs)
+            )
         return False
 
 
@@ -121,8 +166,9 @@ class Tracer:
             self._ring.append(rec)
 
     def span(self, name: str, cat: str = "runtime", **attrs):
-        """Timed span context manager; no-op (shared object) when disabled."""
-        if not self.enabled:
+        """Timed span context manager; no-op (shared object) when the ring
+        is off and no profiler session records."""
+        if not self.enabled and not profiler_active():
             return _NULL_SPAN
         return _Span(self, name, cat, attrs)
 
@@ -133,19 +179,6 @@ class Tracer:
         self._append(
             (name, cat, time.perf_counter(), None, threading.get_ident(),
              attrs)
-        )
-
-    def complete(
-        self, name: str, cat: str, t0_perf: float, dur_s: float, **attrs
-    ) -> None:
-        """Record an already-measured span (perf_counter start + duration)
-        — for call sites that only know AFTER the fact whether the timed
-        region should appear in the trace (e.g. a source wait that turned
-        out to belong to a resume-skipped shard)."""
-        if not self.enabled:
-            return
-        self._append(
-            (name, cat, t0_perf, dur_s, threading.get_ident(), attrs)
         )
 
     # -- lifecycle ---------------------------------------------------------
@@ -292,9 +325,25 @@ TRACER = Tracer()
 
 def span(name: str, cat: str = "runtime", **attrs):
     """Module-level emit against the process tracer (the hot-path form)."""
-    if not TRACER.enabled:
+    if not TRACER.enabled and not profiler_active():
         return _NULL_SPAN
     return _Span(TRACER, name, cat, attrs)
+
+
+def timed(name: str, cat: str = "runtime", **attrs) -> _Span:
+    """A span that is always timed, for the sites whose durations feed an
+    always-on account (the executor's per-sweep record): the caller reads
+    ``t0``/``dur_s`` after the exit, and ring and profiler get the same
+    pair."""
+    return _Span(TRACER, name, cat, attrs)
+
+
+def sweep_span(sweep_id: int, cat: str = "sweep", **attrs) -> _Span:
+    """The span of one full pass over the shards, always timed: in a
+    profiler trace a ``StepTraceAnnotation`` with ``step_num`` =
+    ``sweep_id``, so the profiler's per-step views group by sweep."""
+    attrs["sweep_id"] = sweep_id
+    return _Span(TRACER, "sweep", cat, attrs, step=sweep_id)
 
 
 def instant(name: str, cat: str = "runtime", **attrs) -> None:
@@ -334,6 +383,9 @@ __all__ = [
     "ensure_configured",
     "instant",
     "new_sweep_id",
+    "profiler_active",
     "span",
+    "sweep_span",
+    "timed",
     "write_configured",
 ]

@@ -22,9 +22,10 @@ def rms_norm(
     dtype. The cast order is quality-relevant at bf16, so both are
     reproduced exactly.
     """
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    normed = x32 * jax.lax.rsqrt(var + eps)
-    if unit_offset:
-        return (normed * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
-    return scale * normed.astype(x.dtype)
+    with jax.named_scope("rms_norm"):
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        normed = x32 * jax.lax.rsqrt(var + eps)
+        if unit_offset:
+            return (normed * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
+        return scale * normed.astype(x.dtype)

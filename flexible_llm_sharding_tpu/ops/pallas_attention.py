@@ -246,26 +246,37 @@ def flash_causal_attention(
         _causal_kernel, scale=scale, lk=lk, bk=bk, window=window, chunk=chunk,
         softcap=softcap,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bq, hd), lambda h, qb, flags: (h, qb, 0)),
-                pl.BlockSpec((1, lk, hd), kv_head),
-                pl.BlockSpec((1, lk, dv), kv_head),
-            ],
-            out_specs=pl.BlockSpec((1, bq, dv), lambda h, qb, flags: (h, qb, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_q, lq, dv), q.dtype),
-        interpret=interpret,
-    )(
-        _flags(valid_len, local_on),
-        q.transpose(1, 0, 2),
-        k.transpose(1, 0, 2),
-        v.transpose(1, 0, 2),
-    )
+    # Named three times over. The TPU names the HLO instruction after the
+    # innermost scope of its ``op_name``, and under vmap the call runs in
+    # Pallas's own batching loop (the flags are a batched scalar-prefetch
+    # operand), whose body is a ``closed_call`` no scope of ours can get
+    # inside. So: ``name`` for the kernel's own name, the scope for the
+    # ``op_name``, and ``metadata`` for the instruction's
+    # ``frontend_attributes``, which is in the HLO line a profiler trace
+    # shows for the op whatever the instruction is called.
+    with jax.named_scope("flash_causal_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((1, bq, hd), lambda h, qb, flags: (h, qb, 0)),
+                    pl.BlockSpec((1, lk, hd), kv_head),
+                    pl.BlockSpec((1, lk, dv), kv_head),
+                ],
+                out_specs=pl.BlockSpec((1, bq, dv), lambda h, qb, flags: (h, qb, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((n_q, lq, dv), q.dtype),
+            interpret=interpret,
+            name="flash_causal_attention",
+            metadata={"kernel": "flash_causal_attention"},
+        )(
+            _flags(valid_len, local_on),
+            q.transpose(1, 0, 2),
+            k.transpose(1, 0, 2),
+            v.transpose(1, 0, 2),
+        )
     return out.transpose(1, 0, 2)[..., :dv_true]
 
 
@@ -369,30 +380,33 @@ def flash_prefix_shared_attention(
         _prefix_shared_kernel, scale=scale, lp=lp, bkp=bkp, window=window,
         chunk=chunk, softcap=softcap,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, bq, hd), q_map),
-                pl.BlockSpec((1, lp, hd), kv_head),
-                pl.BlockSpec((1, lp, dv), kv_head),
-                pl.BlockSpec((1, 1, ls, hd), skv_head),
-                pl.BlockSpec((1, 1, ls, dv), skv_head),
-            ],
-            out_specs=pl.BlockSpec((1, 1, bq, dv), q_map),
-        ),
-        out_shape=jax.ShapeDtypeStruct((s, n_q, ls, dv), q.dtype),
-        interpret=interpret,
-    )(
-        _flags(prefix_len, local_on),
-        q.transpose(0, 2, 1, 3),
-        k_prefix.transpose(1, 0, 2),
-        v_prefix.transpose(1, 0, 2),
-        k_suffix.transpose(0, 2, 1, 3),
-        v_suffix.transpose(0, 2, 1, 3),
-    )
+    with jax.named_scope("flash_prefix_shared_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((1, 1, bq, hd), q_map),
+                    pl.BlockSpec((1, lp, hd), kv_head),
+                    pl.BlockSpec((1, lp, dv), kv_head),
+                    pl.BlockSpec((1, 1, ls, hd), skv_head),
+                    pl.BlockSpec((1, 1, ls, dv), skv_head),
+                ],
+                out_specs=pl.BlockSpec((1, 1, bq, dv), q_map),
+            ),
+            out_shape=jax.ShapeDtypeStruct((s, n_q, ls, dv), q.dtype),
+            interpret=interpret,
+            name="flash_prefix_shared_attention",
+            metadata={"kernel": "flash_prefix_shared_attention"},
+        )(
+            _flags(prefix_len, local_on),
+            q.transpose(0, 2, 1, 3),
+            k_prefix.transpose(1, 0, 2),
+            v_prefix.transpose(1, 0, 2),
+            k_suffix.transpose(0, 2, 1, 3),
+            v_suffix.transpose(0, 2, 1, 3),
+        )
     return out.transpose(0, 2, 1, 3)[..., :dv_true]
 
 
@@ -557,25 +571,28 @@ def flash_decode_attention(
         _decode_kernel, scale=scale, lp=lpp, bkp=bkp, window=window,
         chunk=chunk, softcap=softcap,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, gp, hd), skv),
-                pl.BlockSpec((1, lpp, hd), kv_head),
-                pl.BlockSpec((1, lpp, hd), kv_head),
-                pl.BlockSpec((1, 1, ks.shape[2], hd), skv),
-                pl.BlockSpec((1, 1, ks.shape[2], hd), skv),
-                pl.BlockSpec((1, 1, kg.shape[2], hd), skv),
-                pl.BlockSpec((1, 1, kg.shape[2], hd), skv),
-            ],
-            out_specs=pl.BlockSpec((1, 1, gp, hd), skv),
-        ),
-        out_shape=jax.ShapeDtypeStruct((s, n_kv, gp, hd), q.dtype),
-        interpret=interpret,
-    )(flags, qg, kp, vp, ks, vs, kg, vg)
+    with jax.named_scope("flash_decode_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((1, 1, gp, hd), skv),
+                    pl.BlockSpec((1, lpp, hd), kv_head),
+                    pl.BlockSpec((1, lpp, hd), kv_head),
+                    pl.BlockSpec((1, 1, ks.shape[2], hd), skv),
+                    pl.BlockSpec((1, 1, ks.shape[2], hd), skv),
+                    pl.BlockSpec((1, 1, kg.shape[2], hd), skv),
+                    pl.BlockSpec((1, 1, kg.shape[2], hd), skv),
+                ],
+                out_specs=pl.BlockSpec((1, 1, gp, hd), skv),
+            ),
+            out_shape=jax.ShapeDtypeStruct((s, n_kv, gp, hd), q.dtype),
+            interpret=interpret,
+            name="flash_decode_attention",
+            metadata={"kernel": "flash_decode_attention"},
+        )(flags, qg, kp, vp, ks, vs, kg, vg)
     return out[:, :, :g, :hd_true].reshape(s, 1, n_q, hd_true)
 
 

@@ -44,6 +44,7 @@ from flexible_llm_sharding_tpu.integrity.manifest import (
     SpillCorruptError,
     SpillReadError,
 )
+from flexible_llm_sharding_tpu.obs import trace as obs_trace
 from flexible_llm_sharding_tpu.runtime.pressure import (
     DiskFullError,
     note_event as _note_pressure_event,
@@ -174,6 +175,11 @@ class ActivationStore:
         # was the host sync that serialised MP pipeline stages). Depth 1
         # bounds the extra HBM to one block's activations.
         self._pending: list[object] = []
+        # Seconds the consumer stood blocked on the device inside this
+        # store (_finalize), and the ids its device_wait spans carry: the
+        # executor's sweep account reads the one and sets the other.
+        self.device_wait_s = 0.0
+        self.trace_ids: dict = {}
         self._writer = None  # lazy single-thread pool for async disk writes
         self._write_futs: list = []
         self._store_gen = 0  # disk write/read generations (see set_shard)
@@ -413,6 +419,13 @@ class ActivationStore:
         releasing its device buffers."""
         if block_id in self._mem:
             p, s = self._mem[block_id]
+            # The wait for the block's compute (which waits for its
+            # shard's upload), told apart from the copy that follows it.
+            with obs_trace.timed(
+                "device_wait", cat="sweep", at="act_store", **self.trace_ids
+            ) as wait:
+                jax.block_until_ready((p, s))
+            self.device_wait_s += wait.dur_s
             self._mem[block_id] = (
                 None if p is None else np.asarray(p),
                 np.asarray(s),

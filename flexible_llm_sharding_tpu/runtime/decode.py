@@ -110,7 +110,8 @@ def _prefill_decoders(
             one_layer,
             in_axes=(None, None, 0, 0, 0, 0 if total_len is not None else None),
         )
-        p, s, kv = step(layer_params, cfg, p, s, prefix_len, total_len)
+        with jax.named_scope("decoder_layer"):
+            p, s, kv = step(layer_params, cfg, p, s, prefix_len, total_len)
         return (p, s), kv
 
     (prefix_h, suffix_h), kv = jax.lax.scan(
@@ -171,7 +172,8 @@ def _suffix_prefill_decoders(
             one_layer,
             in_axes=(None, None, 0, 0, 0, 0, 0 if total_len is not None else None),
         )
-        s, kv_s = step(layer_params, cfg, kp_l, vp_l, s, prefix_len, total_len)
+        with jax.named_scope("decoder_layer"):
+            s, kv_s = step(layer_params, cfg, kp_l, vp_l, s, prefix_len, total_len)
         return s, kv_s
 
     suffix_h, kv_s = jax.lax.scan(body, suffix_h, xs_in)
@@ -229,7 +231,8 @@ def _decode_decoders_impl(
             ),
             in_axes=(None, None, 0, 0, 0, 0, t_in_axis),
         )
-        x, layer_kv = step(layer_params, cfg, x, layer_kv, prefix_len, suffix_eos, t)
+        with jax.named_scope("decoder_layer"):
+            x, layer_kv = step(layer_params, cfg, x, layer_kv, prefix_len, suffix_eos, t)
         if gen_only:
             layer_kv = {"kg": layer_kv["kg"], "vg": layer_kv["vg"]}
         return x, layer_kv
@@ -249,11 +252,15 @@ def _decode_norm_head_impl(cfg: LlamaConfig, norm_params, head_params, x):
     """x [B, S, 1, D] -> float32 next-token distributions [B, S, V]."""
     from flexible_llm_sharding_tpu.ops import rms_norm
 
-    h = rms_norm(x, norm_params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-    return jax.vmap(
-        partial(llama.lm_head_scores, softcap=cfg.final_logit_softcap),
-        in_axes=(None, 0),
-    )(head_params, h)
+    with jax.named_scope("final_norm"):
+        h = rms_norm(
+            x, norm_params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset
+        )
+    with jax.named_scope("lm_head"):
+        return jax.vmap(
+            partial(llama.lm_head_scores, softcap=cfg.final_logit_softcap),
+            in_axes=(None, 0),
+        )(head_params, h)
 
 
 _decode_norm_head = jax.jit(_decode_norm_head_impl, static_argnums=(0,))
@@ -350,10 +357,14 @@ def _spec_norm_head(cfg: LlamaConfig, norm_params, head_params, x):
     position scored — position j's distribution verifies draft j+1)."""
     from flexible_llm_sharding_tpu.ops import rms_norm
 
-    h = rms_norm(x, norm_params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-    return llama.lm_head_scores_multi(
-        head_params, h, softcap=cfg.final_logit_softcap
-    )
+    with jax.named_scope("final_norm"):
+        h = rms_norm(
+            x, norm_params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset
+        )
+    with jax.named_scope("lm_head"):
+        return llama.lm_head_scores_multi(
+            head_params, h, softcap=cfg.final_logit_softcap
+        )
 
 
 # propose_draft scans at most this many trailing tokens of each haystack
@@ -1288,9 +1299,9 @@ class DecodeGenerator:
             _stream_pass_untraced = stream_pass
 
             def stream_pass(embed_ids, decoders_fn, head_fn, skip_block=None):
-                sid = obs_trace.new_sweep_id() if obs_trace.enabled() else 0
-                with obs_trace.span(
-                    "sweep", cat="decode", sweep_id=sid, mode="decode_step",
+                with obs_trace.sweep_span(
+                    obs_trace.new_sweep_id(), cat="decode",
+                    mode="decode_step",
                 ):
                     return _stream_pass_untraced(
                         embed_ids, decoders_fn, head_fn, skip_block

@@ -27,8 +27,9 @@ from __future__ import annotations
 import os
 import threading
 import time
+from collections import deque
 from functools import partial
-from queue import Empty, Full, Queue
+from queue import Empty, Full, Queue, SimpleQueue
 from typing import Any, Callable, Sequence
 
 import jax
@@ -52,6 +53,7 @@ from flexible_llm_sharding_tpu.models import llama
 from flexible_llm_sharding_tpu.obs import events as obs_events
 from flexible_llm_sharding_tpu.obs import trace as obs_trace
 from flexible_llm_sharding_tpu.obs.registry import REGISTRY as _OBS_REGISTRY
+from flexible_llm_sharding_tpu.obs.registry import describe as _describe_gauges
 from flexible_llm_sharding_tpu.parallel.planner import ShardPlan, plan_shards_dp
 from flexible_llm_sharding_tpu.runtime.activations import ActivationStore
 from flexible_llm_sharding_tpu.runtime.pressure import (
@@ -67,6 +69,7 @@ from flexible_llm_sharding_tpu.runtime.tokenization import (
 )
 from flexible_llm_sharding_tpu.runtime import resume
 from flexible_llm_sharding_tpu.utils import checkpoint, metrics
+from flexible_llm_sharding_tpu.utils.intervals import union_seconds
 
 Params = dict[str, Any]
 
@@ -91,10 +94,13 @@ def np_dtype_for(dtype_name: str) -> np.dtype:
 @partial(jax.jit, static_argnums=(0, 1))
 def _embed_block(cfg: LlamaConfig, dtype, embed_params, prefix_ids, suffix_ids):
     """ids [B, Lp], [B, S, Ls] -> hidden [B, Lp, D], [B, S, Ls, D]."""
-    return (
-        llama.embed(embed_params, prefix_ids, dtype, cfg),
-        llama.embed(embed_params, suffix_ids, dtype, cfg),
-    )
+    # Each jitted step's body runs under a scope of its own name, so the
+    # device's ops carry the step in their op_name whatever jit they ran in.
+    with jax.named_scope("embed"):
+        return (
+            llama.embed(embed_params, prefix_ids, dtype, cfg),
+            llama.embed(embed_params, suffix_ids, dtype, cfg),
+        )
 
 
 @partial(jax.jit, static_argnums=(0, 5, 6), donate_argnums=(2, 3))
@@ -135,7 +141,8 @@ def _decoder_block(
             one_layer,
             in_axes=(None, None, 0, 0, 0, 0 if total_len is not None else None),
         )
-        p, s = step(layer_params, cfg, p, s, prefix_len, total_len)
+        with jax.named_scope("decoder_layer"):
+            p, s = step(layer_params, cfg, p, s, prefix_len, total_len)
         return (p, s), None
 
     # flags may be None: scan treats them as empty subtrees, and the body's
@@ -150,19 +157,21 @@ def _decoder_block(
 def _norm_block(cfg: LlamaConfig, norm_params, suffix_h, suffix_eos):
     """[B, S, Ls, D], eos [B, S] -> last-token normed [B, S, 1, D]
     (``/root/reference/utils.py:281-286``)."""
-    return jax.vmap(llama.select_eos_and_norm, in_axes=(None, None, 0, 0))(
-        norm_params, cfg, suffix_h, suffix_eos
-    )
+    with jax.named_scope("final_norm"):
+        return jax.vmap(llama.select_eos_and_norm, in_axes=(None, None, 0, 0))(
+            norm_params, cfg, suffix_h, suffix_eos
+        )
 
 
 @partial(jax.jit, static_argnums=(0,))
 def _head_block(cfg: LlamaConfig, head_params, suffix_h):
     """[B, S, 1, D] -> float32 scores [B, S, V] (``/root/reference/utils.py:287-290``);
     applies Gemma2's final-logit softcap when the config carries one."""
-    return jax.vmap(
-        partial(llama.lm_head_scores, softcap=cfg.final_logit_softcap),
-        in_axes=(None, 0),
-    )(head_params, suffix_h)
+    with jax.named_scope("lm_head"):
+        return jax.vmap(
+            partial(llama.lm_head_scores, softcap=cfg.final_logit_softcap),
+            in_axes=(None, 0),
+        )(head_params, suffix_h)
 
 
 def process_block(
@@ -181,6 +190,7 @@ def process_block(
     use_pallas: bool = False,
     tp_mesh=None,
     fetched=None,
+    clock=None,
 ):
     """Run one shard over one block: fetch its activations (unless this shard
     starts at the embed layer), apply the segments, scatter any head scores,
@@ -195,11 +205,16 @@ def process_block(
     recompute path re-derives a block's inputs when its spill failed
     verification, then re-enters here).
 
+    ``clock``: the pass's ``SweepClock`` (or None: the pipeline runner) —
+    the store's round trip is charged to it as ``act_fetch`` /
+    ``act_store``.
+
     Returns the block's suffix activations (device array) for optional
     synchronisation by the caller.
     """
     first, last = layer_idxs[0], layer_idxs[-1]
     prefix_ids, suffix_ids, prefix_len, suffix_eos = meta
+    ids = {} if clock is None else clock.span_ids()
     if first == 0:
         prefix_h, suffix_h = None, None  # produced by the embed segment
     elif fetched is not None:
@@ -208,13 +223,19 @@ def process_block(
             prefix_h = None
     else:
         with_prefix = first <= n_layers - 3
-        prefix_h, suffix_h = store.fetch(b, idxs, with_prefix=with_prefix)
-        # Host->HBM upload, or the chip-to-chip ICI hop in pipeline mode.
-        # Under TpPlacement activations are replicated over the tp mesh.
-        act_target = getattr(device, "act", device)
-        suffix_h = jax.device_put(suffix_h, act_target)
-        if prefix_h is not None:
-            prefix_h = jax.device_put(prefix_h, act_target)
+        with obs_trace.timed("act_fetch", cat="sweep", block=b, **ids) as sp:
+            prefix_h, suffix_h = store.fetch(b, idxs, with_prefix=with_prefix)
+            nbytes = _host_nbytes(prefix_h, suffix_h)
+            # Host->HBM upload, or the chip-to-chip ICI hop in pipeline
+            # mode. Under TpPlacement activations are replicated over the
+            # tp mesh.
+            act_target = getattr(device, "act", device)
+            suffix_h = jax.device_put(suffix_h, act_target)
+            if prefix_h is not None:
+                prefix_h = jax.device_put(prefix_h, act_target)
+        if clock is not None:
+            clock.act_fetch_s += sp.dur_s
+            clock.act_bytes += nbytes
 
     prefix_h, suffix_h, block_scores = apply_segments(
         model_cfg,
@@ -238,8 +259,21 @@ def process_block(
             row_scores.copy_to_host_async()
             scores[i] = row_scores
     if last != n_layers - 1:
-        store.store(b, idxs, prefix_h, suffix_h)
+        with obs_trace.timed("act_store", cat="sweep", block=b, **ids) as sp:
+            store.store(b, idxs, prefix_h, suffix_h)
+        if clock is not None:
+            clock.act_store_s += sp.dur_s
+            if store.location != "tpu":  # a tpu store keeps them on the chip
+                clock.act_bytes += sum(
+                    a.nbytes for a in (prefix_h, suffix_h) if a is not None
+                )
     return suffix_h
+
+
+def _host_nbytes(*arrays) -> int:
+    """Bytes of the host-resident arrays among ``arrays`` (what a fetch is
+    about to send over the link; device-resident ones cross nothing)."""
+    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
 
 
 class ScoreSink(dict):
@@ -372,21 +406,188 @@ def reset_process_streamed_bytes() -> None:
         _PROCESS_TIED_REQUANTS[0] = 0
 
 
-def stream_stats() -> dict[str, int]:
+# The last sweeps' accounts (one record per pass over the shards, written
+# when the pass ends), kept by the process because executors are built per
+# call: see SweepClock for the record's fields.
+_SWEEP_LOG: deque = deque(maxlen=256)
+_SWEEP_LOG_LOCK = threading.Lock()
+
+
+def process_sweep_log() -> list[dict]:
+    """The last sweeps' accounts, oldest first (at most 256)."""
+    with _SWEEP_LOG_LOCK:
+        return [dict(r) for r in _SWEEP_LOG]
+
+
+def stream_stats() -> dict[str, float]:
     """The process-wide stream counters as ONE registry source — shared
     by the process registry here and the serve engine's per-engine
-    registry, so the two surfaces can never drift."""
-    return {
+    registry, so the two surfaces can never drift. The last sweep's
+    account rides along as ``last_sweep_*`` gauges, so a link that slowed
+    or a pipeline that starved shows on ``/metrics`` without a trace."""
+    out = {
         "streamed_bytes": process_streamed_bytes(),
         "host_casts": process_host_casts(),
         "tied_head_requants": process_tied_head_requants(),
     }
+    with _SWEEP_LOG_LOCK:
+        last = _SWEEP_LOG[-1] if _SWEEP_LOG else None
+    if last is not None:
+        out.update({f"last_sweep_{k}": v for k, v in last.items()})
+    return out
+
+
+class SweepClock:
+    """The account of one pass over the shards, kept on the consumer's
+    thread: the same ``perf_counter`` pairs that the pass's spans record.
+
+    Opened where the pass starts (before the executor's construction for a
+    ``run_prompts`` call's first pass, in ``StreamingExecutor.__call__``
+    otherwise) and finished where its scores are on the host; as a context
+    manager it closes whatever an error left open. The consumer's five
+    phases partition the wall: ``head_s`` (everything before the first wait
+    for a shard), then per shard ``source_wait_s`` (blocked on the weight
+    source) and the ``compute`` span, split into ``device_wait_s`` (blocked
+    on the device's results: the ``block_until_ready`` at the shard's end
+    and the one inside the activation store, which resolves a block's
+    device->host copy one store later; that part is also ``act_wait_s``)
+    and ``dispatch_s`` (the rest: the host's own work, dispatching the
+    steps and copying activations), then ``tail_s`` (after the last
+    shard's dispatch: the source's close, the scores' fetch, the store's
+    clear). The producer's side comes from the source's own account
+    (``ShardWeightSource.account``). One thread opens, drives and finishes
+    a clock: its profiler annotations nest on that thread."""
+
+    def __init__(self):
+        self.sweep_id = obs_trace.new_sweep_id()
+        self.shard_idx = -1  # the shard the consumer is on
+        self.source_wait_s = self.compute_s = self.device_wait_s = 0.0
+        self.act_fetch_s = self.act_store_s = 0.0
+        self.act_bytes = 0
+        self.head_s = 0.0
+        self._sweep = obs_trace.sweep_span(self.sweep_id, mode="offline")
+        self._head = obs_trace.timed(
+            "sweep_head", cat="sweep", sweep_id=self.sweep_id
+        )
+        self._tail = None
+        self._sweep.__enter__()
+        self._head.__enter__()
+        self.t0 = self._sweep.t0
+
+    def __enter__(self) -> "SweepClock":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.abandon()  # no-op after a finished pass
+        return False
+
+    def span_ids(self) -> dict:
+        return {"sweep_id": self.sweep_id, "shard_idx": self.shard_idx}
+
+    def end_head(self) -> None:
+        """The consumer reaches its first wait for a shard."""
+        if self._head is not None:
+            self._head.__exit__(None, None, None)
+            self.head_s = self._head.dur_s
+            self._head = None
+
+    def start_tail(self) -> None:
+        """The last shard's compute is dispatched."""
+        self.end_head()  # a pass that streamed nothing still has one
+        self._tail = obs_trace.timed(
+            "sweep_tail", cat="sweep", sweep_id=self.sweep_id
+        )
+        self._tail.__enter__()
+
+    def abandon(self) -> None:
+        """Close whatever is still open and write no record (an aborted
+        pass). Idempotent."""
+        if self._tail is not None:
+            self._tail.__exit__(None, None, None)
+        self.end_head()
+        if self._sweep is not None:
+            self._sweep.__exit__(None, None, None)
+        self._tail = self._sweep = None
+
+    def finish(self, source, store=None) -> dict | None:
+        """Scores are on the host: close the tail and the sweep, and write
+        the pass's record to the process log. None when already closed.
+        ``store``: the pass's activation store, whose waits for the device
+        (inside ``compute``) count as ``device_wait_s``, not as the host's
+        ``dispatch_s``."""
+        sweep, tail = self._sweep, self._tail
+        if sweep is None:
+            return None
+        self.abandon()
+        act_wait_s = store.device_wait_s if store is not None else 0.0
+        device_wait_s = self.device_wait_s + act_wait_s
+        rec = {
+            "sweep_id": self.sweep_id,
+            "t_end": time.monotonic(),
+            "wall_s": sweep.dur_s,
+            "head_s": self.head_s,
+            "source_wait_s": self.source_wait_s,
+            "dispatch_s": self.compute_s - device_wait_s,
+            "device_wait_s": device_wait_s,
+            "tail_s": tail.dur_s if tail is not None else 0.0,
+            "act_fetch_s": self.act_fetch_s,
+            "act_store_s": self.act_store_s,
+            "act_wait_s": act_wait_s,
+            "act_bytes": self.act_bytes,
+        }
+        account = getattr(source, "account", None)
+        if account is not None:  # a shared (broadcast) source keeps none
+            rec.update(account(sweep.t0, sweep.t0 + sweep.dur_s))
+        with _SWEEP_LOG_LOCK:
+            _SWEEP_LOG.append(rec)
+        return rec
 
 
 # The process-wide stream counters are registry citizens (obs/registry.py):
 # the serve metrics endpoint and the batch CLI's --metrics_out both expose
 # streamed bytes from here, the same numbers the stats lines print.
 _OBS_REGISTRY.register("stream", stream_stats)
+
+# What each field of a sweep's record means, as the gauges' HELP lines.
+SWEEP_RECORD_HELP = {
+    "wall_s": "The last sweep, from before its executor was built to its "
+    "scores on the host; head_s + source_wait_s + dispatch_s + "
+    "device_wait_s + tail_s.",
+    "head_s": "Consumer: before its first wait for a shard (executor and "
+    "loader construction, tokenising, the source's start).",
+    "source_wait_s": "Consumer: blocked on the weight source (compute "
+    "starved for weights).",
+    "dispatch_s": "Consumer: the host's own work inside the shards' compute "
+    "(dispatching steps, copying activations); waits for the device are "
+    "NOT in here but in device_wait_s.",
+    "device_wait_s": "Consumer: blocked on the device's results, at each "
+    "shard's end and inside the activation store; near wall_s the sweep is "
+    "device- or link-bound (see upload_busy_s), not host-bound.",
+    "tail_s": "Consumer: after the last shard's dispatch (source close, "
+    "scores to the host, store clear).",
+    "act_fetch_s": "Consumer: inside the activation store's fetches "
+    "(host->device), waits included.",
+    "act_store_s": "Consumer: inside the activation store's stores "
+    "(device->host), waits included.",
+    "act_wait_s": "The part of device_wait_s spent inside the activation "
+    "store.",
+    "act_bytes": "Activation bytes that crossed the link, both ways.",
+    "host_build_s": "Producer: host shard builds (mmap, verify, stack).",
+    "upload_dispatch_s": "Producer: inside jax.device_put calls, which "
+    "return at the enqueue; not a transfer time.",
+    "producer_blocked_s": "Producer: holding a built shard while the "
+    "prefetch queue was full.",
+    "upload_busy_s": "Union of the weight uploads' intervals (dispatch to "
+    "arrival) inside the sweep: the time the link carried weights.",
+    "upload_bytes": "Host bytes handed to device_put for streamed layers "
+    "(upload_bytes / upload_busy_s is the link's rate while it carries).",
+    "uploads": "Weight uploads seen to completion.",
+    "upload_misses": "Uploads whose arrays were deleted before the "
+    "completion thread could wait on them.",
+}
+_describe_gauges(
+    "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}
+)
 
 
 def _check_precision_plan(model_path: str, manifest: dict) -> None:
@@ -516,6 +717,10 @@ class _HostShardLoader:
         self._tied_head: Params | None = None
         self.load_time = 0.0  # file->numpy wall time (cf. load_weights_time,
         # /root/reference/utils.py:223,304)
+        self.build_time = 0.0  # the shard_load spans' total: every host
+        # build's wall, cache hits included (the account's host_build_s)
+        self.trace_ids: dict = {}  # sweep_id / shard_idx of the build under
+        # way, set by the owning source's producer for the spans below
         self.bytes_loaded = 0  # post-cast host bytes built for upload; for a
         # single-chip stream this IS the host->HBM link traffic (quantized
         # leaves travel packed, so int8/int4 count their narrow bytes)
@@ -786,13 +991,16 @@ class _HostShardLoader:
         # Traced wrapper: one "shard_load" span per host build (cache hits
         # included — their near-zero duration IS the cache's evidence in
         # the timeline; the hostcache emits its own hit/miss instants).
-        with obs_trace.span(
+        with obs_trace.timed(
             "shard_load",
             cat="stream",
             first=layer_idxs[0] if layer_idxs else -1,
             n=len(layer_idxs),
-        ):
-            return self._build_host_shard(layer_idxs)
+            **self.trace_ids,
+        ) as sp:
+            out = self._build_host_shard(layer_idxs)
+        self.build_time += sp.dur_s
+        return out
 
     def _build_host_shard(
         self, layer_idxs: tuple[int, ...]
@@ -1140,6 +1348,74 @@ def _assemble_parts(
     return out
 
 
+class _UploadWatcher:
+    """Completion thread of one ``ShardWeightSource``: times each shard's
+    upload to where it COMPLETES. ``jax.device_put`` returns at the
+    enqueue, so the producer hands the placed arrays over at dispatch and
+    this thread blocks until they are ready: the ``upload`` span runs from
+    the dispatch to that return. References are dropped at once; an array
+    deleted before its wait (a consumer already done with it) is a counted
+    miss, not an error."""
+
+    def __init__(self):
+        self._q: SimpleQueue = SimpleQueue()
+        self._lock = threading.Lock()
+        # Completed uploads, newest last. One pass has a few dozen; the
+        # bound only keeps a long-lived source (a decode stream of many
+        # passes, which no account reads) from growing with its life.
+        self.intervals: deque = deque(maxlen=4096)  # guarded by: _lock
+        self.misses = 0  # guarded by: _lock
+        self._closed = False
+        self._thread = threading.Thread(
+            target=self._run, name="fls-upload-watch", daemon=True
+        )
+        self._thread.start()
+
+    def watch(self, arrays, t_dispatch: float, attrs: dict) -> None:
+        if not self._closed:  # a closed watcher must hold no array
+            self._q.put((arrays, t_dispatch, attrs))
+
+    def _run(self) -> None:
+        while True:
+            item = self._q.get()
+            if item is None:
+                return
+            arrays, t_dispatch, attrs = item
+            del item
+            missed = False
+            with obs_trace.timed("upload", cat="stream", **attrs) as sp:
+                sp.t0 = t_dispatch  # the link may carry from the dispatch on
+                try:
+                    jax.block_until_ready(arrays)
+                except RuntimeError:  # deleted under us: nothing to time
+                    missed = True
+                    sp.drop()
+                del arrays
+            with self._lock:
+                if missed:
+                    self.misses += 1
+                else:
+                    self.intervals.append((sp.t0, sp.t0 + sp.dur_s))
+
+    def close(self, timeout_s: float = 10.0) -> None:
+        """Finish the queued waits and retire the thread (bounded: a wait
+        on a wedged device is abandoned with the daemon thread)."""
+        self._closed = True
+        if self._thread.is_alive():
+            self._q.put(None)
+            self._thread.join(timeout=timeout_s)
+        while True:  # what a dead or abandoned thread left: hold no array
+            try:
+                self._q.get_nowait()
+            except Empty:  # the thread may take the last item under us
+                break
+
+    def snapshot(self) -> tuple[list[tuple[float, float]], int]:
+        """The completed uploads' intervals and the count of misses."""
+        with self._lock:
+            return list(self.intervals), self.misses
+
+
 class ShardWeightSource:
     """Loads shard weights disk -> host -> HBM, optionally prefetching ahead.
 
@@ -1179,6 +1455,8 @@ class ShardWeightSource:
         host_cache=None,
         readahead_threads: int = 2,
         residency=None,
+        sweep_id: int = 0,
+        first_shard_idx: int = 0,
     ):
         # residency: a runtime.residency.DeviceResidencyTier (or None) —
         # pinned layers are subtracted from every shard build (their bytes
@@ -1186,6 +1464,11 @@ class ShardWeightSource:
         # placement. The pin set is FROZEN here so this source's segment
         # structure can never change mid-life (a serving wave's prefill
         # and decode must agree on it).
+        # sweep_id / first_shard_idx: the pass this source feeds and the
+        # global index of its first shard, for the producer's spans (0 for
+        # a cycling source, whose consumer numbers its own sweeps).
+        self.sweep_id = sweep_id
+        self._first_shard_idx = first_shard_idx
         self.shards = list(shards)
         # Either one device for every shard, or (pipeline mode) one target
         # device per shard — shard t's weights upload straight to its stage's
@@ -1219,6 +1502,16 @@ class ShardWeightSource:
                 residency.ensure_pinned(self._loader, dev, idxs)
             self._pinned_idxs = residency.frozen_pinned(self.shards)
         self.produce_time = 0.0  # set BEFORE the producer thread starts
+        # The producer's side of the sweep's account (see account()).
+        self.upload_dispatch_s = 0.0
+        self.producer_blocked_s = 0.0
+        self.upload_bytes = 0
+        # The completion thread exists where an account reads it: a
+        # one-pass source, closed when its sweep's record is written. A
+        # cycling source (the serve engine's, which keeps no account and
+        # lives as long as the engine) gets none: no thread, no
+        # per-upload state, no second join on the watchdog's recovery path.
+        self._watcher = None if cycle else _UploadWatcher()
         self._q: Queue = Queue(maxsize=max(1, prefetch_depth))
         self._close_lock = threading.Lock()  # close() may race abort()/close()
         self._thread: threading.Thread | None = None
@@ -1254,8 +1547,8 @@ class ShardWeightSource:
         own the moment the syscall returns (or dies with the process)."""
         self._stop.set()
         with self._close_lock:
+            deadline = time.monotonic() + join_timeout_s
             if self._thread is not None:
-                deadline = time.monotonic() + join_timeout_s
                 while self._thread.is_alive():
                     if time.monotonic() >= deadline:
                         break  # abandoned, self-terminates via _stop
@@ -1274,6 +1567,32 @@ class ShardWeightSource:
             # (producer thread target holds self), so GC alone would strand
             # thread pools.
             self._loader.close()
+            # The producer is gone, so nothing more is handed over: the
+            # completion thread finishes its queued waits and exits, and
+            # account() reads final numbers. One deadline bounds both joins.
+            if self._watcher is not None:
+                self._watcher.close(max(0.0, deadline - time.monotonic()))
+
+    def account(self, t_lo: float, t_hi: float) -> dict:
+        """The producer's side of a sweep's account over ``[t_lo, t_hi]``
+        (``perf_counter``): host build, upload dispatch and blocked-on-
+        queue seconds, and the link as the completion thread saw it —
+        ``upload_busy_s`` is the UNION of the ``upload`` intervals inside
+        the window, ``upload_bytes`` the host bytes handed to
+        ``device_put`` for streamed parts (the streamed-bytes counter's
+        delta; pinned layers upload nothing). Read after ``close()``."""
+        intervals, misses = (
+            self._watcher.snapshot() if self._watcher is not None else ([], 0)
+        )
+        return {
+            "host_build_s": self._loader.build_time,
+            "upload_dispatch_s": self.upload_dispatch_s,
+            "producer_blocked_s": self.producer_blocked_s,
+            "upload_busy_s": union_seconds(intervals, t_lo, t_hi),
+            "upload_bytes": self.upload_bytes,
+            "uploads": len(intervals),
+            "upload_misses": misses,
+        }
 
     @property
     def load_time(self) -> float:
@@ -1291,7 +1610,7 @@ class ShardWeightSource:
         return _stream_only(idxs, self._pinned_idxs)
 
     def _build_shard(
-        self, layer_idxs: tuple[int, ...], device
+        self, layer_idxs: tuple[int, ...], device, shard_i: int = 0
     ) -> list[tuple[str, Any]]:
         # produce_time covers the producer's WHOLE per-shard wall — host
         # file->numpy load (load_time counts just that part) plus the
@@ -1299,12 +1618,15 @@ class ShardWeightSource:
         # overlap_efficiency (source_wait_s over produce_wall_s compares
         # like with like; load_time alone under-counts what overlap must
         # hide on a slow host->HBM link).
-        t0 = time.perf_counter()
-        first = layer_idxs[0] if layer_idxs else -1
-        with obs_trace.span(
-            "shard_produce", cat="stream", first=first, n=len(layer_idxs)
-        ):
+        ids = self._span_ids(shard_i)
+        attrs = dict(
+            ids, first=layer_idxs[0] if layer_idxs else -1, n=len(layer_idxs)
+        )
+        self._loader.trace_ids = ids
+        with obs_trace.timed("shard_produce", cat="stream", **attrs) as produce:
+            bytes_before = self._loader.bytes_loaded
             parts = _split_parts(self._loader, layer_idxs, self._pinned_idxs)
+            nbytes = self._loader.bytes_loaded - bytes_before
             if self._residency is not None:
                 # Count the sweep's saved link bytes ONCE per build (the put
                 # below may retry; retries must not double-count).
@@ -1328,9 +1650,12 @@ class ShardWeightSource:
                     self._loader,
                 )
 
-            with obs_trace.span(
-                "device_put", cat="stream", first=first, n=len(layer_idxs)
-            ):
+            # upload_dispatch is the CALL (device_put returns at the
+            # enqueue), not the transfer: the completion thread's upload
+            # span, opened at this dispatch, ends where the bytes arrived.
+            with obs_trace.timed(
+                "upload_dispatch", cat="stream", **attrs
+            ) as dispatch:
                 out = retry_call(
                     put,
                     policy=self._retry,
@@ -1339,24 +1664,53 @@ class ShardWeightSource:
                     wrap=ShardLoadError,
                     abort=self._stop.is_set,
                 )
-        self.produce_time += time.perf_counter() - t0
+            if nbytes and self._watcher is not None:
+                self._watcher.watch(
+                    [seg for _, seg in out], dispatch.t0,
+                    dict(attrs, bytes=nbytes),
+                )
+        self.upload_dispatch_s += dispatch.dur_s
+        self.upload_bytes += nbytes
+        self.produce_time += produce.dur_s
         return out
 
+    def _span_ids(self, shard_i: int) -> dict:
+        return {
+            "sweep_id": self.sweep_id,
+            "shard_idx": self._first_shard_idx + shard_i,
+        }
+
     # -- prefetch thread ---------------------------------------------------
-    def _put(self, item) -> bool:
-        while True:
-            # Stop is re-checked BEFORE every put attempt, including the
-            # first: close()/abort() may fire between building the item and
-            # queueing it, and a put landing in the just-drained queue would
-            # strand a shard's HBM buffers (or an error nobody consumes)
-            # while close() joins this thread.
-            if self._stop.is_set():
-                return False
-            try:
-                self._q.put(item, timeout=0.2)
-                return True
-            except Full:
-                continue
+    def _put(self, item, shard_i: int = 0) -> bool:
+        # Stop is re-checked BEFORE every put attempt, including the
+        # first: close()/abort() may fire between building the item and
+        # queueing it, and a put landing in the just-drained queue would
+        # strand a shard's HBM buffers (or an error nobody consumes)
+        # while close() joins this thread.
+        if self._stop.is_set():
+            return False
+        try:
+            self._q.put_nowait(item)
+            return True
+        except Full:
+            pass
+        # The queue is full: the prefetch depth holds the producer (and
+        # with it the next upload) back until the consumer takes a shard.
+        with obs_trace.timed(
+            "producer_blocked", cat="stream", **self._span_ids(shard_i)
+        ) as blocked:
+            queued = None
+            while queued is None:
+                if self._stop.is_set():
+                    queued = False
+                else:
+                    try:
+                        self._q.put(item, timeout=0.2)
+                        queued = True
+                    except Full:
+                        pass
+        self.producer_blocked_s += blocked.dur_s
+        return queued
 
     def _producer(self):
         while True:
@@ -1374,7 +1728,7 @@ class ShardWeightSource:
                         self._loader.warm(self._stream_only(self.shards[nxt]))
                     elif self.cycle:
                         self._loader.warm(self._stream_only(self.shards[0]))
-                    item = self._build_shard(idxs, dev)
+                    item = self._build_shard(idxs, dev, i)
                 except Exception as e:  # flscheck: disable=EXC-TAXONOMY: EVERY producer error must travel to the consumer as a _ShardFault envelope — narrowing would let an unexpected type kill the thread and hang the consumer's get
                     # Surface to the consumer at this shard's position, but
                     # keep the thread ALIVE: retries are already exhausted
@@ -1383,10 +1737,10 @@ class ShardWeightSource:
                     # fails only the in-flight wave and keeps consuming
                     # (offline consumers raise and close(), which stops this
                     # loop via _stop on the next iteration).
-                    if not self._put(_ShardFault(e)):
+                    if not self._put(_ShardFault(e), i):
                         return
                     continue
-                if not self._put(item):
+                if not self._put(item, i):
                     return
             if not self.cycle:
                 return
@@ -1414,7 +1768,7 @@ class ShardWeightSource:
                         return
                     if i + 1 < len(self.shards):
                         self._loader.warm(self._stream_only(self.shards[i + 1]))
-                    yield idxs, self._build_shard(idxs, dev)
+                    yield idxs, self._build_shard(idxs, dev, i)
                 if not self.cycle:
                     return
         else:
@@ -1807,11 +2161,20 @@ class StreamingExecutor:
             manifest_hash=self._manifest_digest,
         )
 
-    def __call__(self, prompts, batch: int = 0) -> list[np.ndarray]:
+    def __call__(
+        self, prompts, batch: int = 0, clock: SweepClock | None = None
+    ) -> list[np.ndarray]:
         # batch: the num_batch loop index (scopes disk activation files and
         # the resume marker per batch — see ActivationStore).
-        t_start = time.perf_counter()
-        toks = self._tokenize(prompts)
+        # clock: the pass's account, already running when orchestration
+        # opened it before building this executor (a call's first pass);
+        # opened here otherwise. The pass finishes it; an error closes it.
+        with clock or SweepClock() as clock:
+            return self._run_pass(prompts, batch, clock)
+
+    def _run_pass(self, prompts, batch: int, clock: SweepClock) -> list[np.ndarray]:
+        with obs_trace.span("tokenize", cat="sweep", sweep_id=clock.sweep_id):
+            toks = self._tokenize(prompts)
         blocks = make_blocks(toks, self.cfg.block_size)
         store = ActivationStore(
             self.cfg.storage_location,
@@ -1883,6 +2246,8 @@ class StreamingExecutor:
                 host_cache=self._host_cache,
                 readahead_threads=self.cfg.readahead_threads,
                 residency=self._residency,
+                sweep_id=clock.sweep_id,
+                first_shard_idx=start_shard,
             )
             skip = 0
             # Baseline taken BEFORE the source's prefetch producer starts
@@ -1916,9 +2281,8 @@ class StreamingExecutor:
                     store.flush()
                     self._mark_progress(store, sig, done)
 
-        compute_time = source_wait = 0.0
         try:
-            compute_time, source_wait = self._stream(
+            self._stream(
                 source,
                 store,
                 toks,
@@ -1929,6 +2293,7 @@ class StreamingExecutor:
                 n_shards=len(self.plan.shards) - start_shard,
                 skip=skip,
                 start_shard=start_shard,
+                clock=clock,
             )
         except BaseException:
             # Error path: retire the async disk writer and drop stored
@@ -1948,11 +2313,13 @@ class StreamingExecutor:
 
         self.stats = {
             "load_weights_time_s": source.load_time,
-            "compute_wall_s": compute_time,
-            # Driver time blocked waiting on the weight source: the produce
-            # time prefetch did NOT hide (serialized schedule -> ~all of
-            # produce_wall_s; perfect overlap -> the first shard only).
-            "source_wait_s": source_wait,
+            # From the pass's clock: the compute spans' total, and the
+            # driver time blocked waiting on the weight source — the
+            # produce time prefetch did NOT hide (serialized schedule ->
+            # ~all of produce_wall_s; perfect overlap -> the first shard
+            # only).
+            "compute_wall_s": clock.compute_s,
+            "source_wait_s": clock.source_wait_s,
             # The producer's whole per-shard wall (host load + device
             # placement dispatch) — overlap_efficiency's denominator.
             # Absent on shared (broadcast) sources, whose producer serves
@@ -1962,7 +2329,7 @@ class StreamingExecutor:
                 if getattr(source, "produce_time", None) is not None
                 else {}
             ),
-            "total_wall_s": time.perf_counter() - t_start,
+            "total_wall_s": time.perf_counter() - clock.t0,
             "num_layers_streamed": float(self.plan.num_local_layers),
             "tokens_processed": float(sum(t.tokens_processed for t in toks)),
         }
@@ -2063,6 +2430,7 @@ class StreamingExecutor:
                 **{k: v for k, v in self.stats.items() if k != "total_wall_s"},
             )
         store.clear()
+        clock.finish(source, store)
         return [scores[i] for i in range(len(prompts))]
 
     def _stream(
@@ -2077,12 +2445,10 @@ class StreamingExecutor:
         n_shards: int | None = None,
         skip: int = 0,
         start_shard: int = 0,
-    ) -> tuple[float, float]:
+        *,
+        clock: SweepClock,
+    ) -> None:
         n_layers = len(self.layer_names)
-        compute_time = 0.0
-        source_wait = 0.0  # driver time blocked on the weight source — the
-        # exact NOT-hidden load time (prefetch hides the rest); the
-        # numerator of bench.py's overlap_efficiency
         total = (n_shards or len(self.plan.shards)) * max(len(blocks), 1)
         bar = metrics.progress_bar(total, desc="stream", unit="blk")
         it = enumerate(source)
@@ -2099,113 +2465,121 @@ class StreamingExecutor:
         # Correlation id for this full pass over the shards — the offline
         # equivalent of one serving sweep; every span below carries it so
         # the trace analyzer can group a pass's phases back together.
-        sweep_id = obs_trace.new_sweep_id() if obs_trace.enabled() else 0
+        sweep_id = clock.sweep_id
         try:
-            with obs_trace.span(
-                "sweep", cat="sweep", sweep_id=sweep_id, mode="offline",
-                blocks=len(blocks),
-            ):
-                while True:
-                    t_wait = time.perf_counter()
+            while True:
+                # The head ends at the consumer's first wait for a shard:
+                # that wait, which nothing can overlap, is the sweep's
+                # first source_wait.
+                clock.end_head()
+                with obs_trace.timed(
+                    "source_wait", cat="sweep", sweep_id=sweep_id
+                ) as wait:
                     try:
                         shard_i, (layer_idxs, segments) = next(it)
                     except StopIteration:
+                        wait.drop()
                         break
                     if shard_i < skip:
                         # Resume over a shared source: this shard already
                         # ran in the crashed attempt; drop its broadcast
                         # weights unused. Its wait is NOT counted against
                         # overlap efficiency — skipped shards run no
-                        # compute that could hide it.
+                        # compute that could hide it — so the trace's
+                        # source_wait total matches the stats/bench
+                        # overlap-efficiency definition exactly.
+                        wait.drop()
                         del segments
                         continue
-                    waited = time.perf_counter() - t_wait
-                    source_wait += waited
-                    # Recorded AFTER the skip check with the measured
-                    # timing, so the trace's source_wait total matches the
-                    # stats/bench overlap-efficiency definition exactly —
-                    # skipped shards' waits appear in neither.
-                    obs_trace.TRACER.complete(
-                        "source_wait", "sweep", t_wait, waited,
-                        sweep_id=sweep_id,
+                # Driver time blocked on the weight source — the exact
+                # NOT-hidden load time (prefetch hides the rest); the
+                # numerator of bench.py's overlap_efficiency.
+                clock.source_wait_s += wait.dur_s
+                # Global shard index: shared sources yield every shard
+                # from 0 (skip consumed the resumed prefix); an own
+                # source yields only the resumed tail.
+                shard_idx = shard_i + (0 if skip else start_shard)
+                clock.shard_idx = shard_idx
+                store.set_shard(shard_idx)
+                store.trace_ids = clock.span_ids()
+                with obs_trace.timed(
+                    "compute", cat="sweep", sweep_id=sweep_id,
+                    shard_idx=shard_idx,
+                ) as compute:
+                    self._stream_shard(
+                        store, toks, blocks, block_meta, scores,
+                        layer_idxs, segments, n_layers, prev_shard,
+                        bar, clock,
                     )
-                    # Global shard index: shared sources yield every shard
-                    # from 0 (skip consumed the resumed prefix); an own
-                    # source yields only the resumed tail.
-                    shard_idx = shard_i + (0 if skip else start_shard)
-                    store.set_shard(shard_idx)
-                    t0 = time.perf_counter()
-                    with obs_trace.span(
-                        "compute", cat="sweep", sweep_id=sweep_id,
-                        shard_idx=shard_idx,
-                    ):
-                        self._stream_shard(
-                            store, toks, blocks, block_meta, scores,
-                            layer_idxs, segments, n_layers, prev_shard,
-                            bar, sweep_id,
-                        )
-                    compute_time += time.perf_counter() - t0
                     if on_shard_done is not None:
                         on_shard_done(shard_i)
-                    prev_shard = (
-                        (layer_idxs, segments) if heal_spills else None
-                    )
+                clock.compute_s += compute.dur_s
+                prev_shard = (
+                    (layer_idxs, segments) if heal_spills else None
+                )
+            clock.start_tail()
         finally:
             bar.close()
-        return compute_time, source_wait
 
     def _stream_shard(
         self, store, toks, blocks, block_meta, scores, layer_idxs, segments,
-        n_layers, prev_shard, bar, sweep_id,
+        n_layers, prev_shard, bar, clock,
     ) -> None:
         """One shard's compute over every block — the body the traced
-        ``compute`` span wraps in ``_stream`` (same invariants as before
-        the split; the spill-corruption recompute path lives here)."""
-        for b, idxs in enumerate(blocks):
-            fetched = None
-            while True:
-                try:
-                    suffix_h = process_block(
-                        self.model_cfg,
-                        self.dtype,
-                        segments,
-                        layer_idxs,
-                        n_layers,
-                        store,
-                        b,
-                        idxs,
-                        block_meta[b],
-                        self.device,
-                        toks,
-                        scores,
-                        use_pallas=self._use_pallas,
-                        tp_mesh=self._tp_mesh,
-                        fetched=fetched,
-                    )
-                    break
-                except SpillCorruptError:
-                    # The block's input spill is corrupt even after
-                    # re-reads. Recompute it from the last good shard
-                    # boundary — bounded to ONE recompute per block per
-                    # shard (a recompute that fails again means the
-                    # previous generation is corrupt too: raise).
-                    if prev_shard is None or fetched is not None:
-                        raise
-                    self._integrity.count("recomputes")
-                    obs_trace.instant(
-                        "spill_recompute", cat="integrity", block=b,
-                        sweep_id=sweep_id,
-                    )
-                    obs_events.emit(
-                        "spill_recompute", block=b, sweep_id=sweep_id
-                    )
-                    fetched = self._recompute_block(
-                        prev_shard, store, b, idxs, block_meta[b],
-                        n_layers,
-                    )
-            bar.update(1)
-        if not blocks:
-            bar.update(1)
+        ``compute`` span wraps in ``_stream``: its ``dispatch`` child is
+        the consumer's pass over the blocks (inside it, the activation
+        store's own ``device_wait`` where it resolves a block's copy), its
+        ``device_wait`` child the wait for the device at the shard's end
+        (the spill-corruption recompute path lives here)."""
+        sweep_id = clock.sweep_id
+        ids = clock.span_ids()
+        with obs_trace.span("dispatch", cat="sweep", **ids):
+            for b, idxs in enumerate(blocks):
+                fetched = None
+                while True:
+                    try:
+                        suffix_h = process_block(
+                            self.model_cfg,
+                            self.dtype,
+                            segments,
+                            layer_idxs,
+                            n_layers,
+                            store,
+                            b,
+                            idxs,
+                            block_meta[b],
+                            self.device,
+                            toks,
+                            scores,
+                            use_pallas=self._use_pallas,
+                            tp_mesh=self._tp_mesh,
+                            fetched=fetched,
+                            clock=clock,
+                        )
+                        break
+                    except SpillCorruptError:
+                        # The block's input spill is corrupt even after
+                        # re-reads. Recompute it from the last good shard
+                        # boundary — bounded to ONE recompute per block
+                        # per shard (a recompute that fails again means
+                        # the previous generation is corrupt too: raise).
+                        if prev_shard is None or fetched is not None:
+                            raise
+                        self._integrity.count("recomputes")
+                        obs_trace.instant(
+                            "spill_recompute", cat="integrity", block=b,
+                            sweep_id=sweep_id,
+                        )
+                        obs_events.emit(
+                            "spill_recompute", block=b, sweep_id=sweep_id
+                        )
+                        fetched = self._recompute_block(
+                            prev_shard, store, b, idxs, block_meta[b],
+                            n_layers,
+                        )
+                bar.update(1)
+            if not blocks:
+                bar.update(1)
         # Every store path is async now (cpu: copy_to_host_async +
         # depth-1 finalize; disk: writer thread), so block once per
         # shard to keep compute_wall_s a device-time measure — the
@@ -2213,7 +2587,11 @@ class StreamingExecutor:
         # disk writer keeps writing, concurrently with this wait.
         # (blocks can be empty: num_batch > prompt count -> ex([]).)
         if blocks and layer_idxs[-1] != n_layers - 1:
-            jax.block_until_ready(suffix_h)
+            with obs_trace.timed(
+                "device_wait", cat="sweep", at="shard_end", **ids
+            ) as wait:
+                jax.block_until_ready(suffix_h)
+            clock.device_wait_s += wait.dur_s
 
     def _recompute_block(
         self, prev_shard, store, b, idxs, meta, n_layers: int
@@ -2259,7 +2637,9 @@ __all__ = [
     "ShardWeightSource",
     "BroadcastShardSource",
     "process_host_casts",
+    "process_sweep_log",
     "process_tied_head_requants",
+    "SweepClock",
     "ShardLoadError",
     "ShardCorruptError",
     "SpillCorruptError",

@@ -18,6 +18,7 @@ import numpy as np
 
 from flexible_llm_sharding_tpu.config import FrameworkConfig, LlamaConfig
 from flexible_llm_sharding_tpu.faults.inject import FaultInjector
+from flexible_llm_sharding_tpu.obs import trace as obs_trace
 from flexible_llm_sharding_tpu.parallel.planner import (
     batch_ranges,
     plan_shards_dp,
@@ -28,6 +29,7 @@ from flexible_llm_sharding_tpu.runtime.executor import (
     BroadcastShardSource,
     SourceClosed,
     StreamingExecutor,
+    SweepClock,
     np_dtype_for,
 )
 from flexible_llm_sharding_tpu.runtime.generation import Prompt
@@ -84,15 +86,33 @@ def _gather_dp(pool: ThreadPoolExecutor, futures, source) -> list:
 _probe_chip = residency.probe_chip
 
 
-def _run_batched(ex: StreamingExecutor, prompts: list[Prompt], num_batch: int):
+def _run_batched(
+    ex: StreamingExecutor, prompts: list[Prompt], num_batch: int,
+    clock: SweepClock | None = None,
+):
     """The reference's num_batch loop (``/root/reference/main.py:19-23``):
     each batch is a full streaming pass (bounds activation-store footprint).
     The batch index scopes disk activation files/markers so crash resume of
-    one batch can't be clobbered by another's re-run."""
+    one batch can't be clobbered by another's re-run. ``clock``: an account
+    already running for the first pass; later passes open their own."""
     out: list[np.ndarray] = []
     for i, (lo, hi) in enumerate(batch_ranges(len(prompts), num_batch)):
-        out += ex(prompts[lo:hi], batch=i)
+        out += ex(prompts[lo:hi], batch=i, clock=clock if i == 0 else None)
     return out
+
+
+def _run_single(
+    cfg: FrameworkConfig, device, prompts: list[Prompt], tokenizer
+) -> list[np.ndarray]:
+    """One executor on one placement target. The first sweep's account
+    opens here, before the executor exists, so that its head holds the
+    executor's construction: every call builds its own."""
+    with SweepClock() as clock:
+        with obs_trace.span(
+            "executor_init", cat="sweep", sweep_id=clock.sweep_id
+        ):
+            ex = StreamingExecutor(cfg, device=device, tokenizer=tokenizer)
+        return _run_batched(ex, prompts, cfg.num_batch, clock)
 
 
 def _long_context_split(cfg: FrameworkConfig, prompts, tokenizer):
@@ -221,10 +241,9 @@ def run_prompts(
         # chips' MXUs, XLA emits the ICI all-reduces. The reference has no
         # equivalent — its layers always live whole on one device
         # (/root/reference/utils.py:128-130).
-        ex = StreamingExecutor(
-            cfg, device=_tp_placement(cfg, devices), tokenizer=tokenizer
+        return _run_single(
+            cfg, _tp_placement(cfg, devices), prompts, tokenizer
         )
-        return _run_batched(ex, prompts, cfg.num_batch)
 
     # dp x tp must NOT degrade to the single-device/pipeline branches on a
     # short device list — _dp_targets fails loudly instead (an unsharded
@@ -235,8 +254,7 @@ def run_prompts(
             from flexible_llm_sharding_tpu.runtime.pipeline import run_pipeline
 
             return run_pipeline(cfg, prompts, devices, tokenizer=tokenizer)
-        ex = StreamingExecutor(cfg, device=devices[0], tokenizer=tokenizer)
-        return _run_batched(ex, prompts, cfg.num_batch)
+        return _run_single(cfg, devices[0], prompts, tokenizer)
 
     # DP: prompt ranges per execution target (np.array_split semantics,
     # /root/reference/main.py:70), one streaming executor per target. All

@@ -1480,9 +1480,9 @@ class ServeEngine:
         """One full weight pass: prefill segments for waves at step 0,
         one decode step for everyone else."""
         wd = self._watchdog
-        sweep_id = obs_trace.new_sweep_id() if obs_trace.enabled() else 0
-        with obs_trace.span(
-            "sweep", cat="serve", sweep_id=sweep_id, mode="serve",
+        sweep_id = obs_trace.new_sweep_id()
+        with obs_trace.sweep_span(
+            sweep_id, cat="serve", mode="serve",
             waves=len(self.batcher.waves),
         ):
             for shard_pos, (layer_idxs, segments) in self._sweep_shards():

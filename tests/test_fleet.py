@@ -394,7 +394,11 @@ def test_fleet_chaos_replica_stall_liveness_failover(model_dir, offline_oracle):
                 error_rate=1.0, sites=("replica_stall",), max_faults=1
             ),
         ),
-        _serve_cfg(replicas=2, watchdog_abort_s=2.0),
+        # 5 s, not 2: on a loaded machine the SURVIVOR compiles the
+        # re-dispatched wave's shapes for over 2 s without a heartbeat,
+        # is declared dead too, and the second reclaim surfaces
+        # WaveAborted (seen under xdist, at this commit and its parent).
+        _serve_cfg(replicas=2, watchdog_abort_s=5.0),
         tokenizer=FakeTokenizer(),
     )
     try:

@@ -1155,6 +1155,7 @@ def _bare_source():
     src._close_lock = threading.Lock()
     src._thread = None
     src._loader = types.SimpleNamespace(close=lambda: None)
+    src._watcher = None
     return src
 
 

@@ -425,9 +425,14 @@ def test_analyzer_derives_utilization_overlap_and_quantiles():
     evs = [
         {"name": "shard_produce", "cat": "stream", "ts_s": 0.0, "dur_s": 0.2},
         {"name": "shard_load", "cat": "stream", "ts_s": 0.0, "dur_s": 0.15},
-        {"name": "device_put", "cat": "stream", "ts_s": 0.15, "dur_s": 0.05},
+        {"name": "upload_dispatch", "cat": "stream", "ts_s": 0.15,
+         "dur_s": 0.01},
+        {"name": "upload", "cat": "stream", "ts_s": 0.15, "dur_s": 0.1},
         {"name": "shard_produce", "cat": "stream", "ts_s": 0.5, "dur_s": 0.2},
         {"name": "shard_load", "cat": "stream", "ts_s": 0.5, "dur_s": 0.2},
+        # Two uploads in flight at once count once (the union).
+        {"name": "upload", "cat": "stream", "ts_s": 0.2, "dur_s": 0.1},
+        {"name": "upload", "cat": "stream", "ts_s": 0.7, "dur_s": 0.25},
         {"name": "source_wait", "cat": "sweep", "ts_s": 0.0, "dur_s": 0.1,
          "sweep_id": 1},
         {"name": "compute", "cat": "sweep", "ts_s": 0.2, "dur_s": 0.3,
@@ -440,7 +445,8 @@ def test_analyzer_derives_utilization_overlap_and_quantiles():
     ]
     rep = obs_report.analyze(evs)
     assert rep["wall_s"] == pytest.approx(1.0)
-    # Stream busy: union of shard_load/device_put = [0,0.2] + [0.5,0.7].
+    # Link busy: the union of the upload intervals = [0.15,0.3] +
+    # [0.7,0.95]; host builds and the dispatch calls carry nothing.
     assert rep["stream_busy_s"] == pytest.approx(0.4)
     assert rep["link_utilization"] == pytest.approx(0.4)
     # overlap = 1 - wait/produce = 1 - 0.1/0.4.
@@ -450,7 +456,11 @@ def test_analyzer_derives_utilization_overlap_and_quantiles():
     assert rep["sweep_wall_s"] == pytest.approx(1.0)
     q = rep["ttft_s"]
     assert q["count"] == 4 and q["p50"] == 0.3 and q["max"] == 0.4
-    assert obs_report.format_report(rep)  # human rendering never raises
+    assert "link utilization: 40.0%" in obs_report.format_report(rep)
+    # A trace without upload spans (a serve engine's) says so, not "0%".
+    bare = obs_report.analyze([e for e in evs if e["name"] != "upload"])
+    assert bare["link_utilization"] == 0.0
+    assert "link utilization: not timed" in obs_report.format_report(bare)
 
 
 def test_analyzer_roundtrips_both_export_formats(tmp_path):
@@ -489,8 +499,11 @@ def test_executor_run_produces_sweep_timeline(model, process_tracer):
     ex(list(PROMPTS))
     spans = process_tracer.snapshot()
     names = {s["name"] for s in spans}
-    assert {"sweep", "compute", "source_wait", "shard_load",
-            "shard_produce", "device_put"} <= names
+    assert {"sweep", "sweep_head", "sweep_tail", "compute", "dispatch",
+            "device_wait", "source_wait", "shard_load", "shard_produce",
+            "upload_dispatch", "upload", "act_fetch", "act_store",
+            "tokenize"} <= names
+    assert "device_put" not in names  # renamed for what it measures
     # Correlation: every compute span carries the pass's sweep_id.
     sweep_ids = {s["sweep_id"] for s in spans if s["name"] == "compute"}
     assert len(sweep_ids) == 1
@@ -548,3 +561,180 @@ def test_serve_run_traces_waves_and_exposes_metrics(model, process_tracer):
     assert rep["ttft_s"]["count"] == len(PROMPTS)
     assert rep["token_latency_s"]["count"] >= 1
     assert rep["event_counts"]["wave_admit"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# The program's spans ride whatever profiler session is running
+# ---------------------------------------------------------------------------
+
+def ONE_CHIP():
+    """The suite runs on 8 virtual devices, where run_prompts would take
+    its pipeline path: the sweep's spans are the single-executor path's."""
+    return jax.devices()[:1]
+
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` inside a jax.profiler session (host spans on, Python
+    tracer off, as the benchmark's traced window starts it) and return the
+    ``fls.`` events of the trace: {name: [stats dict, ...]}."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    out: dict[str, list[dict]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(obs_trace.ANNOTATION_PREFIX):
+                    out.setdefault(ev.name, []).append(
+                        dict(ev.stats, _start=ev.start_ns, _dur=ev.duration_ns)
+                    )
+    return out
+
+
+def test_span_with_ring_off_lands_in_profiler_trace(tmp_path):
+    t = obs_trace.TRACER
+    assert not t.enabled
+    t.clear()
+    assert not obs_trace.profiler_active()
+    # No ring, no session: the shared no-op, as before.
+    assert obs_trace.span("a") is obs_trace.span("b", cat="c", k=1)
+
+    def body():
+        assert obs_trace.profiler_active()
+        with obs_trace.sweep_span(41, mode="offline"):
+            with obs_trace.span("probe_span", cat="t", sweep_id=41, shard_idx=3):
+                time.sleep(0.005)
+
+    evs = _profiled_host_events(tmp_path, body)
+    (probe,) = evs["fls.probe_span"]
+    assert probe["sweep_id"] == 41 and probe["shard_idx"] == 3
+    assert probe["_dur"] >= 4e6  # ns, on the profiler's clock
+    # The sweep is a step annotation: the profiler groups by step_num.
+    (sweep,) = evs["fls.sweep"]
+    assert sweep["step_num"] == 41 and sweep["sweep_id"] == 41
+    assert sweep["_start"] <= probe["_start"]
+    assert sweep["_start"] + sweep["_dur"] >= probe["_start"] + probe["_dur"]
+    # ...and the ring stayed off and empty: --trace means what it meant.
+    assert len(t) == 0 and not t.enabled
+    assert not obs_trace.profiler_active()
+
+
+def test_timed_span_feeds_ring_and_caller_the_same_clock_pair():
+    t = obs_trace.TRACER
+    assert not t.enabled
+    with obs_trace.timed("work", cat="test", sweep_id=7) as sp:
+        time.sleep(0.005)
+    # Always timed, ring off: the account's reading, nothing recorded.
+    assert sp.dur_s >= 0.004 and len(t) == 0
+    t.clear()
+    t.enable()
+    try:
+        with obs_trace.timed("work", cat="test", sweep_id=7) as sp:
+            time.sleep(0.002)
+        with obs_trace.timed("skipped", cat="test") as dropped:
+            dropped.drop()
+        (rec,) = t.snapshot()
+    finally:
+        t.disable()
+        t.clear()
+    assert rec["name"] == "work" and rec["dur_s"] == round(sp.dur_s, 6)
+    assert dropped.dur_s >= 0.0  # timing stays readable; the ring skips it
+
+
+def test_executor_spans_land_in_profiler_trace_without_the_ring(model, tmp_path):
+    from flexible_llm_sharding_tpu.runtime import orchestration
+
+    assert not obs_trace.TRACER.enabled
+    evs = _profiled_host_events(
+        tmp_path / "prof",
+        lambda: orchestration.run_prompts(
+            _fw(model, prefetch_depth=2), list(PROMPTS), tokenizer=FakeTokenizer(),
+            devices=ONE_CHIP(),
+        ),
+    )
+    for name in ("sweep", "sweep_head", "executor_init", "tokenize",
+                 "source_wait", "compute", "dispatch", "device_wait",
+                 "act_fetch", "act_store", "sweep_tail", "shard_produce",
+                 "shard_load", "upload_dispatch", "upload"):
+        assert f"fls.{name}" in evs, name
+    (sweep,) = evs["fls.sweep"]
+    lo, hi = sweep["_start"], sweep["_start"] + sweep["_dur"]
+    for name in ("sweep_head", "source_wait", "compute", "sweep_tail"):
+        for ev in evs[f"fls.{name}"]:  # the consumer's phases nest in the sweep
+            assert lo <= ev["_start"] and ev["_start"] + ev["_dur"] <= hi, name
+            assert ev["sweep_id"] == sweep["step_num"]
+    assert len(obs_trace.TRACER) == 0
+
+
+def test_producer_and_consumer_spans_share_the_sweep_id(model, process_tracer):
+    from flexible_llm_sharding_tpu.runtime import orchestration
+
+    orchestration.run_prompts(
+        _fw(model, prefetch_depth=2), list(PROMPTS), tokenizer=FakeTokenizer(),
+        devices=ONE_CHIP(),
+    )
+    spans = process_tracer.snapshot()
+    producer = ("shard_produce", "shard_load", "upload_dispatch", "upload")
+    consumer = ("sweep", "sweep_head", "source_wait", "compute", "dispatch",
+                "device_wait", "act_fetch", "act_store", "sweep_tail")
+    ids = {n: {s.get("sweep_id") for s in spans if s["name"] == n}
+           for n in producer + consumer}
+    (sweep_id,) = ids["sweep"]
+    assert sweep_id > 0
+    for n, got in ids.items():
+        assert got == {sweep_id}, (n, got)
+    # Below a shard the producer's and the consumer's spans name it alike.
+    n_shards = len({s["shard_idx"] for s in spans if s["name"] == "compute"})
+    for n in ("shard_produce", "shard_load", "upload_dispatch", "upload"):
+        assert {s["shard_idx"] for s in spans if s["name"] == n} == set(
+            range(n_shards)
+        ), n
+    # Every upload interval starts at or after its dispatch was called,
+    # and ends at or after that call returned.
+    dispatch = {s["shard_idx"]: s for s in spans if s["name"] == "upload_dispatch"}
+    for up in (s for s in spans if s["name"] == "upload"):
+        d = dispatch[up["shard_idx"]]
+        assert up["ts_s"] >= d["ts_s"] - 1e-6
+        assert up["ts_s"] + up["dur_s"] >= d["ts_s"] + d["dur_s"] - 1e-5
+        assert up["bytes"] > 0
+    rep = obs_report.analyze(spans)
+    assert 0.0 < rep["link_utilization"] <= 1.0
+
+
+def test_last_sweep_gauges_on_the_stream_source(model):
+    from flexible_llm_sharding_tpu.obs.registry import REGISTRY
+    from flexible_llm_sharding_tpu.runtime import executor, orchestration
+
+    orchestration.run_prompts(
+        _fw(model), list(PROMPTS), tokenizer=FakeTokenizer(), devices=ONE_CHIP()
+    )
+    last = executor.process_sweep_log()[-1]
+    stream = REGISTRY.collect()["stream"]
+    for key in ("wall_s", "head_s", "source_wait_s", "upload_busy_s",
+                "upload_bytes", "producer_blocked_s"):
+        assert stream[f"last_sweep_{key}"] == last[key]
+    text = REGISTRY.prometheus_text()
+    assert "fls_stream_last_sweep_upload_busy_s" in text
+    # Every field an operator reads has its definition on the endpoint, and
+    # dispatch_s says what it leaves out.
+    for key in last:
+        if key not in ("sweep_id", "t_end"):
+            assert f"# HELP fls_stream_last_sweep_{key} " in text, key
+    (line,) = [
+        ln for ln in text.splitlines()
+        if ln.startswith("# HELP fls_stream_last_sweep_dispatch_s ")
+    ]
+    assert "device_wait_s" in line and "\n" not in line
