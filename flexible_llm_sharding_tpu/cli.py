@@ -137,15 +137,21 @@ def _add_robustness_flags(p: argparse.ArgumentParser) -> None:
                         "runs) skip disk read+parse+checksum and go "
                         "straight to device_put. 'auto' (default) = a "
                         "fraction of free RAM (off under --chaos); 0 = off")
-    p.add_argument("--hbm_pin_gb", type=_float_or_auto, default=0.0,
+    p.add_argument("--hbm_pin_gb", type=_float_or_auto, default=None,
                    help="device residency tier budget in GB: pin the "
                         "hottest layers (embedding, lm_head, norms, then "
                         "as many transformer blocks as fit) permanently in "
                         "HBM and stream only the rest — every sweep's "
-                        "host->HBM traffic drops by exactly the pinned "
-                        "bytes, outputs token-identical. 'auto' = measured "
-                        "free HBM minus activation headroom (off under "
-                        "--chaos and on unknown chips); 0 (default) = off")
+                        "host->HBM traffic drops by "
+                        "exactly the pinned bytes, outputs token-identical; "
+                        "the first sweep streams everything and keeps what "
+                        "it placed of the planned layers. 'auto' (default) "
+                        "= measured free HBM minus a headroom: 35%% of the "
+                        "chip, or what the weight stream holds in flight "
+                        "((prefetch depth + 2) shards) plus 5%% if that is "
+                        "more; a model that fits becomes resident whole "
+                        "(off under --chaos and on the CPU backend); "
+                        "0 = off")
     p.add_argument("--kv_page_tokens", type=int, default=16,
                    help="rows per paged prefix-KV page (runtime/kvpool.py) "
                         "— the cross-wave sharing granularity; <= 0 "

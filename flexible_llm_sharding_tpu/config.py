@@ -1061,15 +1061,20 @@ class FrameworkConfig:
     # many transformer blocks as fit) permanently on chip — pinned layers
     # are subtracted from every sweep's weight stream, cutting the
     # host->HBM link traffic by exactly their bytes while outputs stay
-    # token-identical. None = auto: measured free HBM minus an activation
-    # headroom (ACTIVATION_HEADROOM_FRACTION), OFF under fault injection
-    # (chaos schedules must keep their per-load draws; an explicit budget
-    # still wins) and on chips with unknown HBM. 0 (default) disables.
-    # Pins are loaded once through the manifest-verified path and survive
-    # serving source restarts and wave recoveries; a pin-time load whose
-    # corruption survives every re-read is demoted back to streaming, so
-    # wrong bytes are never resident.
-    hbm_pin_gb: float | None = 0.0
+    # token-identical. None (default) = auto: measured free HBM minus a
+    # headroom, the larger of ACTIVATION_HEADROOM_FRACTION of the chip and
+    # what the weight source itself holds in flight ((prefetch depth + 2)
+    # x the largest shard) plus SCRATCH_HEADROOM_FRACTION; a model that
+    # fits is resident whole, one that does not pins what the headroom
+    # allows. Auto is OFF under fault injection (chaos schedules must keep
+    # their per-load draws; an explicit budget still wins) and on chips
+    # with unknown HBM (the CPU backend). 0 disables. A pin is the first
+    # sweep's own bytes: the first source of a process streams every layer
+    # through the manifest-verified path as ever and keeps what it placed
+    # of the planned ones; pins survive serving source restarts and wave
+    # recoveries; a planned layer whose load fails past its retries is
+    # demoted back to streaming, so wrong bytes are never resident.
+    hbm_pin_gb: float | None = None
     # Threads in the loader's page-cache readahead pool
     # (utils/native.py FilePrefetcher — posix_fadvise(WILLNEED) issuers,
     # ~zero CPU each; more threads help deep dirs on high-QD storage).
@@ -1227,16 +1232,18 @@ class FrameworkConfig:
 
         return _auto_budget_bytes()
 
-    def effective_hbm_pin_bytes(self, device=None) -> int:
+    def effective_hbm_pin_bytes(self, device=None, in_flight_bytes: int = 0) -> int:
         """Resolve the tri-state ``hbm_pin_gb`` to a pin-tier byte budget.
 
-        Explicit value -> that many GB (0 = off). None (auto) -> measured
-        free HBM minus the activation headroom
-        (residency.auto_pin_budget_bytes) — except under fault injection,
-        where auto resolves to OFF: pinned layers skip the per-sweep load
-        path, silently starving a seeded chaos schedule of its draws (an
-        EXPLICIT budget still wins, for chaos pin-parity tests). Unknown
-        HBM (the CPU backend, unrecognized chips) also resolves to off."""
+        Explicit value -> that many GB (0 = off). None (auto, the
+        default) -> measured free HBM minus a headroom that covers
+        ``in_flight_bytes``, what a weight source holds on the chip while
+        it streams (residency.auto_pin_budget_bytes) — except under fault
+        injection, where auto resolves to OFF: pinned layers skip the
+        per-sweep load path, silently starving a seeded chaos schedule of
+        its draws (an EXPLICIT budget still wins, for chaos pin-parity
+        tests). Unknown HBM (the CPU backend, unrecognized chips) also
+        resolves to off."""
         if self.hbm_pin_gb is not None:
             return int(self.hbm_pin_gb * 1e9)
         if self.faults.enabled:
@@ -1245,7 +1252,7 @@ class FrameworkConfig:
             auto_pin_budget_bytes,
         )
 
-        return auto_pin_budget_bytes(device)
+        return auto_pin_budget_bytes(device, in_flight_bytes)
 
     def retry_policy(self):
         """The transient-I/O RetryPolicy for this run's weight stream

@@ -584,6 +584,11 @@ SWEEP_RECORD_HELP = {
     "uploads": "Weight uploads seen to completion.",
     "upload_misses": "Uploads whose arrays were deleted before the "
     "completion thread could wait on them.",
+    "pinned_bytes": "Bytes the residency tier holds on the chip (its "
+    "heaviest target) when the sweep ends: what this sweep's stream did "
+    "not have to carry once seated.",
+    "pin_hits": "Planned layers this sweep merged from the residency tier "
+    "instead of uploading (a layer the sweep seated is not a hit).",
 }
 _describe_gauges(
     "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}
@@ -1299,52 +1304,89 @@ def _place(
     return out
 
 
-def _stream_only(idxs, pinned: frozenset) -> tuple[int, ...]:
-    """A shard's still-streamed layer idxs (readahead targets) — shared by
-    both sources so the pin-subtraction rule can't drift between them."""
-    return tuple(i for i in idxs if i not in pinned)
+def _to_read(idxs, pinned: frozenset, residency, devices) -> tuple[int, ...]:
+    """The layer idxs of a shard that its build will read from disk (the
+    readahead's targets): every layer but a pin that is seated already —
+    shared by both sources so the pin-subtraction rule can't drift
+    between them."""
+    return tuple(
+        i
+        for i in idxs
+        if i not in pinned or residency.seat_state(i, devices) != "seated"
+    )
 
 
 def _split_parts(
-    loader: _HostShardLoader, layer_idxs: tuple[int, ...], pinned: frozenset
-) -> list[tuple[str, Any]]:
-    """One shard's build, partial-residency aware: ``[("stream", host_
-    segments) | ("pin", idx), ...]`` in layer order. Only the streamed
-    runs touch disk; pinned layers contribute a marker that
-    ``_assemble_parts`` resolves to the tier's resident placed segments.
-    With no pins this is exactly one ("stream", build_host_shard(idxs))
-    part — the pre-residency fast path, byte for byte."""
+    loader: _HostShardLoader,
+    layer_idxs: tuple[int, ...],
+    pinned: frozenset,
+    residency=None,
+    devices: Sequence = (None,),
+) -> list[tuple[str, int, Any]]:
+    """One shard's build, partial-residency aware: ``(kind, idx, host
+    segments)`` parts in layer order. ``("stream", -1, host)`` is a run of
+    unpinned layers; a layer of the source's frozen pin set is always a
+    part of its own, so the segment structure the consumer sees never
+    depends on what is seated yet: ``("pin", idx, None)`` once it is
+    resident on every one of ``devices`` (nothing read, nothing
+    uploaded), ``("seat", idx, host)`` in the sweep that finds it planned
+    and not yet resident (read and verified here like any streamed layer;
+    ``_assemble_parts`` keeps what it places), ``("stream", idx, host)``
+    after a demotion. A seat whose build fails demotes the layer and
+    raises the stream path's own typed error. With no pins this is
+    exactly one ("stream", -1, build_host_shard(idxs)) part — the
+    pre-residency fast path, byte for byte."""
     if not pinned or not any(i in pinned for i in layer_idxs):
-        return [("stream", loader.build_host_shard(tuple(layer_idxs)))]
-    parts: list[tuple[str, Any]] = []
+        return [("stream", -1, loader.build_host_shard(tuple(layer_idxs)))]
+    parts: list[tuple[str, int, Any]] = []
     run: list[int] = []
+
+    def flush() -> None:
+        if run:
+            parts.append(("stream", -1, loader.build_host_shard(tuple(run))))
+            run.clear()
+
     for i in layer_idxs:
-        if i in pinned:
-            if run:
-                parts.append(("stream", loader.build_host_shard(tuple(run))))
-                run = []
-            parts.append(("pin", i))
-        else:
+        if i not in pinned:
             run.append(i)
-    if run:
-        parts.append(("stream", loader.build_host_shard(tuple(run))))
+            continue
+        flush()
+        state = residency.seat_state(i, devices)
+        if state == "seated":
+            parts.append(("pin", i, None))
+            continue
+        try:
+            host = loader.build_host_shard((i,))
+        except Exception:
+            residency.demote(i)
+            raise
+        parts.append(("seat" if state == "unseated" else "stream", i, host))
+    flush()
     return parts
 
 
-def _assemble_parts(
-    parts, device, np_dtype, residency, loader
-) -> list[tuple[str, Any]]:
+def _assemble_parts(parts, device, np_dtype, residency) -> list[tuple[str, Any]]:
     """Place the streamed runs and merge the pinned layers' resident
     segments back at their positions — the full shard's segment list in
     layer order, exactly what an unpinned ``_place(build_host_shard(...))``
     would have produced (same trees, same order; the pinned ones just
-    weren't re-read or re-uploaded)."""
+    weren't re-read or re-uploaded). A ``seat`` part is placed like a
+    streamed one and then kept by the tier: the sweep's own upload is the
+    pin's only one. A placement that fails (no room) demotes the layer."""
     out: list[tuple[str, Any]] = []
-    for kind, val in parts:
+    for kind, idx, host in parts:
         if kind == "stream":
-            out.extend(_place(val, device, np_dtype=np_dtype))
-        else:
-            out.extend(residency.segments(val, device, loader))
+            out.extend(_place(host, device, np_dtype=np_dtype))
+            continue
+        placed = residency.seated(idx, device)
+        if placed is None:  # a seat, or a retried put whose seat landed
+            try:
+                placed = _place(host, device, np_dtype=np_dtype)
+            except Exception:
+                residency.demote(idx)
+                raise
+            placed = residency.seat(idx, device, host, placed)
+        out.extend(placed)
     return out
 
 
@@ -1492,15 +1534,16 @@ class ShardWeightSource:
             host_cache=host_cache, readahead_threads=readahead_threads,
         )
         self._residency = residency
-        self._pinned_idxs: frozenset = frozenset()
-        if residency is not None:
-            # Pre-pin (verified load + placement) BEFORE the producer
-            # thread starts; a pin that fails persistently demotes the
-            # layer back to streaming, where its typed error surfaces
-            # through the normal fault envelopes.
-            for idxs, dev in zip(self.shards, self.shard_devices):
-                residency.ensure_pinned(self._loader, dev, idxs)
-            self._pinned_idxs = residency.frozen_pinned(self.shards)
+        # Nothing is loaded here: a planned layer that is not resident yet
+        # is seated from this source's own stream (_split_parts), on the
+        # producer's thread, by the read, check and upload the sweep makes
+        # of it anyway.
+        self._pinned_idxs: frozenset = (
+            residency.frozen_pinned(self.shards)
+            if residency is not None
+            else frozenset()
+        )
+        self.pin_hits = 0  # pinned layers this source merged, not uploaded
         self.produce_time = 0.0  # set BEFORE the producer thread starts
         # The producer's side of the sweep's account (see account()).
         self.upload_dispatch_s = 0.0
@@ -1592,6 +1635,12 @@ class ShardWeightSource:
             "upload_bytes": self.upload_bytes,
             "uploads": len(intervals),
             "upload_misses": misses,
+            "pinned_bytes": (
+                self._residency.max_pinned_device_bytes()
+                if self._residency is not None
+                else 0
+            ),
+            "pin_hits": self.pin_hits,
         }
 
     @property
@@ -1606,8 +1655,11 @@ class ShardWeightSource:
     def host_casts(self) -> int:
         return self._loader.host_casts
 
-    def _stream_only(self, idxs) -> tuple[int, ...]:
-        return _stream_only(idxs, self._pinned_idxs)
+    def _to_read(self, shard_i: int) -> tuple[int, ...]:
+        return _to_read(
+            self.shards[shard_i], self._pinned_idxs, self._residency,
+            (self.shard_devices[shard_i],),
+        )
 
     def _build_shard(
         self, layer_idxs: tuple[int, ...], device, shard_i: int = 0
@@ -1625,14 +1677,17 @@ class ShardWeightSource:
         self._loader.trace_ids = ids
         with obs_trace.timed("shard_produce", cat="stream", **attrs) as produce:
             bytes_before = self._loader.bytes_loaded
-            parts = _split_parts(self._loader, layer_idxs, self._pinned_idxs)
+            parts = _split_parts(
+                self._loader, layer_idxs, self._pinned_idxs, self._residency,
+                (device,),
+            )
             nbytes = self._loader.bytes_loaded - bytes_before
-            if self._residency is not None:
-                # Count the sweep's saved link bytes ONCE per build (the put
-                # below may retry; retries must not double-count).
-                for kind, val in parts:
-                    if kind == "pin":
-                        self._residency.note_skip(val)
+            # Count the sweep's saved link bytes ONCE per build (the put
+            # below may retry; retries must not double-count).
+            for kind, idx, _ in parts:
+                if kind == "pin":
+                    self._residency.note_skip(idx)
+                    self.pin_hits += 1
 
             # The host->device put retries under the same policy as the
             # reads: a transfer that surfaces OSError/TimeoutError is
@@ -1646,8 +1701,7 @@ class ShardWeightSource:
                     self._injector.fire("link_throttle", detail=str(layer_idxs))
                     self._injector.fire("device_put", detail=str(layer_idxs))
                 return _assemble_parts(
-                    parts, device, self._loader.np_dtype, self._residency,
-                    self._loader,
+                    parts, device, self._loader.np_dtype, self._residency
                 )
 
             # upload_dispatch is the CALL (device_put returns at the
@@ -1725,9 +1779,9 @@ class ShardWeightSource:
                     # sweep wraps, so the last shard warms shard 0 again.
                     nxt = i + 1
                     if nxt < len(self.shards):
-                        self._loader.warm(self._stream_only(self.shards[nxt]))
+                        self._loader.warm(self._to_read(nxt))
                     elif self.cycle:
-                        self._loader.warm(self._stream_only(self.shards[0]))
+                        self._loader.warm(self._to_read(0))
                     item = self._build_shard(idxs, dev, i)
                 except Exception as e:  # flscheck: disable=EXC-TAXONOMY: EVERY producer error must travel to the consumer as a _ShardFault envelope — narrowing would let an unexpected type kill the thread and hang the consumer's get
                     # Surface to the consumer at this shard's position, but
@@ -1767,7 +1821,7 @@ class ShardWeightSource:
                     if self._stop.is_set():
                         return
                     if i + 1 < len(self.shards):
-                        self._loader.warm(self._stream_only(self.shards[i + 1]))
+                        self._loader.warm(self._to_read(i + 1))
                     yield idxs, self._build_shard(idxs, dev, i)
                 if not self.cycle:
                     return
@@ -1833,17 +1887,15 @@ class BroadcastShardSource:
         # pinned copies (pinned once per chip, process lifetime); the ONE
         # host build per shard then skips the pinned layers' disk work and
         # every chip's upload skips their link bytes.
+        # A planned layer not resident yet is seated from the first
+        # round's own stream: the shard's ONE host build, placed on every
+        # DP chip, each of which keeps its copy.
         self._residency = residency
-        self._pinned_idxs: frozenset = frozenset()
-        if residency is not None:
-            # Read-once pre-pin: ONE host build per pinned layer, placed
-            # on every DP chip — the same convention as the stream below.
-            residency.ensure_pinned_broadcast(
-                self._loader,
-                self.devices,
-                sorted({i for s in self.shards for i in s}),
-            )
-            self._pinned_idxs = residency.frozen_pinned(self.shards)
+        self._pinned_idxs: frozenset = (
+            residency.frozen_pinned(self.shards)
+            if residency is not None
+            else frozenset()
+        )
         depth = max(1, prefetch_depth)
         self._queues = [Queue(maxsize=depth) for _ in self.devices]
         self._thread = threading.Thread(target=self._producer, daemon=True)
@@ -1870,18 +1922,21 @@ class BroadcastShardSource:
                 try:
                     if i + 1 < len(self.shards):
                         self._loader.warm(
-                            _stream_only(self.shards[i + 1], self._pinned_idxs)
+                            _to_read(
+                                self.shards[i + 1], self._pinned_idxs,
+                                self._residency, self.devices,
+                            )
                         )
                     parts = _split_parts(
-                        self._loader, tuple(idxs), self._pinned_idxs
+                        self._loader, tuple(idxs), self._pinned_idxs,
+                        self._residency, self.devices,
                     )
-                    if self._residency is not None:
-                        # Saved bytes counted once per HOST build — the
-                        # same convention as streamed_bytes (one host
-                        # build serves every DP chip).
-                        for kind, val in parts:
-                            if kind == "pin":
-                                self._residency.note_skip(val)
+                    # Saved bytes counted once per HOST build — the same
+                    # convention as streamed_bytes (one host build serves
+                    # every DP chip).
+                    for kind, idx, _ in parts:
+                        if kind == "pin":
+                            self._residency.note_skip(idx)
                 except Exception as e:  # flscheck: disable=EXC-TAXONOMY: every producer error must reach ALL ranks as a _ShardFault envelope — a narrowed miss would hang every consumer
                     # Broadcast streams are offline (one DP run): every rank
                     # sees the failure and the run fails, so no per-shard
@@ -1896,7 +1951,7 @@ class BroadcastShardSource:
                     try:
                         item = _assemble_parts(
                             parts, dev, self._loader.np_dtype,
-                            self._residency, self._loader,
+                            self._residency,
                         )
                     except Exception as e:  # flscheck: disable=EXC-TAXONOMY: per-rank placement errors also travel as envelopes to every rank (same hang hazard as above)
                         for r2 in range(len(self.devices)):

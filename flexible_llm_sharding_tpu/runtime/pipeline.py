@@ -177,8 +177,9 @@ class PipelineRunner:
         from flexible_llm_sharding_tpu.runtime import hostcache, residency
 
         # Partial residency over the pipeline: a pinned layer stays on its
-        # STAGE's chip (ensure_pinned runs per (shard, stage device) pair
-        # inside the source), so each stage's sweep skips its own pins.
+        # STAGE's chip (the source seats each planned layer from its own
+        # stream, on the device of the layer's shard), so each stage's
+        # sweep skips its own pins.
         tier = residency.tier_for(
             self.cfg,
             self.layer_names,

@@ -5,27 +5,34 @@ model through the host->HBM link (PAPER.md §0: the loop inversion) while
 the chip's HBM sits nearly empty — the resident-vs-streaming gate was
 all-or-nothing (``config.decode_resident``). This module spends leftover
 HBM on a *partial* residency tier: given a byte budget
-(``FrameworkConfig.hbm_pin_gb``), a planner selects the layers with the
+(``FrameworkConfig.hbm_pin_gb``; by default derived from the chip, see
+``auto_pin_budget_bytes``), a planner selects the layers with the
 highest streamed-bytes-per-sweep — the always-hot non-decoder layers
 (embedding, lm_head, final norm) first, then as many transformer blocks
-as fit — loads them ONCE through the existing manifest-verified loader
-path, and keeps them device-resident for the process lifetime. Every
-shard source subtracts pinned layers from its builds: their bytes never
-cross the link again, and the forward pass sees them merged back into the
-shard's segment list at placement (consumers already iterate per-segment,
-so a pinned layer is just one more pre-placed segment).
+as fit — and the first sweep that streams them
+keeps what it placed: device-resident for the process lifetime. Every
+later shard build subtracts them: their bytes never cross the link
+again, and the forward pass sees them merged back into the shard's
+segment list at placement (consumers already iterate per-segment, so a
+pinned layer is just one more pre-placed segment).
 
 Safety model (mirrors ``runtime/hostcache.py``):
 
-- Pins are loaded via ``_HostShardLoader.build_host_shard`` — retried,
-  checksum-verified, re-read-healed, and chaos-injected exactly like a
-  streamed load. A pinned tree is *verified-clean by construction*.
+- A pin is the stream's own tree: built by ``_HostShardLoader.
+  build_host_shard`` on the source's producer thread — retried,
+  checksum-verified, re-read-healed, and chaos-injected like every
+  streamed load, because it IS one — placed by ``executor._place``, and
+  then seated (``DeviceResidencyTier.seat``) instead of dropped after
+  use. No second read, crc pass or upload. A pinned tree is
+  *verified-clean by construction*.
 - A load whose corruption survives every re-read is NEVER pinned: the
-  layer is demoted back to streaming (where the quarantine's typed error
-  surfaces through the normal degrade machinery) instead of poisoning a
-  resident copy for the process lifetime.
-- The pin set is frozen per source at construction, so a wave's prefill
-  and its decode steps always see the same segment structure.
+  layer is demoted back to streaming (the stream path's typed error and
+  quarantine surface through the normal degrade machinery) instead of
+  poisoning a resident copy for the process lifetime.
+- The pin set is frozen per source at construction and every layer of it
+  is a segment of its own whether it is seated yet or not, so a wave's
+  prefill and its decode steps, and the seating sweep and every sweep
+  after it, see the same segment structure (nothing recompiles).
 - Budget precedence follows the host cache's rule: an EXPLICIT
   ``hbm_pin_gb`` pins the cap (a later auto-config component in the same
   process cannot grow it); an auto budget only ever grows an auto-sized
@@ -33,9 +40,10 @@ Safety model (mirrors ``runtime/hostcache.py``):
   keep their per-load draws) and on chips with unknown HBM.
 
 Accounting honesty: pinned bytes are device-resident for the whole run,
-so ``peak_hbm_gb`` figures are floored at the pin tier's bytes and the
-serve stats line carries ``pinned_bytes`` / ``stream_bytes_saved`` —
-the low-memory claim can never silently exclude the tier.
+so ``peak_hbm_gb`` figures are floored at the pin tier's bytes, the serve
+stats line carries ``pinned_bytes`` / ``stream_bytes_saved`` and every
+sweep's record ``pinned_bytes`` / ``pin_hits`` — the low-memory claim can
+never silently exclude the tier.
 
 Budget caveat: layers are charged at their on-disk (streamed) size. For
 int4/int8 checkpoints the pinned copy dequantizes to the compute dtype on
@@ -58,6 +66,13 @@ from flexible_llm_sharding_tpu.utils import checkpoint
 # KV caches, the prefetch queue, and XLA scratch — the pin tier only
 # spends what is left of the measured free HBM after this headroom.
 ACTIVATION_HEADROOM_FRACTION = 0.35
+# Where a source's own in-flight shards (``in_flight_bytes``) outgrow that
+# fraction, the headroom is what they take plus this fraction of the chip
+# for the step's scratch and activations (measured on the v5e: 0.14-0.26
+# GB, 1-2% of its HBM, at 8 to 32 prompts a batch; PERF.md, PR 26): a model
+# with large shards then pins less instead of failing to allocate where,
+# unpinned, it ran.
+SCRATCH_HEADROOM_FRACTION = 0.05
 
 
 def layer_stream_bytes(
@@ -230,12 +245,29 @@ def full_pin_plan(
     )
 
 
-def auto_pin_budget_bytes(device=None) -> int:
-    """Auto pin budget: measured free HBM minus the activation headroom.
+def in_flight_bytes(cfg, layer_names: Sequence[str], tied_embeddings: bool) -> int:
+    """What one weight source holds on the chip beside the pins while it
+    streams: the prefetch queue's depth, the shard in the producer's hand
+    and the one at the consumer, each taken at the largest shard's size
+    (by the layer files, as the planner counts)."""
+    from flexible_llm_sharding_tpu.parallel.planner import plan_shards_dp
+
+    sizes = layer_stream_bytes(cfg.model_path, layer_names, tied_embeddings)
+    shards = plan_shards_dp(len(layer_names), cfg.layer_num_per_shard).shards
+    largest = max(sum(sizes[i] for i in shard) for shard in shards)
+    return (max(1, cfg.effective_prefetch_depth()) + 2) * largest
+
+
+def auto_pin_budget_bytes(device=None, in_flight_bytes: int = 0) -> int:
+    """Auto pin budget: measured free HBM minus the headroom, which is the
+    larger of ``ACTIVATION_HEADROOM_FRACTION`` of the chip and
+    ``in_flight_bytes`` (see the function of that name) plus
+    ``SCRATCH_HEADROOM_FRACTION`` of the chip.
 
     Free = the allocator's ``bytes_limit - bytes_in_use`` when the device
-    reports memory stats, else the device-kind HBM table (assumed empty).
-    The CPU backend has neither and resolves to 0 (off) — the budget is
+    reports memory stats, else the device-kind HBM table (assumed empty);
+    ``device`` may be a placement, which resolves to its first chip. The
+    CPU backend has neither and resolves to 0 (off) — the budget is
     only ever spent where it is real. On a TPU a failed stats query or an
     unknown device kind raises (utils/metrics.py); it is never read as
     "off"."""
@@ -244,6 +276,7 @@ def auto_pin_budget_bytes(device=None) -> int:
         device_memory_stats,
     )
 
+    target, device = device, probe_chip(device)
     stats = device_memory_stats(device)
     limit = stats.get("bytes_limit")
     in_use = stats.get("bytes_in_use", 0.0)
@@ -253,8 +286,24 @@ def auto_pin_budget_bytes(device=None) -> int:
             return 0
         limit = hbm * 1e9
         in_use = 0.0
-    free = limit - in_use
-    return int(max(0.0, free - ACTIVATION_HEADROOM_FRACTION * limit))
+    else:
+        # The process tier's own pins on this target are in ``in_use``
+        # but are the budget's to spend, not someone else's: a later
+        # source must read the budget its pins were planned under, not
+        # what is left beside them.
+        tier = process_tier()
+        if tier is not None:
+            # This target's, or the heaviest target's for a caller that
+            # probes a chip under another handle than its source places on.
+            in_use -= (
+                tier.pinned_device_bytes(target)
+                or tier.max_pinned_device_bytes()
+            )
+    headroom = max(
+        ACTIVATION_HEADROOM_FRACTION * limit,
+        in_flight_bytes + SCRATCH_HEADROOM_FRACTION * limit,
+    )
+    return int(max(0.0, limit - in_use - headroom))
 
 
 def placement_key(device) -> tuple:
@@ -332,19 +381,24 @@ def _placed_device_nbytes(segments) -> int:
 class DeviceResidencyTier:
     """Process-lifetime pins of the planned layers' placed parameter trees.
 
-    ``segments(idx, device, loader)`` returns the pinned layer's placed
-    segment list for a placement target, loading and placing it on first
-    request THROUGH THE CALLER'S LOADER — the same manifest-verified,
-    retried, chaos-injected path every streamed byte takes. Callers treat
-    the returned segments as immutable (they are shared across sweeps and
-    across sources; the jitted blocks never donate parameter trees).
+    A streaming source asks ``seat_state`` for each planned layer of a
+    shard it builds: a seated layer is merged (``seated``) and costs
+    nothing; an unseated one is read, verified and uploaded as the sweep
+    would anyway, and the placed segments are kept (``seat``) — the
+    first sweep of a process seats the whole plan from its own stream.
+    ``segments(idx, device, loader)`` is the load-on-first-request form
+    for a model pinned whole at construction (the resident draft).
+    Callers treat the seated segments as immutable (they are shared
+    across sweeps and across sources; the jitted blocks never donate
+    parameter trees).
 
-    A pin-time load that fails persistently (quarantined corruption,
-    exhausted retries) permanently demotes the layer back to streaming
-    for this tier's lifetime: wrong bytes are never pinned, and the
-    layer's typed error keeps surfacing through the normal stream-side
-    degrade machinery. Demotion is one-way so a source's frozen pin set
-    can never disagree with a later source's segment structure mid-wave.
+    A planned layer whose load or placement fails persistently
+    (quarantined corruption, exhausted retries, no room) is demoted back
+    to streaming for this tier's lifetime (``demote``): wrong bytes are
+    never pinned, and the layer's typed error keeps surfacing through the
+    normal stream-side degrade machinery. Demotion is one-way, and a
+    demoted layer of a source's frozen pin set stays a segment of its
+    own, so segment structures never change under a live source.
     """
 
     def __init__(
@@ -354,14 +408,8 @@ class DeviceResidencyTier:
         self.layer_names = list(layer_names)
         self.plan = plan  # guarded by: _lock
         self._lock = threading.RLock()
-        # (placement key, idx) -> Event while a pin load is in flight: the
-        # slow work (disk read, checksum, retry ladder, device placement)
-        # runs OFF the tier lock so stats()/note_skip()/other pins never
-        # stall behind one load's backoff deadline; concurrent callers of
-        # the same pin wait on the event instead of loading a duplicate.
-        self._inflight: dict[tuple, threading.Event] = {}  # guarded by: _lock
         self._failed: set[int] = set()  # guarded by: _lock
-        # idx -> host-tree bytes at pin time (the exact per-sweep link
+        # idx -> host-tree bytes when seated (the exact per-sweep link
         # bytes a skip saves; recorded once, device-independent).
         self._host_nbytes: dict[int, int] = {}  # guarded by: _lock
         # Planner's byte estimates, dict-shaped once: note_skip runs under
@@ -384,10 +432,6 @@ class DeviceResidencyTier:
 
     # -- membership --------------------------------------------------------
 
-    def is_pinned(self, idx: int) -> bool:
-        with self._lock:
-            return idx in self.plan.pinned_set and idx not in self._failed
-
     def frozen_pinned(self, layer_idxs_groups) -> frozenset:
         """The pin set a source captures at construction: planned-and-
         healthy layers among the shards it will stream. Frozen per source
@@ -402,28 +446,71 @@ class DeviceResidencyTier:
 
     # -- pinning -----------------------------------------------------------
 
+    def seated(self, idx: int, device) -> list | None:
+        """The layer's resident placed segments on ``device``, or None
+        while it is not seated there."""
+        with self._lock:
+            return self._placed.get(placement_key(device), {}).get(idx)
+
+    def seat_state(self, idx: int, devices) -> str:
+        """What a shard build does with a planned layer: ``"seated"`` (on
+        every one of ``devices``: merge the resident copy, upload
+        nothing), ``"failed"`` (demoted: stream it) or ``"unseated"``
+        (stream it this once and keep what was placed)."""
+        with self._lock:
+            if idx in self._failed:
+                return "failed"
+            for d in devices:
+                if self._placed.get(placement_key(d), {}).get(idx) is None:
+                    return "unseated"
+            return "seated"
+
+    def seat(self, idx: int, device, host, placed: list) -> list:
+        """Keep ``placed`` — the verified host tree ``host`` of one layer,
+        already uploaded — as ``idx``'s pin on ``device``, and return the seated
+        list. The read-once shape: the bytes are the sweep's own, read,
+        checked and uploaded by the source that needed them anyway. The
+        first seat wins (a concurrent source's duplicate is dropped, never
+        double-counted); a demoted layer is never seated."""
+        key = placement_key(device)
+        with self._lock:
+            if idx in self._failed:
+                return placed
+            seats = self._placed.setdefault(key, {})
+            if seats.get(idx) is None:
+                seats[idx] = placed
+                self._host_nbytes.setdefault(idx, _tree_nbytes(host))
+                self._dev_bytes[key] = self._dev_bytes.get(
+                    key, 0
+                ) + _placed_device_nbytes(placed)
+                self.pin_loads += 1
+            return seats[idx]
+
+    def demote(self, idx: int) -> None:
+        """A planned layer's load or placement failed (quarantined
+        corruption, exhausted retries, no room): never pin unverified
+        bytes — the layer streams for this tier's lifetime, where its
+        typed error surfaces through the normal degrade machinery."""
+        with self._lock:
+            if idx not in self._failed:
+                self._failed.add(idx)
+                self.pin_failures += 1
+
     def segments(self, idx: int, device, loader) -> list:
-        """The pinned layer's placed segment list on ``device`` (pin on
-        first request). Raises the loader's typed error when the pin load
-        fails — after demoting the layer so no later source plans it."""
+        """The pinned layer's placed segment list on ``device``, loaded
+        through ``loader`` on first request (the resident draft's path: a
+        model pinned whole at construction; a streaming source seats from
+        its own stream instead, see ``seat``). Raises the loader's typed
+        error when the load fails — after demoting the layer so no later
+        source plans it."""
         from flexible_llm_sharding_tpu.runtime.executor import _place
 
-        key = placement_key(device)
-        while True:
-            with self._lock:
-                hit = self._placed.setdefault(key, {}).get(idx)
-                if hit is not None:
-                    return hit
-                if idx in self._failed:
-                    raise checkpoint_unavailable(self.layer_names[idx])
-                gate = self._inflight.get((key, idx))
-                if gate is None:
-                    gate = threading.Event()
-                    self._inflight[(key, idx)] = gate
-                    break
-            # Another caller owns this pin's load: wait off-lock, then
-            # re-check (their success seats it; their failure demotes).
-            gate.wait()
+        hit = self.seated(idx, device)
+        if hit is not None:
+            return hit
+        with self._lock:
+            if idx in self._failed:
+                raise checkpoint_unavailable(self.layer_names[idx])
         try:
             # One traced span per pin load: pins ride the same verified/
             # retried path as the stream, but load ONCE per process — the
@@ -435,108 +522,9 @@ class DeviceResidencyTier:
                 host = loader.build_host_shard((idx,))
                 placed = _place(host, device, np_dtype=loader.np_dtype)
         except Exception:
-            # Persistent corruption / exhausted retries: never pin
-            # unverified bytes — demote to streaming for good (the
-            # stream path surfaces the typed error and quarantine).
-            with self._lock:
-                self._failed.add(idx)
-                self.pin_failures += 1
-                self._inflight.pop((key, idx), None)
-            gate.set()
+            self.demote(idx)
             raise
-        with self._lock:
-            seats = self._placed.setdefault(key, {})
-            if seats.get(idx) is None:
-                seats[idx] = placed
-                self._host_nbytes.setdefault(idx, _tree_nbytes(host))
-                self._dev_bytes[key] = self._dev_bytes.get(
-                    key, 0
-                ) + _placed_device_nbytes(placed)
-                self.pin_loads += 1
-            # else: a concurrent pin_from_host seated this pin while our
-            # load was in flight (it doesn't ride the _inflight gate) —
-            # the earlier seat wins, our duplicate placement is dropped,
-            # never double-counted. Same rule as pin_from_host.
-            placed = seats[idx]
-            self._inflight.pop((key, idx), None)
-        gate.set()
-        return placed
-
-    def ensure_pinned(self, loader, device, layer_idxs) -> None:
-        """Best-effort pre-pin of the planned layers among ``layer_idxs``
-        on ``device`` (source construction). Failures demote the layer —
-        the caller's frozen pin set then streams it, and the stream load
-        surfaces the typed error through the normal envelopes instead of
-        failing construction."""
-        for i in layer_idxs:
-            if not self.is_pinned(i):
-                continue
-            try:
-                self.segments(i, device, loader)
-            except Exception:  # flscheck: disable=EXC-TAXONOMY: pre-pin is best-effort; segments() already demoted the layer and the streamed path surfaces its typed error
-                pass  # demoted inside segments(); streamed path reports
-
-    def pin_from_host(self, idx: int, device, host, np_dtype) -> None:
-        """Seat an already-built (verified) host tree as ``idx``'s pin on
-        ``device`` — the broadcast pre-pin's read-once path. No-op when
-        already seated (a concurrent seat wins; the duplicate placement is
-        dropped, never double-counted)."""
-        from flexible_llm_sharding_tpu.runtime.executor import _place
-
-        key = placement_key(device)
-        with self._lock:
-            if self._placed.setdefault(key, {}).get(idx) is not None:
-                return
-        placed = _place(host, device, np_dtype=np_dtype)
-        with self._lock:
-            seats = self._placed.setdefault(key, {})
-            if seats.get(idx) is not None:
-                return
-            seats[idx] = placed
-            self._host_nbytes.setdefault(idx, _tree_nbytes(host))
-            self._dev_bytes[key] = self._dev_bytes.get(
-                key, 0
-            ) + _placed_device_nbytes(placed)
-            self.pin_loads += 1
-
-    def ensure_pinned_broadcast(self, loader, devices, layer_idxs) -> None:
-        """Best-effort pre-pin across a DP broadcast's chips with ONE host
-        build per pinned layer (the broadcast source's read-once
-        convention) — ``ensure_pinned`` per device would re-read and
-        re-checksum each pinned layer N times. Failures demote the layer
-        exactly like the per-device path."""
-        for i in layer_idxs:
-            if not self.is_pinned(i):
-                continue
-            with self._lock:
-                missing = [
-                    d
-                    for d in devices
-                    if self._placed.get(placement_key(d), {}).get(i) is None
-                ]
-            if not missing:
-                continue
-            try:
-                host = loader.build_host_shard((i,))
-            except Exception:  # flscheck: disable=EXC-TAXONOMY: any pin-load failure demotes the layer to streaming, where the typed error surfaces
-                # Same demotion rule as segments(): never pin unverified
-                # bytes; the streamed path surfaces the typed error.
-                with self._lock:
-                    self._failed.add(i)
-                    self.pin_failures += 1
-                continue
-            for d in missing:
-                try:
-                    self.pin_from_host(i, d, host, loader.np_dtype)
-                except Exception:  # flscheck: disable=EXC-TAXONOMY: placement failure demotes the layer; streaming it everywhere keeps segment structure uniform
-                    # Placement failure demotes too (mirrors segments());
-                    # copies already seated on other chips sit unused —
-                    # frozen_pinned excludes the layer, so it streams
-                    # everywhere and the structure stays uniform.
-                    with self._lock:
-                        self._failed.add(i)
-                        self.pin_failures += 1
-                    break
+        return self.seat(idx, device, host, placed)
 
     def note_skip(self, idx: int) -> None:
         """One pinned layer's bytes were subtracted from one shard build
@@ -658,9 +646,9 @@ class DeviceResidencyTier:
     def pressure_restore(self) -> int:
         """Reverse :meth:`pressure_unpin`: re-install the saved plan.
         Pins whose placed trees survived (live sources kept them seated)
-        serve again immediately; dropped ones reload lazily through the
-        verified pin path on the next source construction. Returns the
-        number of layers restored to the plan."""
+        serve again immediately; dropped ones are seated again from the
+        next source's own stream. Returns the number of layers restored
+        to the plan."""
         with self._lock:
             if not self.pressure_demoted:
                 return 0
@@ -682,7 +670,7 @@ def checkpoint_unavailable(name: str):
     from flexible_llm_sharding_tpu.integrity.manifest import ShardCorruptError
 
     return ShardCorruptError(
-        f"{name}: pin-time load failed persistently; layer demoted to "
+        f"{name}: the pin's load failed persistently; layer demoted to "
         "streaming (audit with the `verify` CLI subcommand)"
     )
 
@@ -705,10 +693,14 @@ def tier_for(
 ) -> DeviceResidencyTier | None:
     """The process residency tier for ``cfg``, or None when the budget
     resolves to 0 (hbm_pin_gb=0, chaos auto-off, unknown HBM)."""
-    budget = cfg.effective_hbm_pin_bytes(device)
+    explicit = cfg.hbm_pin_gb is not None
+    # An auto budget leaves room for what the source will hold in flight.
+    budget = cfg.effective_hbm_pin_bytes(
+        device,
+        0 if explicit else in_flight_bytes(cfg, layer_names, tied_embeddings),
+    )
     if budget <= 0:
         return None
-    explicit = cfg.hbm_pin_gb is not None
     key = (
         os.path.abspath(cfg.model_path),
         cfg.dtype,
@@ -881,6 +873,7 @@ __all__ = [
     "DeviceResidencyTier",
     "ResidencyPlan",
     "auto_pin_budget_bytes",
+    "in_flight_bytes",
     "layer_stream_bytes",
     "placement_key",
     "plan_report",

@@ -179,10 +179,10 @@ def test_tier_for_install_race_applies_losers_explicit_cap(model_dir, monkeypatc
     raced = []
     loser_plans = []
 
-    def racing_plan(path, layer_names, budget_bytes, tied_embeddings=False):
+    def racing_plan(path, layer_names, budget_bytes, tied_embeddings=False, **kw):
         if budget_bytes == int(5e8):
             loser_plans.append(budget_bytes)
-        plan = real_plan(path, layer_names, budget_bytes, tied_embeddings)
+        plan = real_plan(path, layer_names, budget_bytes, tied_embeddings, **kw)
         if not raced:
             raced.append(True)
             # While the explicit caller plans off the lock, an auto caller
@@ -223,7 +223,7 @@ def test_auto_grow_apply_revalidates_against_explicit_cap(model_dir, monkeypatch
     monkeypatch.setattr(
         FrameworkConfig,
         "effective_hbm_pin_bytes",
-        lambda self, device=None: (
+        lambda self, device=None, in_flight_bytes=0: (
             auto_budget[0]
             if self.hbm_pin_gb is None
             else int(self.hbm_pin_gb * 1e9)
@@ -234,14 +234,14 @@ def test_auto_grow_apply_revalidates_against_explicit_cap(model_dir, monkeypatch
     auto_budget[0] = int(2e9)
     raced = []
 
-    def racing_plan(path, layer_names, budget_bytes, tied_embeddings=False):
+    def racing_plan(path, layer_names, budget_bytes, tied_embeddings=False, **kw):
         if budget_bytes == int(2e9) and not raced:
             raced.append(True)
             # The explicit cap lands while the auto grower is planning.
             residency.tier_for(
                 _fw(model_dir, hbm_pin_gb=0.5), names, False, None
             )
-        return real_plan(path, layer_names, budget_bytes, tied_embeddings)
+        return real_plan(path, layer_names, budget_bytes, tied_embeddings, **kw)
 
     monkeypatch.setattr(residency, "plan_residency", racing_plan)
     grown = residency.tier_for(_fw(model_dir, hbm_pin_gb=None), names, False, None)
@@ -260,7 +260,7 @@ def test_auto_grow_apply_revalidates_against_bigger_auto(model_dir, monkeypatch)
     monkeypatch.setattr(
         FrameworkConfig,
         "effective_hbm_pin_bytes",
-        lambda self, device=None: (
+        lambda self, device=None, in_flight_bytes=0: (
             auto_budget[0]
             if self.hbm_pin_gb is None
             else int(self.hbm_pin_gb * 1e9)
@@ -271,7 +271,7 @@ def test_auto_grow_apply_revalidates_against_bigger_auto(model_dir, monkeypatch)
     auto_budget[0] = int(15e8)
     raced = []
 
-    def racing_plan(path, layer_names, budget_bytes, tied_embeddings=False):
+    def racing_plan(path, layer_names, budget_bytes, tied_embeddings=False, **kw):
         if budget_bytes == int(15e8) and not raced:
             raced.append(True)
             # A bigger auto grower lands while this one is planning.
@@ -279,7 +279,7 @@ def test_auto_grow_apply_revalidates_against_bigger_auto(model_dir, monkeypatch)
             residency.tier_for(
                 _fw(model_dir, hbm_pin_gb=None), names, False, None
             )
-        return real_plan(path, layer_names, budget_bytes, tied_embeddings)
+        return real_plan(path, layer_names, budget_bytes, tied_embeddings, **kw)
 
     monkeypatch.setattr(residency, "plan_residency", racing_plan)
     grown = residency.tier_for(_fw(model_dir, hbm_pin_gb=None), names, False, None)
@@ -299,7 +299,7 @@ def test_failed_explicit_resize_does_not_latch_explicit(model_dir, monkeypatch):
     monkeypatch.setattr(
         FrameworkConfig,
         "effective_hbm_pin_bytes",
-        lambda self, device=None: (
+        lambda self, device=None, in_flight_bytes=0: (
             auto_budget[0]
             if self.hbm_pin_gb is None
             else int(self.hbm_pin_gb * 1e9)
@@ -308,10 +308,10 @@ def test_failed_explicit_resize_does_not_latch_explicit(model_dir, monkeypatch):
     seeded = residency.tier_for(_fw(model_dir, hbm_pin_gb=None), names, False, None)
     assert seeded is not None and seeded.plan.budget_bytes == int(1e9)
 
-    def failing_plan(path, layer_names, budget_bytes, tied_embeddings=False):
+    def failing_plan(path, layer_names, budget_bytes, tied_embeddings=False, **kw):
         if budget_bytes == int(5e8):
             raise OSError("transient stat failure")
-        return real_plan(path, layer_names, budget_bytes, tied_embeddings)
+        return real_plan(path, layer_names, budget_bytes, tied_embeddings, **kw)
 
     monkeypatch.setattr(residency, "plan_residency", failing_plan)
     with pytest.raises(OSError):
@@ -423,9 +423,10 @@ def test_decode_parity_with_pins(model_dir):
     for a, b in zip(sc_off, sc_on):
         np.testing.assert_array_equal(a, b)
     assert up_off == up_on
-    # Multi-sweep decode is the tier's sweet spot: prefill + each step
-    # skipped the pinned layers every pass.
-    assert residency.process_tier().stats()["pin_hits"] >= 4 * 3
+    # Multi-sweep decode is the tier's sweet spot: the prefill sweep seats
+    # the four pins from its own stream, each step after it skips them.
+    st = residency.process_tier().stats()
+    assert st["pin_loads"] == 4 and st["pin_hits"] == 4 * 2
 
 
 # ---------------------------------------------------------------------------
@@ -628,13 +629,15 @@ def test_bench_pinned_fraction_zeroes_when_tier_disengaged(
     assert engaged["pinned_fraction"] > 0.0
 
 
-def test_segments_respects_concurrent_pin_from_host_seat(model_dir):
-    """pin_from_host does not ride segments()' in-flight gate, so a
-    broadcast pre-pin can seat the same (device, idx) while a segments()
-    load is mid-flight. The earlier seat must win: one pin_load, device
-    bytes counted exactly once, and the seated copy returned (the race
-    previously double-counted _dev_bytes and replaced the seated pin)."""
-    from flexible_llm_sharding_tpu.runtime.executor import _HostShardLoader
+def test_first_seat_wins_and_is_counted_once(model_dir):
+    """Two sources in their seating sweep can place the same layer (each
+    from its own stream). The earlier seat must win: one pin_load, device
+    bytes counted exactly once, the seated copy handed to both — and the
+    resident draft's load-on-first-request path (``segments``) finds it."""
+    from flexible_llm_sharding_tpu.runtime.executor import (
+        _HostShardLoader,
+        _place,
+    )
     from flexible_llm_sharding_tpu.runtime.residency import (
         DeviceResidencyTier,
         _placed_device_nbytes,
@@ -646,23 +649,341 @@ def test_segments_respects_concurrent_pin_from_host_seat(model_dir):
     plan = plan_residency(model_dir, names, 10**12, False)
     tier = DeviceResidencyTier(model_dir, names, plan)
     dev = jax.devices()[0]
-    inner = _HostShardLoader(model_dir, names, np.float32)
-
-    class _RacingLoader:
-        np_dtype = np.float32
-
-        def build_host_shard(self, idxs):
-            host = inner.build_host_shard(idxs)
-            # Seat the same pin via the broadcast read-once path while
-            # segments()' own load is still in flight.
-            tier.pin_from_host(idxs[0], dev, host, np.float32)
-            return host
-
-    placed = tier.segments(0, dev, _RacingLoader())
+    loader = _HostShardLoader(model_dir, names, np.float32)
+    host = loader.build_host_shard((0,))
+    first = _place(host, dev, np_dtype=np.float32)
+    dup = _place(host, dev, np_dtype=np.float32)
+    assert tier.seat_state(0, (dev,)) == "unseated"
+    assert tier.seat(0, dev, host, first) is first
+    assert tier.seat(0, dev, host, dup) is first  # the duplicate is dropped
+    assert tier.seat_state(0, (dev,)) == "seated"
+    assert tier.segments(0, dev, loader) is first  # no second load
     key = placement_key(dev)
     with tier._lock:
-        seated = tier._placed[key][0]
         dev_bytes = tier._dev_bytes[key]
-    assert placed is seated
     assert tier.pin_loads == 1
-    assert dev_bytes == _placed_device_nbytes(seated)
+    assert dev_bytes == _placed_device_nbytes(first)
+    # A demoted layer is never seated, whoever brings it.
+    tier.demote(1)
+    placed = _place(loader.build_host_shard((1,)), dev, np_dtype=np.float32)
+    assert tier.seat(1, dev, host, placed) is placed
+    assert tier.seated(1, dev) is None
+    assert tier.seat_state(1, (dev,)) == "failed"
+    assert tier.stats()["pin_failures"] == 1
+
+
+# ---------------------------------------------------------------------------
+# Seated from the first sweep's own stream (PR 26)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+@pytest.mark.parametrize("lnps", [1, 2])
+def test_seating_sweep_streams_once_then_only_the_remainder(
+    model_dir, lnps, prefetch
+):
+    """The first source of a process streams every layer as an untiered
+    run does, and keeps what it placed of the planned layers: no second
+    read, check or upload of a pin. The next source streams the remainder
+    only. Scores are the untiered run's bit for bit in both sweeps, also
+    where a pin splits a stacked run (``layer_num_per_shard`` 2)."""
+    kw = dict(layer_num_per_shard=lnps, prefetch_depth=prefetch)
+    off = StreamingExecutor(
+        _fw(model_dir, hbm_pin_gb=0.0, **kw), tokenizer=FakeTokenizer()
+    )
+    want = off(list(PROMPTS))
+    full = off.stats["streamed_bytes"]
+    ex = StreamingExecutor(
+        _fw(model_dir, hbm_pin_gb=_partial_budget_gb(model_dir), **kw),
+        tokenizer=FakeTokenizer(),
+    )
+    seating = ex(list(PROMPTS))
+    s1 = dict(ex.stats)
+    tier = residency.process_tier()
+    assert tier.plan.pinned == (0, 1, 5, 6)
+    # The seating sweep: every byte crossed once, nothing was skipped,
+    # and the four planned layers are resident at its end.
+    assert s1["streamed_bytes"] == full
+    assert "pin_hits" not in s1 and "stream_bytes_saved" not in s1
+    assert tier.stats()["pin_loads"] == 4 and tier.stats()["pinned_layers"] == 4
+    seated = ex(list(PROMPTS))
+    s2 = dict(ex.stats)
+    assert s2["pin_hits"] == 4.0
+    assert s2["streamed_bytes"] + s2["stream_bytes_saved"] == full
+    assert 0 < s2["streamed_bytes"] < full
+    assert tier.stats()["pin_loads"] == 4  # nothing loaded a second time
+    for got in (seating, seated):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+    # The sweep's own account carries the engagement.
+    from flexible_llm_sharding_tpu.runtime.executor import process_sweep_log
+
+    first, second = process_sweep_log()[-2:]
+    assert first["pin_hits"] == 0 and second["pin_hits"] == 4
+    assert first["pinned_bytes"] == second["pinned_bytes"] == s2["pinned_bytes"]
+    assert second["upload_bytes"] == s2["streamed_bytes"]
+
+
+def _host_nbytes(model_dir, *idxs) -> int:
+    """What the loader builds (and the link carries) for these layers."""
+    from flexible_llm_sharding_tpu.runtime.executor import _HostShardLoader
+
+    loader = _HostShardLoader(model_dir, layer_names_for(4), np.float32)
+    return sum(residency._tree_nbytes(loader.build_host_shard((i,))) for i in idxs)
+
+
+def _placement_fault(monkeypatch):
+    """``_place`` fails once, on the embedding (no room on the chip)."""
+    from flexible_llm_sharding_tpu.runtime import executor
+
+    real, fired = executor._place, []
+
+    def place(segments, device, np_dtype=None):
+        if not fired and segments and segments[0][0] == "embed":
+            fired.append(True)
+            raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+        return real(segments, device, np_dtype=np_dtype)
+
+    monkeypatch.setattr(executor, "_place", place)
+    return {}, RuntimeError
+
+
+def _read_fault(monkeypatch):
+    """The first layer read (the embedding's) fails past its retries."""
+    faults = FaultConfig(
+        enabled=True, seed=CHAOS_SEED, error_rate=1.0,
+        sites=("shard_read",), max_faults=1,
+    )
+    from flexible_llm_sharding_tpu.faults.retry import ShardLoadError
+
+    return dict(faults=faults, io_retry_attempts=1), ShardLoadError
+
+
+@pytest.mark.parametrize("fault", [_read_fault, _placement_fault])
+def test_failed_seat_demotes_to_streaming(model_dir, clean_scores, monkeypatch, fault):
+    """A planned layer whose load or placement fails in the seating sweep
+    is demoted: the sweep raises the stream path's own error, and every
+    later source streams that layer and pins the rest."""
+    kw, error = fault(monkeypatch)
+    ex = StreamingExecutor(
+        _fw(model_dir, hbm_pin_gb=1.0, **kw), tokenizer=FakeTokenizer()
+    )
+    with pytest.raises(error):
+        ex(list(PROMPTS))
+    tier = residency.process_tier()
+    assert tier.stats()["pin_failures"] == 1
+    assert tier.seat_state(0, (None,)) == "failed"
+    ex(list(PROMPTS))  # seats what the aborted sweep had not reached
+    got = ex(list(PROMPTS))
+    for g, w in zip(got, clean_scores):
+        np.testing.assert_array_equal(g, w)
+    assert ex.stats["streamed_bytes"] == _host_nbytes(model_dir, 0)  # it alone
+    assert ex.stats["pin_hits"] == 6.0
+    assert tier.stats()["pinned_layers"] == 6
+
+
+def _sized_dir(tmp_path, n_blocks, block=1000):
+    """A model dir of empty files with the planner's sizes only."""
+    names = layer_names_for(n_blocks)
+    for name in names:
+        size = block if name.startswith("model.layers.") else 10
+        with open(tmp_path / f"{name}.safetensors", "wb") as f:
+            f.write(b"\0" * size)
+    return str(tmp_path), names
+
+
+@pytest.mark.parametrize(
+    "n_blocks,n_pinned", [(14, 8), (13, 7), (4, 1), (5, 4), (3, 3), (3, 0)]
+)
+def test_plan_takes_the_first_of_equal_sized_blocks(tmp_path, n_blocks, n_pinned):
+    """Of equal-sized blocks the budget covers in part, the plan takes the
+    first N, and a grown budget only ever adds to them. (PR 26 measured a
+    plan spread over the depth on the chip: weight uploads and compute
+    alternate there, the resident head is where compute runs unhindered,
+    and the spread read 5-7% slower; PERF.md section 6.)"""
+    path, names = _sized_dir(tmp_path, n_blocks)
+    plan = residency.plan_residency(path, names, 30 + 1000 * n_pinned + 999)
+    assert plan.pinned == (
+        0, *range(1, n_pinned + 1), n_blocks + 1, n_blocks + 2
+    )
+    assert plan.pinned_bytes_est == 30 + 1000 * n_pinned
+    assert plan.skipped == tuple(range(n_pinned + 1, n_blocks + 1))
+    grown = residency.plan_residency(path, names, 30 + 1000 * n_blocks)
+    assert set(plan.pinned) <= set(grown.pinned)
+
+
+def test_source_walks_resident_shards_and_uploads_only_the_rest(model_dir):
+    """A source over a tier that holds layers 0, 1, 3, 5, 6 uploads layers 2
+    and 4 and nothing else, whatever the consumer's pace; the shards it
+    hands over are the full segment lists in order."""
+    from flexible_llm_sharding_tpu.runtime.executor import ShardWeightSource
+
+    names = layer_names_for(4)
+    sizes = _sizes(model_dir)
+    plan = residency.ResidencyPlan(
+        budget_bytes=1 << 40,
+        pinned=(0, 1, 3, 5, 6),
+        layer_bytes=tuple(sizes.items()),
+        skipped=(2, 4),
+    )
+    tier = residency.DeviceResidencyTier(model_dir, names, plan)
+    shards = [(i,) for i in range(7)]
+
+    def source():
+        return ShardWeightSource(
+            model_dir, names, shards, np.float32, prefetch_depth=1,
+            residency=tier,
+        )
+
+    seating = source()
+    try:
+        first = [(idxs, [k for k, _ in segs]) for idxs, segs in seating]
+    finally:
+        seating.close()
+    assert tier.stats()["pin_loads"] == 5 and seating.pin_hits == 0
+    src = source()
+    try:
+        second = [(idxs, [k for k, _ in segs]) for idxs, segs in src]
+    finally:
+        src.close()
+    assert first == second and [idxs for idxs, _ in second] == shards
+    assert src.pin_hits == 5
+    assert src.upload_bytes == src.bytes_loaded == _host_nbytes(model_dir, 2, 4)
+
+
+# ---------------------------------------------------------------------------
+# The default (PR 26): auto, which the CPU backend and chaos resolve to off
+# ---------------------------------------------------------------------------
+
+def _batch_cfg(argv):
+    from flexible_llm_sharding_tpu import cli
+
+    args = cli.build_parser().parse_args(
+        [*argv, "--prompt_pickle", "-", "--output_file", "-"]
+    )
+    return args, cli.config_from_args(args)
+
+
+def _serve_cfg(argv):
+    from flexible_llm_sharding_tpu import cli
+
+    args = cli.build_serve_parser().parse_args(argv)
+    return args, FrameworkConfig(
+        model_path=args.model_path,
+        hbm_pin_gb=args.hbm_pin_gb,
+        faults=cli._fault_config_from_args(args),
+    )
+
+
+@pytest.mark.parametrize("chaos", [False, True])
+@pytest.mark.parametrize("build", [_batch_cfg, _serve_cfg, None])
+def test_default_budget_is_auto_and_off_where_it_must_be(model_dir, build, chaos):
+    """The dataclass's and both parsers' default is auto; the CPU backend
+    (no HBM to read) and a chaos run resolve it to 0, so no tier exists."""
+    if build is None:  # the dataclass itself
+        cfg = FrameworkConfig(
+            model_path=model_dir, faults=FaultConfig(enabled=chaos, seed=1)
+        )
+    else:
+        args, cfg = build(
+            ["--model_path", model_dir, *(["--chaos"] if chaos else [])]
+        )
+        assert args.hbm_pin_gb is None
+    assert cfg.hbm_pin_gb is None and cfg.faults.enabled is chaos
+    assert cfg.effective_hbm_pin_bytes() == 0
+    assert residency.tier_for(cfg, layer_names_for(4), False, None) is None
+    assert residency.process_tier() is None
+
+
+def test_auto_budget_reads_the_chip_behind_a_placement(monkeypatch):
+    """Auto asks a device for its memory, so a target that is a placement
+    resolves to a chip first; and what the tier itself holds there is the
+    budget's to spend, not in use by someone else."""
+    from flexible_llm_sharding_tpu.utils import metrics
+
+    class _Chip:
+        platform, device_kind, id = "tpu", "TPU v5 lite", 0
+
+        def memory_stats(self):
+            return {"bytes_limit": 16_000, "bytes_in_use": self.in_use}
+
+    chip = _Chip()
+    chip.in_use = 1_000
+    mesh = type("M", (), {"devices": np.array([chip], dtype=object)})()
+    placement = type("P", (), {"mesh": mesh, "segment_target": None})()
+    want = 16_000 - 1_000 - int(residency.ACTIVATION_HEADROOM_FRACTION * 16_000)
+    for target in (chip, mesh, placement):
+        assert residency.auto_pin_budget_bytes(target) == want
+
+    class _Tier:
+        def pinned_device_bytes(self, device=None):
+            return 9_000
+
+    chip.in_use = 10_000  # of which the tier's own pins are 9,000
+    monkeypatch.setattr(residency, "process_tier", lambda: _Tier())
+    assert residency.auto_pin_budget_bytes(placement) == want
+
+
+@pytest.mark.parametrize(
+    "in_flight,headroom",
+    [(0, 5_600), (4_000, 5_600), (4_800, 5_600), (6_000, 6_800), (20_000, 16_000)],
+)
+def test_auto_headroom_covers_what_the_source_holds_in_flight(in_flight, headroom):
+    """The headroom is 35% of the chip, or the source's own in-flight
+    shards plus 5% where that is more; never a negative budget."""
+
+    class _Chip:
+        platform, device_kind, id = "tpu", "TPU v5 lite", 0
+
+        def memory_stats(self):
+            return {"bytes_limit": 16_000, "bytes_in_use": 0}
+
+    assert residency.auto_pin_budget_bytes(_Chip(), in_flight) == 16_000 - headroom
+
+
+@pytest.mark.parametrize("lnps,depth", [(1, 0), (1, 2), (2, 1), (7, 3)])
+def test_in_flight_bytes_is_depth_plus_two_largest_shards(model_dir, lnps, depth):
+    from flexible_llm_sharding_tpu.parallel.planner import plan_shards_dp
+
+    sizes = _sizes(model_dir)
+    cfg = _fw(model_dir, layer_num_per_shard=lnps, prefetch_depth=depth)
+    largest = max(
+        sum(sizes[i] for i in s) for s in plan_shards_dp(7, lnps).shards
+    )
+    assert residency.in_flight_bytes(cfg, layer_names_for(4), False) == (
+        max(1, depth) + 2
+    ) * largest
+
+
+def test_first_seat_holds_under_contention(model_dir):
+    """More threads than cores race to seat one layer and to demote
+    another: one seat wins and is counted once, one failure is counted."""
+    import sys
+    import threading
+
+    names = layer_names_for(4)
+    plan = residency.plan_residency(model_dir, names, 10**12, False)
+    tier = residency.DeviceResidencyTier(model_dir, names, plan)
+    winners, n = [], 4 * (os.cpu_count() or 4)
+    start = threading.Barrier(n)
+
+    def race(k):
+        start.wait(timeout=30)
+        for _ in range(50):
+            winners.append(tier.seat(2, None, [], [("decoders", k)]))
+            tier.demote(3)
+            assert tier.seat_state(2, (None,)) == "seated"
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=race, args=(k,)) for k in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert len(winners) == 50 * n and len({id(w) for w in winners}) == 1
+    st = tier.stats()
+    assert st["pin_loads"] == 1 and st["pin_failures"] == 1
