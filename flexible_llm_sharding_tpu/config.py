@@ -404,6 +404,53 @@ class LlamaConfig:
     # keeps the analytic param/FLOPs accounting (utils/metrics.py) and
     # init_mixed_params consistent with it.
     n_shared_experts: int = 1
+    # Expert parallelism's share of an expert layer (the router keeps its
+    # num_local_experts outputs and its top-k; this process holds the
+    # experts ``moe_ep_rank * E/moe_ep_size ...`` and computes their part of
+    # the layer's result: models/llama.py _deepseek_moe_mlp). 1 = all held.
+    moe_ep_size: int = 1
+    moe_ep_rank: int = 0
+    # Two attention shapes in one model (MiMo-V2: window layers carry other
+    # KV-head counts than full layers). (heads, kv heads, qk dim, v dim) of
+    # the LOCAL (layer_sliding) layers; None = the model's one shape. The
+    # layer functions take a layer's kind from the weights they are given.
+    local_attn_shape: tuple[int, int, int, int] | None = None
+    # Rotary on the first rotary_dim dims of a head only (None = all of it).
+    rotary_dim: int | None = None
+    # Values scaled by this factor before attention (MiMo-V2).
+    attn_value_scale: float | None = None
+    # A learned per-head sink logit in the softmax's denominator, in the
+    # local / the global layers (the weights' ``attn.sink`` leaf is what the
+    # forward passes go by; these say which layers a checkpoint has it in).
+    attn_sink_local: bool = False
+    attn_sink_global: bool = False
+
+    def attn_shape(self, sliding: bool = False) -> tuple[int, int, int, int]:
+        """(heads, kv heads, qk head dim, v head dim) of a layer kind."""
+        if sliding and self.local_attn_shape is not None:
+            return self.local_attn_shape
+        return (
+            self.num_attention_heads, self.num_key_value_heads,
+            self.head_dim, self.v_dim,
+        )
+
+    def require_one_attention_shape(self, path: str) -> None:
+        """Fail loudly on a path that sizes its KV state or shards its heads
+        from ``num_key_value_heads`` alone, for a model whose layer kinds
+        differ in attention shape or that holds a share of its experts: only
+        the streamed scoring path and the layer functions carry those."""
+        if self.local_attn_shape is not None or self.moe_ep_size > 1:
+            raise NotImplementedError(
+                f"{path} does not support {self.model_type}: per-kind "
+                "attention shapes and a held share of the experts run on the "
+                "streamed scoring path only"
+            )
+
+    @property
+    def held_experts(self) -> range:
+        """Ids of the routed experts this process holds."""
+        n = self.num_local_experts // self.moe_ep_size
+        return range(self.moe_ep_rank * n, (self.moe_ep_rank + 1) * n)
 
     @property
     def head_dim(self) -> int:
@@ -504,6 +551,85 @@ class LlamaConfig:
             return
         mwl = d.get("max_window_layers", 28)
         cls._apply_sliding_pattern(kwargs, d, "qwen", lambda i, n: i >= mwl, 4096)
+
+    @staticmethod
+    def _apply_mimo_v2(kwargs: dict[str, Any], d: dict[str, Any]) -> None:
+        """MiMo-V2-Flash (``mimo_v2_flash``): full and sliding-window layers
+        of different attention shapes in one model (``hybrid_layer_pattern``:
+        1 = window), each kind with its own KV-head count and rope base;
+        qk and v head dims differ without MLA; rotary on the leading
+        ``partial_rotary_factor`` share of a head; values scaled; a learned
+        sink logit per head in the window layers' softmax; the DeepSeek
+        router (sigmoid, ``noaux_tc`` bias) over experts with no shared one.
+        Width convention as deepseek_v3: intermediate_size = the expert
+        width, intermediate_size_mlp = the dense layers'."""
+        n = int(d.get("num_hidden_layers", 32))
+        hd = int(d.get("head_dim", 192))
+        vd = int(d.get("v_head_dim", hd))
+        kwargs["explicit_head_dim"] = hd
+        kwargs["v_head_dim"] = vd
+        kwargs["rms_norm_eps"] = float(d.get("layernorm_epsilon", 1e-5))
+        kwargs["rotary_dim"] = int(hd * float(d.get("partial_rotary_factor", 1.0)))
+        if kwargs["rotary_dim"] % 2:
+            raise ValueError(f"mimo_v2_flash rotary dim {kwargs['rotary_dim']} is odd")
+        avs = d.get("attention_value_scale")
+        kwargs["attn_value_scale"] = None if avs is None else float(avs)
+        if d.get("attention_bias"):
+            kwargs["attention_in_bias"] = kwargs["attention_out_bias"] = True
+        pattern = tuple(bool(x) for x in d.get("hybrid_layer_pattern") or [0] * n)
+        if len(pattern) != n:
+            raise ValueError(
+                f"mimo_v2_flash hybrid_layer_pattern has {len(pattern)} entries "
+                f"for {n} layers"
+            )
+        kwargs["sliding_window"] = d.get("sliding_window") if any(pattern) else None
+        kwargs["layer_sliding"] = pattern if any(pattern) and not all(pattern) else None
+        kwargs["rope_local_theta"] = float(d.get("swa_rope_theta", 10000.0))
+        nq = int(d.get("num_attention_heads", 32))
+        kwargs["local_attn_shape"] = (
+            int(d.get("swa_num_attention_heads", nq)),
+            int(d.get("swa_num_key_value_heads", d.get("num_key_value_heads", nq))),
+            int(d.get("swa_head_dim", hd)),
+            int(d.get("swa_v_head_dim", vd)),
+        )
+        kwargs["attn_sink_local"] = bool(d.get("add_swa_attention_sink_bias", False))
+        kwargs["attn_sink_global"] = bool(d.get("add_full_attention_sink_bias", False))
+        n_routed = int(d.get("n_routed_experts") or 0)
+        kwargs["num_local_experts"] = n_routed
+        if not n_routed:
+            return
+        if d.get("scoring_func", "sigmoid") != "sigmoid":
+            raise NotImplementedError(
+                f"mimo_v2_flash scoring_func {d.get('scoring_func')!r} (sigmoid is supported)"
+            )
+        kwargs["intermediate_size_mlp"] = int(d.get("intermediate_size", 11008))
+        kwargs["intermediate_size"] = int(d.get("moe_intermediate_size", 2048))
+        kwargs["num_experts_per_tok"] = int(d.get("num_experts_per_tok", 8))
+        kwargs["moe_norm_topk_prob"] = bool(d.get("norm_topk_prob", True))
+        kwargs["moe_n_group"] = int(d.get("n_group") or 1)
+        kwargs["moe_topk_group"] = int(d.get("topk_group") or 1)
+        rsf = d.get("routed_scaling_factor")
+        kwargs["moe_routed_scaling_factor"] = 1.0 if rsf is None else float(rsf)
+        kwargs["n_shared_experts"] = int(d.get("n_shared_experts") or 0)  # null = none
+        freq = d.get("moe_layer_freq", 1)
+        moe = tuple(bool(x) for x in freq) if isinstance(freq, (list, tuple)) else (
+            tuple(i % int(freq) == 0 for i in range(n))
+        )
+        if len(moe) != n:
+            raise ValueError(
+                f"mimo_v2_flash moe_layer_freq has {len(moe)} entries for {n} layers"
+            )
+        if not all(moe):
+            kwargs["moe_layer_pattern"] = moe
+        # Which experts this process holds comes from the model's own
+        # config.json: the share of rank ep_rank among ep_size.
+        ep, rank = int(d.get("ep_size") or 1), int(d.get("ep_rank") or 0)
+        if n_routed % ep or not 0 <= rank < ep:
+            raise ValueError(
+                f"mimo_v2_flash: {n_routed} experts do not split over ep_size {ep} "
+                f"(ep_rank {rank})"
+            )
+        kwargs["moe_ep_size"], kwargs["moe_ep_rank"] = ep, rank
 
     @classmethod
     def from_hf_config(cls, d: dict[str, Any]) -> "LlamaConfig":
@@ -752,6 +878,9 @@ class LlamaConfig:
                     kwargs["query_pre_attn_scalar"] = qk_hd / m**4
                 else:
                     kwargs["query_pre_attn_scalar"] = float(qk_hd)
+        elif model_type == "mimo_v2_flash":
+            if not native:
+                cls._apply_mimo_v2(kwargs, d)
         elif model_type in ("mistral", "mixtral", "phi3"):
             # sliding_window flows through by field name (may be null);
             # mixtral's num_local_experts/num_experts_per_tok likewise.
@@ -764,9 +893,11 @@ class LlamaConfig:
             raise NotImplementedError(
                 f"model_type {model_type!r} is not supported "
                 "(llama, mistral, phi3, qwen2, qwen3, qwen3_moe, mixtral, gemma, "
-                "gemma2, gemma3_text, llama4_text, deepseek_v3 are)"
+                "gemma2, gemma3_text, llama4_text, deepseek_v3, mimo_v2_flash are)"
             )
-        if model_type not in ("mixtral", "llama4_text", "qwen3_moe", "deepseek_v3"):
+        if model_type not in (
+            "mixtral", "llama4_text", "qwen3_moe", "deepseek_v3", "mimo_v2_flash"
+        ):
             # A stray num_local_experts key in a dense export must not flip
             # the model into MoE mode (same stray-key defence as
             # sliding_window above).
@@ -783,6 +914,7 @@ class LlamaConfig:
             "moe_layer_pattern",
             "rope_long_factor",
             "rope_short_factor",
+            "local_attn_shape",
         ):
             if kwargs.get(key) is not None:
                 # json round-trips tuples as lists; fields must stay hashable.

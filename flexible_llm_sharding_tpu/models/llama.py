@@ -39,6 +39,7 @@ transpose of HF's [out, in], so matmuls need no transposes on device):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
@@ -86,13 +87,36 @@ def _lin(x: jax.Array, params: Params, w: str, b: str) -> jax.Array:
     return y
 
 
+def layer_kind(cfg: LlamaConfig, attn: Params, sliding):
+    """((heads, kv heads, qk dim, v dim), sliding) of the layer whose
+    attention weights are ``attn``. A model with ONE attention shape answers
+    from its config and hands ``sliding`` back as given. A model whose local
+    layers have another shape than its full ones (``cfg.local_attn_shape``:
+    MiMo-V2) is answered by the weights: the projections' widths say which
+    kind this layer is, so ``sliding`` comes back as a python bool (static)
+    whatever traced flag the caller held."""
+    full, local = cfg.attn_shape(False), cfg.attn_shape(True)
+    if full == local:
+        return full, sliding
+    widths = tuple(attn[w].shape[-1] for w in ("wq", "wk", "wv"))
+    for (nq, nkv, hd, vd), is_local in ((full, False), (local, True)):
+        if widths == (nq * hd, nkv * hd, nkv * vd):
+            return (nq, nkv, hd, vd), is_local
+    raise ValueError(
+        f"attention projections of widths {widths} fit neither the full "
+        f"{full} nor the local {local} (heads, kv heads, qk, v) shape"
+    )
+
+
 @jax.named_scope("qkv")
-def _qkv(attn: Params, cfg: LlamaConfig, x: jax.Array):
-    """x: [..., L, D] -> q [..., L, n_q, hd], k/v [..., L, n_kv, hd]."""
-    hd = cfg.head_dim
-    q = _lin(x, attn, "wq", "bq").reshape(*x.shape[:-1], cfg.num_attention_heads, hd)
-    k = _lin(x, attn, "wk", "bk").reshape(*x.shape[:-1], cfg.num_key_value_heads, hd)
-    v = _lin(x, attn, "wv", "bv").reshape(*x.shape[:-1], cfg.num_key_value_heads, hd)
+def _qkv(attn: Params, cfg: LlamaConfig, x: jax.Array, shape=None):
+    """x: [..., L, D] -> q [..., L, n_q, hd], k [..., L, n_kv, hd],
+    v [..., L, n_kv, vd]. ``shape``: the layer kind's (heads, kv heads, qk
+    dim, v dim) from ``layer_kind``; None = the config's one shape."""
+    nq, nkv, hd, vd = shape or cfg.attn_shape()
+    q = _lin(x, attn, "wq", "bq").reshape(*x.shape[:-1], nq, hd)
+    k = _lin(x, attn, "wk", "bk").reshape(*x.shape[:-1], nkv, hd)
+    v = _lin(x, attn, "wv", "bv").reshape(*x.shape[:-1], nkv, vd)
     if "q_norm" in attn:
         # Per-head-dim RMSNorm on q/k, pre-RoPE (Qwen3 llama-style; Gemma3
         # (1+w)-style — the family's norm_unit_offset covers both).
@@ -168,8 +192,11 @@ def positioned_qkv(
     rope key, distinct value dim)."""
     if cfg.kv_lora_rank:
         return _qkv_mla(params["attn"], cfg, h, positions, total_len)
-    q, k, v = _qkv(params["attn"], cfg, h)
+    shape, sliding = layer_kind(cfg, params["attn"], sliding)
+    q, k, v = _qkv(params["attn"], cfg, h, shape)
     q, k = position_qk(cfg, q, k, positions, sliding, rope_on, total_len)
+    if cfg.attn_value_scale is not None:
+        v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
     return q, k, v
 
 
@@ -266,16 +293,29 @@ def _llama4_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     return shared + routed
 
 
-def _deepseek_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
+def _deepseek_moe_mlp(
+    mlp: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None
+) -> jax.Array:
     """DeepSeek-V3 MoE (DeepseekV3MoE/TopkRouter): fp32 sigmoid scores;
     SELECTION adds a trained correction bias and is group-limited (experts
     partition into n_group groups, each scored by its top-2 sum, only the
     best topk_group groups stay eligible) — the combine WEIGHTS come from
     the unbiased scores, renormalised (+1e-20) iff norm_topk_prob and
     scaled by routed_scaling_factor. A shared expert
-    (n_shared_experts x the routed width) adds unconditionally. Same
-    compute-all stacked-einsum layout as the Mixtral path."""
-    e, k = cfg.num_local_experts, cfg.num_experts_per_tok
+    (n_shared_experts x the routed width) adds where the weights have one
+    (MiMo-V2 has none). Same compute-all stacked-einsum layout as the
+    Mixtral path.
+
+    The layer routes over the router's width and computes over the experts
+    it HOLDS, the stacked arrays' leading axis: with fewer held than routed
+    (expert parallelism's share, ``cfg.held_experts``) it takes the held
+    ids' columns of the combine weights and returns its own experts' part of
+    the layer's result; what the absent experts would add is another
+    process's to compute. ``stats`` (a list): gets one int32 [2] array,
+    (assignments that landed on a held expert, all assignments), over every
+    row of ``x``."""
+    e, k = mlp["router"].shape[-1], cfg.num_experts_per_tok
+    held = mlp["gate"].shape[0]
     g = cfg.moe_n_group
     with jax.named_scope("moe_router"):
         logits = jnp.einsum(
@@ -306,6 +346,19 @@ def _deepseek_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
             jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_w[..., None],
             axis=-2,
         ).astype(x.dtype)  # [..., L, E]
+        if held != e:
+            ids = cfg.held_experts
+            if len(ids) != held:
+                raise ValueError(
+                    f"expert layer holds {held} experts, the config's share is "
+                    f"{len(ids)} of {e}"
+                )
+            combine = combine[..., ids.start : ids.stop]  # [..., L, held]
+            if stats is not None:
+                hits = (top_idx >= ids.start) & (top_idx < ids.stop)
+                stats.append(
+                    jnp.stack([hits.sum(), jnp.asarray(top_idx.size)]).astype(jnp.int32)
+                )
     act = _ACT[cfg.hidden_act]
     with jax.named_scope("moe_experts"):
         h = act(
@@ -316,6 +369,8 @@ def _deepseek_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
         routed = jnp.einsum(
             "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
         )
+    if "shared_gate" not in mlp:
+        return routed
     with jax.named_scope("moe_shared_experts"):
         shared = _mm(
             act(_mm(x, mlp["shared_gate"])) * _mm(x, mlp["shared_up"]),
@@ -324,10 +379,13 @@ def _deepseek_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     return routed + shared
 
 
-def _mlp(mlp: Params, x: jax.Array, cfg: LlamaConfig | None = None) -> jax.Array:
+def _mlp(
+    mlp: Params, x: jax.Array, cfg: LlamaConfig | None = None,
+    stats: list | None = None,
+) -> jax.Array:
     if "correction_bias" in mlp:
         assert cfg is not None and cfg.num_local_experts > 0
-        return _deepseek_moe_mlp(mlp, cfg, x)
+        return _deepseek_moe_mlp(mlp, cfg, x, stats)
     if "shared_gate" in mlp:
         assert cfg is not None and cfg.num_local_experts > 0
         return _llama4_moe_mlp(mlp, cfg, x)
@@ -351,7 +409,9 @@ def _residual_attn(params: Params, cfg: LlamaConfig, x: jax.Array, attn_out) -> 
     return x + y
 
 
-def _residual_mlp(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
+def _residual_mlp(
+    params: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None
+) -> jax.Array:
     """Residual add of the MLP sublayer. Standard layout norms the input
     with post_attention_layernorm; Gemma2 norms input AND output with the
     pre/post_feedforward_layernorms."""
@@ -361,7 +421,7 @@ def _residual_mlp(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
         else "post_attention_layernorm"
     )
     h = rms_norm(x, params[pre]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-    y = _mlp(params["mlp"], h, cfg)
+    y = _mlp(params["mlp"], h, cfg, stats)
     if cfg.ffw_sandwich_norms:
         y = rms_norm(
             y,
@@ -404,7 +464,14 @@ def position_qk(cfg: LlamaConfig, q, k, positions, sliding, rope_on, total_len=N
     """
     cos, sin = rope_for_layer(cfg, positions, sliding, total_len)
     rot = apply_rope_interleaved if cfg.rope_interleaved else apply_rope
-    q_r, k_r = rot(q, cos, sin), rot(k, cos, sin)
+    rd = cfg.rotary_dim
+    if rd is not None and rd < q.shape[-1]:
+        # Partial rotary: the first rd dims of a head rotate (among
+        # themselves), the rest pass through.
+        q_r = jnp.concatenate([rot(q[..., :rd], cos, sin), q[..., rd:]], axis=-1)
+        k_r = jnp.concatenate([rot(k[..., :rd], cos, sin), k[..., rd:]], axis=-1)
+    else:
+        q_r, k_r = rot(q, cos, sin), rot(k, cos, sin)
     if cfg.qk_l2_norm:
         # HF builds Llama4TextL2Norm with config.rms_norm_eps.
         q_r = _l2_norm(q_r, cfg.rms_norm_eps)
@@ -446,16 +513,17 @@ def rope_for_layer(cfg: LlamaConfig, positions: jax.Array, sliding, total_len=No
     per-layer choice, traced bool = select between the two static tables
     (both tiny) inside the scan program. ``total_len``: longrope's dynamic
     long/short selector (only the scaled global table uses it)."""
+    dim = cfg.rotary_dim or cfg.head_dim
     if cfg.rope_local_theta is None:
         return rope_cos_sin(
-            positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling_spec,
+            positions, dim, cfg.rope_theta, cfg.rope_scaling_spec,
             total_len=total_len,
         )
     cos_g, sin_g = rope_cos_sin(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling_spec,
+        positions, dim, cfg.rope_theta, cfg.rope_scaling_spec,
         total_len=total_len,
     )
-    cos_l, sin_l = rope_cos_sin(positions, cfg.head_dim, cfg.rope_local_theta, None)
+    cos_l, sin_l = rope_cos_sin(positions, dim, cfg.rope_local_theta, None)
     if sliding is None:
         sliding = cfg.sliding_window is not None
     if isinstance(sliding, bool):
@@ -480,6 +548,40 @@ def _effective_window(cfg: LlamaConfig, sliding) -> tuple[int | None, int | None
             return None, None, None
         return window, chunk, None
     return window, chunk, sliding
+
+
+def _attn_kind(cfg: LlamaConfig, params: Params, sliding):
+    """``layer_kind`` for any family: MLA's effective shape is one KV head
+    per query head (positioned_qkv hands the kernels per-head decompressed
+    K and V), whatever the config's num_key_value_heads says."""
+    if cfg.kv_lora_rank:
+        nh = cfg.num_attention_heads
+        return (nh, nh, cfg.head_dim, cfg.v_dim), sliding
+    return layer_kind(cfg, params["attn"], sliding)
+
+
+def _attention_scope(cfg: LlamaConfig, sliding):
+    """The layer's ``attention`` scope, with the layer's kind inside it
+    (``attention_window`` / ``attention_full``) where the kind is static: a
+    traced per-layer flag (one scan over both kinds) has no one name."""
+    if sliding is None:
+        local = cfg.sliding_window is not None or cfg.attention_chunk_size is not None
+    elif isinstance(sliding, bool):
+        local = sliding
+    else:
+        return jax.named_scope("attention")
+    stack = contextlib.ExitStack()
+    stack.enter_context(jax.named_scope("attention"))
+    stack.enter_context(
+        jax.named_scope("attention_window" if local else "attention_full")
+    )
+    return stack
+
+
+def _moe_counts(stats: list) -> jax.Array:
+    """Sum of a layer's ``_deepseek_moe_mlp`` stats: int32 [2], zeros for a
+    layer that routed nothing over held experts."""
+    return sum(stats, jnp.zeros((2,), jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -517,7 +619,8 @@ def decoder_layer(
     h = rms_norm(x, params["input_layernorm"]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
     q, k, v = positioned_qkv(params, cfg, h, positions, sliding, rope_on, total_len)
     attn_out = attention(
-        q, k, v, mask, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap
+        q, k, v, mask, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
+        sink=params["attn"].get("sink"),
     )
     x = _residual_attn(params, cfg, x, attn_out)
     return _residual_mlp(params, cfg, x)
@@ -597,6 +700,7 @@ def prefix_suffix_layer(
     rope_on=None,
     tp_mesh=None,
     total_len=None,
+    moe_stats: bool = False,
 ) -> tuple[jax.Array, ...]:
     """One decoder layer over a (prefix, suffixes) prompt — the streaming hot op.
 
@@ -620,11 +724,18 @@ def prefix_suffix_layer(
     ``use_pallas`` (static) swaps both attention ops for the Pallas flash
     kernels (ops/pallas_attention.py) when the shapes are eligible — same
     semantics, no [Lq, Lk] score materialisation.
+
+    ``moe_stats`` (static): one more output at the end, int32 [2]: the
+    layer's (assignments on held experts, all assignments) where the expert
+    layer holds a share of its experts, zeros elsewhere.
     """
     lp, _ = prefix_h.shape
     s, ls, _ = suffix_h.shape
     eps = cfg.rms_norm_eps
-    rope_sliding = sliding  # rope base selection survives the window shortcut
+    (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
+    sink = params["attn"].get("sink")
+    stats = [] if moe_stats else None
+    rope_sliding = sliding  # rope base and scope survive the window shortcut
     window, chunk, sliding = _effective_window(cfg, sliding)
     if (window is not None and lp + ls <= window) or (
         chunk is not None and lp + ls <= chunk
@@ -649,19 +760,12 @@ def prefix_suffix_layer(
     # carry q/k's head dim and V's own dim independently (QK^T over
     # head_dim, PV over v_dim) — positioned_qkv hands them per-head
     # decompressed K (nope + shared rope key) and V, so the EFFECTIVE kv
-    # head count is the attention head count (GQA ratio 1), whatever the
-    # config's num_key_value_heads field says.
-    n_kv_eff = (
-        cfg.num_attention_heads if cfg.kv_lora_rank else cfg.num_key_value_heads
-    )
+    # head count is the attention head count (GQA ratio 1: _attn_kind).
     flash = use_pallas and pallas_attention.supports(
-        cfg.num_attention_heads // tp_size,
-        n_kv_eff // tp_size,
-        cfg.head_dim,
-        ls,
-        lp,
-        v_dim=cfg.v_dim,
+        n_q // tp_size, n_kv // tp_size, hd, ls, lp, v_dim=vd
     )
+    if sink is not None and tp_mesh is not None:
+        flash = False  # the head-sharded kernels carry no sink yet (XLA op)
 
     # --- prefix: causal self-attention, keep post-RoPE KV ---
     h = rms_norm(prefix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
@@ -677,14 +781,14 @@ def prefix_suffix_layer(
             chunk=chunk,
             softcap=cfg.attn_logit_softcap,
         )
-    with jax.named_scope("attention"):
+    with _attention_scope(cfg, rope_sliding):
         if flash and tp_mesh is not None:
             attn_out = _flash_tp_causal(
                 tp_mesh, q, k, v, prefix_len, sliding, flash_kw
             )
         elif flash:
             attn_out = pallas_attention.flash_causal_attention(
-                q, k, v, prefix_len, local_on=sliding, **flash_kw
+                q, k, v, prefix_len, local_on=sliding, sink=sink, **flash_kw
             )
         else:
             if sliding is None:
@@ -697,10 +801,10 @@ def prefix_suffix_layer(
                 )
             attn_out = attention(
                 q, k, v, mask, scale=cfg.attn_scale,
-                softcap=cfg.attn_logit_softcap,
+                softcap=cfg.attn_logit_softcap, sink=sink,
             )
     prefix_mid = _residual_attn(params, cfg, prefix_h, attn_out)
-    prefix_out = _residual_mlp(params, cfg, prefix_mid)
+    prefix_out = _residual_mlp(params, cfg, prefix_mid, stats)
 
     # --- suffixes: batched attention over [shared prefix KV ; own causal KV],
     # prefix KV never expanded across suffixes (ops.prefix_shared_attention) ---
@@ -710,14 +814,15 @@ def prefix_suffix_layer(
         params, cfg, hs, pos_s, rope_sliding, rope_on, total_len
     )
 
-    with jax.named_scope("attention"):
+    with _attention_scope(cfg, rope_sliding):
         if flash and tp_mesh is not None:
             attn_s = _flash_tp_prefix_shared(
                 tp_mesh, qs, k, v, ks, vs, prefix_len, sliding, flash_kw
             )
         elif flash:
             attn_s = pallas_attention.flash_prefix_shared_attention(
-                qs, k, v, ks, vs, prefix_len, local_on=sliding, **flash_kw
+                qs, k, v, ks, vs, prefix_len, local_on=sliding, sink=sink,
+                **flash_kw,
             )
         else:
             attn_s = prefix_shared_attention(
@@ -732,13 +837,17 @@ def prefix_suffix_layer(
                 softcap=cfg.attn_logit_softcap,
                 sliding=sliding,
                 chunk=chunk,
+                sink=sink,
             )
     suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s)
-    suffix_out = _residual_mlp(params, cfg, suffix_mid)
+    suffix_out = _residual_mlp(params, cfg, suffix_mid, stats)
+    out = (prefix_out, suffix_out)
     if return_kv:
         # Post-RoPE KV, reusable across decode steps (runtime/decode.py).
-        return prefix_out, suffix_out, {"kp": k, "vp": v, "ks": ks, "vs": vs}
-    return prefix_out, suffix_out
+        out += ({"kp": k, "vp": v, "ks": ks, "vs": vs},)
+    if moe_stats:
+        out += (_moe_counts(stats),)
+    return out
 
 
 def suffix_only_layer(
@@ -772,7 +881,9 @@ def suffix_only_layer(
     lp = kp.shape[0]
     s, ls, _ = suffix_h.shape
     eps = cfg.rms_norm_eps
-    rope_sliding = sliding  # rope base selection survives the window shortcut
+    (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
+    sink = params["attn"].get("sink")
+    rope_sliding = sliding  # rope base and scope survive the window shortcut
     window, chunk, sliding = _effective_window(cfg, sliding)
     if (window is not None and lp + ls <= window) or (
         chunk is not None and lp + ls <= chunk
@@ -781,17 +892,11 @@ def suffix_only_layer(
         # local mask equals full causal, so drop it (keeps flash eligible).
         window = chunk = sliding = None
     tp_size = tp_mesh.shape["tp"] if tp_mesh is not None else 1
-    n_kv_eff = (
-        cfg.num_attention_heads if cfg.kv_lora_rank else cfg.num_key_value_heads
-    )
     flash = use_pallas and pallas_attention.supports(
-        cfg.num_attention_heads // tp_size,
-        n_kv_eff // tp_size,
-        cfg.head_dim,
-        ls,
-        lp,
-        v_dim=cfg.v_dim,
+        n_q // tp_size, n_kv // tp_size, hd, ls, lp, v_dim=vd
     )
+    if sink is not None and tp_mesh is not None:
+        flash = False  # the head-sharded kernels carry no sink yet (XLA op)
 
     hs = rms_norm(suffix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
     pos_s = prefix_len + jnp.arange(ls)
@@ -799,7 +904,7 @@ def suffix_only_layer(
         params, cfg, hs, pos_s, rope_sliding, rope_on, total_len
     )
 
-    with jax.named_scope("attention"):
+    with _attention_scope(cfg, rope_sliding):
         if flash:
             flash_kw = dict(
                 scale=cfg.attn_scale,
@@ -813,7 +918,8 @@ def suffix_only_layer(
                 )
             else:
                 attn_s = pallas_attention.flash_prefix_shared_attention(
-                    qs, kp, vp, ks, vs, prefix_len, local_on=sliding, **flash_kw
+                    qs, kp, vp, ks, vs, prefix_len, local_on=sliding,
+                    sink=sink, **flash_kw,
                 )
         else:
             attn_s = prefix_shared_attention(
@@ -828,6 +934,7 @@ def suffix_only_layer(
                 softcap=cfg.attn_logit_softcap,
                 sliding=sliding,
                 chunk=chunk,
+                sink=sink,
             )
     suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s)
     suffix_out = _residual_mlp(params, cfg, suffix_mid)
@@ -863,6 +970,8 @@ def decode_step_layer(
     (``tp_mesh``) the kernel runs per head-shard via shard_map.
     """
     eps = cfg.rms_norm_eps
+    (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
+    sink = params["attn"].get("sink")
     rope_sliding = sliding
     kq = x.shape[1]
     base = jnp.asarray(t, jnp.int32)
@@ -897,11 +1006,13 @@ def decode_step_layer(
 
     window, chunk, sliding = _effective_window(cfg, sliding)
     tp_size = tp_mesh.shape["tp"] if tp_mesh is not None else 1
-    with jax.named_scope("attention"):
-        if use_pallas and not cfg.kv_lora_rank and kq == 1 and base.ndim == 0 and pallas_attention.supports_decode(
-            cfg.num_attention_heads // tp_size,
-            cfg.num_key_value_heads // tp_size,
-            cfg.head_dim,
+    with _attention_scope(cfg, rope_sliding):
+        if (
+            use_pallas and not cfg.kv_lora_rank and kq == 1 and base.ndim == 0
+            and not (sink is not None and tp_mesh is not None)
+            and pallas_attention.supports_decode(
+                n_q // tp_size, n_kv // tp_size, hd, v_dim=vd
+            )
         ):
             flash_kw = dict(
                 scale=cfg.attn_scale,
@@ -928,6 +1039,7 @@ def decode_step_layer(
                     suffix_eos,
                     t,
                     local_on=sliding,
+                    sink=sink,
                     **flash_kw,
                 )
         else:
@@ -947,6 +1059,7 @@ def decode_step_layer(
                 softcap=cfg.attn_logit_softcap,
                 sliding=sliding,
                 chunk=chunk,
+                sink=sink,
             )
     mid = _residual_attn(params, cfg, x, attn_out)
     return _residual_mlp(params, cfg, mid), kv
@@ -1063,9 +1176,13 @@ def forward_full(
 # Initialisation (tests / training-from-scratch)
 # ---------------------------------------------------------------------------
 
-def init_layer_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Params:
-    d, f, hd = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
-    nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
+def init_layer_params(
+    rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32, sliding: bool = False
+) -> Params:
+    """``sliding``: the layer's kind, for a model whose local layers have
+    their own attention shape or sink (``cfg.attn_shape``)."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    nq, nkv, hd, vd = cfg.attn_shape(sliding)
     ks = jax.random.split(rng, 14)
 
     def lin(key, fan_in, fan_out):
@@ -1105,14 +1222,18 @@ def init_layer_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Pa
         attn = {
             "wq": lin(ks[0], d, nq * hd),
             "wk": lin(ks[1], d, nkv * hd),
-            "wv": lin(ks[2], d, nkv * hd),
-            "wo": lin(ks[3], nq * hd, d),
+            "wv": lin(ks[2], d, nkv * vd),
+            "wo": lin(ks[3], nq * vd, d),
         }
+        if cfg.attn_sink_local if sliding else cfg.attn_sink_global:
+            attn["sink"] = jax.random.normal(
+                jax.random.fold_in(rng, 77), (nq,)
+            ).astype(dtype)
     if cfg.attention_in_bias and not cfg.kv_lora_rank:
         attn |= {
             "bq": bias(ks[7], nq * hd),
             "bk": bias(ks[8], nkv * hd),
-            "bv": bias(ks[9], nkv * hd),
+            "bv": bias(ks[9], nkv * vd),
         }
     if cfg.attention_out_bias:
         attn["bo"] = bias(ks[10], d)
@@ -1122,8 +1243,12 @@ def init_layer_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Pa
         e = cfg.num_local_experts
 
         def elin(key, fan_in, fan_out):
+            # Every expert is drawn, the held ones kept: a share's weights
+            # are the uncut layer's own (the held ids' slices).
             scale = (2.0 / (fan_in + fan_out)) ** 0.5
-            return (jax.random.normal(key, (e, fan_in, fan_out)) * scale).astype(dtype)
+            held = cfg.held_experts
+            w = jax.random.normal(key, (e, fan_in, fan_out)) * scale
+            return w[held.start : held.stop].astype(dtype)
 
         mlp = {
             "router": lin(ks[4], d, e),
@@ -1171,8 +1296,18 @@ def init_mixed_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Pa
     moe_cfg = dataclasses.replace(cfg, moe_layer_pattern=None)
     keys = jax.random.split(rng, cfg.num_hidden_layers)
     layers = []
+    sliding = layer_sliding_pattern(cfg)
     for i, is_moe in enumerate(cfg.moe_layer_pattern):
-        lp = init_layer_params(keys[i], moe_cfg if is_moe else dense_cfg, dtype)
+        lp = init_layer_params(
+            keys[i], moe_cfg if is_moe else dense_cfg, dtype, sliding=sliding[i]
+        )
+        if is_moe and cfg.model_type == "mimo_v2_flash":
+            # The DeepSeek router's correction bias, no shared expert.
+            lp["mlp"]["correction_bias"] = (
+                jax.random.normal(
+                    jax.random.fold_in(keys[i], 99), (cfg.num_local_experts,)
+                ) * 0.1
+            ).astype(jnp.float32)
         if is_moe and cfg.model_type in ("llama4_text", "deepseek_v3"):
             d, f = cfg.hidden_size, cfg.intermediate_size
             if cfg.model_type == "deepseek_v3":

@@ -37,6 +37,20 @@ def _softcap(scores: jax.Array, cap: float | None) -> jax.Array:
     return jnp.tanh(scores / cap) * cap
 
 
+def _softmax_with_sink(scores: jax.Array, sink, n_kv: int) -> jax.Array:
+    """Softmax over the last axis of ``scores`` [..., n_kv, g, Lq, Lk] (fp32).
+    ``sink`` [n_q] (or None) is a learned per-head logit that joins the
+    denominator and carries no value: one more column in the softmax, dropped
+    again afterwards (MiMo-V2's ``attention_sink_bias``; HF concatenates it
+    the same way)."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    col = jnp.broadcast_to(
+        sink.astype(jnp.float32).reshape(n_kv, -1, 1, 1), (*scores.shape[:-1], 1)
+    )
+    return jax.nn.softmax(jnp.concatenate([scores, col], axis=-1), axis=-1)[..., :-1]
+
+
 def _local_clause(
     mask: jax.Array,
     q_pos: jax.Array,
@@ -71,11 +85,13 @@ def attention(
     mask: jax.Array | None,
     scale: float | None = None,
     softcap: float | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Scaled dot-product attention with GQA via grouped einsums.
 
     q: [..., Lq, n_q, hd]; k, v: [..., Lk, n_kv, hd] with n_q % n_kv == 0.
     mask: broadcastable to [..., Lq, Lk]; True = attend, False = masked.
+    sink: optional per-head logit [n_q] in the softmax's denominator.
     Returns [..., Lq, n_q, hd].
 
     KV heads are never replicated in memory (no jnp.repeat): queries are
@@ -92,7 +108,7 @@ def attention(
     scores = _softcap(scores.astype(jnp.float32) * scale, softcap)
     if mask is not None:
         scores = jnp.where(mask[..., None, None, :, :], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = _softmax_with_sink(scores, sink, n_kv).astype(q.dtype)
     out = jnp.einsum("...ngqk,...knh->...qngh", probs, v, precision=_PRECISION)
     # V's own head dim (MLA: v_head_dim != qk head dim).
     return out.reshape(*q.shape[:-1], v.shape[-1])
@@ -110,6 +126,7 @@ def prefix_shared_attention(
     softcap: float | None = None,
     sliding=None,
     chunk: int | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Attention of S suffix continuations over [shared prefix KV ; own causal KV].
 
@@ -122,7 +139,8 @@ def prefix_shared_attention(
 
     q: [S, Ls, n_q, hd] (RoPE already applied at positions prefix_len+i);
     k_prefix/v_prefix: [Lp, n_kv, hd]; k_suffix/v_suffix: [S, Ls, n_kv, hd];
-    prefix_len: int32 scalar — prefix keys at j >= prefix_len are padding.
+    prefix_len: int32 scalar — prefix keys at j >= prefix_len are padding;
+    sink: optional per-head logit [n_q] in the softmax's denominator.
     Returns [S, Ls, n_q, hd].
     """
     s, ls, n_q, hd = q.shape
@@ -150,7 +168,7 @@ def prefix_shared_attention(
         mask = _local_clause(mask, prefix_len + qi, abs_k, window, sliding, chunk)
     scores = jnp.where(mask[None, None, None], scores, _NEG_INF)
 
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = _softmax_with_sink(scores, sink, n_kv).astype(q.dtype)
     probs_p, probs_s = probs[..., :lp], probs[..., lp:]
     out = jnp.einsum("sngqk,knh->sqngh", probs_p, v_prefix, precision=_PRECISION)
     out = out + jnp.einsum(
@@ -175,6 +193,7 @@ def decode_attention(
     softcap: float | None = None,
     sliding=None,
     chunk: int | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Decode attention over three cached KV regions, one joint softmax.
 
@@ -194,7 +213,8 @@ def decode_attention(
     k/v_gen [S, T, n_kv, hd] (slots t..t+K-1 already hold this step's KV);
     prefix_len int32 scalar; t: int32 scalar or per-suffix [S] (speculative
     passes advance each suffix by its own accepted count); suffix_eos int32
-    [S]. Returns [S, K, n_q, hd].
+    [S]; sink: optional per-head logit [n_q] in the softmax's denominator.
+    Returns [S, K, n_q, hd].
     """
     s, kq, n_q, hd = q.shape
     n_kv = k_prefix.shape[-2]
@@ -252,7 +272,7 @@ def decode_attention(
         )
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
 
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = _softmax_with_sink(scores, sink, n_kv).astype(q.dtype)
     pp, ps, pg = (
         probs[..., :lp],
         probs[..., lp : lp + ls],

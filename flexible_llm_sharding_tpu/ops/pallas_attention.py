@@ -34,6 +34,12 @@ Model-family envelope (mirrors the XLA ops' full surface):
 - ``local_on`` — the per-layer local-attention toggle (Gemma2/3, Llama4
   alternation under one ``lax.scan`` program): a traced bool that rides the
   scalar-prefetch channel next to ``prefix_len``.
+- ``sink`` — an optional learned logit per query head (MiMo-V2's window
+  layers) that joins the softmax's denominator and carries no value: it is
+  where the online softmax STARTS (running max = sink, denominator = 1,
+  accumulator = 0), so no block sees it again. It rides a second
+  scalar-prefetch operand (float32 [n_q]); ``None`` leaves the kernels as
+  they are without one.
 
 Shape eligibility is checked by :func:`supports` / :func:`supports_decode`;
 callers fall back to the XLA path otherwise. Ragged head dims >= 64 (phi3's
@@ -123,6 +129,18 @@ def _online_block(q, kb, vb, mask, m, l, acc, scale, softcap=None):
     return m_new, l, acc
 
 
+def _start(rows: int, dv: int, sink=None):
+    """The online softmax's initial (m, l, acc) for ``rows`` query rows.
+    ``sink``: None, or the rows' sink logits (a scalar or [rows, 1] fp32):
+    exp(sink - m) = 1 is already in the denominator."""
+    acc = jnp.zeros((rows, dv), jnp.float32)
+    if sink is None:
+        m = jnp.full((rows, 1), _NEG_INF, jnp.float32)
+        return m, jnp.zeros((rows, 1), jnp.float32), acc
+    m = jnp.broadcast_to(jnp.asarray(sink, jnp.float32), (rows, 1))
+    return m, jnp.ones((rows, 1), jnp.float32), acc
+
+
 def _finish(l, acc, dtype):
     """acc / l with fully-masked rows (padding queries) zeroed."""
     return jnp.where(l > 0, acc / jnp.maximum(l, 1e-30), 0.0).astype(dtype)
@@ -159,9 +177,9 @@ def _local_start_block(first_q_pos, window, chunk, bk, local_on):
 # ---------------------------------------------------------------------------
 
 def _causal_kernel(
-    flags_ref, q_ref, k_ref, v_ref, o_ref, *, scale, lk, bk, window, chunk,
-    softcap,
+    flags_ref, *refs, scale, lk, bk, window, chunk, softcap, has_sink,
 ):
+    sink_ref, (q_ref, k_ref, v_ref, o_ref) = _split_sink(refs, has_sink)
     # Head-major blocks: q_ref [1, bq, hd]; k_ref [1, lk, hd]; v_ref
     # [1, lk, dv] (dv == hd except MLA, where V has its own head dim). The
     # TPU lowering constrains only the last two block dims, so the head axis
@@ -174,9 +192,9 @@ def _causal_kernel(
     local_on = flags_ref[1] != 0
     qi = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
-    m = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, dv), jnp.float32)
+    m, l, acc = _start(
+        bq, dv, None if sink_ref is None else sink_ref[pl.program_id(0)]
+    )
 
     def body(blk, carry):
         m, l, acc = carry
@@ -203,6 +221,20 @@ def _causal_kernel(
     o_ref[0] = _finish(l, acc, o_ref.dtype)
 
 
+def _split_sink(refs, has_sink: bool):
+    """(sink_ref or None, the other refs): the sink, when there is one, is
+    the second scalar-prefetch operand and so the first of ``refs``."""
+    return (refs[0], refs[1:]) if has_sink else (None, refs)
+
+
+def _prefetch(flags, sink) -> tuple:
+    """The scalar-prefetch operands: the int32 flags, then the float32
+    per-head sink logits when the layer has them."""
+    if sink is None:
+        return (flags,)
+    return (flags, jnp.asarray(sink, jnp.float32))
+
+
 def _flags(prefix_len, local_on) -> jax.Array:
     """Scalar-prefetch payload: [prefix_len, local_on] int32. ``local_on``
     None means the static local form (if any) applies unconditionally."""
@@ -218,13 +250,14 @@ def _flags(prefix_len, local_on) -> jax.Array:
 )
 def flash_causal_attention(
     q, k, v, valid_len, scale=None, window=None, chunk=None, softcap=None,
-    local_on=None, interpret=None,
+    local_on=None, interpret=None, sink=None,
 ):
     """q [L, n_q, hd], k [L, n_kv, hd], v [L, n_kv, dv], valid_len int32
     scalar -> [L, n_q, dv] (dv == hd everywhere but MLA, whose V has its
     own head dim). Query i attends keys j with j <= i and j < valid_len,
     optionally restricted to a sliding ``window`` / position ``chunk``
-    (``local_on``: traced per-layer toggle, None = on)."""
+    (``local_on``: traced per-layer toggle, None = on). ``sink`` [n_q]: a
+    logit per head in the softmax's denominator."""
     if interpret is None:
         # Auto: compiled on real TPU, interpreter elsewhere (lets the CPU
         # test mesh exercise the kernels end-to-end, incl. under shard_map).
@@ -240,11 +273,12 @@ def flash_causal_attention(
     bq = _block(lq, _MAX_BLOCK_Q)
     bk = _block(lk, _MAX_BLOCK_K)
     grid = (n_q, lq // bq)
-    kv_head = lambda h, qb, flags: (h * n_kv // n_q, 0, 0)
+    kv_head = lambda h, qb, *_: (h * n_kv // n_q, 0, 0)
+    prefetch = _prefetch(_flags(valid_len, local_on), sink)
 
     kernel = functools.partial(
         _causal_kernel, scale=scale, lk=lk, bk=bk, window=window, chunk=chunk,
-        softcap=softcap,
+        softcap=softcap, has_sink=sink is not None,
     )
     # Named three times over. The TPU names the HLO instruction after the
     # innermost scope of its ``op_name``, and under vmap the call runs in
@@ -258,21 +292,21 @@ def flash_causal_attention(
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=len(prefetch),
                 grid=grid,
                 in_specs=[
-                    pl.BlockSpec((1, bq, hd), lambda h, qb, flags: (h, qb, 0)),
+                    pl.BlockSpec((1, bq, hd), lambda h, qb, *_: (h, qb, 0)),
                     pl.BlockSpec((1, lk, hd), kv_head),
                     pl.BlockSpec((1, lk, dv), kv_head),
                 ],
-                out_specs=pl.BlockSpec((1, bq, dv), lambda h, qb, flags: (h, qb, 0)),
+                out_specs=pl.BlockSpec((1, bq, dv), lambda h, qb, *_: (h, qb, 0)),
             ),
             out_shape=jax.ShapeDtypeStruct((n_q, lq, dv), q.dtype),
             interpret=interpret,
             name="flash_causal_attention",
             metadata={"kernel": "flash_causal_attention"},
         )(
-            _flags(valid_len, local_on),
+            *prefetch,
             q.transpose(1, 0, 2),
             k.transpose(1, 0, 2),
             v.transpose(1, 0, 2),
@@ -285,9 +319,11 @@ def flash_causal_attention(
 # ---------------------------------------------------------------------------
 
 def _prefix_shared_kernel(
-    flags_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref, *, scale, lp,
-    bkp, window, chunk, softcap,
+    flags_ref, *refs, scale, lp, bkp, window, chunk, softcap, has_sink,
 ):
+    sink_ref, (q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref) = _split_sink(
+        refs, has_sink
+    )
     # Head-major blocks: q_ref [1, 1, bq, hd]; kp_ref [1, lp, hd]; vp_ref
     # [1, lp, dv]; ks_ref [1, 1, ls, hd]; vs_ref [1, 1, ls, dv] (dv == hd
     # except MLA, where V has its own head dim).
@@ -302,9 +338,9 @@ def _prefix_shared_kernel(
     # j at j; suffix key j at prefix_len + j (ops.attention convention).
     q_abs = plen + qi
 
-    m = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, dv), jnp.float32)
+    m, l, acc = _start(
+        bq, dv, None if sink_ref is None else sink_ref[pl.program_id(1)]
+    )
 
     # Prefix KV: visible iff the key is real (j < plen); no causality.
     def p_body(blk, carry):
@@ -348,6 +384,7 @@ def _prefix_shared_kernel(
 def flash_prefix_shared_attention(
     q, k_prefix, v_prefix, k_suffix, v_suffix, prefix_len, scale=None,
     window=None, chunk=None, softcap=None, local_on=None, interpret=None,
+    sink=None,
 ):
     """Kernel form of ``ops.attention.prefix_shared_attention``.
 
@@ -356,7 +393,8 @@ def flash_prefix_shared_attention(
     [S, Ls, n_kv, dv]; prefix_len int32 scalar. dv == hd everywhere but
     MLA, whose V has its own head dim.
     ``window``/``chunk``/``softcap``/``scale`` mirror the XLA op;
-    ``local_on`` is the traced per-layer local toggle (None = on).
+    ``local_on`` is the traced per-layer local toggle (None = on);
+    ``sink`` [n_q] a logit per head in the softmax's denominator.
     Returns [S, Ls, n_q, dv].
     """
     if interpret is None:
@@ -372,19 +410,20 @@ def flash_prefix_shared_attention(
     bq = _block(ls, _MAX_BLOCK_Q)
     bkp = _block(lp, _MAX_BLOCK_K)
     grid = (s, n_q, ls // bq)
-    kv_head = lambda si, h, qb, flags: (h * n_kv // n_q, 0, 0)
-    skv_head = lambda si, h, qb, flags: (si, h * n_kv // n_q, 0, 0)
-    q_map = lambda si, h, qb, flags: (si, h, qb, 0)
+    kv_head = lambda si, h, qb, *_: (h * n_kv // n_q, 0, 0)
+    skv_head = lambda si, h, qb, *_: (si, h * n_kv // n_q, 0, 0)
+    q_map = lambda si, h, qb, *_: (si, h, qb, 0)
+    prefetch = _prefetch(_flags(prefix_len, local_on), sink)
 
     kernel = functools.partial(
         _prefix_shared_kernel, scale=scale, lp=lp, bkp=bkp, window=window,
-        chunk=chunk, softcap=softcap,
+        chunk=chunk, softcap=softcap, has_sink=sink is not None,
     )
     with jax.named_scope("flash_prefix_shared_attention"):
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=len(prefetch),
                 grid=grid,
                 in_specs=[
                     pl.BlockSpec((1, 1, bq, hd), q_map),
@@ -400,7 +439,7 @@ def flash_prefix_shared_attention(
             name="flash_prefix_shared_attention",
             metadata={"kernel": "flash_prefix_shared_attention"},
         )(
-            _flags(prefix_len, local_on),
+            *prefetch,
             q.transpose(0, 2, 1, 3),
             k_prefix.transpose(1, 0, 2),
             v_prefix.transpose(1, 0, 2),
@@ -415,14 +454,17 @@ def flash_prefix_shared_attention(
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(
-    flags_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref, kg_ref, vg_ref, o_ref,
-    *, scale, lp, bkp, window, chunk, softcap,
+    flags_ref, *refs, scale, lp, bkp, window, chunk, softcap, g, has_sink,
 ):
     # Head-major blocks: q_ref [1, 1, gp, hd] (the query group rows of one
     # (suffix, kv-head) program, padded to the sublane multiple);
-    # kp_ref/vp_ref [1, lp, hd]; ks_ref/vs_ref/kg_ref/vg_ref [1, 1, L, hd].
+    # kp_ref [1, lp, hd], vp_ref [1, lp, dv]; ks_ref/kg_ref [1, 1, L, hd],
+    # vs_ref/vg_ref [1, 1, L, dv] (dv == hd except where V has its own dim).
+    sink_ref, refs = _split_sink(refs, has_sink)
+    q_ref, kp_ref, vp_ref, ks_ref, vs_ref, kg_ref, vg_ref, o_ref = refs
     si = pl.program_id(0)
     _, _, gp, hd = q_ref.shape
+    dv = vp_ref.shape[-1]
     q = q_ref[0, 0]
     plen = flags_ref[0]
     t = flags_ref[1]
@@ -432,9 +474,15 @@ def _decode_kernel(
     # (ops.attention.decode_attention convention).
     q_abs = plen + eos + 1 + t
 
-    m = jnp.full((gp, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((gp, 1), jnp.float32)
-    acc = jnp.zeros((gp, hd), jnp.float32)
+    sink = None
+    if sink_ref is not None:
+        # Row j of the group is query head kv_head * g + j; rows past g are
+        # the sublane padding (sliced off by the wrapper).
+        row = jax.lax.broadcasted_iota(jnp.int32, (gp, 1), 0)
+        sink = jnp.zeros((gp, 1), jnp.float32)
+        for j in range(g):
+            sink = jnp.where(row == j, sink_ref[pl.program_id(1) * g + j], sink)
+    m, l, acc = _start(gp, dv, sink)
 
     # Shared prefix KV: visible iff the key is real (j < plen).
     def p_body(blk, carry):
@@ -483,14 +531,20 @@ def _decode_kernel(
     o_ref[0, 0] = _finish(l, acc, o_ref.dtype)
 
 
-def supports_decode(n_q: int, n_kv: int, head_dim: int) -> bool:
+def supports_decode(
+    n_q: int, n_kv: int, head_dim: int, v_dim: int | None = None
+) -> bool:
     """Decode-kernel eligibility: whole query groups and a lane-aligned
     head_dim. Unlike the scoring kernels, ragged head dims DON'T pad here:
     the wrapper would re-pad the entire parked KV cache every layer every
     token — a full-cache HBM round trip added to exactly the bandwidth-bound
     loop the kernel exists to speed up — so those models keep the XLA decode
     op. (Ragged KV lengths still pad; masks exclude the padding.)"""
-    return n_q % n_kv == 0 and head_dim % 128 == 0
+    return (
+        n_q % n_kv == 0
+        and head_dim % 128 == 0
+        and (v_dim is None or v_dim % 128 == 0)
+    )
 
 
 def _pad_dim(a, axis: int, mult: int):
@@ -509,7 +563,7 @@ def _pad_dim(a, axis: int, mult: int):
 def flash_decode_attention(
     q, k_prefix, v_prefix, k_suffix, v_suffix, k_gen, v_gen, prefix_len,
     suffix_eos, t, scale=None, window=None, chunk=None, softcap=None,
-    local_on=None, interpret=None,
+    local_on=None, interpret=None, sink=None,
 ):
     """Kernel form of ``ops.attention.decode_attention`` — ONE new token per
     suffix attending jointly over [shared prefix KV ; own suffix KV ;
@@ -517,8 +571,10 @@ def flash_decode_attention(
     the whole prompt per token instead, ``/root/reference/main.py:65-76``).
 
     q [S, 1, n_q, hd]; k/v_prefix [Lp, n_kv, hd]; k/v_suffix [S, Ls, n_kv, hd];
-    k/v_gen [S, T, n_kv, hd]; prefix_len/t int32 scalars; suffix_eos int32 [S].
-    Returns [S, 1, n_q, hd]. Unlike the XLA op, KV blocks past the real
+    k/v_gen [S, T, n_kv, hd]; prefix_len/t int32 scalars; suffix_eos int32 [S];
+    the values may have their own head dim dv; ``sink`` [n_q] is a logit per
+    head in the softmax's denominator.
+    Returns [S, 1, n_q, dv]. Unlike the XLA op, KV blocks past the real
     prefix (and wholly outside a binding window/chunk) are SKIPPED, so a
     short prompt in a long bucket only pays for its real keys.
     """
@@ -529,10 +585,10 @@ def flash_decode_attention(
     g = n_q // n_kv
     if scale is None:
         scale = 1.0 / (hd**0.5)
-    (q, k_prefix, v_prefix, k_suffix, v_suffix, k_gen, v_gen), hd_true = (
-        _pad_head_dim(q, k_prefix, v_prefix, k_suffix, v_suffix, k_gen, v_gen)
-    )
-    hd = q.shape[-1]
+    # q/k pad together (QK^T dim); v pads on its OWN dim.
+    (q, k_prefix, k_suffix, k_gen), _ = _pad_head_dim(q, k_prefix, k_suffix, k_gen)
+    (v_prefix, v_suffix, v_gen), dv_true = _pad_head_dim(v_prefix, v_suffix, v_gen)
+    hd, dv = q.shape[-1], v_prefix.shape[-1]
 
     # Head-major layouts; ragged axes pad up (masks exclude the padding):
     # the query group to the fp32 sublane multiple, KV lengths to the lane
@@ -564,36 +620,37 @@ def flash_decode_attention(
     )
 
     grid = (s, n_kv)
-    kv_head = lambda si, h, flags: (h, 0, 0)
-    skv = lambda si, h, flags: (si, h, 0, 0)
+    kv_head = lambda si, h, *_: (h, 0, 0)
+    skv = lambda si, h, *_: (si, h, 0, 0)
+    prefetch = _prefetch(flags, sink)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, lp=lpp, bkp=bkp, window=window,
-        chunk=chunk, softcap=softcap,
+        chunk=chunk, softcap=softcap, g=g, has_sink=sink is not None,
     )
     with jax.named_scope("flash_decode_attention"):
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=len(prefetch),
                 grid=grid,
                 in_specs=[
                     pl.BlockSpec((1, 1, gp, hd), skv),
                     pl.BlockSpec((1, lpp, hd), kv_head),
-                    pl.BlockSpec((1, lpp, hd), kv_head),
+                    pl.BlockSpec((1, lpp, dv), kv_head),
                     pl.BlockSpec((1, 1, ks.shape[2], hd), skv),
-                    pl.BlockSpec((1, 1, ks.shape[2], hd), skv),
+                    pl.BlockSpec((1, 1, ks.shape[2], dv), skv),
                     pl.BlockSpec((1, 1, kg.shape[2], hd), skv),
-                    pl.BlockSpec((1, 1, kg.shape[2], hd), skv),
+                    pl.BlockSpec((1, 1, kg.shape[2], dv), skv),
                 ],
-                out_specs=pl.BlockSpec((1, 1, gp, hd), skv),
+                out_specs=pl.BlockSpec((1, 1, gp, dv), skv),
             ),
-            out_shape=jax.ShapeDtypeStruct((s, n_kv, gp, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((s, n_kv, gp, dv), q.dtype),
             interpret=interpret,
             name="flash_decode_attention",
             metadata={"kernel": "flash_decode_attention"},
-        )(flags, qg, kp, vp, ks, vs, kg, vg)
-    return out[:, :, :g, :hd_true].reshape(s, 1, n_q, hd_true)
+        )(*prefetch, qg, kp, vp, ks, vs, kg, vg)
+    return out[:, :, :g, :dv_true].reshape(s, 1, n_q, dv_true)
 
 
 __all__ = [

@@ -877,6 +877,7 @@ class DecodeGenerator:
         REGISTRY.register("decode", weak_source(self))
         self.cfg = cfg
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
+        self.model_cfg.require_one_attention_shape("KV-cache decoding")
         self.device = device
         self.dtype = _DTYPES[cfg.dtype]
         if tokenizer is None:

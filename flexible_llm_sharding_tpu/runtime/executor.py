@@ -103,10 +103,10 @@ def _embed_block(cfg: LlamaConfig, dtype, embed_params, prefix_ids, suffix_ids):
         )
 
 
-@partial(jax.jit, static_argnums=(0, 5, 6), donate_argnums=(2, 3))
+@partial(jax.jit, static_argnums=(0, 5, 6, 8), donate_argnums=(2, 3))
 def _decoder_block(
     cfg: LlamaConfig, seg, prefix_h, suffix_h, prefix_len, use_pallas=False,
-    tp_mesh=None, total_len=None,
+    tp_mesh=None, total_len=None, moe_stats=False,
 ):
     """Scan k stacked decoder layers over a block of prompts.
 
@@ -118,14 +118,17 @@ def _decoder_block(
     attention through the flash kernels; ``tp_mesh`` (static, hashable)
     makes them run per head-shard via shard_map under tensor parallelism.
     ``total_len`` int32 [B] (longrope only): per-prompt real total length
-    for the long/short rope table choice.
+    for the long/short rope table choice. ``moe_stats`` (static): a third
+    output, int32 [2], the block's (assignments on held experts, all
+    assignments) summed over its layers and prompts (an expert layer that
+    holds a share of its experts: ``llama._deepseek_moe_mlp``).
     """
     stacked, flags = seg["layers"], seg["sliding"]
     rflags = seg.get("rope")
 
     def body(carry, xs):
         layer_params, sliding, rope_on = xs
-        p, s = carry
+        p, s, counts = carry
 
         def one_layer(lp_, c_, p_, s_, plen_, tlen_):
             return llama.prefix_suffix_layer(
@@ -135,6 +138,7 @@ def _decoder_block(
                 rope_on=rope_on,
                 tp_mesh=tp_mesh,
                 total_len=tlen_,
+                moe_stats=moe_stats,
             )
 
         step = jax.vmap(
@@ -142,14 +146,20 @@ def _decoder_block(
             in_axes=(None, None, 0, 0, 0, 0 if total_len is not None else None),
         )
         with jax.named_scope("decoder_layer"):
-            p, s = step(layer_params, cfg, p, s, prefix_len, total_len)
-        return (p, s), None
+            p, s, *st = step(layer_params, cfg, p, s, prefix_len, total_len)
+        if moe_stats:
+            counts = counts + st[0].sum(axis=0)
+        return (p, s, counts), None
 
     # flags may be None: scan treats them as empty subtrees, and the body's
     # sliding/rope args arrive as None (the static uniform paths).
-    (prefix_h, suffix_h), _ = jax.lax.scan(
-        body, (prefix_h, suffix_h), (stacked, flags, rflags)
+    (prefix_h, suffix_h, counts), _ = jax.lax.scan(
+        body,
+        (prefix_h, suffix_h, jnp.zeros((2,), jnp.int32) if moe_stats else None),
+        (stacked, flags, rflags),
     )
+    if moe_stats:
+        return prefix_h, suffix_h, counts
     return prefix_h, suffix_h
 
 
@@ -249,6 +259,7 @@ def process_block(
         suffix_eos,
         use_pallas,
         tp_mesh,
+        moe_counts=None if clock is None else clock.moe_counts,
     )
     if block_scores is not None:
         for row, i in enumerate(idxs):
@@ -320,6 +331,7 @@ def apply_segments(
     suffix_eos,
     use_pallas: bool = False,
     tp_mesh=None,
+    moe_counts: list | None = None,
 ):
     """Run one shard's segments over a block.
 
@@ -328,9 +340,12 @@ def apply_segments(
     None — no host sync here: a device_get per block would stall the driver
     thread and serialise pipeline stages; callers convert to numpy once at
     the end of the run. Shared by the single-device executor and the MP
-    pipeline runner.
+    pipeline runner. ``moe_counts`` (the sweep's ``SweepClock.moe_counts``):
+    gets each decoder segment's device-resident int32 [2] expert counts
+    where the model holds a share of its experts; nothing is read here.
     """
     block_scores = None
+    moe_stats = moe_counts is not None and model_cfg.moe_ep_size > 1
     # longrope: per-prompt real total length (prefix + longest suffix)
     # selects the long/short rope table; tokenization has already rejected
     # prompts whose suffixes straddle the boundary (check_longrope_regime).
@@ -341,10 +356,12 @@ def apply_segments(
                 model_cfg, dtype, params, prefix_ids, suffix_ids
             )
         elif kind == "decoders":
-            prefix_h, suffix_h = _decoder_block(
+            prefix_h, suffix_h, *counts = _decoder_block(
                 model_cfg, params, prefix_h, suffix_h, prefix_len, use_pallas,
-                tp_mesh, total_len,
+                tp_mesh, total_len, moe_stats,
             )
+            if moe_stats:
+                moe_counts.extend(counts)
         elif kind == "norm":
             suffix_h = _norm_block(model_cfg, params, suffix_h, suffix_eos)
             prefix_h = None
@@ -465,6 +482,11 @@ class SweepClock:
         self.act_fetch_s = self.act_store_s = 0.0
         self.act_bytes = 0
         self.head_s = 0.0
+        # Device-resident int32 [2] counts, one per decoder segment and
+        # block, of a model that holds a share of its experts: summed and
+        # read once, in finish(). ``model`` is the pass's LlamaConfig.
+        self.moe_counts: list = []
+        self.model = None
         self._sweep = obs_trace.sweep_span(self.sweep_id, mode="offline")
         self._head = obs_trace.timed(
             "sweep_head", cat="sweep", sweep_id=self.sweep_id
@@ -538,9 +560,28 @@ class SweepClock:
         account = getattr(source, "account", None)
         if account is not None:  # a shared (broadcast) source keeps none
             rec.update(account(sweep.t0, sweep.t0 + sweep.dur_s))
+        if self.model is not None:
+            rec.update(_model_account(self.model, self.moe_counts))
         with _SWEEP_LOG_LOCK:
             _SWEEP_LOG.append(rec)
         return rec
+
+
+def _model_account(model: LlamaConfig, moe_counts: list) -> dict:
+    """What a sweep's record says of the model it ran: layers by attention
+    kind, the expert layer's share, and (one device read, at the sweep's
+    end) how many of the router's assignments landed on a held expert."""
+    sliding = llama.layer_sliding_pattern(model)
+    rec = {
+        "window_layers": sum(sliding),
+        "full_layers": len(sliding) - sum(sliding),
+        "experts_held": len(model.held_experts),
+        "router_width": model.num_local_experts,
+    }
+    if moe_counts:
+        hits, routed = np.asarray(jnp.sum(jnp.stack(moe_counts), axis=0))
+        rec.update(held_expert_hits=int(hits), routed_assignments=int(routed))
+    return rec
 
 
 # The process-wide stream counters are registry citizens (obs/registry.py):
@@ -589,6 +630,17 @@ SWEEP_RECORD_HELP = {
     "not have to carry once seated.",
     "pin_hits": "Planned layers this sweep merged from the residency tier "
     "instead of uploading (a layer the sweep seated is not a hit).",
+    "window_layers": "Decoder layers with local (sliding-window or chunked) "
+    "attention in the model the sweep ran.",
+    "full_layers": "Decoder layers with full causal attention.",
+    "experts_held": "Routed experts this process holds of each expert layer "
+    "(all of them unless the model's config gives it a share).",
+    "router_width": "Experts the router scores (0 for a dense model).",
+    "held_expert_hits": "Router assignments that landed on a held expert, "
+    "summed on the device over the sweep's expert layers and rows (padding "
+    "rows included); only where a share is held.",
+    "routed_assignments": "All router assignments of those rows (rows x "
+    "experts per token x expert layers).",
 }
 _describe_gauges(
     "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}
@@ -1069,10 +1121,11 @@ class _HostShardLoader:
                 name = self.layer_names[idx]
                 params = self._cast(self._load_one(name))
                 if name.startswith("model.layers."):
-                    if run and jax.tree.structure(run[-1]) != jax.tree.structure(params):
-                        # Mixed-structure stacks can't scan as one program
-                        # (llama4 interleaves dense and MoE layers): start a new
-                        # homogeneous run.
+                    if run and not _stackable(run[-1], params):
+                        # Mixed stacks can't scan as one program: another
+                        # structure (llama4 interleaves dense and MoE
+                        # layers) or other leaf shapes (MiMo-V2's window
+                        # and full layers) start a new homogeneous run.
                         flush()
                     run.append(params)
                     run_decoder_idx.append(int(name.split(".")[2]))
@@ -1109,6 +1162,16 @@ class _HostShardLoader:
             # treat cached segments as immutable (_place only reads).
             cache.put(cache_key, segments, nbytes=shard_bytes, guard=guard)
         return segments
+
+
+def _stackable(a: Params, b: Params) -> bool:
+    """Whether two layers' trees stack into one scan: the same structure
+    and, leaf for leaf, the same shape and dtype."""
+    la, ta = jax.tree.flatten(a)
+    lb, tb = jax.tree.flatten(b)
+    return ta == tb and all(
+        x.shape == y.shape and x.dtype == y.dtype for x, y in zip(la, lb)
+    )
 
 
 class _ShardFault:
@@ -2228,6 +2291,7 @@ class StreamingExecutor:
             return self._run_pass(prompts, batch, clock)
 
     def _run_pass(self, prompts, batch: int, clock: SweepClock) -> list[np.ndarray]:
+        clock.model = self.model_cfg
         with obs_trace.span("tokenize", cat="sweep", sweep_id=clock.sweep_id):
             toks = self._tokenize(prompts)
         blocks = make_blocks(toks, self.cfg.block_size)

@@ -319,6 +319,7 @@ class LongContextScorer:
         REGISTRY.register("longcontext", weak_source(self))
         self.cfg = cfg
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
+        self.model_cfg.require_one_attention_shape("the long-context scorer")
         devices = list(devices) if devices else None
         self.mesh = make_mesh(
             {"sp": len(devices)} if devices else None, devices=devices

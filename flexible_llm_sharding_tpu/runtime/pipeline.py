@@ -68,6 +68,7 @@ class PipelineRunner:
         self.cfg = cfg
         self.devices = list(devices)
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
+        self.model_cfg.require_one_attention_shape("the pipeline runner")
         self.dtype = _DTYPES[cfg.dtype]
         if tokenizer is None:
             from transformers import AutoTokenizer

@@ -224,6 +224,7 @@ class ServeEngine:
         self._spec_k = self.serve_cfg.speculative_k
         self.device = device
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
+        self.model_cfg.require_one_attention_shape("the serve engine")
         self.dtype = _DTYPES[cfg.dtype]
         if tokenizer is None:
             from transformers import AutoTokenizer

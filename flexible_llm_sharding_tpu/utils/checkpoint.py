@@ -361,6 +361,8 @@ _LAYER_MAP_OPTIONAL = [
     ("attn.bo", "self_attn.o_proj.bias"),
     ("attn.q_norm", "self_attn.q_norm.weight"),  # qwen3 per-head-dim RMSNorm
     ("attn.k_norm", "self_attn.k_norm.weight"),
+    # MiMo-V2: the learned per-head sink logit of the layers that have one
+    ("attn.sink", "self_attn.attention_sink_bias"),
     # gemma2 sandwich norms around the MLP
     ("pre_feedforward_layernorm.scale", "pre_feedforward_layernorm.weight"),
     ("post_feedforward_layernorm.scale", "post_feedforward_layernorm.weight"),
@@ -374,32 +376,56 @@ _LAYER_MAP_OPTIONAL = [
 _IGNORABLE_HF_SUFFIXES = ("rotary_emb.inv_freq",)
 
 
-def _stack_experts(layer_name, prefix, name_map, sd, out, consumed) -> None:
+def _stack_experts(
+    layer_name, prefix, name_map, sd, out, consumed, held=None
+) -> None:
     """Stack per-expert Linear weights ``{prefix}.{e}.{hf_name}.weight`` into
     one transposed [E, in, out] native array per projection (the _moe_mlp
     einsum layout — one tensor per projection keeps a shard upload a single
-    device_put)."""
+    device_put). ``held``: the expert ids this process holds (expert
+    parallelism's share, ``LlamaConfig.held_experts``); the others' tensors
+    are consumed and left out. None = all."""
     probe = name_map[0][1]
     n_exp = 0
     while f"{prefix}.{n_exp}.{probe}.weight" in sd:
         n_exp += 1
     if not n_exp:
         raise ValueError(f"{layer_name}: MoE layer with no expert weights")
+    keep = range(n_exp) if held is None else held
+    if not set(keep) <= set(range(n_exp)):
+        raise ValueError(
+            f"{layer_name}: held experts {keep} of a layer with {n_exp}"
+        )
     for native_key, hf_w in name_map:
-        stack = []
-        for ei in range(n_exp):
-            key = f"{prefix}.{ei}.{hf_w}.weight"
-            stack.append(sd[key].T)
-            consumed.add(key)
-        out[native_key] = np.ascontiguousarray(np.stack(stack))
+        consumed.update(f"{prefix}.{ei}.{hf_w}.weight" for ei in range(n_exp))
+        out[native_key] = np.ascontiguousarray(
+            np.stack([sd[f"{prefix}.{ei}.{hf_w}.weight"].T for ei in keep])
+        )
 
 
-def hf_layer_to_native(layer_name: str, sd: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
+def held_experts_of(src_dir: str):
+    """The expert ids the checkpoint's own config.json gives this process
+    (``ep_size``/``ep_rank``: ``LlamaConfig.held_experts``), or None where
+    it holds every expert or the directory has no parseable config."""
+    from flexible_llm_sharding_tpu.config import LlamaConfig
+
+    try:
+        cfg = LlamaConfig.from_pretrained(src_dir)
+    except (OSError, ValueError, NotImplementedError):
+        return None
+    return cfg.held_experts if cfg.moe_ep_size > 1 else None
+
+
+def hf_layer_to_native(
+    layer_name: str, sd: dict[str, np.ndarray], held_experts=None
+) -> dict[str, np.ndarray]:
     """Convert one layer's HF-keyed state dict to native flat keys/layout.
 
     Projection biases (Qwen2 q/k/v; Llama attention_bias/mlp_bias) map to
     their native slots when present. Tensors with no slot at all (an unknown
     architecture's extras) raise instead of silently dropping.
+    ``held_experts``: the routed experts to keep of an expert layer
+    (``_stack_experts``); the router and its bias keep their full width.
     """
     if layer_name == "model.embed_tokens":
         return {"embedding": sd["model.embed_tokens.weight"]}
@@ -516,7 +542,7 @@ def hf_layer_to_native(layer_name: str, sd: dict[str, np.ndarray]) -> dict[str, 
             out[native_key] = np.ascontiguousarray(sd[key].T)
             consumed.add(key)
     if qmoe:
-        # Qwen3-MoE / DeepSeek: router at mlp.gate [E, D] -> [D, E];
+        # Qwen3-MoE / DeepSeek / MiMo-V2: router at mlp.gate [E, D] -> [D, E];
         # per-expert gate/up/down Linears stack into the same
         # [E, D, F] / [E, F, D] native arrays as Mixtral. DeepSeek adds a
         # routing correction-bias buffer and a shared expert.
@@ -526,7 +552,7 @@ def hf_layer_to_native(layer_name: str, sd: dict[str, np.ndarray]) -> dict[str, 
         _stack_experts(
             layer_name, f"{layer_name}.mlp.experts",
             (("mlp.gate", "gate_proj"), ("mlp.up", "up_proj"), ("mlp.down", "down_proj")),
-            sd, out, consumed,
+            sd, out, consumed, held_experts,
         )
         bk = f"{layer_name}.mlp.gate.e_score_correction_bias"
         if bk in sd:
@@ -694,6 +720,7 @@ def split_into_layers(
         key=lambda l: (min(shard_ids[s] for s in layer2shards[l]), len(layer2shards[l])),
     )
 
+    held = held_experts_of(src_dir) if layout == "native" else None
     quantize = dtype in ("int8", "int4")
     if quantize and layout != "native":
         raise ValueError(f"dtype='{dtype}' requires layout='native'")
@@ -736,7 +763,7 @@ def split_into_layers(
                 for k, v in sd.items()
             }
         if layout == "native":
-            sd = hf_layer_to_native(layer, sd)
+            sd = hf_layer_to_native(layer, sd, held)
         if quantize:
             sd = _quantize_flat(sd, dtype)
         stored = {k: np.ascontiguousarray(v) for k, v in sd.items()}

@@ -17,8 +17,10 @@ The persistent compile cache is off for the whole suite (tests/conftest.py):
 an entry compiled for a described device cannot be read back without a chip.
 """
 
+import dataclasses
 import functools
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,8 @@ from flexible_llm_sharding_tpu.config import LlamaConfig
 from flexible_llm_sharding_tpu.models import llama
 from flexible_llm_sharding_tpu.ops import pallas_attention as pa
 from flexible_llm_sharding_tpu.runtime import decode, executor
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 BF16 = jnp.bfloat16
 
@@ -74,11 +78,15 @@ def _compile(fn, *args):
 
 
 # (n_q, n_kv, qk head dim, v head dim): Llama-3-8B, Llama-3-70B, MLA
-# (DeepSeek-V2/V3, Moonlight: qk 128+64 rope, v 128).
+# (DeepSeek-V2/V3, Moonlight: qk 128+64 rope, v 128; kanana-2 the same at 32
+# heads), MiMo-V2-Flash's full and window layers (qk 192, v 128, no MLA).
 WIDTHS = {
     "llama3_8b": (32, 8, 128, 128),
     "llama3_70b": (64, 8, 128, 128),
     "mla": (16, 16, 192, 128),
+    "kanana2_mla": (32, 32, 192, 128),
+    "mimo_full": (64, 4, 192, 128),
+    "mimo_window": (64, 8, 192, 128),
 }
 
 
@@ -126,6 +134,79 @@ def test_decode_kernel_compiles(one_chip, widths):
         s((), jnp.int32), s((ns,), jnp.int32), s((), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+# --- MiMo-V2-Flash's window layers: the sink, and the whole step ------------
+
+def test_kernels_with_sink_and_window_compile(one_chip):
+    """The sink rides a second scalar-prefetch operand (float32 [n_q]) into
+    all three kernels; the decode kernel also carries V's own head dim."""
+    n_q, n_kv, hd, dv = WIDTHS["mimo_window"]
+    s = functools.partial(_sds, one_chip)
+    lp, ns, ls, t = 4096, 4, 64, 64
+    kw = dict(interpret=False, window=128)
+    sink = s((n_q,), jnp.float32)
+    for fn, args in (
+        (pa.flash_causal_attention,
+         (s((lp, n_q, hd)), s((lp, n_kv, hd)), s((lp, n_kv, dv)), s((), jnp.int32))),
+        (pa.flash_prefix_shared_attention,
+         (s((ns, ls, n_q, hd)), s((lp, n_kv, hd)), s((lp, n_kv, dv)),
+          s((ns, ls, n_kv, hd)), s((ns, ls, n_kv, dv)), s((), jnp.int32))),
+        (pa.flash_decode_attention,
+         (s((ns, 1, n_q, 256)), s((lp, n_kv, 256)), s((lp, n_kv, dv)),
+          s((ns, ls, n_kv, 256)), s((ns, ls, n_kv, dv)),
+          s((ns, t, n_kv, 256)), s((ns, t, n_kv, dv)),
+          s((), jnp.int32), s((ns,), jnp.int32), s((), jnp.int32))),
+    ):
+        wrapped = jax.jit(lambda sk, *a, fn=fn: fn(*a, sink=sk, **kw))
+        _, text = _compile(wrapped, sink, *args)
+        assert "tpu_custom_call" in text, fn.__name__
+
+
+def _mimo_cfg():
+    import json
+
+    from benchmark.families.mimo_v2_flash import weights
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "mimo-v2-flash.json")) as f:
+        model = json.load(f)
+    model.pop("rehearsal")
+    return LlamaConfig.from_hf_config(weights.hf_config(model))
+
+
+@pytest.mark.parametrize("layer", [1, 5])
+def test_mimo_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, layer):
+    """One expert layer of each attention kind (layer 1 window, layer 5 full)
+    as the benchmark's cell runs it: published widths, 16 of 256 experts
+    held, the longest prefix bucket, one prompt a block, the expert counts
+    out. Both kernels in the program, and the step beside four 1 GB shards
+    in flight and ~11 GB of pins inside the chip."""
+    cfg = _mimo_cfg()
+    sliding = cfg.layer_sliding[layer]
+    shapes = jax.eval_shape(
+        lambda: llama.init_mixed_params(
+            jax.random.PRNGKey(0), dataclasses.replace(
+                cfg, num_hidden_layers=layer + 1,
+                layer_sliding=cfg.layer_sliding[: layer + 1],
+                moe_layer_pattern=cfg.moe_layer_pattern[: layer + 1]),
+            dtype=BF16)
+    )["layers"][layer]
+    assert ("sink" in shapes["attn"]) == sliding
+    assert shapes["mlp"]["gate"].shape == (16, 4096, 2048)
+    s = functools.partial(_sds, one_chip)
+    seg = {
+        "layers": jax.tree.map(lambda x: s((1, *x.shape), x.dtype), shapes),
+        "sliding": s((1,), jnp.bool_), "rope": None,
+    }
+    compiled, text = _compile(
+        executor._decoder_block,
+        cfg, seg, s((1, 3392, 4096)), s((1, 4, 64, 4096)), s((1,), jnp.int32), True,
+        None, None, True,
+    )
+    assert text.count("tpu_custom_call") >= 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9
 
 
 # --- whole steps at Llama-3-8B widths (chip_smoke.py's shapes) -------------
