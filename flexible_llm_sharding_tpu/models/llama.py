@@ -53,7 +53,7 @@ from flexible_llm_sharding_tpu.ops import (
     rms_norm,
     rope_cos_sin,
 )
-from flexible_llm_sharding_tpu.ops import pallas_attention
+from flexible_llm_sharding_tpu.ops import grouped_matmul, pallas_attention
 from flexible_llm_sharding_tpu.ops.attention import (
     causal_mask,
     decode_attention,
@@ -226,10 +226,11 @@ def _moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     TPU-first compute layout: experts are stacked arrays ``gate/up [E, D, F]``,
     ``down [E, F, D]`` and every expert runs on every token (one batched
     einsum per projection, MXU-shaped) with the combine weights zeroing the
-    non-selected experts. In the streaming regime this is the right trade:
-    the executor is weight-transfer-bound, the per-token FLOP surplus (E/k)
-    rides idle MXU cycles, and there is no gather/scatter or ragged shape for
-    XLA to choke on. Under expert parallelism (``layer_specs``) the stacked
+    non-selected experts: no gather/scatter or ragged shape, at E/k times the
+    FLOPs the tokens need. That surplus is not free in the streaming regime:
+    in the sigmoid-router families' cells it was 43-75% of a sweep (ledger,
+    PR 27), which is why ``_routed_experts`` exists; this family has not
+    moved onto it (ROADMAP D12). Under expert parallelism (``layer_specs``) the stacked
     E axis is sharded over the mesh, so each chip computes only its own
     experts and GSPMD inserts one psum for the combine — the reference has no
     MoE at all (dense Llama only, SURVEY.md §2.2 'EP: absent').
@@ -293,8 +294,73 @@ def _llama4_moe_mlp(mlp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     return shared + routed
 
 
+def _routed_experts(
+    x: jax.Array,
+    top_idx: jax.Array,
+    top_w: jax.Array,
+    gate: jax.Array,
+    up: jax.Array,
+    down: jax.Array,
+    held: range,
+    act,
+    use_pallas: bool = False,
+) -> jax.Array:
+    """The routed experts' part of an MoE layer, computing a row only in the
+    experts its router chose: rows ``x [R, D]`` with the router's choices
+    ``top_idx [R, k]`` (ids over the router's width) and combine weights
+    ``top_w [R, k]``, over the stacked experts this process holds
+    (``gate/up [H, D, F]``, ``down [H, F, D]``: the ids ``held`` of the
+    router's width) -> ``[R, D]``.
+
+    The R*k (row, choice) assignments are sorted by held expert and the three
+    projections run as grouped matmuls over the sorted rows
+    (``ops/grouped_matmul.py``: ``jax.lax.ragged_dot``, or with
+    ``use_pallas`` the Pallas kernel where the shapes are eligible). An
+    assignment whose expert is not held sorts past the last group; nothing is
+    trusted of such rows (``where``, as the compute-all body zeroes what the
+    router did not choose). The weight goes in before the down projection and
+    a row's k results add up in float32, rounded once, as in the einsum that
+    contracts experts and width together. Not traceable under ``jax.vmap``
+    with per-example groups: callers hold flat rows."""
+    (r, k), h = top_idx.shape, gate.shape[0]
+    n = r * k
+    local = top_idx.astype(jnp.int32) - held.start
+    on_held = ((local >= 0) & (local < h)).T  # [k, R]
+    # Assignments choice-major (a = choice * R + row), so that a row's k
+    # results come back as k slabs of [R, D] and not as [R, k, D], whose
+    # k-row tiles the chip would pad and copy.
+    local = jnp.where(on_held, local.T, h).reshape(n)
+    # Stable sort by held expert, the combine weights riding along: `order`
+    # lists the assignments as the grouped matmuls see them, `inv` finds an
+    # assignment in that order.
+    slot = jnp.arange(n, dtype=jnp.int32)
+    _, order, ws = jax.lax.sort(
+        (local, slot, top_w.T.reshape(n)), num_keys=1, is_stable=True
+    )
+    _, inv = jax.lax.sort((order, slot), num_keys=1)
+    group_sizes = jnp.sum(
+        local[:, None] == jnp.arange(h, dtype=jnp.int32), axis=0, dtype=jnp.int32
+    )
+    # Whole row tiles for the kernel: the padding rows belong to no group.
+    pad = -n % grouped_matmul.ROW_TILE
+    xs = x[jnp.pad(order % r, (0, pad))]  # [R*k + pad, D]
+    ws = jnp.pad(ws, (0, pad)).astype(x.dtype)
+
+    # A 16-bit contraction takes no float32 precision on the chip's grouped
+    # matmul, where an XLA dot takes HIGHEST as a no-op.
+    grouped = grouped_matmul.for_groups(
+        group_sizes, use_pallas, _PRECISION if x.dtype == jnp.float32 else None
+    )
+    y = act(grouped(xs, gate.astype(x.dtype))) * grouped(xs, up.astype(x.dtype))
+    y = grouped(y * ws[:, None], down.astype(x.dtype), jnp.float32)
+    y = y[inv.reshape(k, r)]  # [k, R, D]
+    y = jnp.where(on_held[..., None], y, 0.0)
+    return jnp.sum(y, axis=0).astype(x.dtype)
+
+
 def _deepseek_moe_mlp(
-    mlp: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None
+    mlp: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None,
+    grouped: bool = False, use_pallas: bool = False,
 ) -> jax.Array:
     """DeepSeek-V3 MoE (DeepseekV3MoE/TopkRouter): fp32 sigmoid scores;
     SELECTION adds a trained correction bias and is group-limited (experts
@@ -303,8 +369,13 @@ def _deepseek_moe_mlp(
     the unbiased scores, renormalised (+1e-20) iff norm_topk_prob and
     scaled by routed_scaling_factor. A shared expert
     (n_shared_experts x the routed width) adds where the weights have one
-    (MiMo-V2 has none). Same compute-all stacked-einsum layout as the
-    Mixtral path.
+    (MiMo-V2 has none). Two bodies for the routed experts, the caller's
+    static choice: the compute-all stacked einsums of the Mixtral path (the
+    default: what a caller under ``jax.vmap`` or with the expert axis sharded
+    over a mesh can trace), and with ``grouped`` the rows' chosen experts
+    only (``_routed_experts``; for a caller that holds its rows outside any
+    ``vmap``, of whatever leading shape; ``use_pallas`` lets its grouped
+    matmuls take the Pallas kernel).
 
     The layer routes over the router's width and computes over the experts
     it HOLDS, the stacked arrays' leading axis: with fewer held than routed
@@ -327,8 +398,8 @@ def _deepseek_moe_mlp(
         scores = jax.nn.sigmoid(logits)  # [..., L, E]
         choice = scores + mlp["correction_bias"].astype(jnp.float32)
         if g > 1:
-            grouped = choice.reshape(*choice.shape[:-1], g, e // g)
-            top2, _ = jax.lax.top_k(grouped, 2)
+            by_group = choice.reshape(*choice.shape[:-1], g, e // g)
+            top2, _ = jax.lax.top_k(by_group, 2)
             group_scores = top2.sum(axis=-1)  # [..., L, G]
             _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
             gmask = jnp.sum(
@@ -342,10 +413,7 @@ def _deepseek_moe_mlp(
         if cfg.moe_norm_topk_prob:
             top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
         top_w = top_w * cfg.moe_routed_scaling_factor
-        combine = jnp.sum(
-            jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_w[..., None],
-            axis=-2,
-        ).astype(x.dtype)  # [..., L, E]
+        ids = range(e)
         if held != e:
             ids = cfg.held_experts
             if len(ids) != held:
@@ -353,7 +421,6 @@ def _deepseek_moe_mlp(
                     f"expert layer holds {held} experts, the config's share is "
                     f"{len(ids)} of {e}"
                 )
-            combine = combine[..., ids.start : ids.stop]  # [..., L, held]
             if stats is not None:
                 hits = (top_idx >= ids.start) & (top_idx < ids.stop)
                 stats.append(
@@ -361,14 +428,25 @@ def _deepseek_moe_mlp(
                 )
     act = _ACT[cfg.hidden_act]
     with jax.named_scope("moe_experts"):
-        h = act(
-            jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
-        ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
-        c = combine[..., None]
-        h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
-        routed = jnp.einsum(
-            "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
-        )
+        if grouped:
+            d = x.shape[-1]
+            routed = _routed_experts(
+                x.reshape(-1, d), top_idx.reshape(-1, k), top_w.reshape(-1, k),
+                mlp["gate"], mlp["up"], mlp["down"], ids, act, use_pallas,
+            ).reshape(x.shape)
+        else:
+            combine = jnp.sum(
+                jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_w[..., None],
+                axis=-2,
+            ).astype(x.dtype)[..., ids.start : ids.stop]  # [..., L, held]
+            h = act(
+                jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
+            ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
+            c = combine[..., None]
+            h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
+            routed = jnp.einsum(
+                "...lef,efd->...ld", h, mlp["down"].astype(x.dtype), precision=_PRECISION
+            )
     if "shared_gate" not in mlp:
         return routed
     with jax.named_scope("moe_shared_experts"):
@@ -381,11 +459,11 @@ def _deepseek_moe_mlp(
 
 def _mlp(
     mlp: Params, x: jax.Array, cfg: LlamaConfig | None = None,
-    stats: list | None = None,
+    stats: list | None = None, grouped: bool = False, use_pallas: bool = False,
 ) -> jax.Array:
     if "correction_bias" in mlp:
         assert cfg is not None and cfg.num_local_experts > 0
-        return _deepseek_moe_mlp(mlp, cfg, x, stats)
+        return _deepseek_moe_mlp(mlp, cfg, x, stats, grouped, use_pallas)
     if "shared_gate" in mlp:
         assert cfg is not None and cfg.num_local_experts > 0
         return _llama4_moe_mlp(mlp, cfg, x)
@@ -410,18 +488,22 @@ def _residual_attn(params: Params, cfg: LlamaConfig, x: jax.Array, attn_out) -> 
 
 
 def _residual_mlp(
-    params: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None
+    params: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None,
+    grouped: bool = False, use_pallas: bool = False,
 ) -> jax.Array:
     """Residual add of the MLP sublayer. Standard layout norms the input
     with post_attention_layernorm; Gemma2 norms input AND output with the
-    pre/post_feedforward_layernorms."""
+    pre/post_feedforward_layernorms. Position-wise, so ``x`` may be any
+    stack of rows; ``grouped`` (static): the caller holds them outside any
+    ``vmap`` and a sigmoid-router expert layer may take its routed body
+    (``_deepseek_moe_mlp``), with the Pallas kernel under ``use_pallas``."""
     pre = (
         "pre_feedforward_layernorm"
         if cfg.ffw_sandwich_norms
         else "post_attention_layernorm"
     )
     h = rms_norm(x, params[pre]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-    y = _mlp(params["mlp"], h, cfg, stats)
+    y = _mlp(params["mlp"], h, cfg, stats, grouped, use_pallas)
     if cfg.ffw_sandwich_norms:
         y = rms_norm(
             y,
@@ -701,6 +783,7 @@ def prefix_suffix_layer(
     tp_mesh=None,
     total_len=None,
     moe_stats: bool = False,
+    attn_only: bool = False,
 ) -> tuple[jax.Array, ...]:
     """One decoder layer over a (prefix, suffixes) prompt — the streaming hot op.
 
@@ -728,6 +811,11 @@ def prefix_suffix_layer(
     ``moe_stats`` (static): one more output at the end, int32 [2]: the
     layer's (assignments on held experts, all assignments) where the expert
     layer holds a share of its experts, zeros elsewhere.
+
+    ``attn_only`` (static): stop after the attention half and return the
+    residual streams as they enter the MLP half, for a caller that runs
+    that half (``_residual_mlp``, position-wise) over many prompts' rows at
+    once.
     """
     lp, _ = prefix_h.shape
     s, ls, _ = suffix_h.shape
@@ -803,8 +891,9 @@ def prefix_suffix_layer(
                 q, k, v, mask, scale=cfg.attn_scale,
                 softcap=cfg.attn_logit_softcap, sink=sink,
             )
-    prefix_mid = _residual_attn(params, cfg, prefix_h, attn_out)
-    prefix_out = _residual_mlp(params, cfg, prefix_mid, stats)
+    prefix_out = _residual_attn(params, cfg, prefix_h, attn_out)
+    if not attn_only:
+        prefix_out = _residual_mlp(params, cfg, prefix_out, stats)
 
     # --- suffixes: batched attention over [shared prefix KV ; own causal KV],
     # prefix KV never expanded across suffixes (ops.prefix_shared_attention) ---
@@ -839,8 +928,9 @@ def prefix_suffix_layer(
                 chunk=chunk,
                 sink=sink,
             )
-    suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s)
-    suffix_out = _residual_mlp(params, cfg, suffix_mid, stats)
+    suffix_out = _residual_attn(params, cfg, suffix_h, attn_s)
+    if not attn_only:
+        suffix_out = _residual_mlp(params, cfg, suffix_out, stats)
     out = (prefix_out, suffix_out)
     if return_kv:
         # Post-RoPE KV, reusable across decode steps (runtime/decode.py).

@@ -122,6 +122,15 @@ def _decoder_block(
     output, int32 [2], the block's (assignments on held experts, all
     assignments) summed over its layers and prompts (an expert layer that
     holds a share of its experts: ``llama._deepseek_moe_mlp``).
+
+    A layer's attention half runs per prompt under ``vmap``; its MLP half is
+    position-wise and runs once over the block's B*Lp + B*S*Ls rows, outside
+    the ``vmap``, so that a sigmoid-router expert layer can sort the block's
+    rows by expert and compute each in its chosen experts only
+    (``llama._routed_experts``; under ``use_pallas`` its grouped matmuls are
+    the Pallas kernel where eligible). Under ``tp_mesh`` the stacked experts are
+    sharded on their leading axis and the layer keeps the compute-all
+    einsums that GSPMD partitions (``parallel/sharding.py``).
     """
     stacked, flags = seg["layers"], seg["sliding"]
     rflags = seg.get("rope")
@@ -130,7 +139,7 @@ def _decoder_block(
         layer_params, sliding, rope_on = xs
         p, s, counts = carry
 
-        def one_layer(lp_, c_, p_, s_, plen_, tlen_):
+        def attention_half(lp_, c_, p_, s_, plen_, tlen_):
             return llama.prefix_suffix_layer(
                 lp_, c_, p_, s_, plen_,
                 use_pallas=use_pallas,
@@ -138,17 +147,28 @@ def _decoder_block(
                 rope_on=rope_on,
                 tp_mesh=tp_mesh,
                 total_len=tlen_,
-                moe_stats=moe_stats,
+                attn_only=True,
             )
 
         step = jax.vmap(
-            one_layer,
+            attention_half,
             in_axes=(None, None, 0, 0, 0, 0 if total_len is not None else None),
         )
+        stats = [] if moe_stats else None
         with jax.named_scope("decoder_layer"):
-            p, s, *st = step(layer_params, cfg, p, s, prefix_len, total_len)
+            p, s = step(layer_params, cfg, p, s, prefix_len, total_len)
+            d = p.shape[-1]
+            rows = jnp.concatenate([p.reshape(-1, d), s.reshape(-1, d)])
+            rows = llama._residual_mlp(
+                layer_params, cfg, rows, stats,
+                grouped=tp_mesh is None, use_pallas=use_pallas,
+            )
+            p, s = (
+                rows[: p.size // d].reshape(p.shape),
+                rows[p.size // d :].reshape(s.shape),
+            )
         if moe_stats:
-            counts = counts + st[0].sum(axis=0)
+            counts = counts + llama._moe_counts(stats)
         return (p, s, counts), None
 
     # flags may be None: scan treats them as empty subtrees, and the body's
@@ -259,7 +279,7 @@ def process_block(
         suffix_eos,
         use_pallas,
         tp_mesh,
-        moe_counts=None if clock is None else clock.moe_counts,
+        clock=clock,
     )
     if block_scores is not None:
         for row, i in enumerate(idxs):
@@ -331,7 +351,7 @@ def apply_segments(
     suffix_eos,
     use_pallas: bool = False,
     tp_mesh=None,
-    moe_counts: list | None = None,
+    clock: "SweepClock | None" = None,
 ):
     """Run one shard's segments over a block.
 
@@ -340,12 +360,14 @@ def apply_segments(
     None — no host sync here: a device_get per block would stall the driver
     thread and serialise pipeline stages; callers convert to numpy once at
     the end of the run. Shared by the single-device executor and the MP
-    pipeline runner. ``moe_counts`` (the sweep's ``SweepClock.moe_counts``):
-    gets each decoder segment's device-resident int32 [2] expert counts
-    where the model holds a share of its experts; nothing is read here.
+    pipeline runner. ``clock`` (the sweep's account, where one is kept):
+    its ``expert_rows`` count what each decoder segment's expert layers are
+    dispatched with, from the shapes; its ``moe_counts`` get the segment's
+    device-resident int32 [2] expert counts where the model holds a share of
+    its experts. Nothing is read from the device here.
     """
     block_scores = None
-    moe_stats = moe_counts is not None and model_cfg.moe_ep_size > 1
+    moe_stats = clock is not None and model_cfg.moe_ep_size > 1
     # longrope: per-prompt real total length (prefix + longest suffix)
     # selects the long/short rope table; tokenization has already rejected
     # prompts whose suffixes straddle the boundary (check_longrope_regime).
@@ -356,18 +378,35 @@ def apply_segments(
                 model_cfg, dtype, params, prefix_ids, suffix_ids
             )
         elif kind == "decoders":
+            if clock is not None:
+                body, n = _expert_rows(
+                    params, prefix_h.size + suffix_h.size, tp_mesh
+                )
+                clock.expert_rows[body] += n
             prefix_h, suffix_h, *counts = _decoder_block(
                 model_cfg, params, prefix_h, suffix_h, prefix_len, use_pallas,
                 tp_mesh, total_len, moe_stats,
             )
             if moe_stats:
-                moe_counts.extend(counts)
+                clock.moe_counts.extend(counts)
         elif kind == "norm":
             suffix_h = _norm_block(model_cfg, params, suffix_h, suffix_eos)
             prefix_h = None
         else:  # head
             block_scores = _head_block(model_cfg, params, suffix_h)
     return prefix_h, suffix_h, block_scores
+
+
+def _expert_rows(seg, act_size: int, tp_mesh) -> tuple[str, int]:
+    """Which body ``_decoder_block`` gives a segment's expert layers, and the
+    rows x expert layers it is dispatched with (``act_size``: the elements
+    of the block's prefix and suffix activations). ``grouped``: a row is
+    computed in its chosen experts only, which a sigmoid-router layer outside
+    a ``tp_mesh`` gets; ``dense``: every row in every held expert."""
+    mlp = seg["layers"]["mlp"]
+    grouped = "correction_bias" in mlp and tp_mesh is None
+    layers, d = mlp["router"].shape[:2] if "router" in mlp else (0, 1)  # [k, D, E]
+    return ("grouped" if grouped else "dense"), layers * (act_size // d)
 
 
 # ---------------------------------------------------------------------------
@@ -487,6 +526,9 @@ class SweepClock:
         # read once, in finish(). ``model`` is the pass's LlamaConfig.
         self.moe_counts: list = []
         self.model = None
+        # Rows x expert layers dispatched, by the expert layer's body
+        # (``_expert_rows``): host counts from the shapes.
+        self.expert_rows = {"grouped": 0, "dense": 0}
         self._sweep = obs_trace.sweep_span(self.sweep_id, mode="offline")
         self._head = obs_trace.timed(
             "sweep_head", cat="sweep", sweep_id=self.sweep_id
@@ -562,6 +604,10 @@ class SweepClock:
             rec.update(account(sweep.t0, sweep.t0 + sweep.dur_s))
         if self.model is not None:
             rec.update(_model_account(self.model, self.moe_counts))
+            rec.update(
+                expert_rows_grouped=self.expert_rows["grouped"],
+                expert_rows_dense=self.expert_rows["dense"],
+            )
         with _SWEEP_LOG_LOCK:
             _SWEEP_LOG.append(rec)
         return rec
@@ -641,6 +687,14 @@ SWEEP_RECORD_HELP = {
     "rows included); only where a share is held.",
     "routed_assignments": "All router assignments of those rows (rows x "
     "experts per token x expert layers).",
+    "expert_rows_grouped": "Rows x expert layers dispatched with the routed "
+    "expert body (a row computed in its chosen experts only, as grouped "
+    "matmuls over the block's rows sorted by expert); padding rows included, "
+    "counted on the host from the shapes.",
+    "expert_rows_dense": "Rows x expert layers dispatched with the compute-"
+    "all body (every row in every held expert): a block under a tensor-"
+    "parallel mesh, or a softmax-router family; 0 in a scoring sweep of a "
+    "sigmoid-router model on one chip, DP or MP.",
 }
 _describe_gauges(
     "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}

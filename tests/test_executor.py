@@ -499,5 +499,7 @@ def test_lowered_decoder_block_carries_the_scopes_and_kernel_names():
                   "moe_experts", "moe_shared_experts", "rms_norm",
                   "flash_causal_attention", "flash_prefix_shared_attention"):
         assert scope in text, scope
-    # The experts' einsums sit under their scope inside the layer's.
-    assert "decoder_layer/vmap(moe_experts)" in text
+    # The experts' matmuls sit under their scope inside the layer's, outside
+    # the per-prompt vmap (the MLP half sees the block's rows at once).
+    assert "decoder_layer/moe_experts" in text
+    assert "decoder_layer/vmap(attention)" in text

@@ -205,8 +205,68 @@ def test_mimo_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, la
         None, None, True,
     )
     assert text.count("tpu_custom_call") >= 2
+    # The routed experts are grouped matmuls (the Pallas kernel) over the
+    # 8 x 3648 sorted assignments, of which the held 16 experts' groups are
+    # visited.
+    assert text.count("%grouped_matmul") >= 3 and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.5e9
+
+
+# --- the sigmoid-router expert layer as grouped matmuls ---------------------
+
+# (configuration file, prompts a block, use_pallas)
+EXPERT_BLOCKS = {
+    "moonlight_b8": ("moonlight-16b-a3b", 2, True),   # score-b8: blocks of 1-2 prompts
+    "moonlight_b32": ("moonlight-16b-a3b", 6, True),  # score-b32: blocks of 3-6
+    "kanana_b8": ("kanana-2-30b-a3b", 2, True),
+    "kanana_b8_xla": ("kanana-2-30b-a3b", 2, False),  # the fallback
+}
+
+
+@pytest.mark.parametrize("block", sorted(EXPERT_BLOCKS))
+def test_deepseek_decoder_block_runs_experts_as_grouped_matmuls(
+    one_chip, lowering_sees_tpu, block
+):
+    """One expert layer at published widths over a block of the cells'
+    longest bucket (prefix 768, 4 suffixes of 64): the three expert
+    projections compile to grouped matmuls over the block's rows sorted by
+    expert (the Pallas kernel ``grouped_matmul`` with ``use_pallas``, XLA's own
+    ``ragged-dot`` custom call without), and the step's temporaries stay
+    under ONE [rows, experts, width] bfloat16 array, of which the
+    compute-all einsums held two to three."""
+    import json
+
+    from benchmark import weights
+
+    name, prompts, use_pallas = EXPERT_BLOCKS[block]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:
+        model = json.load(f)
+    model.pop("rehearsal")
+    cfg = LlamaConfig.from_hf_config(weights.hf_config(model))
+    shapes = jax.eval_shape(
+        lambda: llama.init_mixed_params(
+            jax.random.PRNGKey(0), dataclasses.replace(
+                cfg, num_hidden_layers=2, moe_layer_pattern=cfg.moe_layer_pattern[:2]),
+            dtype=BF16)
+    )["layers"][1]
+    e, d, f = shapes["mlp"]["gate"].shape
+    assert e == cfg.num_local_experts and "correction_bias" in shapes["mlp"]
+    s = functools.partial(_sds, one_chip)
+    seg = {
+        "layers": jax.tree.map(lambda x: s((1, *x.shape), x.dtype), shapes),
+        "sliding": None, "rope": None,
+    }
+    lp, ns, ls = 768, 4, 64
+    rows = prompts * (lp + ns * ls)
+    compiled, text = _compile(
+        executor._decoder_block,
+        cfg, seg, s((prompts, lp, d)), s((prompts, ns, ls, d)),
+        s((prompts,), jnp.int32), use_pallas,
+    )
+    assert text.count("%grouped_matmul" if use_pallas else "%ragged-dot") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * e * f * 2
 
 
 # --- whole steps at Llama-3-8B widths (chip_smoke.py's shapes) -------------
