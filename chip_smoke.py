@@ -41,15 +41,67 @@ import shutil
 import subprocess
 import sys
 import time
+import zlib
 
 import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
 
-from bench import BenchTokenizer, make_prompts  # noqa: E402
-
 WORK = os.path.join(ROOT, "chip_smoke_tmp")  # listed in .gitignore
+
+
+class WordHashTokenizer:
+    """Deterministic word-hash tokenizer (no model assets needed)."""
+
+    BOS, EOS, VOCAB = 1, 2, 32000
+
+    eos_token = "</s>"
+    pad_token = "</s>"
+    pad_token_id = EOS
+    padding_side = "right"
+
+    def _one_id(self, w: str) -> int:
+        # decode()'s output round-trips, so the full-recompute generation
+        # loop, which rebuilds its strings, retokenizes a generated token to
+        # the same id: the recompute-against-KV comparison rests on it.
+        if w.startswith("tok") and w[3:].isdigit():
+            return int(w[3:]) % self.VOCAB
+        # crc32, not hash(): Python's hash() is salted per process, and the
+        # children must all see the same ids.
+        return 3 + (zlib.crc32(w.encode()) % (self.VOCAB - 3))
+
+    def _ids(self, text: str) -> list[int]:
+        return [self.BOS] + [self._one_id(w) for w in text.split()]
+
+    def decode(self, ids) -> str:
+        if np.ndim(ids) == 0:
+            ids = [int(ids)]
+        return "".join(f" tok{int(i)}" for i in ids)
+
+    def __call__(self, text, max_length=None, padding=False, **kw):
+        if isinstance(text, str):
+            ids = self._ids(text)[:max_length]
+            return {"input_ids": ids}
+        batch = [self._ids(t)[:max_length] for t in text]
+        if padding:
+            width = max(len(b) for b in batch)
+            batch = [b + [self.pad_token_id] * (width - len(b)) for b in batch]
+        return {"input_ids": batch}
+
+
+def make_prompts(n: int, prefix_words: int, suffix_words: int, n_suffix: int):
+    rng = np.random.default_rng(0)
+    words = [f"w{i}" for i in range(5000)]
+
+    def text(k):
+        return " ".join(rng.choice(words, size=k))
+
+    return [
+        (text(prefix_words), tuple(text(suffix_words) for _ in range(n_suffix)))
+        for _ in range(n)
+    ]
+
 
 # Published Llama-3-8B widths (meta-llama/Meta-Llama-3-8B config.json); only
 # num_hidden_layers is cut.
@@ -69,7 +121,7 @@ TOY = dict(
     intermediate_size=256,
     num_attention_heads=8,
     num_key_value_heads=4,
-    vocab_size=BenchTokenizer.VOCAB,
+    vocab_size=WordHashTokenizer.VOCAB,
     rope_theta=500000.0,
     max_position_embeddings=8192,
     rms_norm_eps=1e-5,
@@ -350,7 +402,7 @@ def _run_cli(argv: list[str]) -> dict:
 
     t0 = time.perf_counter()
     with _stderr_kept() as err:
-        cli.main(argv, tokenizer=BenchTokenizer())
+        cli.main(argv, tokenizer=WordHashTokenizer())
     stats = _last_json(err.getvalue(), '"wall_s"')
     stats["smoke_wall_s"] = round(time.perf_counter() - t0, 2)
     return stats
@@ -481,7 +533,7 @@ def _oracle(a, jax, scores) -> dict:
             llama.forward_full(p, cfg, ids, dtype=jnp.float32)[0, -1]
         )
     )
-    tok = PromptTokenizer(BenchTokenizer())
+    tok = PromptTokenizer(WordHashTokenizer())
     prompts = _load(os.path.join(a.work, "prompts.pkl"))[:ORACLE_PROMPTS]
     want = []
     for prefix, suffixes in prompts:
@@ -588,7 +640,7 @@ def _serve(a, out: str, extra: tuple, recs_targets) -> tuple[dict, dict]:
     ]
     t0 = time.perf_counter()
     with _stderr_kept() as err:
-        cli.main(argv, tokenizer=BenchTokenizer())
+        cli.main(argv, tokenizer=WordHashTokenizer())
     stats = _last_json(err.getvalue(), '"event"')
     stats["smoke_wall_s"] = round(time.perf_counter() - t0, 2)
     return stats, recs

@@ -99,8 +99,8 @@ def stack_layer(
 
 def delta_nbytes(delta: Mapping[str, Any] | None) -> int:
     """Host->HBM bytes one shard's delta arrays cost per sweep — the
-    ``fls_adapter_delta_bytes`` charge the bench ratios against the base
-    stream."""
+    ``fls_adapter_delta_bytes`` charge, to be read against the base
+    stream's bytes (a rank-sized sliver of them)."""
     if not delta:
         return 0
     return sum(

@@ -1,7 +1,6 @@
 """flscheck rule framework: registry, pragmas, baseline, runner, reporters.
 
-Design (mirrors how the perf gate made speed claims un-rottable — here the
-claims are *invariants*):
+Design (the claims here are *invariants*):
 
 - **Rules** register into one table via :func:`file_rule` (runs once per
   parsed module) or :func:`project_rule` (runs once over the whole file

@@ -1643,9 +1643,8 @@ def main(argv: list[str] | None = None, tokenizer=None) -> None:
     from flexible_llm_sharding_tpu.runtime.tokenization import count_tokens
 
     # tokens_processed counts every real prefix/suffix token each full-model
-    # pass runs — the same accounting bench.py and BASELINE.md use (the
-    # reference's stats count only generated tokens, which understates the
-    # work by orders of magnitude for scoring workloads).
+    # pass runs (the reference's stats count only generated tokens, which
+    # understates the work by orders of magnitude for scoring workloads).
     tokens_processed = 0
 
     from flexible_llm_sharding_tpu.runtime.executor import (

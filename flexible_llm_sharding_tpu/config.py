@@ -1054,8 +1054,7 @@ class FrameworkConfig:
     # the host->HBM upload of shard t+1 with shard t's compute), 0 on the CPU
     # backend — there "device" memory IS host memory, so there is no transfer
     # link to overlap and the producer thread only steals cores/GIL from
-    # XLA:CPU's own compute (measured: prefetch=2 is ~10% SLOWER than the
-    # serialized schedule on CPU; see bench.py).
+    # XLA:CPU's own compute.
     prefetch_depth: int | None = None
     num_devices: int = 0  # 0 = all visible devices
     bucket_multiple: int = 64  # sequence lengths padded up to a multiple of this

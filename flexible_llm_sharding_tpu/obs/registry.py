@@ -5,8 +5,8 @@ Before this module the repo had four disjoint recorder classes
 (``ServingMetrics``, ``IntegrityRecorder``, ``RetryRecorder``,
 ``StepWatchdog``) plus ad-hoc stats dicts on the executor, host cache,
 and residency tier, stitched together by hand into a printed stats line.
-A router doing health-based draining (ROADMAP item 4) or a CI perf gate
-(item 5) needs those signals as *scrapeable data*, not log greps. So:
+A router doing health-based draining (ROADMAP item 4) or a benchmark's
+per-layer metric needs those signals as *scrapeable data*, not log greps. So:
 
 - ``MetricsRegistry``: named sources (a callable returning a flat dict,
   or any object with ``stats()`` / ``snapshot()``) registered once,

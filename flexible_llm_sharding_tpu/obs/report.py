@@ -12,9 +12,10 @@ JSONL — both are auto-detected). Output:
   the enqueue, and host builds (``shard_load``) move nothing over the
   link: neither counts.
 - **overlap efficiency**: ``1 - source_wait / shard_produce`` — the
-  fraction of weight-produce time hidden under compute, the same
-  definition bench.py derives from executor stats, now computable from
-  any run's trace after the fact.
+  fraction of weight-produce time hidden under compute, the stats
+  line's definition, computable from any run's trace after the fact.
+  Both terms are host time around asynchronous dispatch: what the link
+  and the device did is the sweep account's (``process_sweep_log``).
 - **per-phase sweep breakdown**: total seconds per span name, plus the
   per-sweep phase profile (grouped by ``sweep_id``) showing where a
   sweep's wall goes.

@@ -48,8 +48,8 @@ from collections import deque
 
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-# Prefix of the program's spans in a profiler trace (the benchmark's own
-# are ``bench.``): readers select by it.
+# Prefix of the program's spans in a profiler trace (the benchmark
+# harness's own, ``benchmark/run.py``, are ``bench.``): readers select by it.
 ANNOTATION_PREFIX = "fls."
 # One read of the profiler's flag: true while any session records.
 profiler_active = TraceAnnotation.is_enabled

@@ -739,11 +739,11 @@ def _check_precision_plan(model_path: str, manifest: dict) -> None:
 # dtype (fp16/bf16 travel at half of fp32's link bytes; fp16<->bf16 at the
 # SAME bytes) and converted to the compute dtype inside one jitted program
 # after placement. Anything outside this set (fp64 checkpoints, exotic
-# dtypes) falls back to the host cast. The host side of the stream is
-# CPU-bound long before the link is (BENCH_r05: 1.75 GB/s cast vs 20.97
-# zero-copy), so even the fp32->bf16 case — which uploads 2x the bytes —
-# wins whenever the link outruns the host caster; XLA's convert is RNE,
-# bit-identical to the numpy/native cast it replaces.
+# dtypes) falls back to the host cast. A host cast is a pass over every
+# byte on the producer's thread where the stored bytes could go page cache
+# -> DMA untouched, so even the fp32->bf16 case — which uploads 2x the
+# bytes — wins whenever the link outruns the host caster; XLA's convert is
+# RNE, bit-identical to the numpy/native cast it replaces.
 _DEVICE_CASTABLE = frozenset({"float16", "bfloat16", "float32"})
 
 
@@ -758,7 +758,7 @@ class _HostShardLoader:
 
     def __init__(self, model_path: str, layer_names: Sequence[str], np_dtype,
                  tied_embeddings: bool = False, layer_sliding=None,
-                 layer_rope=None, readahead: str = "auto",
+                 layer_rope=None,
                  retry_policy: RetryPolicy | None = None,
                  injector: FaultInjector | None = None,
                  retry_recorder=None, retry_abort=None,
@@ -768,9 +768,10 @@ class _HostShardLoader:
         # host_cache: a runtime.hostcache.HostShardCache (or None) —
         # build_host_shard consults it before touching disk and inserts
         # verified-clean trees after a build; quarantine invalidates.
-        # device_cast: False restores the host-side numpy/native cast for
-        # every mismatched dtype (the bench's reference arm); True defers
-        # XLA-castable float dtypes to the on-chip cast in _place.
+        # device_cast: True defers XLA-castable float dtypes to the on-chip
+        # cast in _place. False takes the host-side numpy/native cast for
+        # every mismatched dtype: the reference that the device cast is held
+        # to, bit for bit (tests/test_hostcache.py); no entry point passes it.
         self.model_path = model_path
         self._host_cache = host_cache
         self.device_cast = device_cast
@@ -837,16 +838,10 @@ class _HostShardLoader:
         # leaves travel packed, so int8/int4 count their narrow bytes)
         from flexible_llm_sharding_tpu.utils.native import FilePrefetcher
 
-        # readahead warms via posix_fadvise(WILLNEED) only — async kernel
-        # readahead, ~zero CPU — so 'auto' enables it on ANY core count
-        # (the old pread-based warm stole the caster's core on 1-core
-        # hosts, measured 0.66-0.88x; fadvise-only measures 1.05x there,
-        # scripts/readahead_experiment.py). 'off' still disables for the
-        # bench's baseline arm.
-        if readahead == "off":
-            self._prefetcher = None
-        else:
-            self._prefetcher = FilePrefetcher(threads=readahead_threads)
+        # Readahead warms via posix_fadvise(WILLNEED) only — async kernel
+        # readahead, ~zero CPU — so it is on at ANY core count (a
+        # pread-based warm steals the caster's core on a 1-core host).
+        self._prefetcher = FilePrefetcher(threads=readahead_threads)
         # Shard-cache key prefix: everything besides the layer index tuple
         # that shapes a built host tree. The manifest is identified by its
         # FILE stat (atomic writes = new mtime), mirroring the crc verdict
@@ -1783,10 +1778,12 @@ class ShardWeightSource:
     ) -> list[tuple[str, Any]]:
         # produce_time covers the producer's WHOLE per-shard wall — host
         # file->numpy load (load_time counts just that part) plus the
-        # device placement dispatch — the denominator of bench.py's
-        # overlap_efficiency (source_wait_s over produce_wall_s compares
-        # like with like; load_time alone under-counts what overlap must
-        # hide on a slow host->HBM link).
+        # device placement dispatch — the denominator of the stats line's
+        # and the trace report's overlap_efficiency (source_wait_s over
+        # produce_wall_s compares like with like; load_time alone
+        # under-counts what overlap must hide on a slow host->HBM link).
+        # Host time around asynchronous dispatch, not link or device time:
+        # the sweep account's upload_busy_s is the link's.
         ids = self._span_ids(shard_i)
         attrs = dict(
             ids, first=layer_idxs[0] if layer_idxs else -1, n=len(layer_idxs)
@@ -2486,7 +2483,9 @@ class StreamingExecutor:
 
         self.stats = {
             "load_weights_time_s": source.load_time,
-            # From the pass's clock: the compute spans' total, and the
+            # From the pass's clock, both host time around asynchronous
+            # dispatch: the compute spans' total (dispatch plus the waits
+            # for the device inside them, not device busy time), and the
             # driver time blocked waiting on the weight source — the
             # produce time prefetch did NOT hide (serialized schedule ->
             # ~all of produce_wall_s; perfect overlap -> the first shard
@@ -2659,14 +2658,15 @@ class StreamingExecutor:
                         # weights unused. Its wait is NOT counted against
                         # overlap efficiency — skipped shards run no
                         # compute that could hide it — so the trace's
-                        # source_wait total matches the stats/bench
+                        # source_wait total matches the stats line's
                         # overlap-efficiency definition exactly.
                         wait.drop()
                         del segments
                         continue
                 # Driver time blocked on the weight source — the exact
                 # NOT-hidden load time (prefetch hides the rest); the
-                # numerator of bench.py's overlap_efficiency.
+                # numerator of overlap_efficiency (cli stats line, trace
+                # report).
                 clock.source_wait_s += wait.dur_s
                 # Global shard index: shared sources yield every shard
                 # from 0 (skip consumed the resumed prefix); an own

@@ -31,7 +31,7 @@ Three pieces live here:
 
 The probe holds the whole (calibration-scale) model in host RAM and runs
 monolithic forwards — it is an OFFLINE calibration tool for the same
-small-model regime the test/bench oracles use, not a streaming path. For
+small-model regime the tests' oracles use, not a streaming path. For
 very large models, probe a truncated proxy or raise the calibration
 host's RAM; the plan file it emits is size-independent.
 """
@@ -75,8 +75,8 @@ class PrecisionPlan:
     :data:`PLAN_DTYPES`. ``divergence_cap`` is the plan's DECLARED cap on
     end-to-end next-token KL vs the bf16 oracle: the user's cap in cap
     mode, or the calibration-measured divergence with headroom in budget
-    mode — the bench's e2e check and the acceptance criterion both gate
-    against this declared number."""
+    mode — the tests' end-to-end check (a mixed stream's scores against
+    the bf16 stream's) holds the run to this declared number."""
 
     layers: tuple[tuple[str, str], ...]
     divergence_cap: float
@@ -423,7 +423,7 @@ def _next_token_probs(params_dev, model_cfg, rows) -> np.ndarray:
 
 def kl_divergence(p: np.ndarray, q: np.ndarray) -> float:
     """Mean KL(p || q) over rows, numerically floored — the probe's and
-    the bench's ONE divergence definition."""
+    the tests' ONE divergence definition."""
     p = np.clip(np.asarray(p, np.float64), 1e-12, None)
     q = np.clip(np.asarray(q, np.float64), 1e-12, None)
     p = p / p.sum(axis=-1, keepdims=True)

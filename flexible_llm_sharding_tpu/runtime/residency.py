@@ -825,7 +825,8 @@ def process_tier() -> DeviceResidencyTier | None:
 
 
 def reset_process_tier() -> None:
-    """Drop the process tier and its pins (tests; benches isolating arms).
+    """Drop the process tier and its pins (tests; the benchmark between a
+    run's window and its reference).
     The placed device arrays free once the last source's references go."""
     global _PROCESS_TIER, _PROCESS_TIER_KEY, _PROCESS_BUDGET_EXPLICIT
     with _PROCESS_LOCK:

@@ -60,8 +60,9 @@ class TokenizedPrompt:
     @property
     def tokens_processed(self) -> int:
         """Real (non-padding) tokens one full-model pass runs for this prompt:
-        the prefix plus every true suffix's real tokens. The shared accounting
-        unit for the CLI stats line, bench.py, and BASELINE.md throughput."""
+        the prefix plus every true suffix's real tokens. The accounting unit
+        of the CLI stats line's throughput (the benchmark's
+        ``score_tokens_per_s`` counts the same tokens)."""
         return self.prefix_len + int(
             (self.suffix_eos[: self.num_suffixes] + 1).sum()
         )
@@ -231,7 +232,8 @@ def count_tokens(tokenizer, prompts, max_token_len: int = 4096) -> int:
     the same semantics as PromptTokenizer (prefix truncated to
     ``max_token_len``; per-suffix leading BOS stripped). Host-side only —
     negligible next to a streaming pass; used by the CLI so its throughput
-    line counts the same thing bench.py does."""
+    line counts what ``tokens_processed`` counts without building the
+    padded arrays."""
     total = 0
     for prefix, suffixes in prompts:
         pids = tokenizer(

@@ -1,7 +1,7 @@
 """Online serving subsystem: shard-aware continuous batching over the
 streaming decode runtime.
 
-Every offline entry point (cli scoring, bench) is a batch run
+Every offline entry point (cli scoring, kv decode) is a batch run
 over a fixed prompt set; this package turns the same runtime into a server:
 
 - ``request``  — request/response dataclasses + per-request state machine.

@@ -1155,8 +1155,8 @@ class ServeEngine:
         no stacking, no transfer, identical trace). The [k, G, D, R]
         factor stacks are built and device_put ONCE per (shard,
         segment) and cached on the wave; only then do their bytes count
-        against ``fls_adapter_delta_bytes`` — the link charge the bench
-        ratios against the base stream."""
+        against ``fls_adapter_delta_bytes`` — the link charge to be read
+        against the base stream's bytes."""
         if st.adapter_scales is None:
             return None
         key = (shard_pos, di)

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule, applied by every entry point (``cli.main``, ``cli.serve_main``,
-``bench.py``, ``chip_smoke.py``) before first JAX use: when
+``chip_smoke.py``, ``benchmark/run.py``) before first JAX use: when
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no directory is
 set in code; otherwise the cache sits at ONE fixed path inside the checkout.
 The path is part of the cache key, so it is never a temp name, a pid or a

@@ -424,8 +424,8 @@ class ServingMetrics:
         # Prefix-prefill token accounting (runtime/kvpool.py reuse):
         # prefix_prefill_tokens = prefix tokens actually prefilled;
         # prefix_reuse_tokens = prefix tokens served from pooled pages
-        # with ZERO prefill recompute (the kv_prefix_reuse_frac bench
-        # metric is reuse / (reuse + prefill)).
+        # with ZERO prefill recompute (the share of prefix work the
+        # pool saved is reuse / (reuse + prefill)).
         "prefix_prefill_tokens",
         "prefix_reuse_tokens",
         "sweeps",

@@ -102,30 +102,21 @@ def _load_lib() -> ctypes.CDLL | None:
                 ctypes.c_void_p,
                 ctypes.c_long,
             ]
-            # Newer entry points bind individually: a prebuilt .so from an
+            # The newer entry point binds on its own: a prebuilt .so from an
             # older source set keeps its working symbols instead of taking
             # down the whole native path.
-            for sym, restype, argtypes in (
-                ("fp_drop_cache", ctypes.c_long, [ctypes.c_char_p]),
-                (
-                    "cv_convert",
+            try:
+                lib.cv_convert.restype = ctypes.c_long
+                lib.cv_convert.argtypes = [
+                    ctypes.c_void_p,
+                    ctypes.c_void_p,
                     ctypes.c_long,
-                    [
-                        ctypes.c_void_p,
-                        ctypes.c_void_p,
-                        ctypes.c_long,
-                        ctypes.c_int,
-                        ctypes.c_int,
-                        ctypes.c_int,
-                    ],
-                ),
-            ):
-                try:
-                    fn = getattr(lib, sym)
-                    fn.restype = restype
-                    fn.argtypes = argtypes
-                except AttributeError:
-                    pass  # callers probe with getattr and fall back
+                    ctypes.c_int,
+                    ctypes.c_int,
+                    ctypes.c_int,
+                ]
+            except AttributeError:
+                pass  # convert_array probes with getattr and falls back
             _lib = lib
         except Exception:
             _lib_failed = True
@@ -144,8 +135,7 @@ class FilePrefetcher:
     Native path: C++ worker pool issuing ``posix_fadvise(WILLNEED)`` — the
     kernel schedules the readahead asynchronously (DMA), so warming costs
     ~zero CPU and never contends with the caller's cast/stack work (a
-    full-pread warm measured 0.66-0.88x on a 1-core host; fadvise-only
-    measures 1.05x — scripts/readahead_experiment.py). Fallback: the same
+    full-pread warm steals the caster's core on a 1-core host). Fallback: the same
     fadvise from Python. ``native`` reports which path is active.
     """
 
@@ -293,19 +283,6 @@ def convert_array(a, np_dtype, threads: int | None = None):
     return dst if rc == 0 else None
 
 
-def drop_file_cache(*paths: str) -> bool:
-    """Best-effort eviction of files from the OS page cache (native
-    FADV_DONTNEED). Returns True if the native lib handled every path —
-    the cold-cache loader benchmark is only meaningful when it did."""
-    lib = _load_lib()
-    if lib is None or getattr(lib, "fp_drop_cache", None) is None:
-        return False
-    ok = True
-    for p in paths:
-        ok = lib.fp_drop_cache(p.encode()) == 0 and ok
-    return ok
-
-
 def read_file_native(path: str) -> bytes | None:
     """Whole-file read through the native pread loop (None if no native lib
     or on IO error) — exercised by tests; a pinned-buffer IO building block."""
@@ -324,6 +301,5 @@ __all__ = [
     "FilePrefetcher",
     "available_cpus",
     "convert_array",
-    "drop_file_cache",
     "read_file_native",
 ]

@@ -8,10 +8,8 @@
 // work on that thread. This pool warms upcoming files into the page cache
 // via posix_fadvise(WILLNEED): the KERNEL schedules the readahead (DMA into
 // the page cache) asynchronously, so warming costs ~zero CPU and cannot
-// contend with the cast/stack work — measured on a 1-core host, a
-// fadvise-only warm is 1.05x on the cold cast stream where the previous
-// full-pread warm was 0.66-0.88x (it stole the caster's only core; see
-// scripts/readahead_experiment.py for the rotated-order methodology).
+// contend with the cast/stack work (a full-pread warm steals the caster's
+// only core on a 1-core host).
 // Filesystems that ignore fadvise degrade to a no-op, never to contention.
 //
 // Exposed as a plain C ABI for ctypes (no pybind11 in this environment);
@@ -120,26 +118,6 @@ void fp_prefetch(void* handle, const char* path) {
 void fp_wait_all(void* handle) { static_cast<Pool*>(handle)->wait_all(); }
 
 void fp_destroy(void* handle) { delete static_cast<Pool*>(handle); }
-
-// Evict a file's pages from the OS page cache (fsync + FADV_DONTNEED).
-// Returns 0 on success, -1 if the file can't be opened. Used by the host
-// weight-stream benchmark to measure COLD-cache loader throughput — a
-// warm second pass reads from RAM and says nothing about the disk path.
-long fp_drop_cache(const char* path) {
-  int fd = open(path, O_RDONLY);
-  if (fd < 0) return -1;
-#ifdef POSIX_FADV_DONTNEED
-  fdatasync(fd);
-  int rc = posix_fadvise(fd, 0, 0, POSIX_FADV_DONTNEED);
-  close(fd);
-  return rc == 0 ? 0 : -1;
-#else
-  // No eviction happened: claiming success would let the benchmark label
-  // warm-cache readings as "cold".
-  close(fd);
-  return -1;
-#endif
-}
 
 // Direct bulk read into a caller buffer (ctypes-owned); returns bytes read
 // or -1. Used for tests and as a building block for future pinned-buffer IO.

@@ -121,6 +121,41 @@ def test_stagger_membership_change_drops_holds():
     assert ctl.hold_frac(1) == 0.0
 
 
+def test_stagger_closed_loop_converges_and_reconverges():
+    """The controller in a closed loop on synthetic sweep clocks (no wall
+    clock anywhere): two replicas start dead in phase (error 1.0), its
+    boundary holds feed back into their schedules, and the error must fall
+    under the tolerance; after a recycle (membership change plus a quarter
+    sweep's phase jump) it must fall there again. Holds must have been
+    applied in both rounds: convergence without actuation would be the
+    simulation's accident, not the controller's work."""
+    ctl = _stagger(stagger_tolerance=0.05)
+    wall = 1.0
+    nxt = {0: 0.0, 1: 0.0}  # next shard-0 boundary
+    start = {0: 0.0, 1: 0.0}  # current sweep's start, after any hold
+    t, err_by_round, holds_by_round = 0.0, [1.0, 1.0], [0, 0]
+    for step in range(800):
+        rnd = 0 if step < 400 else 1
+        t = round(t + 0.1, 6)
+        if step == 400:
+            ctl.note_membership_change()
+            nxt[1] = round(nxt[1] + 0.25 * wall, 6)
+            start[1] = nxt[1] - wall
+        for idx in (0, 1):
+            while t >= nxt[idx]:
+                hold = ctl.on_boundary(idx, nxt[idx])
+                holds_by_round[rnd] += hold > 0.0
+                start[idx] = nxt[idx] + hold
+                nxt[idx] = round(start[idx] + wall, 6)
+        err_by_round[rnd] = ctl.observe(
+            {i: min(max((t - start[i]) / wall, 0.0), 0.999) for i in (0, 1)}
+        )
+    assert min(holds_by_round) >= 1, holds_by_round
+    assert max(err_by_round) <= 0.05, err_by_round
+    s = ctl.stats()
+    assert s["restaggers"] == 1 and s["stagger_converged"] == 1
+
+
 def test_stagger_no_wall_no_hold():
     ctl = _stagger()
     # No boundary history: walls unknown, so no hold can be sized.

@@ -17,7 +17,11 @@ from flexible_llm_sharding_tpu.runtime import executor as executor_mod
 from flexible_llm_sharding_tpu.runtime import orchestration
 from flexible_llm_sharding_tpu.runtime.executor import StreamingExecutor
 from flexible_llm_sharding_tpu.runtime.tokenization import PromptTokenizer, make_blocks
-from flexible_llm_sharding_tpu.utils.checkpoint import save_params
+from flexible_llm_sharding_tpu.utils.checkpoint import (
+    layer_names_for,
+    load_layer,
+    save_params,
+)
 
 from tests.fake_tokenizer import FakeTokenizer
 
@@ -130,6 +134,74 @@ def test_executor_shard_sizes(tiny_cfg, model_dir, expected, lnps):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
 
 
+def _layer_at_a_time_scores(path, cfg, prompts, decoder_order=None):
+    """The reference's schedule, plainly, sharing no code with
+    ``runtime/executor.py``: one layer file at a time, one prompt at a time,
+    every activation back on the host between two layers. No shards, no
+    stacked scan, no blocks, no prefetch, no store. ``decoder_order``
+    permutes the decoder layers (the control: scores must then differ)."""
+    tok = PromptTokenizer(FakeTokenizer(), bucket_multiple=8)
+    toks = [tok(prefix, suffixes) for prefix, suffixes in prompts]
+    names = layer_names_for(cfg.num_hidden_layers)
+    if decoder_order is not None:
+        names = [names[0], *(names[1 + i] for i in decoder_order), *names[-2:]]
+    acts = [None] * len(toks)
+    scores = [None] * len(toks)
+    for name in names:
+        params = jax.tree.map(jnp.asarray, load_layer(path, name))
+        for i, t in enumerate(toks):
+            if name == "model.embed_tokens":
+                ph = llama.embed(params, jnp.asarray(t.prefix_ids), jnp.float32, cfg)
+                sh = llama.embed(params, jnp.asarray(t.suffix_ids), jnp.float32, cfg)
+            else:
+                ph, sh = (jnp.asarray(a) for a in acts[i])
+                if name.startswith("model.layers."):
+                    ph, sh = llama.prefix_suffix_layer(
+                        params, cfg, ph, sh, jnp.int32(t.prefix_len)
+                    )
+                elif name == "model.norm":
+                    sh = llama.select_eos_and_norm(
+                        params, cfg, sh, jnp.asarray(t.suffix_eos)
+                    )
+                else:  # lm_head
+                    sc = llama.lm_head_scores(params, sh, cfg.final_logit_softcap)
+                    scores[i] = np.asarray(sc)[: t.num_suffixes, None, :]
+            acts[i] = (np.asarray(ph), np.asarray(sh))
+    return scores
+
+
+@pytest.mark.parametrize("decoder_order", [None, (0, 2, 1, 3)], ids=["in_order", "swapped"])
+def test_reference_schedule_matches_executor(tiny_cfg, model_dir, decoder_order):
+    """The overlapped executor (shards of two stacked layers under one scan,
+    blocks of prompts, a prefetch thread, the cpu activation store) against
+    the plain layer-at-a-time schedule on the same files: the same scores.
+    With two layers of one shard swapped in the plain schedule the scores
+    must differ, so a shard applied out of order cannot pass the first case."""
+    path, _ = model_dir
+    cfg = FrameworkConfig(
+        model_path=path,
+        layer_num_per_shard=2,
+        storage_location="cpu",
+        dtype="float32",
+        bucket_multiple=8,
+        block_size=2,
+        prefetch_depth=2,
+    )
+    got = StreamingExecutor(cfg, tokenizer=FakeTokenizer())(list(PROMPTS))
+    want = _layer_at_a_time_scores(path, tiny_cfg, PROMPTS, decoder_order)
+    assert len(got) == len(want) == len(PROMPTS)
+    for g, w in zip(got, want):
+        assert np.asarray(g).shape == w.shape
+    close = [
+        np.allclose(np.asarray(g, np.float32), w, rtol=1e-5, atol=1e-6)
+        for g, w in zip(got, want)
+    ]
+    if decoder_order is None:
+        assert all(close)
+    else:
+        assert not any(close)
+
+
 def test_executor_tied_embeddings(tiny_cfg, tmp_path):
     """Tied-embedding checkpoints (no lm_head file, Llama-3.2 style): the
     head kernel is re-materialised from the embedding at stream time."""
@@ -194,6 +266,25 @@ def test_make_blocks_groups_by_bucket():
 # ---------------------------------------------------------------------------
 
 PHASES = ("head_s", "source_wait_s", "dispatch_s", "device_wait_s", "tail_s")
+# The consumer's spans on the timeline, and the phases each one's time is.
+SPAN_OF_PHASE = {
+    "sweep_head": ("head_s",),
+    "source_wait": ("source_wait_s",),
+    "compute": ("dispatch_s", "device_wait_s"),
+    "sweep_tail": ("tail_s",),
+}
+
+
+def _assert_phases_partition(rec, slack_s=0.02):
+    """The consumer's five phases are disjoint stretches of the sweep's
+    wall: none counted twice (their sum cannot exceed ``wall_s``) and none
+    missing (what no phase accounts for is the stamps between two spans:
+    microseconds, a thread switch under a loaded machine at most, so an
+    absolute bound and not a share of a toy sweep's tens of milliseconds)."""
+    assert all(rec[k] >= 0.0 for k in PHASES)
+    total = sum(rec[k] for k in PHASES)
+    assert total <= rec["wall_s"] + 1e-9
+    assert rec["wall_s"] - total < slack_s
 
 
 def _account_cfg(path, **kw):
@@ -231,8 +322,7 @@ def test_run_prompts_writes_one_sweep_record(model_dir, prefetch_depth):
     (rec,) = new  # one pass over the shards, one record
     # The consumer's five phases partition the wall (entry of run_prompts ->
     # scores returned), which the same call timed from outside contains.
-    assert sum(rec[k] for k in PHASES) == pytest.approx(rec["wall_s"], rel=0.02)
-    assert all(rec[k] >= 0.0 for k in PHASES)
+    _assert_phases_partition(rec)
     assert 0.9 * outside <= rec["wall_s"] <= outside
     # The link's side: bytes by construction the streamed-bytes counter's
     # delta, one upload per shard, all seen to completion, busy <= wall.
@@ -274,7 +364,26 @@ def test_num_batch_passes_each_write_a_record_and_first_owns_the_head(model_dir)
     first, second = executor_mod.process_sweep_log()[n0:][-2:]
     assert second["sweep_id"] > first["sweep_id"]
     for rec in (first, second):
-        assert sum(rec[k] for k in PHASES) == pytest.approx(rec["wall_s"], rel=0.02)
+        _assert_phases_partition(rec)
+        # The same stamps twice: each phase of the record is the sum of its
+        # spans on the timeline (the ring rounds to the microsecond), and
+        # those spans lie inside the sweep's own, one after another: exact
+        # whatever the machine's load.
+        mine = sorted(
+            (s for s in spans if s.get("sweep_id") == rec["sweep_id"]),
+            key=lambda s: s["ts_s"],
+        )
+        (sweep,) = [s for s in mine if s["name"] == "sweep"]
+        parts = [s for s in mine if s["name"] in SPAN_OF_PHASE]
+        for name, keys in SPAN_OF_PHASE.items():
+            assert sum(s["dur_s"] for s in parts if s["name"] == name) == (
+                pytest.approx(sum(rec[k] for k in keys), abs=1e-5)
+            ), name
+        assert sweep["dur_s"] == pytest.approx(rec["wall_s"], abs=1e-5)
+        ends = [sweep["ts_s"]] + [s["ts_s"] + s["dur_s"] for s in parts]
+        for end, nxt in zip(ends, parts):
+            assert end <= nxt["ts_s"] + 2e-6, (nxt["name"], end)
+        assert ends[-1] <= sweep["ts_s"] + sweep["dur_s"] + 2e-6
     # The executor's construction happens once, inside the first pass's head.
     (init,) = [s for s in spans if s["name"] == "executor_init"]
     assert init["sweep_id"] == first["sweep_id"]

@@ -201,6 +201,23 @@ def test_loader_cache_hits_are_bit_identical(model_dir):
     plain.close()
 
 
+def test_three_sweeps_of_one_loader_hit_two_in_three(model_dir):
+    """A shard a layer, three sweeps, a budget that evicts nothing: sweep 1
+    misses every shard and sweeps 2 and 3 hit every one, so the cache's own
+    cumulative rate reads exactly 2/3 (lower would mean an eviction or a
+    key that changed between sweeps)."""
+    cache = HostShardCache(budget_bytes=1 << 30)
+    loader = _loader(model_dir, cache=cache)
+    n = len(loader.layer_names)
+    for _ in range(3):
+        for i in range(n):
+            loader.build_host_shard((i,))
+    loader.close()
+    s = cache.stats()
+    assert (s["misses"], s["hits"], s["evictions"]) == (n, 2 * n, 0)
+    assert s["hit_rate"] == pytest.approx(2 / 3, abs=1e-4)
+
+
 def test_quarantine_purges_cache_and_verdicts(model_dir):
     cache = HostShardCache(budget_bytes=1 << 30)
     clean = _loader(model_dir, cache=cache)

@@ -1,5 +1,6 @@
 """Pallas flash-attention kernels vs the XLA reference path (interpret mode
-on CPU; the compiled path is exercised on real TPU hardware by bench/drives)."""
+on CPU; the compiled path runs on the chip in `chip_smoke.py` and in every
+cell of the benchmark, and compiles for it in `tests/test_tpu_compile.py`)."""
 
 import numpy as np
 import pytest

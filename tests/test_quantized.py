@@ -214,8 +214,7 @@ def test_int8_tied_embeddings(tiny_cfg, tmp_path):
 
 
 def test_requantize_native_dir(dirs, tiny_cfg, tmp_path):
-    """requantize_native (native dir -> int8, no HF source needed — the
-    bench's path) produces a checkpoint the executor streams correctly."""
+    """requantize_native (native dir -> int8, no HF source needed) produces a checkpoint the executor streams correctly."""
     f32, _, _ = dirs
     q8 = tmp_path / "q8b"
     names = ckpt.requantize_native(f32, str(q8))
@@ -1088,6 +1087,51 @@ def test_layer_dtype_bytes_matches_materialized(dirs_mixed, tiny_cfg):
         )
         actual = sum(np.asarray(v).nbytes for v in flat.values())
         assert est == actual, (name, dt, est, actual)
+
+
+@pytest.mark.parametrize("plan_kind", ["hand_built", "budget_0.6"])
+def test_mixed_stream_moves_the_plans_bytes(dirs_mixed, tmp_path, plan_kind):
+    """What the link carries, by the executors' own ``streamed_bytes`` over
+    identical sweeps: a uniform-bf16 directory streams the planner's bf16
+    estimate and a mixed one the plan's, byte for byte. A plan built for
+    60% of the bf16 bytes therefore takes at least 35% of them off the link
+    (the mixed-precision acceptance line) and stays under the divergence cap
+    it declares; a converter, loader or counter that fell back to bf16 would
+    read 0."""
+    f32, bf16, mixed, plan = dirs_mixed
+    names = ckpt.layer_names_for(4, False)
+    est = {n: pp.layer_dtype_bytes(ckpt.load_layer(f32, n)) for n in names}
+    bf16_bytes = sum(e["bf16"] for e in est.values())
+    if plan_kind == "budget_0.6":
+        budget = int(bf16_bytes * 0.6)
+        plan = pp.build_plan(f32, PROMPTS[:1], FakeTokenizer(), bytes_budget=budget)
+        assert plan.est_bytes <= budget
+        mixed = str(tmp_path / "mixed06")
+        ckpt.requantize_native(f32, mixed, plan=plan)
+
+    def streamed(path):
+        fw = FrameworkConfig(
+            model_path=path, dtype="float32", bucket_multiple=8,
+            prefetch_depth=0, host_cache_gb=0.0, hbm_pin_gb=0.0,
+        )
+        ex = StreamingExecutor(fw, tokenizer=FakeTokenizer())
+        return ex(PROMPTS), ex.stats["streamed_bytes"]
+
+    (scores_b, b), (scores_m, m) = streamed(bf16), streamed(mixed)
+    assert b == bf16_bytes
+    assert m == sum(est[n][dt] for n, dt in plan.layers)
+    if plan_kind == "budget_0.6":
+        assert m == plan.est_bytes
+        assert 1.0 - m / b >= 0.35
+        # And the quality side of the same plan: the mixed stream's
+        # next-token distributions against the bf16 stream's, under the
+        # cap the plan itself declares.
+        divs = [
+            pp.kl_divergence(sb[i, 0][None], sm[i, 0][None])
+            for sb, sm in zip(scores_b, scores_m)
+            for i in range(sb.shape[0])
+        ]
+        assert float(np.mean(divs)) <= plan.divergence_cap
 
 
 def test_mixed_composes_with_tensor_parallel(tmp_path):

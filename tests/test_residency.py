@@ -592,41 +592,38 @@ def test_verify_cli_residency_dry_run(model_dir):
         verify_main(["--spill_dir", model_dir, "--hbm_pin_gb", "1"])
 
 
-def test_bench_pinned_fraction_zeroes_when_tier_disengaged(
-    model_dir, monkeypatch
+def test_half_budget_pins_the_planned_fraction_and_the_sweep_saves_it(
+    model_dir,
 ):
-    """The perf gate uses ``pinned_fraction`` as its tier-disengaged
-    detector, so bench must report the planner's ratio ONLY when the pin
-    arm's executor stats prove the runtime tier engaged (nonzero resident
-    bytes and saved link bytes); a run that silently streamed everything
-    records 0.0 and trips the gate's structural floor."""
-    import bench
+    """A budget of half the model's bytes: the planner's ``pinned_fraction``
+    is its pinned bytes over the model's, and a warm sweep's own counters
+    show the link spared exactly that share (zero saved bytes beside a
+    nonzero plan would be a tier that never engaged)."""
+    names = layer_names_for(4)
+    total = sum(_sizes(model_dir).values())
+    plan = residency.plan_residency(model_dir, names, total // 2)
+    assert plan.total_bytes_est == total
+    assert 0 < plan.pinned_bytes_est <= total // 2
+    assert plan.pinned_fraction == plan.pinned_bytes_est / total
+    assert 0.25 < plan.pinned_fraction <= 0.5
 
-    class _Stub:
-        def __init__(self, stats):
-            self.stats = stats
-
-    def _fake_run_once(stats):
-        return lambda cfg, prompts, tok: (None, 1.0, _Stub(stats))
-
-    def _run(stats):
-        result = {}
-        monkeypatch.setattr(bench, "run_once", _fake_run_once(stats))
-        bench.bench_residency(
-            result,
-            model_dir,
-            list(PROMPTS),
-            FakeTokenizer(),
-            lambda: 1.0,
-            lambda prefetch: _fw(model_dir, prefetch_depth=prefetch),
-        )
-        return result
-
-    disengaged = _run({})  # no residency keys: tier never attached
-    assert disengaged["pinned_fraction"] == 0.0
-
-    engaged = _run({"pinned_bytes": 1.0, "stream_bytes_saved": 1.0})
-    assert engaged["pinned_fraction"] > 0.0
+    off = StreamingExecutor(_fw(model_dir), tokenizer=FakeTokenizer())
+    off(list(PROMPTS))
+    full_stream = off.stats["streamed_bytes"]
+    ex = StreamingExecutor(
+        _fw(model_dir, hbm_pin_gb=(total // 2) / 1e9),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))  # seats the pins
+    ex(list(PROMPTS))
+    s2 = dict(ex.stats)
+    assert residency.process_tier().plan.pinned == plan.pinned
+    assert s2["pinned_bytes"] > 0
+    assert s2["streamed_bytes"] + s2["stream_bytes_saved"] == full_stream
+    # The plan counts file bytes, the sweep tensor bytes: headers apart.
+    assert s2["stream_bytes_saved"] / full_stream == pytest.approx(
+        plan.pinned_fraction, rel=0.01
+    )
 
 
 def test_first_seat_wins_and_is_counted_once(model_dir):

@@ -111,6 +111,16 @@ def _serve(model_dir, spec_k, **serve_kw):
     )
 
 
+def _context_base_len(prompt) -> int:
+    """Length of a one-suffix prompt's draft context before any token is
+    generated (prefix + the suffix's real tokens): what a scripted draft
+    source subtracts to know how many tokens are done."""
+    from flexible_llm_sharding_tpu.runtime.tokenization import PromptTokenizer
+
+    tp = PromptTokenizer(FakeTokenizer(), bucket_multiple=8)(*prompt)
+    return tp.prefix_len + int(tp.suffix_eos[0]) + 1
+
+
 def _assert_same_result(res, want_scores, want_updated):
     assert res.updated == want_updated
     assert (res.tokens == want_scores.argmax(-1)).all()
@@ -258,11 +268,7 @@ def test_spec_serve_zero_acceptance_costs_no_extra_sweeps(
     plain_sweeps = plain_engine.metrics.counter("sweeps")
     chain = [int(t) for t in plain.tokens[0]]
 
-    from flexible_llm_sharding_tpu.runtime.tokenization import PromptTokenizer
-
-    tok = PromptTokenizer(FakeTokenizer(), bucket_multiple=8)
-    tp = tok(*prompt)
-    base_len = tp.prefix_len + int(tp.suffix_eos[0]) + 1
+    base_len = _context_base_len(prompt)
 
     def anti_draft(context_ids, k, ngram=2, corpus=None):
         # done tokens so far (incl. prefill's); the next picks are
@@ -289,6 +295,56 @@ def test_spec_serve_zero_acceptance_costs_no_extra_sweeps(
     assert spec["accepted_tokens"] == 0
     assert spec["drafted_tokens"] > 0
     assert spec["rejected_tokens"] == spec["drafted_tokens"]
+
+
+def test_spec_serve_perfect_draft_emits_k_plus_one_tokens_a_sweep(
+    model_dir, monkeypatch
+):
+    """The mirror case: a draft source that replays the plain run's own
+    greedy chain is accepted in full, so two identical requests of 8 new
+    tokens at k = 7 take the prefill sweep and ONE verify sweep where plain
+    serving takes 8: 16 tokens over 2 sweeps. A verify pass that stopped
+    engaging would read 8 sweeps again, whatever the machine."""
+    n_gen, k = 8, 7
+    prompt = (PROMPTS[0][0], (PROMPTS[0][1][0],))
+
+    def run(spec_k):
+        engine = ServeEngine(
+            _fw(model_dir, num_gen_token=n_gen),
+            ServeConfig(
+                max_wave_requests=2, default_max_new_tokens=n_gen,
+                speculative_k=spec_k,
+            ),
+            tokenizer=FakeTokenizer(),
+            start=False,  # both requests admit at one boundary
+        )
+        try:
+            reqs = [engine.submit(*prompt) for _ in range(2)]
+            engine.start()
+            out = [r.future.result(timeout=300) for r in reqs]
+        finally:
+            engine.shutdown(drain=True)
+        assert engine.error is None
+        return out, engine.stats()
+
+    plain, plain_stats = run(0)
+    chain = [int(t) for t in plain[0].tokens[0]]
+
+    base_len = _context_base_len(prompt)
+
+    def replay_draft(context_ids, k, ngram=2, corpus=None):
+        done = len(context_ids) - base_len  # tokens generated so far
+        d = chain[done : done + k]
+        return np.asarray(d + [chain[-1]] * (k - len(d)), np.int64)
+
+    monkeypatch.setattr(decode_mod, "propose_draft", replay_draft)
+    spec, spec_stats = run(k)
+    for res, p in zip(spec, plain):
+        _assert_same_result(res, p.scores, p.updated)
+    assert plain_stats["sweeps"] == n_gen
+    assert spec_stats["sweeps"] == 2
+    assert spec_stats["tokens_emitted"] == 2 * n_gen
+    assert spec_stats["spec"]["acceptance_rate"] == 1.0
 
 
 # ---------------------------------------------------------------------------
