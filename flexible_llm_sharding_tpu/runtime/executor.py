@@ -24,6 +24,7 @@ TPU-first redesign (SURVEY.md §7):
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -668,6 +669,10 @@ SWEEP_RECORD_HELP = {
     "arrival) inside the sweep: the time the link carried weights.",
     "upload_bytes": "Host bytes handed to device_put for streamed layers "
     "(upload_bytes / upload_busy_s is the link's rate while it carries).",
+    "upload_pinned_bytes": "The part of upload_bytes whose tree the host "
+    "cache held in the chip's pinned_host memory (moved with a memory-space "
+    "device_put, no staging copy); upload_pinned_bytes / upload_bytes is 1 "
+    "once every streamed layer's copy is made.",
     "uploads": "Weight uploads seen to completion.",
     "upload_misses": "Uploads whose arrays were deleted before the "
     "completion thread could wait on them.",
@@ -764,16 +769,26 @@ class _HostShardLoader:
                  retry_recorder=None, retry_abort=None,
                  integrity=None, verify_weights: bool = True,
                  host_cache=None, readahead_threads: int = 2,
-                 device_cast: bool = True):
+                 device_cast: bool = True, pinned_host=None):
         # host_cache: a runtime.hostcache.HostShardCache (or None) —
         # build_host_shard consults it before touching disk and inserts
         # verified-clean trees after a build; quarantine invalidates.
+        # pinned_host: the sharding of the ONE chip's ``pinned_host`` memory
+        # that every tree of this loader is uploaded to (_pinned_host_of;
+        # None for a source with several targets, a placement, or a backend
+        # without that memory). Where set, the cache is asked to hold the
+        # streamed layers' trees there and a hit may return jax.Array leaves
+        # in that memory instead of NumPy ones (same bytes; _place moves
+        # them with a memory-space device_put). The cache's keys stay
+        # chip-free: the cache itself keeps such a tree from a reader with
+        # another target (hostcache.HostShardCache.get).
         # device_cast: True defers XLA-castable float dtypes to the on-chip
         # cast in _place. False takes the host-side numpy/native cast for
         # every mismatched dtype: the reference that the device cast is held
         # to, bit for bit (tests/test_hostcache.py); no entry point passes it.
         self.model_path = model_path
         self._host_cache = host_cache
+        self._pinned_host = pinned_host
         self.device_cast = device_cast
         # Host-cast fallback accounting (the warm path must not take it).
         self.host_casts = 0
@@ -1093,10 +1108,22 @@ class _HostShardLoader:
 
         return jax.tree.map(one, tree, is_leaf=checkpoint.is_quantized_leaf)
 
-    def build_host_shard(self, layer_idxs: tuple[int, ...]) -> list[tuple[str, Any]]:
+    def build_host_shard(
+        self, layer_idxs: tuple[int, ...], streamed: bool = True,
+        upload: bool = True,
+    ) -> list[tuple[str, Any]]:
         # Traced wrapper: one "shard_load" span per host build (cache hits
         # included — their near-zero duration IS the cache's evidence in
         # the timeline; the hostcache emits its own hit/miss instants).
+        # streamed=False: a layer the residency tier is about to seat. It is
+        # uploaded once and then resident, so its tree is worth no pinned
+        # copy, and the host cache takes it only where there is room (a
+        # restart or a re-seat finds it there): the 10-11 GB of a seating
+        # sweep push out no entry of a layer that crosses the link every
+        # sweep.
+        # upload=False: built ahead of its place in the sweep, for the cache
+        # alone (ShardWeightSource._build_streamed_first); the build that
+        # uploads it follows and counts its bytes.
         with obs_trace.timed(
             "shard_load",
             cat="stream",
@@ -1104,17 +1131,33 @@ class _HostShardLoader:
             n=len(layer_idxs),
             **self.trace_ids,
         ) as sp:
-            out = self._build_host_shard(layer_idxs)
+            out = self._build_host_shard(layer_idxs, streamed, upload)
         self.build_time += sp.dur_s
         return out
 
+    def _count_streamed(self, shard_bytes: int) -> None:
+        self.bytes_loaded += shard_bytes
+        with _PROCESS_STREAM_LOCK:
+            _PROCESS_STREAM_BYTES[0] += shard_bytes
+
+    def _ask_pinned(self, cache_key, segments) -> None:
+        """Ask the cache to hold a streamed shard's tree in the target
+        chip's ``pinned_host`` memory (hostcache.HostShardCache.pin: made
+        once, off this thread). Only a tree that travels as stored: one
+        with quantized leaves is dequantized on placement and keeps its
+        NumPy form."""
+        if not any(_has_quantized(seg) for _, seg in segments):
+            self._host_cache.pin(cache_key, segments, self._pinned_host)
+
     def _build_host_shard(
-        self, layer_idxs: tuple[int, ...]
+        self, layer_idxs: tuple[int, ...], streamed: bool = True,
+        upload: bool = True,
     ) -> list[tuple[str, Any]]:
         from flexible_llm_sharding_tpu.runtime.hostcache import stat_guard
 
         cache = self._host_cache
         cache_key = guard = None
+        pin = streamed and self._pinned_host is not None
         if cache is not None:
             cache_key = self._cache_key_base + (tuple(layer_idxs),)
             # Guard stats captured BEFORE any byte is read: a concurrent
@@ -1124,15 +1167,16 @@ class _HostShardLoader:
             guard = stat_guard(
                 [self._layer_file(self.layer_names[i]) for i in layer_idxs]
             )
-            hit = cache.get(cache_key)
+            hit = cache.get(cache_key, self._pinned_host)
             if hit is not None:
                 segments, shard_bytes = hit
                 # The bytes still cross the host->HBM link every sweep —
                 # only the disk read/parse/verify/stack work is skipped —
                 # so the streamed-bytes witness keeps counting them.
-                self.bytes_loaded += shard_bytes
-                with _PROCESS_STREAM_LOCK:
-                    _PROCESS_STREAM_BYTES[0] += shard_bytes
+                if upload:
+                    self._count_streamed(shard_bytes)
+                if pin and _on_pinned_host(segments) is None:
+                    self._ask_pinned(cache_key, segments)
                 return segments
         segments = []
         run: list[Params] = []
@@ -1201,15 +1245,19 @@ class _HostShardLoader:
         shard_bytes = sum(
             a.nbytes for _, seg in segments for a in jax.tree.leaves(seg)
         )
-        self.bytes_loaded += shard_bytes
-        with _PROCESS_STREAM_LOCK:
-            _PROCESS_STREAM_BYTES[0] += shard_bytes
+        if upload:
+            self._count_streamed(shard_bytes)
         if cache is not None and guard is not None:
             # Inserted only AFTER every layer's integrity verification
             # passed (a verify failure raised out of the build above), so
             # cached trees are verified-clean by construction. Consumers
             # treat cached segments as immutable (_place only reads).
-            cache.put(cache_key, segments, nbytes=shard_bytes, guard=guard)
+            put = cache.put(
+                cache_key, segments, nbytes=shard_bytes, guard=guard,
+                evict=streamed,
+            )
+            if put and pin:
+                self._ask_pinned(cache_key, segments)
         return segments
 
 
@@ -1386,6 +1434,36 @@ def _quantized_target(host, target):
     return target
 
 
+def _pinned_host_of(device):
+    """The sharding of ``device``'s ``pinned_host`` memory, or None: where
+    a source's one upload target is a plain chip that lists such a memory,
+    the trees it streams every sweep are worth holding there (the chip reads
+    it directly; pageable memory is staged first). A placement, a sharding
+    or the default device (None, whose uploads stay uncommitted) is not
+    such a target."""
+    if not isinstance(device, jax.Device):
+        return None
+    if not any(m.kind == "pinned_host" for m in device.addressable_memories()):
+        return None
+    return jax.sharding.SingleDeviceSharding(device, memory_kind="pinned_host")
+
+
+def _on_pinned_host(tree):
+    """The chip whose ``pinned_host`` memory holds ``tree``'s arrays (the
+    host cache's second form of a streamed tree: all of its arrays or none),
+    else None. ``tree`` is a segment's pytree or a whole segment list; only
+    the first array's sharding is read, no value is touched."""
+    for leaf in jax.tree.leaves(tree):
+        if isinstance(leaf, jax.Array):
+            if leaf.sharding.memory_kind != "pinned_host":
+                return None
+            (device,) = leaf.sharding.device_set
+            return device
+        if isinstance(leaf, np.ndarray):
+            return None
+    return None
+
+
 def _place(
     segments: list[tuple[str, Any]], device, np_dtype=None
 ) -> list[tuple[str, Any]]:
@@ -1403,6 +1481,12 @@ def _place(
             if quant:
                 target = _quantized_target(p, target)
             d = jax.device_put(p, target)
+        elif (chip := _on_pinned_host(p)) is not None:
+            # The tree already sits in memory the chip reads directly: a
+            # move between two memories of one chip, no staging copy.
+            d = jax.device_put(
+                p, jax.sharding.SingleDeviceSharding(chip, memory_kind="device")
+            )
         else:
             d = jax.device_put(p, device) if device else jax.device_put(p)
         if quant:
@@ -1428,6 +1512,17 @@ def _to_read(idxs, pinned: frozenset, residency, devices) -> tuple[int, ...]:
     )
 
 
+def _runs(layer_idxs, pinned: frozenset):
+    """A shard's layers in order as ``(in_pin_set, idxs)``: each layer of
+    the frozen pin set alone, the layers between them as runs (one host
+    build and one cache entry each)."""
+    for in_set, group in itertools.groupby(layer_idxs, key=pinned.__contains__):
+        if in_set:
+            yield from ((True, (i,)) for i in group)
+        else:
+            yield False, tuple(group)
+
+
 def _split_parts(
     loader: _HostShardLoader,
     layer_idxs: tuple[int, ...],
@@ -1448,32 +1543,23 @@ def _split_parts(
     raises the stream path's own typed error. With no pins this is
     exactly one ("stream", -1, build_host_shard(idxs)) part — the
     pre-residency fast path, byte for byte."""
-    if not pinned or not any(i in pinned for i in layer_idxs):
-        return [("stream", -1, loader.build_host_shard(tuple(layer_idxs)))]
     parts: list[tuple[str, int, Any]] = []
-    run: list[int] = []
-
-    def flush() -> None:
-        if run:
-            parts.append(("stream", -1, loader.build_host_shard(tuple(run))))
-            run.clear()
-
-    for i in layer_idxs:
-        if i not in pinned:
-            run.append(i)
+    for in_set, idxs in _runs(layer_idxs, pinned):
+        if not in_set:
+            parts.append(("stream", -1, loader.build_host_shard(idxs)))
             continue
-        flush()
+        (i,) = idxs
         state = residency.seat_state(i, devices)
         if state == "seated":
             parts.append(("pin", i, None))
             continue
         try:
-            host = loader.build_host_shard((i,))
+            # A demoted layer crosses the link every sweep like any other.
+            host = loader.build_host_shard(idxs, streamed=state != "unseated")
         except Exception:
             residency.demote(i)
             raise
         parts.append(("seat" if state == "unseated" else "stream", i, host))
-    flush()
     return parts
 
 
@@ -1644,6 +1730,14 @@ class ShardWeightSource:
             retry_recorder=retry_recorder, retry_abort=self._stop.is_set,
             integrity=integrity_recorder, verify_weights=verify_weights,
             host_cache=host_cache, readahead_threads=readahead_threads,
+            # One target chip for every shard: its streamed trees may live
+            # in that chip's pinned_host memory. Per-shard devices (MP) and
+            # placements keep NumPy trees.
+            pinned_host=(
+                _pinned_host_of(device)
+                if devices is None and host_cache is not None
+                else None
+            ),
         )
         self._residency = residency
         # Nothing is loaded here: a planned layer that is not resident yet
@@ -1661,6 +1755,7 @@ class ShardWeightSource:
         self.upload_dispatch_s = 0.0
         self.producer_blocked_s = 0.0
         self.upload_bytes = 0
+        self.upload_pinned_bytes = 0  # of upload_bytes, from pinned_host
         # The completion thread exists where an account reads it: a
         # one-pass source, closed when its sweep's record is written. A
         # cycling source (the serve engine's, which keeps no account and
@@ -1745,6 +1840,7 @@ class ShardWeightSource:
             "producer_blocked_s": self.producer_blocked_s,
             "upload_busy_s": union_seconds(intervals, t_lo, t_hi),
             "upload_bytes": self.upload_bytes,
+            "upload_pinned_bytes": self.upload_pinned_bytes,
             "uploads": len(intervals),
             "upload_misses": misses,
             "pinned_bytes": (
@@ -1776,6 +1872,8 @@ class ShardWeightSource:
     def _build_shard(
         self, layer_idxs: tuple[int, ...], device, shard_i: int = 0
     ) -> list[tuple[str, Any]]:
+        from flexible_llm_sharding_tpu.runtime.hostcache import _tree_nbytes
+
         # produce_time covers the producer's WHOLE per-shard wall — host
         # file->numpy load (load_time counts just that part) plus the
         # device placement dispatch — the denominator of the stats line's
@@ -1798,10 +1896,13 @@ class ShardWeightSource:
             nbytes = self._loader.bytes_loaded - bytes_before
             # Count the sweep's saved link bytes ONCE per build (the put
             # below may retry; retries must not double-count).
-            for kind, idx, _ in parts:
+            pinned_nbytes = 0
+            for kind, idx, host in parts:
                 if kind == "pin":
                     self._residency.note_skip(idx)
                     self.pin_hits += 1
+                elif _on_pinned_host(host) is not None:
+                    pinned_nbytes += _tree_nbytes(host)
 
             # The host->device put retries under the same policy as the
             # reads: a transfer that surfaces OSError/TimeoutError is
@@ -1839,6 +1940,7 @@ class ShardWeightSource:
                 )
         self.upload_dispatch_s += dispatch.dur_s
         self.upload_bytes += nbytes
+        self.upload_pinned_bytes += pinned_nbytes
         self.produce_time += produce.dur_s
         return out
 
@@ -1880,7 +1982,41 @@ class ShardWeightSource:
         self.producer_blocked_s += blocked.dur_s
         return queued
 
+    def _build_streamed_first(self) -> None:
+        """Producer, before the first shard of a sweep that will seat layers
+        of the residency tier (a process's first, a re-seat after a
+        release): build the runs that stay streamed, for the host cache
+        alone. The sweep's order would reach them last, behind the seconds
+        of reading and verifying what the tier keeps, and the pinned_host
+        copies the cache then makes of them (hostcache.HostShardCache.pin:
+        seconds a layer, on its own thread) would run under the sweeps that
+        follow. Built first, they are copied while this sweep reads the
+        seats, and their own builds further on are cache hits. The work of
+        the sweep is the same, in another order. The pass ends where the
+        cache starts to evict (what it built would only push itself out)
+        and on an error: the build in the sweep's order meets that again
+        and reports it."""
+        if self._loader._pinned_host is None or not any(
+            # one target chip (a pinned_host loader has no other kind)
+            self._residency.seat_state(i, self.shard_devices[:1]) == "unseated"
+            for i in self._pinned_idxs
+        ):
+            return
+        self._loader.trace_ids = {"sweep_id": self.sweep_id}
+        cache = self._loader._host_cache
+        evictions = cache.evictions
+        for idxs in self.shards:
+            for in_set, run in _runs(idxs, self._pinned_idxs):
+                if self._stop.is_set() or cache.evictions != evictions:
+                    return
+                if not in_set:
+                    try:
+                        self._loader.build_host_shard(run, upload=False)
+                    except Exception:  # flscheck: disable=EXC-TAXONOMY: whatever a build raises, the sweep's own build of the run raises again at the shard's position, where the consumer takes it
+                        return
+
     def _producer(self):
+        self._build_streamed_first()
         while True:
             for i, (idxs, dev) in enumerate(
                 zip(self.shards, self.shard_devices)

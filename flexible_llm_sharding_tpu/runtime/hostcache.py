@@ -28,6 +28,25 @@ Safety model — the cache must never serve stale or unverified bytes:
   a file, purging every entry built from it (and the crc verdict cache,
   integrity/manifest.py, drops its verdicts for the path too).
 
+Where an entry's tree lives: as the loader built it (NumPy leaves, mmap
+views of the layer files where the layout allows: pageable memory, which
+the runtime stages before the chip can read it) or, for a layer that ONE
+chip streams every sweep, as ``jax.Array`` leaves in that chip's
+``pinned_host`` memory, which the chip reads directly (PERF.md, PR 30: 14.0
+against 8.9 GB/s on a v5e). The loader asks for the second form with
+:meth:`HostShardCache.pin`; the copy is made on a thread of the cache's own
+(a pinned allocation costs 2-3 s a GB on that machine, once per file
+generation, so it must not stand in any sweep's way) and replaces the
+entry's tree in place: same key, same guard, same bytes charged (page-
+locked RAM where the NumPy tree was page cache: the budget bounds either).
+Whatever drops an entry (stat drift, ``invalidate_path``, eviction,
+``clear``) drops the pinned tree with it. A key is chip-free: every reader
+of a layer shares its one entry, so the pinned form is only for a key that
+a single target reads. A reader with another target (a second replica's
+chip, a broadcast source) that meets a pinned tree drops it, misses and
+rebuilds the NumPy tree, and from then on that key stays NumPy for all of
+its readers, as before PR 30: one entry a layer, never one a chip.
+
 Budgeting: a byte-budgeted LRU. ``FrameworkConfig.host_cache_gb`` is the
 knob — an explicit number of GB, ``0`` to disable, or ``None`` (auto):
 a fraction of the host's currently-available RAM, and **disabled when
@@ -53,6 +72,9 @@ from flexible_llm_sharding_tpu.obs.registry import REGISTRY as _OBS_REGISTRY
 # purpose — the cache is an accelerator, not a requirement, and the host
 # also holds prefetch queues, activation spills, and the tokenizer.
 AUTO_FRACTION = 0.25
+
+# HostShardCache._readers: a key that readers with different targets share.
+_MANY = object()
 
 
 def available_host_bytes() -> int:
@@ -122,17 +144,39 @@ class HostShardCache:
         self.misses = 0
         self.evictions = 0
         self.invalidations = 0
+        # pinned_host copies (see pin()): requests by key, newest wins, and
+        # the one thread that works them off while there are any.
+        self._pin_requests: dict = {}  # guarded by: _lock
+        self._pin_thread: threading.Thread | None = None  # guarded by: _lock
+        self._pinned_keys: set = set()  # guarded by: _lock
+        self.pinned_host_bytes = 0  # guarded by: _lock
+        self.pin_copies = 0
+        self.pin_copy_s = 0.0
+        self.pin_error: str | None = None  # the first refusal ends pinning
+        # key -> the one target (a pinned_host sharding, or None) all of
+        # its readers so far upload to, or _MANY. Outlives the entry: a key
+        # two targets have read is never pinned again (else each would drop
+        # the other's copy, sweep after sweep). clear() forgets.
+        self._readers: dict = {}  # guarded by: _lock
 
     # -- core API ----------------------------------------------------------
 
-    def get(self, key) -> tuple[Any, int] | None:
+    def get(self, key, target=None) -> tuple[Any, int] | None:
         """(segments, nbytes) for a current entry, else None (counted as a
-        miss). The backing files are stat-validated OUTSIDE the lock: a
+        miss). ``target`` is the ``pinned_host`` sharding of the one chip
+        the reader uploads to, or None (several chips, a placement, no such
+        memory): only a reader with an entry's own target is handed its
+        pinned tree. The backing files are stat-validated OUTSIDE the lock: a
         wedged filesystem (hard-mounted NFS) blocks os.stat indefinitely,
         and holding the lock through that would stall every weight stream
         in the process — including the serve engine's recovery source,
         the one path that must keep moving when storage misbehaves."""
         with self._lock:
+            if self._readers.setdefault(key, target) != target:
+                self._readers[key] = _MANY
+                self._pin_requests.pop(key, None)
+                if key in self._pinned_keys:
+                    self._drop(key)  # one chip's copy serves no other target
             entry = self._entries.get(key)
             if entry is None:
                 self.misses += 1
@@ -146,9 +190,11 @@ class HostShardCache:
         stale = any(_stat_key(path) != stat for path, stat in guard)
         with self._lock:
             cur = self._entries.get(key)
-            if cur is None or cur is not entry:
+            if cur is None or cur[2] is not guard:
                 # Dropped or replaced while we were statting: our verdict
-                # no longer describes what the cache holds — miss.
+                # no longer describes what the cache holds — miss. (The
+                # pinning thread's swap keeps the entry's guard: the same
+                # files' bytes in another memory, which the verdict covers.)
                 self.misses += 1
                 hit = False
             elif stale:
@@ -159,6 +205,7 @@ class HostShardCache:
                 self.misses += 1
                 hit = False
             else:
+                segments = cur[0]
                 self._entries.move_to_end(key)
                 self.hits += 1
                 hit = True
@@ -175,13 +222,16 @@ class HostShardCache:
         paths: Sequence[str] = (),
         nbytes: int | None = None,
         guard: tuple | None = None,
+        evict: bool = True,
     ) -> bool:
         """Insert one shard's host tree, guarded by the backing files'
         stats — pass ``guard`` captured via :func:`stat_guard` BEFORE the
         files were read (see there); bare ``paths`` stat at insert time
         and are only race-free when the caller owns the files. Returns
         False (uncached) when any path can't be stat'ed or the entry
-        alone exceeds the budget."""
+        alone exceeds the budget. ``evict=False`` is for a tree that is
+        read once (a layer the residency tier keeps from then on): it is
+        cached where there is room and pushes nothing out."""
         if guard is None:
             guard = stat_guard(paths)
             if guard is None:
@@ -193,6 +243,8 @@ class HostShardCache:
         with self._lock:
             if key in self._entries:
                 self._drop(key)
+            if not evict and self.bytes + nbytes > self.budget_bytes:
+                return False
             while self.bytes + nbytes > self.budget_bytes and self._entries:
                 oldest = next(iter(self._entries))
                 self._drop(oldest)
@@ -207,12 +259,92 @@ class HostShardCache:
         # flscheck: holds=_lock: internal helper — every caller already owns the lock
         segments, nbytes, guard = self._entries.pop(key)
         self.bytes -= nbytes
+        self._pin_requests.pop(key, None)
+        if key in self._pinned_keys:
+            self._pinned_keys.discard(key)
+            self.pinned_host_bytes -= nbytes
         for p, _ in guard:
             keys = self._by_path.get(p)
             if keys is not None:
                 keys.discard(key)
                 if not keys:
                     del self._by_path[p]
+
+    # -- pinned_host copies ------------------------------------------------
+
+    def pin(self, key, segments, sharding) -> bool:
+        """Ask for ``key``'s tree to be held in ``sharding``'s memory (the
+        ``pinned_host`` memory of the one chip that streams it), given the
+        NumPy ``segments`` the caller just got for it. Returns at once; the
+        copy is made on the cache's own thread and then replaces the
+        entry's tree, if the entry still holds ``segments``. False when
+        nothing was queued: the entry is gone or already pinned, a reader
+        with another target has read the key, or an earlier copy was
+        refused (``pin_error``: the host would not pin more, and every
+        later request would only fail the same way)."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if (
+                self.pin_error is not None
+                or self._readers.get(key, _MANY) != sharding
+                or entry is None
+                or entry[0] is not segments
+                or key in self._pinned_keys
+            ):
+                return False
+            self._pin_requests[key] = (segments, sharding)
+            if self._pin_thread is None:
+                self._pin_thread = threading.Thread(
+                    target=self._pin_worker, name="fls-host-pin", daemon=True
+                )
+                self._pin_thread.start()
+            return True
+
+    def _pin_worker(self) -> None:
+        import jax
+
+        while True:
+            with self._lock:
+                if not self._pin_requests or self.pin_error is not None:
+                    self._pin_requests.clear()
+                    self._pin_thread = None
+                    return
+                key = next(iter(self._pin_requests))
+                segments, sharding = self._pin_requests[key]
+            try:
+                with obs_trace.timed("host_pin", cat="cache") as sp:
+                    trees = jax.block_until_ready(
+                        jax.device_put([seg for _, seg in segments], sharding)
+                    )
+            except Exception as e:  # flscheck: disable=EXC-TAXONOMY: whatever the runtime raises for a pinned allocation it will not make (RESOURCE_EXHAUSTED, an unsupported memory kind) must end pinning, not the thread's owner; the NumPy tree keeps serving
+                with self._lock:
+                    self.pin_error = repr(e)[:200]
+                continue
+            pinned = [(kind, t) for (kind, _), t in zip(segments, trees)]
+            with self._lock:
+                self.pin_copy_s += sp.dur_s
+                entry = self._entries.get(key)
+                if self._pin_requests.get(key, (None,))[0] is segments:
+                    del self._pin_requests[key]
+                if (
+                    entry is not None
+                    and entry[0] is segments
+                    and self._readers.get(key, _MANY) == sharding
+                ):
+                    self._entries[key] = (pinned,) + entry[1:]
+                    self._pinned_keys.add(key)
+                    self.pinned_host_bytes += entry[1]
+                    self.pin_copies += 1
+
+    def pin_wait(self, timeout_s: float = 60.0) -> bool:
+        """Block until the queued copies are made (tests, and callers that
+        want the steady state before they measure). False on timeout."""
+        with self._lock:
+            t = self._pin_thread
+        if t is not None:
+            t.join(timeout=timeout_s)
+            return not t.is_alive()
+        return True
 
     # -- invalidation ------------------------------------------------------
 
@@ -231,7 +363,10 @@ class HostShardCache:
         with self._lock:
             self._entries.clear()
             self._by_path.clear()
-            self.bytes = 0
+            self._pin_requests.clear()
+            self._pinned_keys.clear()
+            self._readers.clear()
+            self.bytes = self.pinned_host_bytes = 0
 
     def set_budget(self, budget_bytes: int) -> None:
         """Resize the budget. A SHRINK is safe for live readers: excess
@@ -259,6 +394,9 @@ class HostShardCache:
                 "bytes": self.bytes,
                 "budget_bytes": self.budget_bytes,
                 "hit_rate": round(self.hits / total, 4) if total else 0.0,
+                "pinned_host_bytes": self.pinned_host_bytes,
+                "pinned_host_copies": self.pin_copies,
+                "pinned_host_copy_s": round(self.pin_copy_s, 4),
             }
 
 

@@ -612,3 +612,324 @@ def test_lowered_decoder_block_carries_the_scopes_and_kernel_names():
     # the per-prompt vmap (the MLP half sees the block's rows at once).
     assert "decoder_layer/moe_experts" in text
     assert "decoder_layer/vmap(attention)" in text
+
+
+# ---------------------------------------------------------------------------
+# The producer's bound and the seating sweep's pass ahead beside a residency tier (PR 30)
+# ---------------------------------------------------------------------------
+
+class _Buffer:
+    """Stands for one streamed shard's arrays on the chip: counted while
+    anything holds it."""
+
+    alive = 0
+    made = 0
+    _lock = threading.Lock()
+
+    def __init__(self):
+        with _Buffer._lock:
+            _Buffer.alive += 1
+            _Buffer.made += 1
+
+    def __del__(self):
+        with _Buffer._lock:
+            _Buffer.alive -= 1
+
+
+class _SeatedTier:
+    """A residency tier whose planned layers are all resident already: such
+    a layer's part is ("pin", idx, None), merged from here, never uploaded."""
+
+    def __init__(self, pinned):
+        self.pinned = frozenset(pinned)
+
+    def frozen_pinned(self, shards):
+        return self.pinned
+
+    def seat_state(self, idx, devices):
+        return "seated"
+
+    def seated(self, idx, device):
+        return [("decoders", {"resident": idx})]
+
+    def note_skip(self, idx):
+        pass
+
+    def max_pinned_device_bytes(self):
+        return 0
+
+
+@pytest.fixture(scope="module")
+def deep_dir(tiny_cfg, tmp_path_factory):
+    import dataclasses
+
+    cfg = dataclasses.replace(tiny_cfg, num_hidden_layers=8)
+    d = tmp_path_factory.mktemp("deep_model")
+    save_params(
+        jax.tree.map(np.asarray, llama.init_params(jax.random.PRNGKey(1), cfg)),
+        str(d), cfg,
+    )
+    return str(d), layer_names_for(8, tie_word_embeddings=False)
+
+
+def _tracked_source(monkeypatch, deep_dir, depth, pinned, cycle=False, tier=None, **kw):
+    path, names = deep_dir
+    _Buffer.alive = _Buffer.made = 0
+    monkeypatch.setattr(
+        executor_mod, "_place", lambda host, device, np_dtype=None: [("decoders", _Buffer())]
+    )
+    # The completion thread would hold a shard's arrays for its wait.
+    monkeypatch.setattr(executor_mod._UploadWatcher, "watch", lambda self, *a: None)
+    return executor_mod.ShardWeightSource(
+        path, names, [(i,) for i in range(len(names))], np.dtype(np.float32),
+        device=jax.devices()[0], prefetch_depth=depth, cycle=cycle,
+        residency=tier or (_SeatedTier(pinned) if pinned else None), **kw,
+    )
+
+
+def _wait_for(cond, timeout_s=20.0):
+    t_end = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < t_end, "timed out"
+        time.sleep(0.005)
+
+
+# embed, layers 0-2 and the last two files resident; layers 3-7 streamed
+_RESIDENT = (0, 1, 2, 3, 9, 10)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_producer_holds_depth_plus_two_streamed_shards(monkeypatch, deep_dir, depth):
+    """The queue counts shards, resident ones too: with the consumer at the
+    head no streamed layer is on its way yet; the queue's places, the shard
+    in the producer's hand and the one at the consumer make at most depth +
+    2 streamed shards alive (what residency.in_flight_bytes reserves), that
+    bound is reached, and shards arrive in order."""
+    source = _tracked_source(monkeypatch, deep_dir, depth, _RESIDENT)
+    try:
+        _wait_for(lambda: source._q.full())
+        time.sleep(0.05)
+        if depth < 3:  # in hand: a resident shard ((2,) or (3,)), not (4,)
+            assert _Buffer.made == 0
+        got, peak = [], 0
+        for idxs, segs in source:
+            got.append(idxs)
+            time.sleep(0.01)  # let the producer use the place a take freed
+            peak = max(peak, _Buffer.alive)
+            assert _Buffer.alive <= depth + 2
+        del segs
+    finally:
+        source.close()
+    assert got == source.shards
+    assert peak == min(depth + 2, 5) and _Buffer.made == 5
+    acct = source.account(0.0, time.perf_counter())
+    assert acct["pin_hits"] == len(_RESIDENT) and acct["producer_blocked_s"] > 0
+    assert _Buffer.alive == 0
+
+
+@pytest.mark.parametrize("how", ["close", "abort_then_close"])
+def test_stopping_a_source_mid_sweep_strands_no_buffer(monkeypatch, deep_dir, how):
+    source = _tracked_source(monkeypatch, deep_dir, 2, _RESIDENT)
+    it = iter(source)
+    for _ in range(6):  # into the streamed layers: the producer is at its bound
+        idxs, segs = next(it)
+    _wait_for(lambda: source._q.full())
+    if how == "abort_then_close":
+        source.abort()
+        with pytest.raises(executor_mod.SourceClosed):
+            while True:  # what was queued before the abort may still arrive
+                idxs, segs = next(it)
+    source.close()
+    assert source._thread is None
+    del it, segs
+    import gc
+
+    gc.collect()
+    assert _Buffer.alive == 0
+
+
+def test_cycling_source_keeps_its_bound_over_sweeps(monkeypatch, deep_dir):
+    source = _tracked_source(monkeypatch, deep_dir, 2, _RESIDENT, cycle=True)
+    try:
+        it = iter(source)
+        for n in range(3 * len(source.shards)):
+            idxs, segs = next(it)
+            assert idxs == source.shards[n % len(source.shards)]
+            assert _Buffer.alive <= 4 and source._q.qsize() <= 2
+        del segs
+    finally:
+        source.close()
+    assert _Buffer.alive == 0
+
+
+def test_runs_are_the_parts_split_parts_builds():
+    pinned = frozenset({0, 3, 4, 9})
+    runs = lambda idxs, pins: list(executor_mod._runs(idxs, pins))  # noqa: E731
+    assert runs((0, 1, 2, 3, 4, 5, 9, 10), pinned) == [
+        (True, (0,)), (False, (1, 2)), (True, (3,)), (True, (4,)),
+        (False, (5,)), (True, (9,)), (False, (10,)),
+    ]
+    assert runs((3, 4), pinned) == [(True, (3,)), (True, (4,))]
+    assert runs((6, 7), frozenset()) == [(False, (6, 7))]
+    built = []
+
+    class _Loader:
+        def build_host_shard(self, idxs, streamed=True, upload=True):
+            built.append((tuple(idxs), streamed))
+            return []
+
+    parts = executor_mod._split_parts(
+        _Loader(), (0, 1, 2, 3, 4, 5, 9, 10), pinned, _SeatedTier(pinned), (None,)
+    )
+    assert [p[:2] for p in parts] == [
+        ("pin", 0), ("stream", -1), ("pin", 3), ("pin", 4), ("stream", -1),
+        ("pin", 9), ("stream", -1),
+    ]
+    assert built == [((1, 2), True), ((5,), True), ((10,), True)]
+    # No pin set: the whole shard is one build, as before the tier.
+    assert [p[:2] for p in executor_mod._split_parts(_Loader(), (6, 7), frozenset())] == [
+        ("stream", -1)
+    ]
+
+
+class _SeatingTier(_SeatedTier):
+    """A tier whose planned layers are not resident yet: a shard build seats
+    each from the sweep's own stream and finds it resident from then on."""
+
+    def __init__(self, pinned):
+        super().__init__(pinned)
+        self.seats = {}
+
+    def seat_state(self, idx, devices):
+        return "seated" if idx in self.seats else "unseated"
+
+    def seated(self, idx, device):
+        return self.seats.get(idx)
+
+    def seat(self, idx, device, host, placed):
+        return self.seats.setdefault(idx, placed)
+
+    def demote(self, idx):
+        raise AssertionError(f"layer {idx} demoted")
+
+
+def _log_builds(monkeypatch, gate=None, entered=None):
+    """Every host build as (layer idxs, streamed, upload), in order; with a
+    gate, a build made ahead of its place waits inside for it."""
+    builds = []
+    real = executor_mod._HostShardLoader._build_host_shard
+
+    def logged(self, layer_idxs, streamed=True, upload=True):
+        builds.append((tuple(layer_idxs), streamed, upload))
+        if gate is not None and not upload:
+            entered.set()
+            assert gate.wait(20)
+        return real(self, layer_idxs, streamed, upload)
+
+    monkeypatch.setattr(executor_mod._HostShardLoader, "_build_host_shard", logged)
+    return builds
+
+
+_STREAMED = [(i,) for i in range(4, 9)]
+
+
+def test_seating_sweep_builds_the_streamed_layers_first(monkeypatch, deep_dir):
+    """A sweep that will seat layers of the tier: the producer first builds
+    the layers that stay streamed, for the cache alone (nothing counted as
+    link traffic), so the cache copies them to pinned_host while the sweep
+    reads the seats; then the sweep runs in its order, the streamed layers'
+    own builds are hits, every file is verified once and the seats are
+    cached where there is room. A sweep with everything seated, and one
+    whose trees have no pinned_host target, build nothing ahead."""
+    from flexible_llm_sharding_tpu.integrity import manifest as iman
+    from flexible_llm_sharding_tpu.runtime import hostcache
+
+    builds = _log_builds(monkeypatch)
+    iman.reset_verdicts()
+    verifies0 = iman.verdict_stats()["full_verifies"]
+    cache = hostcache.HostShardCache(budget_bytes=1 << 30)
+    tier = _SeatingTier(_RESIDENT)
+    before = executor_mod.process_streamed_bytes()
+    source = _seating_source(monkeypatch, deep_dir, tier, cache)
+    try:
+        got = [idxs for idxs, _ in source]
+    finally:
+        source.close()
+    assert got == source.shards
+    assert builds[:5] == [(run, True, False) for run in _STREAMED]
+    in_order = [(idxs, i not in _RESIDENT, True) for idxs in source.shards for i in idxs]
+    assert builds[5:] == in_order
+    assert cache.pin_wait()
+    s = cache.stats()
+    assert (s["misses"], s["hits"], s["entries"]) == (11, 5, 11)
+    assert s["pinned_host_copies"] == 5 and s["evictions"] == 0
+    assert iman.verdict_stats()["full_verifies"] - verifies0 == 11
+    # The seats' bytes and the streamed layers' cross the link once each.
+    assert executor_mod.process_streamed_bytes() - before == source.upload_bytes == s["bytes"]
+    # Everything seated: no pass ahead, every streamed byte from pinned_host.
+    del builds[:]
+    again = _seating_source(monkeypatch, deep_dir, tier, cache)
+    try:
+        assert [idxs for idxs, _ in again] == again.shards
+    finally:
+        again.close()
+    assert builds == [(run, True, True) for run in _STREAMED]
+    assert again.upload_pinned_bytes == again.upload_bytes == s["pinned_host_bytes"]
+    # No cache, so no pinned_host target: the sweep's own order and nothing else.
+    del builds[:]
+    plain = _seating_source(monkeypatch, deep_dir, _SeatingTier(_RESIDENT), None)
+    try:
+        assert [idxs for idxs, _ in plain] == plain.shards
+    finally:
+        plain.close()
+    assert builds == in_order
+
+
+def _seating_source(monkeypatch, deep_dir, tier, cache):
+    return _tracked_source(monkeypatch, deep_dir, 2, _RESIDENT, tier=tier, host_cache=cache)
+
+
+def test_the_pass_ahead_ends_where_the_cache_starts_to_evict(monkeypatch, deep_dir):
+    """A budget of two streamed layers: the third build ahead evicts, the
+    pass ends there, and the sweep completes in order."""
+    from flexible_llm_sharding_tpu.runtime import hostcache
+
+    path, names = deep_dir
+    loader = executor_mod._HostShardLoader(path, names, np.dtype(np.float32))
+    one = hostcache._tree_nbytes(loader.build_host_shard((5,)))
+    loader.close()
+    builds = _log_builds(monkeypatch)
+    cache = hostcache.HostShardCache(budget_bytes=int(2.5 * one))
+    source = _seating_source(monkeypatch, deep_dir, _SeatingTier(_RESIDENT), cache)
+    try:
+        got = [idxs for idxs, _ in source]
+    finally:
+        source.close()
+    assert got == source.shards
+    assert [b[0] for b in builds if not b[2]] == _STREAMED[:3]
+    s = cache.stats()
+    # The big seats found no room and pushed nothing out (put(evict=False):
+    # tests/test_hostcache.py); two streamed layers fill the budget.
+    assert s["bytes"] <= cache.budget_bytes
+    with cache._lock:
+        assert sum(key[-1] in _STREAMED for key in cache._entries) == 2
+        assert not any(key[-1] in [(1,), (2,), (3,)] for key in cache._entries)
+
+
+def test_stopping_a_source_inside_the_pass_ahead(monkeypatch, deep_dir):
+    from flexible_llm_sharding_tpu.runtime import hostcache
+
+    gate, entered = threading.Event(), threading.Event()
+    builds = _log_builds(monkeypatch, gate, entered)
+    cache = hostcache.HostShardCache(budget_bytes=1 << 30)
+    source = _seating_source(monkeypatch, deep_dir, _SeatingTier(_RESIDENT), cache)
+    try:
+        assert entered.wait(20)
+        source.abort()  # the watchdog's, from another thread: no join
+        gate.set()
+    finally:
+        gate.set()
+        source.close()
+    assert source._thread is None
+    assert builds == [(_STREAMED[0], True, False)]  # nothing begun after the stop
+    assert _Buffer.alive == 0

@@ -603,3 +603,512 @@ def test_shrink_evicts_lru_first_without_invalidating_live_hits(tmp_path):
     # Growth back re-admits new entries normally.
     cache.set_budget(300)
     assert cache.put(("k", 9), segs, paths=[paths[1]], nbytes=100)
+
+
+# ---------------------------------------------------------------------------
+# pinned_host copies of the streamed trees (PR 30)
+# ---------------------------------------------------------------------------
+# The CPU backend lists a ``pinned_host`` memory, so the whole path runs
+# here: the loader asks the cache for the copy, the cache's own thread makes
+# it, a later hit returns jax.Array leaves in that memory, and _place moves
+# them with a memory-space device_put.
+
+import json
+import threading
+import time
+
+from flexible_llm_sharding_tpu.runtime import executor as executor_mod
+from flexible_llm_sharding_tpu.runtime.executor import (
+    BroadcastShardSource,
+    ShardWeightSource,
+    _on_pinned_host,
+    _pinned_host_of,
+)
+from flexible_llm_sharding_tpu.utils.checkpoint import requantize_native
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def family_dirs(tmp_path_factory):
+    """bfloat16 layer files of the benchmark's two families at their
+    rehearsal widths: a ``deepseek_v3`` model (a dense layer, then expert
+    layers) and MiMo-V2-Flash ([full+dense, window, window, full])."""
+    from benchmark import weights as dsv3
+    from benchmark.families.mimo_v2_flash import weights as mimo
+
+    out = {}
+    for key, mod, fn in (
+        ("moonlight", dsv3, "moonlight-16b-a3b.json"),
+        ("mimo", mimo, "mimo-v2-flash.json"),
+    ):
+        with open(os.path.join(ROOT, "benchmark", "configs", fn)) as f:
+            model = json.load(f)
+        model.update(model.pop("rehearsal"))
+        d = str(tmp_path_factory.mktemp(key) / "model")
+        mod.write_model(model, 30, d)
+        out[key] = d
+    return out
+
+
+def _family_loader(d, cache, dev, np_dtype="bfloat16"):
+    from flexible_llm_sharding_tpu.config import LlamaConfig
+
+    mc = LlamaConfig.from_pretrained(d)
+    names = layer_names_for(mc.num_hidden_layers, tie_word_embeddings=False)
+    return _HostShardLoader(
+        d, names, np_dtype_for(np_dtype), mc.tie_word_embeddings,
+        mc.layer_sliding, mc.layer_rope, host_cache=cache,
+        pinned_host=_pinned_host_of(dev),
+    )
+
+
+def _bits_equal(a, b) -> None:
+    """Two placed segment lists: the same kinds, shardings, dtypes, bytes."""
+    assert [k for k, _ in a] == [k for k, _ in b]
+    for (_, ga), (_, gb) in zip(a, b):
+        la, lb = jax.tree.leaves(ga), jax.tree.leaves(gb)
+        assert len(la) == len(lb) and la
+        for xa, xb in zip(la, lb):
+            assert xa.sharding == xb.sharding and xa.dtype == xb.dtype
+            assert np.asarray(xa).tobytes() == np.asarray(xb).tobytes()
+
+
+@pytest.mark.parametrize(
+    "family,idxs,compute",
+    [
+        ("moonlight", (2,), "bfloat16"),  # an expert layer, the k=1 [None] view
+        ("moonlight", (2, 3), "bfloat16"),  # two stacked (np.stack's copy)
+        ("moonlight", (2,), "float32"),  # the on-device cast reads the leaves
+        ("mimo", (2,), "bfloat16"),  # a window layer (sink, sliding flag)
+        ("mimo", (4,), "bfloat16"),  # a full layer of other leaf shapes
+        ("mimo", (3, 4), "bfloat16"),  # a run the builder breaks on shape
+    ],
+)
+def test_place_of_a_pinned_host_tree_is_bit_identical(family_dirs, family, idxs, compute):
+    dev = jax.devices()[0]
+    cache = HostShardCache(budget_bytes=1 << 30)
+    loader = _family_loader(family_dirs[family], cache, dev, compute)
+    try:
+        host = loader.build_host_shard(idxs)  # NumPy; asks for the copy
+        assert _on_pinned_host(host) is None
+        assert cache.pin_wait()
+        pinned = loader.build_host_shard(idxs)  # the hit: the copy
+    finally:
+        loader.close()
+    assert _on_pinned_host(pinned) == dev
+    for (_, seg), (_, seg_np) in zip(pinned, host):
+        for leaf, leaf_np in zip(jax.tree.leaves(seg), jax.tree.leaves(seg_np)):
+            assert leaf.sharding.memory_kind == "pinned_host"
+            assert leaf.shape == leaf_np.shape and leaf.dtype == leaf_np.dtype
+    s = cache.stats()
+    assert (s["pinned_host_copies"], s["pinned_host_bytes"]) == (1, s["bytes"])
+    dt = np_dtype_for(compute)
+    want = _place(host, dev, np_dtype=dt)
+    _bits_equal(_place(pinned, dev, np_dtype=dt), want)
+    assert all(
+        x.dtype == dt
+        for _, seg in want
+        for x in jax.tree.leaves(seg)
+        if jax.numpy.issubdtype(x.dtype, jax.numpy.floating)
+    )
+
+
+def _run_source(source):
+    try:
+        for _ in source:
+            pass
+    finally:
+        source.close()
+
+
+def _all_numpy(cache) -> bool:
+    with cache._lock:
+        trees = [e[0] for e in cache._entries.values()]
+    return bool(trees) and all(_on_pinned_host(t) is None for t in trees)
+
+
+@pytest.mark.parametrize("holder", ["quantized", "broadcast", "per_shard_devices", "tp_placement"])
+def test_other_holders_keep_numpy_trees(model_dir, tmp_path, holder):
+    """What holds a tree decides its form: a tree that is dequantized on
+    placement, a source that feeds several chips, and a placement keep the
+    NumPy tree and ask for no copy."""
+    devs = jax.devices()
+    names = layer_names_for(4, tie_word_embeddings=False)
+    shards = [(i,) for i in range(len(names))]
+    cache = HostShardCache(budget_bytes=1 << 30)
+    kw = dict(prefetch_depth=2, host_cache=cache)
+    if holder == "quantized":
+        q8 = str(tmp_path / "q8")
+        requantize_native(model_dir, q8, dtype="int8")
+        source = ShardWeightSource(
+            q8, names, shards, np.dtype(np.float32), device=devs[0], **kw
+        )
+        assert source._loader._pinned_host is not None  # the tree decides
+    elif holder == "broadcast":
+        source = BroadcastShardSource(
+            model_dir, names, shards, np.dtype(np.float32), devs[:2], **kw
+        )
+        views = [source.view(r) for r in range(2)]
+        threads = [
+            threading.Thread(target=_run_source, args=(v,))
+            for v in views[1:]
+        ]
+        for t in threads:
+            t.start()
+        _run_source(views[0])
+        for t in threads:
+            t.join()
+        assert source._loader._pinned_host is None
+    elif holder == "per_shard_devices":
+        source = ShardWeightSource(
+            model_dir, names, shards, np.dtype(np.float32),
+            devices=[devs[i % 2] for i in range(len(shards))], **kw
+        )
+        assert source._loader._pinned_host is None
+    else:
+        from flexible_llm_sharding_tpu.config import LlamaConfig
+        from flexible_llm_sharding_tpu.parallel.sharding import TpPlacement
+
+        placement = TpPlacement(devs[:2], LlamaConfig.from_pretrained(model_dir))
+        source = ShardWeightSource(
+            model_dir, names, shards, np.dtype(np.float32), device=placement, **kw
+        )
+        assert source._loader._pinned_host is None
+    if holder != "broadcast":
+        _run_source(source)
+    else:
+        source.close()
+    assert cache.pin_wait()
+    s = cache.stats()
+    assert s["entries"] == len(shards)
+    if holder == "quantized":
+        # The final norm's scale is stored as it travels and is the one
+        # tree of this directory without a quantized leaf.
+        with cache._lock:
+            trees = [e[0] for e in cache._entries.values()]
+        quantized = [
+            t for t in trees if any(executor_mod._has_quantized(seg) for _, seg in t)
+        ]
+        assert len(quantized) == len(shards) - 1
+        assert all(_on_pinned_host(t) is None for t in quantized)
+        assert s["pinned_host_copies"] == 1
+    else:
+        assert (s["pinned_host_copies"], s["pinned_host_bytes"]) == (0, 0)
+        assert _all_numpy(cache)
+
+
+def _pinned_entry(model_dir, cache, idxs=(1,)):
+    loader = _loader(model_dir, cache=cache, pinned_host=_pinned_host_of(jax.devices()[0]))
+    loader.build_host_shard(idxs)
+    assert cache.pin_wait()
+    tree = loader.build_host_shard(idxs)
+    assert _on_pinned_host(tree) is not None
+    return loader, tree
+
+
+@pytest.mark.parametrize("how", ["stat_drift", "invalidate_path", "eviction", "reset_process_cache"])
+def test_dropping_an_entry_releases_its_pinned_copy(model_dir, tmp_path, how):
+    """The pinned tree lives and dies with its entry: whatever drops the
+    entry gives back the budget's bytes and the cache's hold on the copy
+    (the arrays are freed once no sweep holds them either)."""
+    import gc
+    import shutil
+    import weakref
+
+    d = str(tmp_path / "model")
+    shutil.copytree(model_dir, d)
+    if how == "reset_process_cache":
+        cache = hostcache.cache_for(_fw(d, host_cache_gb=1.0))
+    else:
+        cache = HostShardCache(budget_bytes=1 << 30)
+    loader, tree = _pinned_entry(d, cache)
+    nbytes = cache.stats()["bytes"]
+    assert cache.stats()["pinned_host_bytes"] == nbytes > 0
+    ref = weakref.ref(jax.tree.leaves(tree[0][1])[0])
+    del tree
+    path = loader._layer_file(loader.layer_names[1])
+    if how == "stat_drift":
+        os.utime(path, ns=(1, 1))
+        rebuilt = loader.build_host_shard((1,))  # stale: dropped, re-read
+        assert _on_pinned_host(rebuilt) is None  # a fresh NumPy tree
+        assert cache.stats()["invalidations"] == 1
+        cache.clear()  # its new copy is not this test's
+        del rebuilt
+    elif how == "invalidate_path":
+        assert cache.invalidate_path(path) == 1
+    elif how == "eviction":
+        cache.set_budget(nbytes - 1)
+        assert cache.stats()["evictions"] == 1
+    else:
+        hostcache.reset_process_cache()
+    loader.close()
+    cache.pin_wait()
+    s = cache.stats()
+    assert (s["bytes"], s["pinned_host_bytes"], s["entries"]) == (0, 0, 0)
+    gc.collect()
+    assert ref() is None
+
+
+def test_pinned_copy_is_charged_like_any_entry_and_refusal_ends_pinning(model_dir, monkeypatch):
+    """The copy replaces the entry's tree in place (same key, guard and
+    bytes), hits and misses count as before, and a copy the runtime refuses
+    leaves the NumPy tree serving and asks no more."""
+    cache = HostShardCache(budget_bytes=1 << 30)
+    loader, tree = _pinned_entry(model_dir, cache)
+    s = cache.stats()
+    assert (s["hits"], s["misses"], s["entries"]) == (1, 1, 1)
+    assert s["bytes"] == s["pinned_host_bytes"] == sum(
+        a.nbytes for _, seg in tree for a in jax.tree.leaves(seg)
+    )
+    assert s["pinned_host_copy_s"] > 0
+    assert cache.pin(loader._cache_key_base + ((1,),), tree, loader._pinned_host) is False
+
+    def refuse(*a, **k):
+        raise RuntimeError("RESOURCE_EXHAUSTED: no more pinned host memory")
+
+    real_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", refuse)
+    host = loader.build_host_shard((2,))
+    assert cache.pin_wait()
+    monkeypatch.setattr(jax, "device_put", real_put)
+    assert "RESOURCE_EXHAUSTED" in cache.pin_error
+    again = loader.build_host_shard((2,))
+    assert again is host and _on_pinned_host(again) is None
+    loader.build_host_shard((3,))
+    assert cache.pin_wait() and cache.stats()["pinned_host_copies"] == 1
+    loader.close()
+
+
+def test_executor_sweeps_upload_from_pinned_host_and_match(model_dir, clean_scores):
+    """Sweep 1 builds and uploads NumPy trees and asks for the copies;
+    once they are made every streamed byte takes the new path
+    (upload_pinned_bytes == upload_bytes) and the scores do not move."""
+    dev = jax.devices()[0]
+    cfg = _fw(model_dir, host_cache_gb=1.0, prefetch_depth=2)
+    for sweep in range(3):
+        out = StreamingExecutor(cfg, device=dev, tokenizer=FakeTokenizer())(list(PROMPTS))
+        assert hostcache.process_cache().pin_wait()
+        rec = executor_mod.process_sweep_log()[-1]
+        assert rec["upload_bytes"] > 0
+        if sweep:
+            assert rec["upload_pinned_bytes"] == rec["upload_bytes"]
+        else:
+            assert rec["upload_pinned_bytes"] == 0
+        for a, b in zip(clean_scores, out):
+            np.testing.assert_array_equal(a, b)
+    s = hostcache.process_cache().stats()
+    assert s["pinned_host_bytes"] == s["bytes"] and s["hit_rate"] == pytest.approx(2 / 3, abs=1e-4)
+    # The default device (no stated target) keeps today's uncommitted uploads.
+    hostcache.reset_process_cache()
+    StreamingExecutor(cfg, tokenizer=FakeTokenizer())(list(PROMPTS))
+    assert hostcache.process_cache().pin_wait()
+    assert hostcache.process_cache().stats()["pinned_host_copies"] == 0
+
+
+def test_pins_puts_and_drops_from_many_threads_keep_the_books(tmp_path):
+    """More threads than cores put, pin, hit and drop the same few keys
+    under a shortened switch interval: whatever interleaving, the bytes the
+    cache charges and the bytes it reports as pinned are those of the
+    entries it holds, and its thread retires."""
+    import sys
+
+    files = []
+    for i in range(4):
+        f = tmp_path / f"layer{i}.bin"
+        f.write_bytes(b"x" * 64)
+        files.append(str(f))
+    sharding = _pinned_host_of(jax.devices()[0])
+    cache = HostShardCache(budget_bytes=3 * 4096 + 100)  # three of four fit
+    stop = time.monotonic() + 3.0
+    errors = []
+
+    def worker(seed):
+        rng = np.random.default_rng(seed)
+        try:
+            while time.monotonic() < stop:
+                k = int(rng.integers(4))
+                tree = [("decoders", {"w": np.full((1024,), k, np.float32)})]
+                op = int(rng.integers(4))
+                if op == 0 and cache.put(k, tree, paths=[files[k]]):
+                    cache.pin(k, tree, sharding)
+                elif op == 1:
+                    hit = cache.get(k, sharding)
+                    if hit is not None:
+                        leaf = jax.tree.leaves(hit[0][0][1])[0]
+                        assert float(np.asarray(leaf)[0]) == k
+                        cache.pin(k, hit[0], sharding)
+                elif op == 2:
+                    cache.invalidate_path(files[k])
+                else:
+                    cache.set_budget(int(rng.choice([2, 3, 4])) * 4096 + 100)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(s,)) for s in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors[:1]
+    assert cache.pin_wait(30) and cache.pin_error is None
+    with cache._lock:
+        entries = dict(cache._entries)
+        pinned = set(cache._pinned_keys)
+        assert not cache._pin_requests and cache._pin_thread is None
+    assert cache.bytes == sum(e[1] for e in entries.values()) <= cache.budget_bytes
+    assert pinned <= set(entries)
+    assert cache.pinned_host_bytes == sum(entries[k][1] for k in pinned)
+    for k, e in entries.items():
+        assert (_on_pinned_host(e[0]) is not None) == (k in pinned)
+    assert cache.stats()["pinned_host_copies"] >= 1
+
+
+def test_put_without_evict_takes_only_free_room(tmp_path):
+    """A tree that is read once (a seat of the residency tier) is cached
+    where there is room and pushes nothing out; a later evicting put treats
+    it like any entry."""
+    f = tmp_path / "layer.bin"
+    f.write_bytes(b"x" * 8)
+    tree = [("decoders", {"w": np.zeros((25,), np.float32)})]  # 100 bytes
+    cache = HostShardCache(budget_bytes=250)
+    assert cache.put("a", tree, paths=[str(f)])
+    assert cache.put("seat1", tree, paths=[str(f)], evict=False)
+    assert not cache.put("seat2", tree, paths=[str(f)], evict=False)
+    s = cache.stats()
+    assert (s["entries"], s["bytes"], s["evictions"]) == (2, 200, 0)
+    assert cache.get("a") is not None and cache.get("seat2") is None
+    # A refused put of a key that was held drops the old tree all the same:
+    # the caller built a new one because the old one missed.
+    assert not cache.put("seat1", [("decoders", {"w": np.zeros((50,), np.float32)})],
+                         paths=[str(f)], evict=False)
+    assert cache.get("seat1") is None and cache.stats()["bytes"] == 100
+    assert cache.put("seat1", tree, paths=[str(f)], evict=False)
+    assert cache.put("b", tree, paths=[str(f)])  # evicts the oldest: "a"
+    assert cache.get("a") is None and cache.stats()["evictions"] == 1
+
+
+def test_a_pinned_tree_is_only_for_its_own_target(tmp_path):
+    """Keys are chip-free. The copy in one chip's pinned_host memory is
+    handed to that chip's readers alone: a reader with another target (a
+    second chip, or None for several) drops it and misses, and the key is
+    never pinned again until clear(); a request under way when the second
+    reader comes is not installed."""
+    f = tmp_path / "layer.bin"
+    f.write_bytes(b"x" * 8)
+    devs = jax.devices()
+    on0, on1 = _pinned_host_of(devs[0]), _pinned_host_of(devs[1])
+    tree = [("decoders", {"w": np.arange(64, dtype=np.float32)})]
+    cache = HostShardCache(budget_bytes=1 << 20)
+    assert cache.get("k", on0) is None and cache.put("k", tree, paths=[str(f)])
+    assert cache.pin("k", tree, on1) is False  # not this key's reader
+    assert cache.pin("k", tree, on0) and cache.pin_wait()
+    assert _on_pinned_host(cache.get("k", on0)[0]) == devs[0]
+    for other in (on1, None):
+        assert cache.get("k", other) is None  # dropped, a miss
+        s = cache.stats()
+        assert (s["entries"], s["bytes"], s["pinned_host_bytes"]) == (0, 0, 0)
+        assert cache.put("k", tree, paths=[str(f)])
+        assert cache.pin("k", tree, on0) is False and cache.pin("k", tree, on1) is False
+        for reader in (on0, on1, None):
+            assert cache.get("k", reader)[0] is tree
+        cache.clear()  # forgets the readers too
+        assert cache.get("k", on0) is None and cache.put("k", tree, paths=[str(f)])
+        assert cache.pin("k", tree, on0) and cache.pin_wait()
+    assert cache.stats()["pinned_host_copies"] == 3
+    # The second reader comes while the copy is being made.
+    cache.clear()
+    gate = threading.Event()
+    real_put = jax.device_put
+
+    def slow_put(x, s=None, **kw):
+        if s is on0:
+            assert gate.wait(20)
+        return real_put(x, s, **kw)
+
+    assert cache.get("k", on0) is None and cache.put("k", tree, paths=[str(f)])
+    jax.device_put = slow_put
+    try:
+        assert cache.pin("k", tree, on0)
+        assert cache.get("k", on1)[0] is tree
+        gate.set()
+        assert cache.pin_wait()
+    finally:
+        jax.device_put = real_put
+    assert cache.get("k", on0)[0] is tree
+    s = cache.stats()
+    assert (s["pinned_host_copies"], s["pinned_host_bytes"]) == (3, 0)
+
+
+@pytest.mark.parametrize("second", ["comes_late", "from_the_start"])
+def test_two_chips_over_one_cache_share_one_entry_a_layer(model_dir, second):
+    """A fleet's replicas: one engine a chip over the process's one cache
+    (serve/fleet.py). Each layer has ONE entry whatever the number of
+    chips: the second chip's source rebuilds a layer it finds pinned for
+    the first (once), then both hit the same NumPy tree sweep after sweep;
+    the cache's bytes are one model's, no copy is made again, and every
+    shard lands on its source's own chip."""
+    devs = jax.devices()[:2]
+    names = layer_names_for(4, tie_word_embeddings=False)
+    shards = [(i,) for i in range(len(names))]
+    cache = HostShardCache(budget_bytes=1 << 30)
+
+    def sweep(dev):
+        source = ShardWeightSource(
+            model_dir, names, shards, np.dtype(np.float32), device=dev,
+            prefetch_depth=2, host_cache=cache,
+        )
+        try:
+            for _, segs in source:
+                for leaf in jax.tree.leaves([seg for _, seg in segs]):
+                    assert leaf.devices() == {dev}
+                    assert leaf.sharding.memory_kind == "device"
+        finally:
+            source.close()
+        return source
+
+    n = len(shards)
+    if second == "comes_late":
+        sweep(devs[0])
+        assert cache.pin_wait()
+        one_model = cache.stats()["bytes"]
+        first = sweep(devs[0])
+        assert first.upload_pinned_bytes == first.upload_bytes == one_model
+        s = cache.stats()
+        assert (s["pinned_host_copies"], s["pinned_host_bytes"]) == (n, one_model)
+        sweep(devs[1])  # finds n trees pinned for the other chip
+        s = cache.stats()
+        assert (s["hits"], s["misses"]) == (n, 2 * n)
+    else:
+        threads = [threading.Thread(target=sweep, args=(d,)) for d in devs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert cache.pin_wait()
+        sweep(devs[0]), sweep(devs[1])  # whoever pinned what, it is undone here
+        one_model = cache.stats()["bytes"]
+    before = cache.stats()
+    assert (before["entries"], before["bytes"], before["pinned_host_bytes"]) == (n, one_model, 0)
+    sources = []
+    for _ in range(2):
+        threads = [
+            threading.Thread(target=lambda d=d: sources.append(sweep(d))) for d in devs
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    assert cache.pin_wait()
+    s = cache.stats()
+    assert s["hits"] - before["hits"] == 4 * n and s["misses"] == before["misses"]
+    assert s["pinned_host_copies"] == before["pinned_host_copies"]
+    assert (s["entries"], s["bytes"], s["pinned_host_bytes"]) == (n, one_model, 0)
+    assert s["evictions"] == 0 and _all_numpy(cache)
+    assert all(src.upload_pinned_bytes == 0 and src.upload_bytes == one_model for src in sources)

@@ -984,3 +984,123 @@ def test_first_seat_holds_under_contention(model_dir):
     assert len(winners) == 50 * n and len({id(w) for w in winners}) == 1
     st = tier.stats()
     assert st["pin_loads"] == 1 and st["pin_failures"] == 1
+
+
+# ---------------------------------------------------------------------------
+# pinned_host copies and the upload-counting bound beside the tier (PR 30)
+# ---------------------------------------------------------------------------
+
+def test_resident_layers_are_never_copied_to_pinned_host(model_dir, clean_scores):
+    """The host cache's pinned_host copies are for the layers a chip streams
+    every sweep: a layer of the tier's plan is uploaded once and never
+    copied, seated or not; the others all are, and the scores do not move."""
+    from flexible_llm_sharding_tpu.runtime import executor as executor_mod
+
+    dev = jax.devices()[0]
+    cfg = _fw(
+        model_dir, host_cache_gb=1.0, prefetch_depth=2,
+        hbm_pin_gb=_partial_budget_gb(model_dir),
+    )
+    for sweep in range(3):
+        out = StreamingExecutor(cfg, device=dev, tokenizer=FakeTokenizer())(list(PROMPTS))
+        assert hostcache.process_cache().pin_wait()
+        for a, b in zip(clean_scores, out):
+            np.testing.assert_array_equal(a, b)
+        rec = executor_mod.process_sweep_log()[-1]
+        # The seating sweep built the streamed layers first, so their copies
+        # were under way before its own uploads of them (made or not yet).
+        assert sweep == 0 or rec["upload_pinned_bytes"] == rec["upload_bytes"]
+    planned = set(residency.process_tier().plan.pinned)
+    assert planned == {0, 1, 5, 6}
+    cache = hostcache.process_cache()
+    with cache._lock:
+        held = {key[-1]: entry[0] for key, entry in cache._entries.items()}
+    # The budget has room for everything: the seats are cached too (NumPy,
+    # for a restart or a re-seat), only the streamed layers are copied.
+    assert set(held) == {(i,) for i in range(7)}
+    for idxs, tree in held.items():
+        assert (executor_mod._on_pinned_host(tree) is None) == (idxs[0] in planned)
+    sizes = _sizes(model_dir)
+    streamed = sum(sizes[i] for i in range(7) if i not in planned)
+    s = cache.stats()
+    assert s["pinned_host_copies"] == 3
+    assert rec["upload_bytes"] == s["pinned_host_bytes"]
+    assert rec["pin_hits"] == 4 and 0 < rec["upload_bytes"] <= streamed
+
+
+@pytest.mark.parametrize("room", ["for_everything", "for_the_streamed_layers"])
+def test_re_seat_after_a_release_reads_what_the_cache_kept(model_dir, clean_scores, room):
+    """A seat's tree is cached where the budget has room and pushes nothing
+    out where it has not. With room for everything a re-seat (the tier was
+    dropped, the process lives on) reads no file again: 7 hits, no miss, no
+    verify. With room for the streamed layers and one seat, those stay (the
+    streamed ones pinned) and the other three seats are read again."""
+    from flexible_llm_sharding_tpu.runtime import executor as executor_mod
+
+    dev = jax.devices()[0]
+    planned = {0, 1, 5, 6}
+    kw = dict(prefetch_depth=2, hbm_pin_gb=_partial_budget_gb(model_dir))
+    StreamingExecutor(
+        _fw(model_dir, host_cache_gb=1.0, **kw), device=dev, tokenizer=FakeTokenizer()
+    )(list(PROMPTS))
+    cache = hostcache.process_cache()
+    assert cache.pin_wait()
+    with cache._lock:
+        nbytes = {key[-1][0]: entry[1] for key, entry in cache._entries.items()}
+    streamed = sum(n for i, n in nbytes.items() if i not in planned)
+    if room == "for_the_streamed_layers":
+        # A fresh process with a smaller budget: the smallest seat fits beside
+        # the streamed layers, no other.
+        residency.reset_process_tier()
+        hostcache.reset_process_cache()
+        iman.reset_verdicts()
+        gb = (streamed + min(nbytes[i] for i in planned) + 1) / 1e9
+        cfg = _fw(model_dir, host_cache_gb=gb, **kw)
+        StreamingExecutor(cfg, device=dev, tokenizer=FakeTokenizer())(list(PROMPTS))
+        cache = hostcache.process_cache()
+        assert cache.pin_wait()
+        s = cache.stats()
+        assert s["evictions"] == 0 and s["pinned_host_bytes"] == streamed
+        assert s["entries"] == 4
+    else:
+        cfg = _fw(model_dir, host_cache_gb=1.0, **kw)
+    residency.reset_process_tier()
+    s0, v0 = cache.stats(), iman.verdict_stats()["full_verifies"]
+    out = StreamingExecutor(cfg, device=dev, tokenizer=FakeTokenizer())(list(PROMPTS))
+    for a, b in zip(clean_scores, out):
+        np.testing.assert_array_equal(a, b)
+    s = cache.stats()
+    hits, misses = s["hits"] - s0["hits"], s["misses"] - s0["misses"]
+    verifies = iman.verdict_stats()["full_verifies"] - v0
+    # The pass ahead and the sweep's own builds both hit the three streamed layers.
+    if room == "for_everything":
+        assert (hits, misses, verifies) == (3 + 7, 0, 0)
+    else:
+        assert (hits, misses, verifies) == (3 + 3 + 1, 3, 0)  # the verdict cache spares the crc
+        assert s["evictions"] == 0 and s["pinned_host_bytes"] == streamed
+    rec = executor_mod.process_sweep_log()[-1]
+    assert rec["upload_pinned_bytes"] == streamed < rec["upload_bytes"]
+    assert residency.process_tier().stats()["pinned_layers"] == 4
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_in_flight_bytes_is_what_the_source_can_hold(model_dir, depth):
+    """The queue's places, the shard in the producer's hand and the one at
+    the consumer: the count of streamed shards the tier's budget leaves
+    room for (tests/test_executor.py holds the count itself)."""
+    from flexible_llm_sharding_tpu.runtime.executor import ShardWeightSource
+
+    names = layer_names_for(4)
+    cfg = _fw(model_dir, prefetch_depth=depth)
+    source = ShardWeightSource(
+        model_dir, names, [(i,) for i in range(7)], np.float32,
+        prefetch_depth=cfg.effective_prefetch_depth(),
+    )
+    try:
+        can_hold = source._q.maxsize + 1 + 1
+    finally:
+        source.close()
+    assert can_hold == depth + 2
+    assert residency.in_flight_bytes(cfg, names, False) == can_hold * max(
+        _sizes(model_dir).values()
+    )
