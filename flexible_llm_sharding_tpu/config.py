@@ -424,9 +424,35 @@ class LlamaConfig:
     # forward passes go by; these say which layers a checkpoint has it in).
     attn_sink_local: bool = False
     attn_sink_global: bool = False
+    # Linear-attention layers beside softmax ones (MiniCPM-SALA's
+    # ``lightning-attn``): layer_linear[i] True = layer i keeps a decayed
+    # recurrent state [heads, qk dim, v dim] instead of keys and values
+    # (ops/lightning_attention.py), at linear_attn_shape = (heads, kv heads,
+    # qk dim, v dim), with a per-head RMSNorm on its output
+    # (linear_output_norm) and a sigmoid gate from the layer's input on it
+    # (linear_output_gate; attn_output_gate: the same gate on the softmax
+    # layers). As everywhere, the forward passes go by the weights they are
+    # given (``attn.o_norm``, ``attn.wg``); these say what a checkpoint has.
+    layer_linear: tuple[bool, ...] | None = None
+    linear_attn_shape: tuple[int, int, int, int] | None = None
+    linear_output_norm: bool = False
+    linear_output_gate: bool = False
+    attn_output_gate: bool = False
+    # From this many tokens on the model's softmax layers select blocks of
+    # keys (learned block-sparse attention), which no path here computes:
+    # such a prompt is refused, never run dense. None = always dense.
+    sparse_attn_from: int | None = None
+    # MiniCPM's muP scalings: embeddings times embed_multiplier, every
+    # sublayer's output times residual_multiplier before its residual add,
+    # the final norm's output over logit_divisor before the head.
+    embed_multiplier: float | None = None
+    residual_multiplier: float | None = None
+    logit_divisor: float | None = None
 
-    def attn_shape(self, sliding: bool = False) -> tuple[int, int, int, int]:
+    def attn_shape(self, sliding: bool = False, linear: bool = False) -> tuple[int, int, int, int]:
         """(heads, kv heads, qk head dim, v head dim) of a layer kind."""
+        if linear and self.linear_attn_shape is not None:
+            return self.linear_attn_shape
         if sliding and self.local_attn_shape is not None:
             return self.local_attn_shape
         return (
@@ -434,11 +460,23 @@ class LlamaConfig:
             self.head_dim, self.v_dim,
         )
 
-    def require_one_attention_shape(self, path: str) -> None:
-        """Fail loudly on a path that sizes its KV state or shards its heads
-        from ``num_key_value_heads`` alone, for a model whose layer kinds
-        differ in attention shape or that holds a share of its experts: only
-        the streamed scoring path and the layer functions carry those."""
+    def require_one_attention_shape(self, path: str, layer_fn: bool = False) -> None:
+        """Fail loudly on a path that assumes pages of keys and values of ONE
+        shape: it sizes its KV state or shards its heads from
+        ``num_key_value_heads`` alone. Refused: a model with linear-attention
+        layers (their state is no KV: nothing pools, pages, snapshots or
+        shards it yet), and, unless ``layer_fn`` (the layer functions, which
+        take a layer's shape from its weights), a model whose layer kinds
+        differ in attention shape or that holds a share of its experts. Only
+        the streamed scoring path carries those."""
+        if self.layer_linear is not None:
+            raise NotImplementedError(
+                f"{path} does not support {self.model_type}: a linear-"
+                "attention layer's recurrent state is not a KV cache; such a "
+                "model runs on the streamed scoring path only"
+            )
+        if layer_fn:
+            return
         if self.local_attn_shape is not None or self.moe_ep_size > 1:
             raise NotImplementedError(
                 f"{path} does not support {self.model_type}: per-kind "
@@ -630,6 +668,68 @@ class LlamaConfig:
                 f"(ep_rank {rank})"
             )
         kwargs["moe_ep_size"], kwargs["moe_ep_rank"] = ep, rank
+
+    @staticmethod
+    def _apply_minicpm_sala(kwargs: dict[str, Any], d: dict[str, Any]) -> None:
+        """MiniCPM-SALA (``minicpm_sala``): ``mixer_types`` lists each layer's
+        kind, ``lightning-attn`` (linear attention with a per-head decay; the
+        ``lightning_*`` keys give its shape and whether it rotates) or
+        ``minicpm4`` (grouped-query softmax attention, rotary only under
+        ``attn_use_rope``; its learned block-sparse selection starts at
+        ``sparse_config.dense_len`` tokens, MiniCPM4's published 8192 where
+        the config carries none, and is refused from there); a per-head q/k
+        norm and an output gate on both kinds, an output norm on the linear
+        one; MiniCPM's muP scalings."""
+        n = int(d.get("num_hidden_layers", 32))
+        mixers = list(d.get("mixer_types") or ["minicpm4"] * n)
+        unknown = sorted(set(mixers) - {"minicpm4", "lightning-attn"})
+        if len(mixers) != n or unknown:
+            raise ValueError(
+                f"minicpm_sala mixer_types has {len(mixers)} entries for {n} "
+                f"layers (unknown kinds: {unknown})"
+            )
+        linear = tuple(m == "lightning-attn" for m in mixers)
+        nq = int(d.get("num_attention_heads", 32))
+        hd = int(d.get("head_dim") or d.get("hidden_size", 4096) // nq)
+        kwargs["qk_norm"] = bool(d.get("qk_norm", False))
+        kwargs["attn_output_gate"] = bool(d.get("attn_use_output_gate", False))
+        kwargs["sliding_window"] = None
+        if any(linear):
+            lh, ld = int(d.get("lightning_nh", nq)), int(d.get("lightning_head_dim", hd))
+            if int(d.get("lightning_nkv", lh)) != lh:
+                raise NotImplementedError(
+                    "minicpm_sala: lightning_nkv != lightning_nh (grouped "
+                    "linear attention) is not supported"
+                )
+            if d.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)":
+                raise NotImplementedError(
+                    f"minicpm_sala lightning_scale {d.get('lightning_scale')!r} "
+                    "('1/sqrt(d)' is supported)"
+                )
+            kwargs["layer_linear"] = linear
+            kwargs["linear_attn_shape"] = (lh, lh, ld, ld)
+            kwargs["linear_output_norm"] = bool(d.get("use_output_norm", False))
+            kwargs["linear_output_gate"] = bool(d.get("use_output_gate", False))
+            if (lh, lh, ld, ld) == (
+                nq, int(d.get("num_key_value_heads", nq)), hd, hd
+            ) and not all(linear) and not kwargs["linear_output_norm"]:
+                raise NotImplementedError(
+                    "minicpm_sala: the two layer kinds have one shape and no "
+                    "output norm: a layer's weights cannot say its kind"
+                )
+        rope = tuple(
+            bool(d.get("lightning_use_rope", True) if lin else d.get("attn_use_rope", True))
+            for lin in linear
+        )
+        if not all(rope):
+            kwargs["layer_rope"] = rope
+        sparse = d.get("sparse_config") or {}
+        kwargs["sparse_attn_from"] = int(sparse.get("dense_len", 8192))
+        kwargs["embed_multiplier"] = float(d.get("scale_emb", 1.0))
+        kwargs["residual_multiplier"] = float(d.get("scale_depth", 1.0)) / n**0.5
+        kwargs["logit_divisor"] = float(d.get("hidden_size", 4096)) / float(
+            d.get("dim_model_base", d.get("hidden_size", 4096))
+        )
 
     @classmethod
     def from_hf_config(cls, d: dict[str, Any]) -> "LlamaConfig":
@@ -881,6 +981,9 @@ class LlamaConfig:
         elif model_type == "mimo_v2_flash":
             if not native:
                 cls._apply_mimo_v2(kwargs, d)
+        elif model_type == "minicpm_sala":
+            if not native:
+                cls._apply_minicpm_sala(kwargs, d)
         elif model_type in ("mistral", "mixtral", "phi3"):
             # sliding_window flows through by field name (may be null);
             # mixtral's num_local_experts/num_experts_per_tok likewise.
@@ -893,7 +996,8 @@ class LlamaConfig:
             raise NotImplementedError(
                 f"model_type {model_type!r} is not supported "
                 "(llama, mistral, phi3, qwen2, qwen3, qwen3_moe, mixtral, gemma, "
-                "gemma2, gemma3_text, llama4_text, deepseek_v3, mimo_v2_flash are)"
+                "gemma2, gemma3_text, llama4_text, deepseek_v3, mimo_v2_flash, "
+                "minicpm_sala are)"
             )
         if model_type not in (
             "mixtral", "llama4_text", "qwen3_moe", "deepseek_v3", "mimo_v2_flash"
@@ -915,6 +1019,8 @@ class LlamaConfig:
             "rope_long_factor",
             "rope_short_factor",
             "local_attn_shape",
+            "layer_linear",
+            "linear_attn_shape",
         ):
             if kwargs.get(key) is not None:
                 # json round-trips tuples as lists; fields must stay hashable.

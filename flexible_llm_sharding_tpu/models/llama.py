@@ -44,6 +44,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from flexible_llm_sharding_tpu.config import SUPPORTED_ACTIVATIONS, LlamaConfig
 from flexible_llm_sharding_tpu.ops import (
@@ -53,7 +54,11 @@ from flexible_llm_sharding_tpu.ops import (
     rms_norm,
     rope_cos_sin,
 )
-from flexible_llm_sharding_tpu.ops import grouped_matmul, pallas_attention
+from flexible_llm_sharding_tpu.ops import (
+    grouped_matmul,
+    lightning_attention,
+    pallas_attention,
+)
 from flexible_llm_sharding_tpu.ops.attention import (
     causal_mask,
     decode_attention,
@@ -94,7 +99,10 @@ def layer_kind(cfg: LlamaConfig, attn: Params, sliding):
     layers have another shape than its full ones (``cfg.local_attn_shape``:
     MiMo-V2) is answered by the weights: the projections' widths say which
     kind this layer is, so ``sliding`` comes back as a python bool (static)
-    whatever traced flag the caller held."""
+    whatever traced flag the caller held. A linear-attention layer
+    (``is_linear``) answers with the linear shape and ``sliding`` as given."""
+    if is_linear(cfg, attn):
+        return cfg.attn_shape(linear=True), sliding
     full, local = cfg.attn_shape(False), cfg.attn_shape(True)
     if full == local:
         return full, sliding
@@ -106,6 +114,39 @@ def layer_kind(cfg: LlamaConfig, attn: Params, sliding):
         f"attention projections of widths {widths} fit neither the full "
         f"{full} nor the local {local} (heads, kv heads, qk, v) shape"
     )
+
+
+def is_linear(cfg: LlamaConfig, attn: Params) -> bool:
+    """Whether the layer whose attention weights are ``attn`` is a linear-
+    attention layer of a model that has them (``cfg.layer_linear``): told by
+    the widths of its key and value projections where the two kinds differ
+    in shape, else by the output norm only that kind carries. Static: a
+    layer's kind is a fact of its weights' shapes, so a jitted step compiles
+    once a kind, not once a layer."""
+    if cfg.layer_linear is None:
+        return False
+    widths = lambda shape: (shape[1] * shape[2], shape[1] * shape[3])
+    linear, full = cfg.attn_shape(linear=True), cfg.attn_shape()
+    if widths(linear) != widths(full):
+        return (attn["wk"].shape[-1], attn["wv"].shape[-1]) == widths(linear)
+    return "o_norm" in attn
+
+
+def layer_log_decay(cfg: LlamaConfig) -> np.ndarray | None:
+    """float32 [layers, linear heads]: each linear-attention layer's per-head
+    log-decay (its softmax layers' rows are 0 and unread); None for a model
+    without such layers. Lightning Attention-2's per-head slopes with
+    MiniMax-01's per-layer factor: head n of H in layer l of L decays at
+    ``2^(-8 (n + 1) / H) * (1 - l / (L - 1) + 1e-5)`` a token. No tensor of a
+    checkpoint: a function of the layer's index among all layers, which is
+    why it reaches a layer as an argument."""
+    if cfg.layer_linear is None:
+        return None
+    h, n = cfg.linear_attn_shape[0], cfg.num_hidden_layers
+    slopes = 2.0 ** (-8.0 * (np.arange(h) + 1) / h)
+    factor = 1.0 - np.arange(n) / max(n - 1, 1) + 1e-5
+    table = -slopes[None, :] * factor[:, None]
+    return np.where(np.asarray(cfg.layer_linear)[:, None], table, 0.0).astype(np.float32)
 
 
 @jax.named_scope("qkv")
@@ -473,10 +514,37 @@ def _mlp(
     return _dense_mlp(mlp, x, _ACT[cfg.hidden_act if cfg is not None else "silu"])
 
 
-def _residual_attn(params: Params, cfg: LlamaConfig, x: jax.Array, attn_out) -> jax.Array:
+def _gate_heads(attn: Params, cfg: LlamaConfig, o: jax.Array, h: jax.Array) -> jax.Array:
+    """What MiniCPM-SALA puts between a mixer's heads and its output
+    projection, where the layer's weights have it: a per-head RMSNorm of the
+    heads' outputs (``o_norm``, the linear layers), then a sigmoid gate from
+    the layer's normed input ``h`` (``wg``, both kinds). o: [..., L, n_q, vd];
+    h: [..., L, D]."""
+    if "o_norm" in attn:
+        with jax.named_scope("output_norm"):
+            o = rms_norm(o, attn["o_norm"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    if "wg" in attn:
+        with jax.named_scope("output_gate"):
+            o = o * jax.nn.sigmoid(_mm(h, attn["wg"])).reshape(o.shape)
+    return o
+
+
+def _scaled(cfg: LlamaConfig, y: jax.Array) -> jax.Array:
+    """A sublayer's output times the model's residual multiplier (muP's
+    ``scale_depth / sqrt(layers)``), where it has one."""
+    if cfg.residual_multiplier is None:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+
+
+def _residual_attn(
+    params: Params, cfg: LlamaConfig, x: jax.Array, attn_out, h=None
+) -> jax.Array:
     """Residual add of the attention sublayer. Gemma2's sandwich layout
-    (``ffw_sandwich_norms``) norms the sublayer OUTPUT before the add."""
-    y = _out_proj(params["attn"], attn_out)
+    (``ffw_sandwich_norms``) norms the sublayer OUTPUT before the add.
+    ``h``: the layer's normed input, which an output gate reads
+    (``_gate_heads``)."""
+    y = _out_proj(params["attn"], _gate_heads(params["attn"], cfg, attn_out, h))
     if cfg.ffw_sandwich_norms:
         y = rms_norm(
             y,
@@ -484,7 +552,7 @@ def _residual_attn(params: Params, cfg: LlamaConfig, x: jax.Array, attn_out) -> 
             cfg.rms_norm_eps,
             cfg.norm_unit_offset,
         )
-    return x + y
+    return x + _scaled(cfg, y)
 
 
 def _residual_mlp(
@@ -511,7 +579,7 @@ def _residual_mlp(
             cfg.rms_norm_eps,
             cfg.norm_unit_offset,
         )
-    return x + y
+    return x + _scaled(cfg, y)
 
 
 def layer_sliding_pattern(cfg: LlamaConfig) -> tuple[bool, ...]:
@@ -544,7 +612,7 @@ def position_qk(cfg: LlamaConfig, q, k, positions, sliding, rope_on, total_len=N
     only): real sequence length for the long/short table choice — see
     ops/rope.py rope_cos_sin.
     """
-    cos, sin = rope_for_layer(cfg, positions, sliding, total_len)
+    cos, sin = rope_for_layer(cfg, positions, sliding, total_len, q.shape[-1])
     rot = apply_rope_interleaved if cfg.rope_interleaved else apply_rope
     rd = cfg.rotary_dim
     if rd is not None and rd < q.shape[-1]:
@@ -587,15 +655,19 @@ def layer_rope_pattern(cfg: LlamaConfig) -> tuple[bool, ...]:
     return (True,) * cfg.num_hidden_layers
 
 
-def rope_for_layer(cfg: LlamaConfig, positions: jax.Array, sliding, total_len=None):
+def rope_for_layer(
+    cfg: LlamaConfig, positions: jax.Array, sliding, total_len=None, head_dim=None
+):
     """cos/sin for one layer. Gemma3 gives sliding (local) layers their own
     UNSCALED rope base while full (global) layers use rope_theta +
     rope_scaling; other families have a single base. ``sliding`` follows the
     layer-fn convention: None = uniform per cfg, python bool = static
     per-layer choice, traced bool = select between the two static tables
     (both tiny) inside the scan program. ``total_len``: longrope's dynamic
-    long/short selector (only the scaled global table uses it)."""
-    dim = cfg.rotary_dim or cfg.head_dim
+    long/short selector (only the scaled global table uses it). ``head_dim``:
+    the width of the heads at hand where a layer kind has its own (a
+    linear-attention layer's); None = the config's."""
+    dim = cfg.rotary_dim or head_dim or cfg.head_dim
     if cfg.rope_local_theta is None:
         return rope_cos_sin(
             positions, dim, cfg.rope_theta, cfg.rope_scaling_spec,
@@ -660,6 +732,14 @@ def _attention_scope(cfg: LlamaConfig, sliding):
     return stack
 
 
+def _linear_attention_scope():
+    """``linear_attention`` inside the layer's ``attention`` scope."""
+    stack = contextlib.ExitStack()
+    stack.enter_context(jax.named_scope("attention"))
+    stack.enter_context(jax.named_scope("linear_attention"))
+    return stack
+
+
 def _moe_counts(stats: list) -> jax.Array:
     """Sum of a layer's ``_deepseek_moe_mlp`` stats: int32 [2], zeros for a
     layer that routed nothing over held experts."""
@@ -681,6 +761,8 @@ def embed(
     x = params["embedding"].astype(dtype)[ids]
     if cfg is not None and cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden_size**0.5, dtype)
+    if cfg is not None and cfg.embed_multiplier is not None:
+        x = x * jnp.asarray(cfg.embed_multiplier, dtype)  # MiniCPM's scale_emb
     return x
 
 
@@ -693,18 +775,26 @@ def decoder_layer(
     sliding=None,
     rope_on=None,
     total_len=None,
+    log_decay=None,
 ) -> jax.Array:
     """Plain decoder layer. x: [..., L, D]; positions int [..., L] or [L];
     mask broadcastable to [..., L, L] (caller bakes any local mask in;
     ``sliding``/``rope_on`` select the per-layer rope base / NoPE;
-    ``total_len`` is longrope's real-length selector)."""
+    ``total_len`` is longrope's real-length selector). A linear-attention
+    layer (``is_linear``) takes ``log_decay`` [heads] and no mask: it is
+    causal over x's L rows (x: [B, L, D])."""
     h = rms_norm(x, params["input_layernorm"]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
     q, k, v = positioned_qkv(params, cfg, h, positions, sliding, rope_on, total_len)
-    attn_out = attention(
-        q, k, v, mask, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
-        sink=params["attn"].get("sink"),
-    )
-    x = _residual_attn(params, cfg, x, attn_out)
+    if is_linear(cfg, params["attn"]):
+        with _linear_attention_scope():
+            rows = jnp.broadcast_to(log_decay, (x.shape[-2], log_decay.shape[-1]))
+            attn_out, _ = lightning_attention.lightning_attention_xla(q, k, v, rows)
+    else:
+        attn_out = attention(
+            q, k, v, mask, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
+            sink=params["attn"].get("sink"),
+        )
+    x = _residual_attn(params, cfg, x, attn_out, h)
     return _residual_mlp(params, cfg, x)
 
 
@@ -770,6 +860,48 @@ def _flash_tp_decode(mesh, q, kp, vp, ks, vs, kg, vg, plen, eos, t, local_on, kw
     )(q, kp, vp, ks, vs, kg, vg, plen, eos, t, flag)
 
 
+def linear_uses_kernel(cfg: LlamaConfig, lp: int, ls: int, use_pallas: bool, tp_mesh=None) -> bool:
+    """Whether a linear-attention layer over a (prefix bucket ``lp``, suffix
+    bucket ``ls``) prompt runs the Pallas kernel or the XLA op: one choice for
+    both of its calls, from the shapes (the sweep's record counts by it)."""
+    _, _, d, vd = cfg.attn_shape(linear=True)
+    return (
+        use_pallas and tp_mesh is None
+        and lightning_attention.supports(d, vd, lp)
+        and lightning_attention.supports(d, vd, ls)
+    )
+
+
+def _linear_prefix_suffix(
+    params, cfg, prefix_h, suffix_h, prefix_len, log_decay, kernel: bool, rope_on, total_len,
+):
+    """The attention half of ``prefix_suffix_layer`` for a linear-attention
+    layer: (prefix, suffixes) residual streams as they enter the MLP half."""
+    lp, ls = prefix_h.shape[0], suffix_h.shape[1]
+    eps = cfg.rms_norm_eps
+    op = (
+        lightning_attention.lightning_attention if kernel
+        else lightning_attention.lightning_attention_xla
+    )
+    h = rms_norm(prefix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
+    q, k, v = positioned_qkv(params, cfg, h, jnp.arange(lp), None, rope_on, total_len)
+    # Rows past the real prefix stop the clock: no decay, nothing added.
+    live = jnp.arange(lp) < prefix_len
+    k = jnp.where(live[:, None, None], k, jnp.zeros_like(k))
+    rows = jnp.where(live[:, None], log_decay[None, :], 0.0)
+    with _linear_attention_scope():
+        o, state = op(q[None], k[None], v[None], rows)
+    prefix_out = _residual_attn(params, cfg, prefix_h, o[0], h)
+
+    hs = rms_norm(suffix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
+    qs, ks, vs = positioned_qkv(
+        params, cfg, hs, prefix_len + jnp.arange(ls), None, rope_on, total_len
+    )
+    with _linear_attention_scope():
+        os_, _ = op(qs, ks, vs, jnp.broadcast_to(log_decay, (ls, log_decay.shape[-1])), state[0])
+    return prefix_out, _residual_attn(params, cfg, suffix_h, os_, hs)
+
+
 def prefix_suffix_layer(
     params: Params,
     cfg: LlamaConfig,
@@ -784,6 +916,7 @@ def prefix_suffix_layer(
     total_len=None,
     moe_stats: bool = False,
     attn_only: bool = False,
+    log_decay=None,
 ) -> tuple[jax.Array, ...]:
     """One decoder layer over a (prefix, suffixes) prompt — the streaming hot op.
 
@@ -816,13 +949,34 @@ def prefix_suffix_layer(
     residual streams as they enter the MLP half, for a caller that runs
     that half (``_residual_mlp``, position-wise) over many prompts' rows at
     once.
+
+    ``log_decay`` (float32 [heads]): the layer's per-head log-decay where it
+    is a linear-attention layer (``is_linear``; ``layer_log_decay``). Such a
+    layer shares a STATE where a softmax layer shares keys and values: the
+    prefix runs the decayed recurrence from a zero state, its rows at
+    ``t >= prefix_len`` neither decaying the state nor adding to it, so the
+    state at the bucket's end is the state at ``prefix_len``; each suffix
+    continues from that one state at positions ``prefix_len + i``. The state
+    (float32 [heads, qk dim, v dim]) lives inside this call.
     """
     lp, _ = prefix_h.shape
     s, ls, _ = suffix_h.shape
     eps = cfg.rms_norm_eps
+    stats = [] if moe_stats else None
+    if return_kv:
+        cfg.require_one_attention_shape("a KV cache (return_kv)", layer_fn=True)
+    if is_linear(cfg, params["attn"]):
+        prefix_out, suffix_out = _linear_prefix_suffix(
+            params, cfg, prefix_h, suffix_h, prefix_len, log_decay,
+            linear_uses_kernel(cfg, lp, ls, use_pallas, tp_mesh), rope_on, total_len,
+        )
+        if not attn_only:
+            prefix_out = _residual_mlp(params, cfg, prefix_out, stats)
+            suffix_out = _residual_mlp(params, cfg, suffix_out, stats)
+        out = (prefix_out, suffix_out)
+        return out + (_moe_counts(stats),) if moe_stats else out
     (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
     sink = params["attn"].get("sink")
-    stats = [] if moe_stats else None
     rope_sliding = sliding  # rope base and scope survive the window shortcut
     window, chunk, sliding = _effective_window(cfg, sliding)
     if (window is not None and lp + ls <= window) or (
@@ -891,7 +1045,7 @@ def prefix_suffix_layer(
                 q, k, v, mask, scale=cfg.attn_scale,
                 softcap=cfg.attn_logit_softcap, sink=sink,
             )
-    prefix_out = _residual_attn(params, cfg, prefix_h, attn_out)
+    prefix_out = _residual_attn(params, cfg, prefix_h, attn_out, h)
     if not attn_only:
         prefix_out = _residual_mlp(params, cfg, prefix_out, stats)
 
@@ -928,7 +1082,7 @@ def prefix_suffix_layer(
                 chunk=chunk,
                 sink=sink,
             )
-    suffix_out = _residual_attn(params, cfg, suffix_h, attn_s)
+    suffix_out = _residual_attn(params, cfg, suffix_h, attn_s, hs)
     if not attn_only:
         suffix_out = _residual_mlp(params, cfg, suffix_out, stats)
     out = (prefix_out, suffix_out)
@@ -968,6 +1122,7 @@ def suffix_only_layer(
     Returns ``(suffix_out, {"ks": ks, "vs": vs})`` — the caller re-attaches
     kp/vp to rebuild the full decode-KV dict.
     """
+    cfg.require_one_attention_shape("a cached prefix KV (suffix_only_layer)", layer_fn=True)
     lp = kp.shape[0]
     s, ls, _ = suffix_h.shape
     eps = cfg.rms_norm_eps
@@ -1026,7 +1181,7 @@ def suffix_only_layer(
                 chunk=chunk,
                 sink=sink,
             )
-    suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s)
+    suffix_mid = _residual_attn(params, cfg, suffix_h, attn_s, hs)
     suffix_out = _residual_mlp(params, cfg, suffix_mid)
     return suffix_out, {"ks": ks, "vs": vs}
 
@@ -1059,6 +1214,7 @@ def decode_step_layer(
     prefix-KV blocks past the real prefix length. Under tensor parallelism
     (``tp_mesh``) the kernel runs per head-shard via shard_map.
     """
+    cfg.require_one_attention_shape("KV-cache decoding (decode_step_layer)", layer_fn=True)
     eps = cfg.rms_norm_eps
     (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
     sink = params["attn"].get("sink")
@@ -1151,7 +1307,7 @@ def decode_step_layer(
                 chunk=chunk,
                 sink=sink,
             )
-    mid = _residual_attn(params, cfg, x, attn_out)
+    mid = _residual_attn(params, cfg, x, attn_out, h)
     return _residual_mlp(params, cfg, mid), kv
 
 
@@ -1165,7 +1321,16 @@ def select_eos_and_norm(
     Returns [S, 1, D].
     """
     last = jnp.take_along_axis(suffix_h, suffix_eos[:, None, None], axis=1)
-    return rms_norm(last, params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    return final_norm(params, cfg, last)
+
+
+def final_norm(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
+    """``model.norm`` and, where the model has one, muP's logit divisor
+    (``hidden_size / dim_model_base``) on what the head reads."""
+    x = rms_norm(x, params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    if cfg.logit_divisor is not None:
+        x = x / jnp.asarray(cfg.logit_divisor, x.dtype)
+    return x
 
 
 def lm_head_scores_multi(
@@ -1231,6 +1396,7 @@ def forward_full(
     )
     pattern = layer_sliding_pattern(cfg)
     rope_pat = layer_rope_pattern(cfg)
+    decay = layer_log_decay(cfg)
     layers = params["layers"]
     if isinstance(layers, (list, tuple)):
         for i, lp in enumerate(layers):
@@ -1238,8 +1404,13 @@ def forward_full(
                 lp, cfg, x, positions,
                 banded if pattern[i] else full,
                 sliding=pattern[i], rope_on=rope_pat[i], total_len=total_len,
+                log_decay=None if decay is None else jnp.asarray(decay[i]),
             )
     else:  # stacked pytree with leading layer axis -> scan (one compile)
+        if decay is not None:
+            raise NotImplementedError(
+                "a model with linear-attention layers does not stack into one scan"
+            )
         flags = jnp.asarray(pattern)
         rflags = jnp.asarray(rope_pat)
 
@@ -1255,7 +1426,7 @@ def forward_full(
             )
 
         x, _ = jax.lax.scan(body, x, (layers, flags, rflags))
-    x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    x = final_norm(params["norm"], cfg, x)
     logits = _mm(x, head_params(params)["kernel"]).astype(jnp.float32)
     if cfg.final_logit_softcap is not None:
         logits = jnp.tanh(logits / cfg.final_logit_softcap) * cfg.final_logit_softcap
@@ -1267,12 +1438,14 @@ def forward_full(
 # ---------------------------------------------------------------------------
 
 def init_layer_params(
-    rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32, sliding: bool = False
+    rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32, sliding: bool = False,
+    linear: bool = False,
 ) -> Params:
-    """``sliding``: the layer's kind, for a model whose local layers have
-    their own attention shape or sink (``cfg.attn_shape``)."""
+    """``sliding`` / ``linear``: the layer's kind, for a model whose local
+    or linear-attention layers have their own attention shape, sink, output
+    norm or gate (``cfg.attn_shape``)."""
     d, f = cfg.hidden_size, cfg.intermediate_size
-    nq, nkv, hd, vd = cfg.attn_shape(sliding)
+    nq, nkv, hd, vd = cfg.attn_shape(sliding, linear)
     ks = jax.random.split(rng, 14)
 
     def lin(key, fan_in, fan_out):
@@ -1329,6 +1502,10 @@ def init_layer_params(
         attn["bo"] = bias(ks[10], d)
     if cfg.qk_norm:
         attn |= {"q_norm": jnp.ones((hd,), dtype), "k_norm": jnp.ones((hd,), dtype)}
+    if linear and cfg.linear_output_norm:
+        attn["o_norm"] = jnp.ones((vd,), dtype)
+    if cfg.linear_output_gate if linear else cfg.attn_output_gate:
+        attn["wg"] = lin(jax.random.fold_in(rng, 78), d, nq * vd)
     if cfg.num_local_experts:
         e = cfg.num_local_experts
 
@@ -1441,7 +1618,10 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Params:
             ).astype(dtype)
         },
         "layers": [
-            init_layer_params(keys[i + 1], cfg, dtype)
+            init_layer_params(
+                keys[i + 1], cfg, dtype,
+                linear=cfg.layer_linear is not None and cfg.layer_linear[i],
+            )
             for i in range(cfg.num_hidden_layers)
         ],
         "norm": {"scale": jnp.ones((cfg.hidden_size,), dtype)},

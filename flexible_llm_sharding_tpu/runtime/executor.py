@@ -63,6 +63,7 @@ from flexible_llm_sharding_tpu.runtime.pressure import (
 )
 from flexible_llm_sharding_tpu.runtime.tokenization import (
     PromptTokenizer,
+    check_dense_len,
     check_longrope_regime,
     longrope_total_len,
     TokenizedPrompt,
@@ -113,7 +114,10 @@ def _decoder_block(
 
     seg: {"layers": pytree with leading [k] axis, "sliding": bool [k] per-
     layer local-attention flags or None (uniform), "rope": bool [k]
-    per-layer rope flags or None}; prefix_h [B, Lp, D]; suffix_h
+    per-layer rope flags or None, "index": int32 [k], the layers' indices
+    among the model's decoder layers, for a model with linear-attention
+    layers (absent otherwise: what such a layer's decay follows,
+    ``llama.layer_log_decay``)}; prefix_h [B, Lp, D]; suffix_h
     [B, S, Ls, D]; prefix_len int32 [B]. Activations are donated — each scan
     step's output reuses the input buffers. ``use_pallas`` (static) routes
     attention through the flash kernels; ``tp_mesh`` (static, hashable)
@@ -135,9 +139,15 @@ def _decoder_block(
     """
     stacked, flags = seg["layers"], seg["sliding"]
     rflags = seg.get("rope")
+    # A float leaf of the segment would go through the placement's cast to
+    # the compute dtype: the decays are float32 constants of the program,
+    # picked by the layers' indices.
+    decay = llama.layer_log_decay(cfg)
+    if decay is not None:
+        decay = jnp.asarray(decay)[seg["index"]]
 
     def body(carry, xs):
-        layer_params, sliding, rope_on = xs
+        layer_params, sliding, rope_on, log_decay = xs
         p, s, counts = carry
 
         def attention_half(lp_, c_, p_, s_, plen_, tlen_):
@@ -149,6 +159,7 @@ def _decoder_block(
                 tp_mesh=tp_mesh,
                 total_len=tlen_,
                 attn_only=True,
+                log_decay=log_decay,
             )
 
         step = jax.vmap(
@@ -177,7 +188,7 @@ def _decoder_block(
     (prefix_h, suffix_h, counts), _ = jax.lax.scan(
         body,
         (prefix_h, suffix_h, jnp.zeros((2,), jnp.int32) if moe_stats else None),
-        (stacked, flags, rflags),
+        (stacked, flags, rflags, decay),
     )
     if moe_stats:
         return prefix_h, suffix_h, counts
@@ -384,6 +395,12 @@ def apply_segments(
                     params, prefix_h.size + suffix_h.size, tp_mesh
                 )
                 clock.expert_rows[body] += n
+                body, n, state = _linear_rows(
+                    model_cfg, params, prefix_h.shape, suffix_h.shape,
+                    use_pallas, tp_mesh,
+                )
+                clock.linear_rows[body] += n
+                clock.linear_state_bytes = max(clock.linear_state_bytes, state)
             prefix_h, suffix_h, *counts = _decoder_block(
                 model_cfg, params, prefix_h, suffix_h, prefix_len, use_pallas,
                 tp_mesh, total_len, moe_stats,
@@ -408,6 +425,26 @@ def _expert_rows(seg, act_size: int, tp_mesh) -> tuple[str, int]:
     grouped = "correction_bias" in mlp and tp_mesh is None
     layers, d = mlp["router"].shape[:2] if "router" in mlp else (0, 1)  # [k, D, E]
     return ("grouped" if grouped else "dense"), layers * (act_size // d)
+
+
+def _linear_rows(
+    model: LlamaConfig, seg, prefix_shape, suffix_shape, use_pallas: bool, tp_mesh
+) -> tuple[str, int, int]:
+    """Which body ``_decoder_block`` gives a segment's linear-attention
+    layers over a block ([B, Lp, D] and [B, S, Ls, D]), the rows x such
+    layers it is dispatched with, and the float32 state its call holds (one
+    a prompt); no rows and no state for a segment of softmax layers. A
+    layer's kind is read off its weights' shapes, stacked or not."""
+    if not llama.is_linear(model, seg["layers"]["attn"]):
+        return "xla", 0, 0
+    (b, lp, _), (_, s, ls, _) = prefix_shape, suffix_shape
+    kernel = llama.linear_uses_kernel(model, lp, ls, use_pallas, tp_mesh)
+    heads, _, d, vd = model.attn_shape(linear=True)
+    return (
+        "kernel" if kernel else "xla",
+        seg["index"].shape[0] * b * (lp + s * ls),
+        b * heads * d * vd * 4,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -530,6 +567,11 @@ class SweepClock:
         # Rows x expert layers dispatched, by the expert layer's body
         # (``_expert_rows``): host counts from the shapes.
         self.expert_rows = {"grouped": 0, "dense": 0}
+        # Rows x linear-attention layers dispatched, by the body that runs
+        # them (the Pallas kernel or the XLA op), and the most recurrent
+        # state one dispatch held: host counts from the shapes.
+        self.linear_rows = {"kernel": 0, "xla": 0}
+        self.linear_state_bytes = 0
         self._sweep = obs_trace.sweep_span(self.sweep_id, mode="offline")
         self._head = obs_trace.timed(
             "sweep_head", cat="sweep", sweep_id=self.sweep_id
@@ -608,6 +650,9 @@ class SweepClock:
             rec.update(
                 expert_rows_grouped=self.expert_rows["grouped"],
                 expert_rows_dense=self.expert_rows["dense"],
+                linear_rows_kernel=self.linear_rows["kernel"],
+                linear_rows_xla=self.linear_rows["xla"],
+                linear_state_bytes=self.linear_state_bytes,
             )
         with _SWEEP_LOG_LOCK:
             _SWEEP_LOG.append(rec)
@@ -619,9 +664,12 @@ def _model_account(model: LlamaConfig, moe_counts: list) -> dict:
     kind, the expert layer's share, and (one device read, at the sweep's
     end) how many of the router's assignments landed on a held expert."""
     sliding = llama.layer_sliding_pattern(model)
+    linear = sum(model.layer_linear or ())
     rec = {
         "window_layers": sum(sliding),
-        "full_layers": len(sliding) - sum(sliding),
+        "full_layers": len(sliding) - sum(sliding) - linear,
+        "linear_layers": linear,
+        "softmax_layers": len(sliding) - linear,
         "experts_held": len(model.held_experts),
         "router_width": model.num_local_experts,
     }
@@ -684,6 +732,10 @@ SWEEP_RECORD_HELP = {
     "window_layers": "Decoder layers with local (sliding-window or chunked) "
     "attention in the model the sweep ran.",
     "full_layers": "Decoder layers with full causal attention.",
+    "linear_layers": "Decoder layers with linear attention (a decayed "
+    "recurrent state in place of keys and values).",
+    "softmax_layers": "Decoder layers with softmax attention, window and "
+    "full together.",
     "experts_held": "Routed experts this process holds of each expert layer "
     "(all of them unless the model's config gives it a share).",
     "router_width": "Experts the router scores (0 for a dense model).",
@@ -700,6 +752,16 @@ SWEEP_RECORD_HELP = {
     "all body (every row in every held expert): a block under a tensor-"
     "parallel mesh, or a softmax-router family; 0 in a scoring sweep of a "
     "sigmoid-router model on one chip, DP or MP.",
+    "linear_rows_kernel": "Rows x linear-attention layers dispatched with the "
+    "Pallas kernel (ops/lightning_attention.py); padding rows included, "
+    "counted on the host from the shapes.",
+    "linear_rows_xla": "Rows x linear-attention layers dispatched with the "
+    "XLA op of the same mathematics (use_pallas off, a tensor-parallel mesh, "
+    "or shapes the kernel does not take); 0 in a scoring sweep on one chip.",
+    "linear_state_bytes": "The most recurrent state one dispatch of linear-"
+    "attention layers held: prompts in the block x heads x qk dim x v dim x "
+    "4 bytes (float32), inside the layer call; it never enters the "
+    "activation store.",
 }
 _describe_gauges(
     "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}
@@ -764,6 +826,7 @@ class _HostShardLoader:
     def __init__(self, model_path: str, layer_names: Sequence[str], np_dtype,
                  tied_embeddings: bool = False, layer_sliding=None,
                  layer_rope=None,
+                 layer_linear=None,
                  retry_policy: RetryPolicy | None = None,
                  injector: FaultInjector | None = None,
                  retry_recorder=None, retry_abort=None,
@@ -841,6 +904,9 @@ class _HostShardLoader:
         self.tied = tied_embeddings
         self.layer_sliding = layer_sliding  # per-decoder local-attn flags or None
         self.layer_rope = layer_rope  # per-decoder rope flags (llama4 NoPE)
+        # per-decoder linear-attention flags or None; where set, a decoder
+        # segment carries its layers' indices (their decay follows them)
+        self.layer_linear = layer_linear
         self._tied_head: Params | None = None
         self.load_time = 0.0  # file->numpy wall time (cf. load_weights_time,
         # /root/reference/utils.py:223,304)
@@ -876,6 +942,7 @@ class _HostShardLoader:
             bool(tied_embeddings),
             tuple(layer_sliding) if layer_sliding is not None else None,
             tuple(layer_rope) if layer_rope is not None else None,
+            tuple(layer_linear) if layer_linear is not None else None,
             manifest_stat,
             bool(verify_weights and self._manifest is not None),
             device_cast,
@@ -1202,9 +1269,10 @@ class _HostShardLoader:
                     rflags = np.asarray(
                         [self.layer_rope[i] for i in run_decoder_idx], bool
                     )
-                segments.append(
-                    ("decoders", {"layers": stacked, "sliding": flags, "rope": rflags})
-                )
+                seg = {"layers": stacked, "sliding": flags, "rope": rflags}
+                if self.layer_linear is not None:
+                    seg["index"] = np.asarray(run_decoder_idx, np.int32)
+                segments.append(("decoders", seg))
                 run.clear()
                 run_decoder_idx.clear()
 
@@ -1687,6 +1755,7 @@ class ShardWeightSource:
         layer_sliding=None,
         layer_rope=None,
         cycle: bool = False,
+        layer_linear=None,
         retry_policy: RetryPolicy | None = None,
         injector: FaultInjector | None = None,
         retry_recorder=None,
@@ -1726,7 +1795,7 @@ class ShardWeightSource:
         self._stop = threading.Event()
         self._loader = _HostShardLoader(
             model_path, layer_names, np_dtype, tied_embeddings, layer_sliding,
-            layer_rope, retry_policy=self._retry, injector=injector,
+            layer_rope, layer_linear, retry_policy=self._retry, injector=injector,
             retry_recorder=retry_recorder, retry_abort=self._stop.is_set,
             integrity=integrity_recorder, verify_weights=verify_weights,
             host_cache=host_cache, readahead_threads=readahead_threads,
@@ -2113,6 +2182,7 @@ class BroadcastShardSource:
         rounds: int = 1,
         layer_sliding=None,
         layer_rope=None,
+        layer_linear=None,
         retry_policy: RetryPolicy | None = None,
         injector: FaultInjector | None = None,
         retry_recorder=None,
@@ -2128,7 +2198,7 @@ class BroadcastShardSource:
         self._stop = threading.Event()
         self._loader = _HostShardLoader(
             model_path, layer_names, np_dtype, tied_embeddings, layer_sliding,
-            layer_rope, retry_policy=retry_policy, injector=injector,
+            layer_rope, layer_linear, retry_policy=retry_policy, injector=injector,
             retry_recorder=retry_recorder, retry_abort=self._stop.is_set,
             integrity=integrity_recorder, verify_weights=verify_weights,
             host_cache=host_cache, readahead_threads=readahead_threads,
@@ -2426,6 +2496,7 @@ class StreamingExecutor:
         # regime uniformity matters (the slow generation loop re-chooses
         # the table each pass, exactly like HF's full recompute).
         check_longrope_regime(self.model_cfg, toks)
+        check_dense_len(self.model_cfg, toks)
         return toks
 
     # -- disk-mode crash resume (markers shared with the pipeline: see
@@ -2544,6 +2615,7 @@ class StreamingExecutor:
                 tied_embeddings=self.model_cfg.tie_word_embeddings,
                 layer_sliding=self.model_cfg.layer_sliding,
                 layer_rope=self.model_cfg.layer_rope,
+                layer_linear=self.model_cfg.layer_linear,
                 retry_policy=self._retry_policy,
                 injector=self._injector,
                 retry_recorder=self._retry_recorder,

@@ -294,6 +294,7 @@ def run_prompts(
         ),
         layer_sliding=model_cfg.layer_sliding,
         layer_rope=model_cfg.layer_rope,
+        layer_linear=model_cfg.layer_linear,
         retry_policy=cfg.retry_policy(),
         injector=FaultInjector.from_config(cfg.faults),
         verify_weights=cfg.verify_weights,

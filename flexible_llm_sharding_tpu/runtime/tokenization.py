@@ -227,6 +227,25 @@ def check_longrope_regime(model_cfg, toks, extra_len: int = 0, labels=None) -> N
             )
 
 
+def check_dense_len(model_cfg, toks, labels=None) -> None:
+    """Loud precondition for a model whose softmax layers select blocks of
+    keys from ``sparse_attn_from`` tokens on (learned block-sparse
+    attention, which no path here computes): a prompt that long is refused,
+    never truncated to fit nor run dense as if the rule were not there."""
+    limit = model_cfg.sparse_attn_from
+    if limit is None:
+        return
+    for i, t in enumerate(toks):
+        longest = t.prefix_len + int(t.suffix_eos[: t.num_suffixes].max()) + 1
+        if longest >= limit:
+            label = labels[i] if labels is not None else i
+            raise NotImplementedError(
+                f"prompt {label}: {longest} tokens; {model_cfg.model_type} "
+                f"switches its softmax layers to learned block-sparse "
+                f"attention from {limit} tokens, which is not supported"
+            )
+
+
 def count_tokens(tokenizer, prompts, max_token_len: int = 4096) -> int:
     """Tokens one full scoring pass processes for ``prompts``, counted with
     the same semantics as PromptTokenizer (prefix truncated to
@@ -274,5 +293,6 @@ __all__ = [
     "extend_tokenized",
     "make_blocks",
     "bucket_len",
+    "check_dense_len",
     "count_tokens",
 ]

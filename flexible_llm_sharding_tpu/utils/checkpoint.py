@@ -363,6 +363,8 @@ _LAYER_MAP_OPTIONAL = [
     ("attn.k_norm", "self_attn.k_norm.weight"),
     # MiMo-V2: the learned per-head sink logit of the layers that have one
     ("attn.sink", "self_attn.attention_sink_bias"),
+    # MiniCPM-SALA: the per-head RMSNorm on a linear-attention layer's output
+    ("attn.o_norm", "self_attn.o_norm.weight"),
     # gemma2 sandwich norms around the MLP
     ("pre_feedforward_layernorm.scale", "pre_feedforward_layernorm.weight"),
     ("post_feedforward_layernorm.scale", "post_feedforward_layernorm.weight"),
@@ -501,6 +503,10 @@ def hf_layer_to_native(
         f_dim = gu.shape[0] // 2
         out["mlp.gate"] = np.ascontiguousarray(gu[:f_dim].T)
         out["mlp.up"] = np.ascontiguousarray(gu[f_dim:].T)
+    gate_key = f"{layer_name}.self_attn.o_gate.weight"
+    if gate_key in sd:  # MiniCPM-SALA: the output gate's kernel, both kinds
+        consumed.add(gate_key)
+        out["attn.wg"] = np.ascontiguousarray(sd[gate_key].T)
     for native_key, hf_sub in _LAYER_MAP_OPTIONAL:
         if mla and native_key in ("attn.bq", "attn.bk", "attn.bv"):
             continue  # HF MLA projections are bias-free (q_a/kv_a aside)

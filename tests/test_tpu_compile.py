@@ -380,3 +380,62 @@ def test_tp4_decoder_block_carries_kernels(tp_mesh, lowering_sees_tpu):
     )
     assert text.count("tpu_custom_call") >= 2
     assert "all-reduce" in text  # the row-parallel products' ICI reduction
+
+
+# --- MiniCPM-SALA: the lightning kernel and both layer kinds' steps ------------
+
+@pytest.mark.parametrize("n,length", [(1, 3392), (1, 4096), (4, 64)])
+def test_lightning_kernel_compiles(one_chip, n, length):
+    """The cell's calls: a prefix alone (3392 rows: thirteen 256-row chunks
+    and a ragged one), the longest bucket, four suffixes from one state."""
+    from flexible_llm_sharding_tpu.ops import lightning_attention as la
+
+    s = functools.partial(_sds, one_chip)
+    f = jax.jit(functools.partial(la.lightning_attention, interpret=False))
+    _, text = _compile(
+        f, s((n, length, 32, 128)), s((n, length, 32, 128)), s((n, length, 32, 128)),
+        s((length, 32), jnp.float32), s((32, 128, 128), jnp.float32),
+    )
+    assert "tpu_custom_call" in text and "lightning_attention" in text
+
+
+def _sala_cfg():
+    import json
+
+    from benchmark.families.minicpm_sala import weights
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "minicpm-sala.json")) as f:
+        model = json.load(f)
+    model.pop("rehearsal")
+    return LlamaConfig.from_hf_config(weights.hf_config(model))
+
+
+@pytest.mark.parametrize("linear", [True, False])
+def test_sala_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, linear):
+    """A run of three linear layers and one softmax layer as the benchmark's
+    cell runs them: published widths, the longest prefix bucket, one prompt a
+    block. The linear run carries the lightning kernel (a prefix call and a
+    suffix call) and no flash kernel; the softmax layer the two flash kernels
+    at 32 query heads over 2 KV heads. Both beside ~11 GB of pins and four
+    0.57 GB shards in flight inside the chip."""
+    cfg = _sala_cfg()
+    k = 3 if linear else 1
+    shapes = jax.eval_shape(
+        lambda: llama.init_layer_params(jax.random.PRNGKey(0), cfg, BF16, linear=linear)
+    )
+    assert ("o_norm" in shapes["attn"]) == linear and "wg" in shapes["attn"]
+    assert shapes["attn"]["wk"].shape == (4096, 4096 if linear else 256)
+    s = functools.partial(_sds, one_chip)
+    seg = {
+        "layers": jax.tree.map(lambda x: s((k, *x.shape), x.dtype), shapes),
+        "sliding": None, "rope": s((k,), jnp.bool_), "index": s((k,), jnp.int32),
+    }
+    compiled, text = _compile(
+        executor._decoder_block,
+        cfg, seg, s((1, 3392, 4096)), s((1, 4, 64, 4096)), s((1,), jnp.int32), True,
+    )
+    assert ("lightning_attention" in text) == linear
+    assert ("flash_causal_attention" in text) != linear
+    assert text.count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
