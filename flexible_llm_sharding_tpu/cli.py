@@ -10,7 +10,9 @@ Differences, all deliberate:
 - ``--data_parallel`` parses real booleans (the reference's ``type=bool``
   treats any non-empty string as True, ``/root/reference/main.py:40``).
 - ``--storage_location`` accepts ``tpu`` (activations stay in HBM); ``gpu``
-  is kept as an alias.
+  is kept as an alias. Not set, a scoring pass keeps on the chip the
+  activations that fit its free memory (``residency.
+  activation_budget_bytes``) and sends the rest the ``cpu`` way.
 - TPU-specific knobs (``--dtype``, ``--block_size``, ``--prefetch_depth``,
   ``--num_devices``, ``--max_token_len``) extend the surface.
 """
@@ -573,8 +575,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Path to the LLM output scores file")
     p.add_argument("--num_batch", type=int, default=1)
     p.add_argument("--layer_num_per_shard", type=int, default=1)
-    p.add_argument("--storage_location", type=str, default="cpu",
-                   help="'tpu' (HBM), 'cpu' (host RAM), or 'disk'; 'gpu' = alias of 'tpu'")
+    p.add_argument("--storage_location", type=str, default=None,
+                   help="'tpu' (HBM), 'cpu' (host RAM), or 'disk'; 'gpu' = alias of 'tpu'. "
+                        "Not set: a scoring pass keeps the activations that fit the "
+                        "chip's free memory in HBM and sends the rest the 'cpu' way; "
+                        "the pipeline and KV decode read it as 'cpu'")
     p.add_argument("--max_activation_in_cpu", type=int, default=100)
     p.add_argument("--data_parallel", type=_str2bool, default=False,
                    help="True: split prompts across chips; False: interleaved layer pipeline across chips")
@@ -734,8 +739,8 @@ def build_serve_parser() -> argparse.ArgumentParser:
     p.add_argument("--dtype", type=str, default="bfloat16",
                    choices=["bfloat16", "float16", "float32"])
     p.add_argument("--layer_num_per_shard", type=int, default=1)
-    p.add_argument("--storage_location", type=str, default="cpu",
-                   help="'tpu' parks per-wave KV in HBM; 'cpu' in host RAM")
+    p.add_argument("--storage_location", type=str, default=None,
+                   help="'tpu' parks per-wave KV in HBM; 'cpu' (and not set) in host RAM")
     p.add_argument("--block_size", type=int, default=8)
     p.add_argument("--bucket_multiple", type=int, default=64)
     p.add_argument("--prefetch_depth", type=int, default=None)

@@ -1138,7 +1138,12 @@ class FrameworkConfig:
     (``/root/reference/main.py:30-49``) plus TPU-specific knobs.
 
     ``storage_location`` gains a ``tpu`` value (activations stay in HBM); the
-    reference's ``gpu`` is accepted as an alias. Unlike the reference's
+    reference's ``gpu`` is accepted as an alias. Unset (``None``, the
+    default) the single-executor scoring pass keeps each block's activations
+    on the chip while they fit a budget it derives from the chip's memory
+    and the residency tier's plan, and sends the rest the ``cpu`` way
+    (``StreamingExecutor._run_pass``); every other path (the MP pipeline,
+    KV decode, serving, training) reads unset as ``cpu``. Unlike the reference's
     ``--data_parallel`` bool footgun (any non-empty string parsed as True,
     ``/root/reference/main.py:40``), this is a real bool everywhere.
     """
@@ -1146,7 +1151,8 @@ class FrameworkConfig:
     model_path: str = "./"
     num_batch: int = 1
     layer_num_per_shard: int = 1
-    storage_location: str = "cpu"  # 'tpu' | 'cpu' | 'disk' ('gpu' alias of 'tpu')
+    # 'tpu' | 'cpu' | 'disk' ('gpu' alias of 'tpu'); None = not set (see above)
+    storage_location: str | None = None
     max_activation_in_cpu: int = 100
     data_parallel: bool = False
     disk_folder: str = "./temp"
@@ -1336,7 +1342,7 @@ class FrameworkConfig:
         loc = self.storage_location
         if loc == "gpu":
             object.__setattr__(self, "storage_location", "tpu")
-        elif loc not in ("tpu", "cpu", "disk"):
+        elif loc not in (None, "tpu", "cpu", "disk"):
             raise ValueError(f"storage_location must be tpu|cpu|disk, got {loc!r}")
         if self.layer_num_per_shard < 1:
             raise ValueError("layer_num_per_shard must be >= 1")

@@ -22,6 +22,12 @@ TPU-first differences:
 - ``tpu`` keeps activations as device arrays; ``cpu`` uses
   ``jax.device_get`` (async transfer flushed at store time); ``disk`` writes
   float32-preserving raw dtypes via numpy.
+- A ``cpu`` store given a ``device_budget`` tiers per block, the way it
+  already spills to disk past ``max_in_cpu``: a block whose bytes fit what
+  is left of the budget stays a device array (nothing crosses the link),
+  one that does not goes to host RAM. ``tpu`` is the same store with no
+  bound. The scoring executor derives the budget from the chip where
+  nobody set ``--storage_location`` (``residency.activation_budget_bytes``).
 - Every ``.npy`` spill carries a checksum sidecar (integrity/manifest.py)
   verified on fetch with a short re-read loop; truncated/undecodable or
   persistently corrupt spills raise typed errors naming the file and shard
@@ -132,6 +138,7 @@ class ActivationStore:
         integrity=None,
         retry_policy=None,
         retry_recorder=None,
+        device_budget: int = 0,
     ):
         # injector: chaos-only FaultInjector (corrupt_activation site fires
         # on every spill read; disk_full inside every retried spill
@@ -148,6 +155,8 @@ class ActivationStore:
         # re-run would overwrite the files a crashed batch B resumes from
         # (same 0-based prompt indices, same folder). Batch 0 keeps the
         # reference's exact names.
+        # device_budget: bytes of blocks a cpu store may keep on the chip
+        # at once (0: none; a tpu store keeps every block).
         if location not in ("tpu", "cpu", "disk"):
             raise ValueError(f"storage_location must be tpu|cpu|disk, got {location!r}")
         self.location = location
@@ -159,6 +168,13 @@ class ActivationStore:
             f".b{batch}" if batch else ""
         )
         self._mem: dict[object, tuple] = {}
+        # Blocks of _mem kept as device arrays (id -> bytes) under the
+        # budget: all of a tpu store's, those that fit of a cpu store's.
+        self.device_budget = {"tpu": float("inf"), "disk": 0}.get(
+            location, device_budget
+        )
+        self._on_device: dict[object, int] = {}
+        self._device_held = 0
         # cpu-mode bound (reference's max_activation_in_cpu backpressure,
         # /root/reference/utils.py:179-180): at most this many prompts' worth
         # of activations stay in host RAM; overflow blocks spill to disk.
@@ -177,8 +193,12 @@ class ActivationStore:
         self._pending: list[object] = []
         # Seconds the consumer stood blocked on the device inside this
         # store (_finalize), and the ids its device_wait spans carry: the
-        # executor's sweep account reads the one and sets the other.
+        # executor's sweep account reads the one and sets the other. It
+        # also reads how the pass's bytes split: link_bytes went to the
+        # host or came back from it (or from disk), device_bytes stayed on
+        # the chip (counted as stored).
         self.device_wait_s = 0.0
+        self.link_bytes = self.device_bytes = 0
         self.trace_ids: dict = {}
         self._writer = None  # lazy single-thread pool for async disk writes
         self._write_futs: list = []
@@ -336,9 +356,29 @@ class ActivationStore:
         return prefix, suffix
 
     def store(self, block_id, prompt_idxs: list[int], prefix_h, suffix_h) -> None:
-        if self.location == "tpu":
+        if block_id in self._on_device:  # a re-store is decided anew
+            self._device_held -= self._on_device.pop(block_id)
+            del self._mem[block_id]
+        arrays = [a for a in (prefix_h, suffix_h) if a is not None]
+        nbytes = sum(a.nbytes for a in arrays)
+        # On the chip: what fits the budget's rest, of a tpu store whatever
+        # it is handed, of a cpu store the device arrays of a block it holds
+        # nowhere else (a block in host RAM or on disk keeps its slot there).
+        if self._device_held + nbytes <= self.device_budget and (
+            self.location == "tpu"
+            or (
+                block_id not in self._mem
+                and block_id not in self._spilled
+                and all(isinstance(a, jax.Array) for a in arrays)
+            )
+        ):
+            self._on_device[block_id] = nbytes
+            self._device_held += nbytes
             self._mem[block_id] = (prefix_h, suffix_h)
-        elif self.location == "cpu":
+            self.device_bytes += nbytes
+            return
+        self.link_bytes += nbytes
+        if self.location == "cpu":
             if block_id in self._spilled:
                 # A re-store of a currently-spilled block supersedes the disk
                 # copy; drop it so fetch() can't return stale data.
@@ -433,11 +473,24 @@ class ActivationStore:
 
     def fetch(self, block_id, prompt_idxs: list[int], with_prefix: bool = True):
         """Returns (prefix_h | None, suffix_h) as host or device arrays; the
-        executor device_puts them as part of the next shard's input feed.
+        executor device_puts them as part of the next shard's input feed (a
+        block kept on the chip comes back as it was stored, and that
+        device_put moves nothing).
 
         Disk reads flush the async writer first (the queued write may be this
         very block's files); in-memory cpu/tpu fetches don't wait on
         unrelated spill I/O."""
+        if block_id in self._on_device:
+            self._device_held -= self._on_device.pop(block_id)
+            prefix, suffix = self._mem.pop(block_id)
+            return (prefix if with_prefix else None), suffix
+        prefix, suffix = self._fetch_host(block_id, prompt_idxs, with_prefix)
+        self.link_bytes += sum(
+            a.nbytes for a in (prefix, suffix) if isinstance(a, np.ndarray)
+        )
+        return prefix, suffix
+
+    def _fetch_host(self, block_id, prompt_idxs: list[int], with_prefix: bool):
         if self.location == "cpu" and block_id in self._pending:
             self._pending.remove(block_id)
             self._finalize(block_id)
@@ -445,7 +498,7 @@ class ActivationStore:
             self._spilled.discard(block_id)
             self.flush()
             return self._fetch_disk(prompt_idxs, with_prefix)
-        if self.location in ("tpu", "cpu"):
+        if self.location != "disk":  # tpu: a block it never held is a KeyError
             prefix, suffix = self._mem.pop(block_id)
             if self.location == "cpu":
                 self._cpu_prompts -= len(prompt_idxs)
@@ -486,6 +539,8 @@ class ActivationStore:
                 self._writer = None
             self._write_futs.clear()
             self._mem.clear()
+            self._on_device.clear()
+            self._device_held = 0
             self._spilled.clear()
             self._pending.clear()
             self._cpu_prompts = 0

@@ -267,7 +267,6 @@ def process_block(
         with_prefix = first <= n_layers - 3
         with obs_trace.timed("act_fetch", cat="sweep", block=b, **ids) as sp:
             prefix_h, suffix_h = store.fetch(b, idxs, with_prefix=with_prefix)
-            nbytes = _host_nbytes(prefix_h, suffix_h)
             # Host->HBM upload, or the chip-to-chip ICI hop in pipeline
             # mode. Under TpPlacement activations are replicated over the
             # tp mesh.
@@ -277,7 +276,6 @@ def process_block(
                 prefix_h = jax.device_put(prefix_h, act_target)
         if clock is not None:
             clock.act_fetch_s += sp.dur_s
-            clock.act_bytes += nbytes
 
     prefix_h, suffix_h, block_scores = apply_segments(
         model_cfg,
@@ -306,17 +304,7 @@ def process_block(
             store.store(b, idxs, prefix_h, suffix_h)
         if clock is not None:
             clock.act_store_s += sp.dur_s
-            if store.location != "tpu":  # a tpu store keeps them on the chip
-                clock.act_bytes += sum(
-                    a.nbytes for a in (prefix_h, suffix_h) if a is not None
-                )
     return suffix_h
-
-
-def _host_nbytes(*arrays) -> int:
-    """Bytes of the host-resident arrays among ``arrays`` (what a fetch is
-    about to send over the link; device-resident ones cross nothing)."""
-    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
 
 
 class ScoreSink(dict):
@@ -544,7 +532,9 @@ class SweepClock:
     source) and the ``compute`` span, split into ``device_wait_s`` (blocked
     on the device's results: the ``block_until_ready`` at the shard's end
     and the one inside the activation store, which resolves a block's
-    device->host copy one store later; that part is also ``act_wait_s``)
+    device->host copy one store later; that part is also ``act_wait_s``;
+    a block the store keeps on the chip waits for nothing and counts in
+    ``act_device_bytes`` instead of ``act_bytes``)
     and ``dispatch_s`` (the rest: the host's own work, dispatching the
     steps and copying activations), then ``tail_s`` (after the last
     shard's dispatch: the source's close, the scores' fetch, the store's
@@ -557,7 +547,6 @@ class SweepClock:
         self.shard_idx = -1  # the shard the consumer is on
         self.source_wait_s = self.compute_s = self.device_wait_s = 0.0
         self.act_fetch_s = self.act_store_s = 0.0
-        self.act_bytes = 0
         self.head_s = 0.0
         # Device-resident int32 [2] counts, one per decoder segment and
         # block, of a model that holds a share of its experts: summed and
@@ -621,12 +610,17 @@ class SweepClock:
         the pass's record to the process log. None when already closed.
         ``store``: the pass's activation store, whose waits for the device
         (inside ``compute``) count as ``device_wait_s``, not as the host's
-        ``dispatch_s``."""
+        ``dispatch_s``, and which counted its own bytes: those that crossed
+        the link and those it kept on the chip."""
         sweep, tail = self._sweep, self._tail
         if sweep is None:
             return None
         self.abandon()
-        act_wait_s = store.device_wait_s if store is not None else 0.0
+        act_wait_s, act_bytes, act_device_bytes = (
+            (store.device_wait_s, store.link_bytes, store.device_bytes)
+            if store is not None
+            else (0.0, 0, 0)
+        )
         device_wait_s = self.device_wait_s + act_wait_s
         rec = {
             "sweep_id": self.sweep_id,
@@ -640,7 +634,8 @@ class SweepClock:
             "act_fetch_s": self.act_fetch_s,
             "act_store_s": self.act_store_s,
             "act_wait_s": act_wait_s,
-            "act_bytes": self.act_bytes,
+            "act_bytes": act_bytes,
+            "act_device_bytes": act_device_bytes,
         }
         account = getattr(source, "account", None)
         if account is not None:  # a shared (broadcast) source keeps none
@@ -702,12 +697,17 @@ SWEEP_RECORD_HELP = {
     "tail_s": "Consumer: after the last shard's dispatch (source close, "
     "scores to the host, store clear).",
     "act_fetch_s": "Consumer: inside the activation store's fetches "
-    "(host->device), waits included.",
+    "(host->device for a block not kept on the chip), waits included.",
     "act_store_s": "Consumer: inside the activation store's stores "
-    "(device->host), waits included.",
+    "(device->host for a block not kept on the chip), waits included.",
     "act_wait_s": "The part of device_wait_s spent inside the activation "
     "store.",
-    "act_bytes": "Activation bytes that crossed the link, both ways.",
+    "act_bytes": "Activation bytes that crossed the link, both ways: the "
+    "blocks the activation store sent to the host (0 when it kept them all "
+    "on the chip).",
+    "act_device_bytes": "Activation bytes of the blocks the store kept on "
+    "the chip between shards, counted as they were stored; beside act_bytes "
+    "it says how a pass split.",
     "host_build_s": "Producer: host shard builds (mmap, verify, stack).",
     "upload_dispatch_s": "Producer: inside jax.device_put calls, which "
     "return at the enqueue; not a transfer time.",
@@ -2553,8 +2553,23 @@ class StreamingExecutor:
         with obs_trace.span("tokenize", cat="sweep", sweep_id=clock.sweep_id):
             toks = self._tokenize(prompts)
         blocks = make_blocks(toks, self.cfg.block_size)
+        location, device_budget = self.cfg.storage_location, 0
+        if location is None:
+            # Nobody said where: a 'cpu' store that keeps on the chip the
+            # blocks that fit what the chip has free by the tier's plan.
+            from flexible_llm_sharding_tpu.runtime import residency
+
+            location = "cpu"
+            device_budget = residency.activation_budget_bytes(
+                self.device,
+                self._residency,
+                residency.in_flight_bytes(
+                    self.cfg, self.layer_names,
+                    self.model_cfg.tie_word_embeddings,
+                ),
+            )
         store = ActivationStore(
-            self.cfg.storage_location,
+            location,
             self.cfg.disk_folder,
             device_rank=self.plan.device_rank,
             rank_tag=self.plan.num_devices > 1 and self.cfg.data_parallel,
@@ -2569,6 +2584,7 @@ class StreamingExecutor:
             # under the 'spill_write' label.
             retry_policy=self._retry_policy,
             retry_recorder=self._retry_recorder,
+            device_budget=device_budget,
         )
         resumable = self.cfg.storage_location == "disk"
         sig = self._resume_signature(toks) if resumable else ""

@@ -154,7 +154,9 @@ class PipelineRunner:
         toks = [self.tokenizer(p, s) for p, s in prompts]
         blocks = make_blocks(toks, self.cfg.block_size)
         store = ActivationStore(
-            self.cfg.storage_location,
+            # Not set reads as 'cpu' here: 'tpu' is the user's order for
+            # the chip-to-chip hop, never derived.
+            self.cfg.storage_location or "cpu",
             self.cfg.disk_folder,
             max_in_cpu=self.cfg.max_activation_in_cpu,
             np_dtype=self._np_dtype,

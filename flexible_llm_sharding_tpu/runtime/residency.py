@@ -258,52 +258,77 @@ def in_flight_bytes(cfg, layer_names: Sequence[str], tied_embeddings: bool) -> i
     return (max(1, cfg.effective_prefetch_depth()) + 2) * largest
 
 
+def _chip_account(target) -> tuple[float, float]:
+    """``(limit, in_use)`` of the chip behind ``target`` (a device, or a
+    placement, which resolves to its first chip): the allocator's
+    ``bytes_limit`` and its ``bytes_in_use`` less the process tier's own
+    pins there, or the device-kind HBM table (assumed empty) where the
+    device reports no stats. ``(0, 0)`` where neither is known (the CPU
+    backend). On a TPU a failed stats query or an unknown device kind
+    raises (utils/metrics.py); it is never read as "nothing there"."""
+    from flexible_llm_sharding_tpu.utils.metrics import (
+        chip_hbm_gb,
+        device_memory_stats,
+    )
+
+    device = probe_chip(target)
+    stats = device_memory_stats(device)
+    limit = stats.get("bytes_limit")
+    if not limit:
+        return (chip_hbm_gb(device) or 0.0) * 1e9, 0.0
+    in_use = stats.get("bytes_in_use", 0.0)
+    # The process tier's own pins on this target are in ``in_use`` but are
+    # the plan's to spend, not someone else's: a later source must read
+    # the budget its pins were planned under, not what is left beside them.
+    tier = process_tier()
+    if tier is not None:
+        # This target's, or the heaviest target's for a caller that probes
+        # a chip under another handle than its source places on.
+        in_use -= (
+            tier.pinned_device_bytes(target) or tier.max_pinned_device_bytes()
+        )
+    return limit, in_use
+
+
 def auto_pin_budget_bytes(device=None, in_flight_bytes: int = 0) -> int:
     """Auto pin budget: measured free HBM minus the headroom, which is the
     larger of ``ACTIVATION_HEADROOM_FRACTION`` of the chip and
     ``in_flight_bytes`` (see the function of that name) plus
     ``SCRATCH_HEADROOM_FRACTION`` of the chip.
 
-    Free = the allocator's ``bytes_limit - bytes_in_use`` when the device
-    reports memory stats, else the device-kind HBM table (assumed empty);
-    ``device`` may be a placement, which resolves to its first chip. The
-    CPU backend has neither and resolves to 0 (off) — the budget is
-    only ever spent where it is real. On a TPU a failed stats query or an
-    unknown device kind raises (utils/metrics.py); it is never read as
-    "off"."""
-    from flexible_llm_sharding_tpu.utils.metrics import (
-        chip_hbm_gb,
-        device_memory_stats,
-    )
-
-    target, device = device, probe_chip(device)
-    stats = device_memory_stats(device)
-    limit = stats.get("bytes_limit")
-    in_use = stats.get("bytes_in_use", 0.0)
-    if not limit:
-        hbm = chip_hbm_gb(device)
-        if not hbm:
-            return 0
-        limit = hbm * 1e9
-        in_use = 0.0
-    else:
-        # The process tier's own pins on this target are in ``in_use``
-        # but are the budget's to spend, not someone else's: a later
-        # source must read the budget its pins were planned under, not
-        # what is left beside them.
-        tier = process_tier()
-        if tier is not None:
-            # This target's, or the heaviest target's for a caller that
-            # probes a chip under another handle than its source places on.
-            in_use -= (
-                tier.pinned_device_bytes(target)
-                or tier.max_pinned_device_bytes()
-            )
+    Free = what ``_chip_account`` reads. The CPU backend has no account and
+    resolves to 0 (off) — the budget is only ever spent where it is
+    real."""
+    limit, in_use = _chip_account(device)
     headroom = max(
         ACTIVATION_HEADROOM_FRACTION * limit,
         in_flight_bytes + SCRATCH_HEADROOM_FRACTION * limit,
     )
     return int(max(0.0, limit - in_use - headroom))
+
+
+def activation_budget_bytes(device, tier, in_flight_bytes: int) -> int:
+    """Bytes of activations a scoring pass may keep on the chip between
+    shards (``ActivationStore(device_budget=)``), for a pass whose user set
+    no ``storage_location``: half of what the chip has free BY THE PLAN.
+
+    By the plan, not by the instant: the limit less what is in use beside
+    the tier's pins, less the pins ``tier`` has planned (or holds, where
+    that is more: during the seating sweep the pins are not there yet, so
+    the allocator's count at the pass's start flatters), less
+    ``in_flight_bytes``, the shards the source holds while it streams. What
+    is left is the headroom ``auto_pin_budget_bytes`` kept for the step's
+    scratch and the activations together (5% of the chip at the least,
+    against a step's measured 1-2%); the store is charged to it, not to a
+    second reserve, and takes half so that the other half stays the
+    step's. ``_decoder_block`` is given its activations to overwrite, so a
+    block held here is the one the step works in: one generation of blocks
+    is all a pass has on the chip. 0 on the CPU backend (no account) and
+    wherever explicit pins leave nothing: every block then goes the
+    ``cpu`` way, as before."""
+    limit, in_use = _chip_account(device)
+    pins = tier.committed_device_bytes(device) if tier is not None else 0
+    return int(max(0.0, limit - in_use - pins - in_flight_bytes) // 2)
 
 
 def placement_key(device) -> tuple:
@@ -543,6 +568,16 @@ class DeviceResidencyTier:
         HBM cost of the tier (the peak_hbm floor)."""
         with self._lock:
             return self._dev_bytes.get(placement_key(device), 0)
+
+    def committed_device_bytes(self, device=None) -> int:
+        """What the tier takes of ONE placement target by its plan: the
+        planned layers' bytes, or what is seated there where that is more
+        (a brownout's empty plan leaves its seats)."""
+        with self._lock:
+            return max(
+                self.plan.pinned_bytes_est,
+                self._dev_bytes.get(placement_key(device), 0),
+            )
 
     def max_pinned_device_bytes(self) -> int:
         """The heaviest single placement target's resident bytes — the
@@ -873,6 +908,7 @@ __all__ = [
     "ACTIVATION_HEADROOM_FRACTION",
     "DeviceResidencyTier",
     "ResidencyPlan",
+    "activation_budget_bytes",
     "auto_pin_budget_bytes",
     "in_flight_bytes",
     "layer_stream_bytes",
