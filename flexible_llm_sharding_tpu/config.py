@@ -346,6 +346,10 @@ class LlamaConfig:
             raise ValueError(
                 "sliding_window and attention_chunk_size are mutually exclusive"
             )
+        if self.total_ut_steps < 1:
+            raise ValueError(
+                f"total_ut_steps must be >= 1, got {self.total_ut_steps}"
+            )
 
     @property
     def attn_scale(self) -> float:
@@ -448,6 +452,15 @@ class LlamaConfig:
     embed_multiplier: float | None = None
     residual_multiplier: float | None = None
     logit_divisor: float | None = None
+    # A looped (universal-transformer) stack (Ouro): every token visits the
+    # SAME decoder layers total_ut_steps times; the final norm closes every
+    # step (its output feeds the next) and an exit gate beside it (the norm
+    # file's ``gate`` leaves) gives each scored token a probability of
+    # stopping there. early_exit_threshold q: a scored token reads the first
+    # step at which the cumulative exit probability reaches q; q >= 1 (the
+    # published value) reads the last step. 1 = every other model.
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0
 
     def attn_shape(self, sliding: bool = False, linear: bool = False) -> tuple[int, int, int, int]:
         """(heads, kv heads, qk head dim, v head dim) of a layer kind."""
@@ -482,6 +495,21 @@ class LlamaConfig:
                 f"{path} does not support {self.model_type}: per-kind "
                 "attention shapes and a held share of the experts run on the "
                 "streamed scoring path only"
+            )
+
+    def require_single_visit(self, path: str) -> None:
+        """Fail loudly on a path that keeps its state BY LAYER (pages of
+        keys and values, a pipeline stage, a head shard, a gradient): a
+        looped model (``total_ut_steps`` > 1) visits each layer several
+        times a token with keys and values of that step only, so it needs
+        its state by (step, layer), which only the streamed scoring path's
+        plan of visits has (``parallel/planner.py``)."""
+        if self.total_ut_steps > 1:
+            raise NotImplementedError(
+                f"{path} does not support {self.model_type}: total_ut_steps="
+                f"{self.total_ut_steps} visits every layer that many times "
+                "and needs state by (step, layer); such a model runs on the "
+                "streamed scoring path only (single executor or DP)"
             )
 
     @property
@@ -730,6 +758,25 @@ class LlamaConfig:
         kwargs["logit_divisor"] = float(d.get("hidden_size", 4096)) / float(
             d.get("dim_model_base", d.get("hidden_size", 4096))
         )
+
+    @staticmethod
+    def _apply_ouro(kwargs: dict[str, Any], d: dict[str, Any]) -> None:
+        """Ouro (``ouro``, a looped language model): one stack of plain
+        multi-head softmax layers visited ``total_ut_steps`` times, each
+        layer with FOUR norms (the sandwich residual on both sublayers:
+        ``ffw_sandwich_norms`` with a plain ``x * scale`` RMSNorm), the
+        final norm at every step's end and an exit gate beside it. No
+        biases, no q/k norm, rotary over the whole head. The published
+        configs carry ``use_sliding_window`` false; a window is refused,
+        not guessed."""
+        if d.get("use_sliding_window"):
+            raise NotImplementedError(
+                "ouro with use_sliding_window is not supported"
+            )
+        kwargs["sliding_window"] = None
+        kwargs["ffw_sandwich_norms"] = True
+        kwargs["total_ut_steps"] = int(d.get("total_ut_steps", 4))
+        kwargs["early_exit_threshold"] = float(d.get("early_exit_threshold", 1.0))
 
     @classmethod
     def from_hf_config(cls, d: dict[str, Any]) -> "LlamaConfig":
@@ -984,6 +1031,9 @@ class LlamaConfig:
         elif model_type == "minicpm_sala":
             if not native:
                 cls._apply_minicpm_sala(kwargs, d)
+        elif model_type == "ouro":
+            if not native:
+                cls._apply_ouro(kwargs, d)
         elif model_type in ("mistral", "mixtral", "phi3"):
             # sliding_window flows through by field name (may be null);
             # mixtral's num_local_experts/num_experts_per_tok likewise.
@@ -997,7 +1047,7 @@ class LlamaConfig:
                 f"model_type {model_type!r} is not supported "
                 "(llama, mistral, phi3, qwen2, qwen3, qwen3_moe, mixtral, gemma, "
                 "gemma2, gemma3_text, llama4_text, deepseek_v3, mimo_v2_flash, "
-                "minicpm_sala are)"
+                "minicpm_sala, ouro are)"
             )
         if model_type not in (
             "mixtral", "llama4_text", "qwen3_moe", "deepseek_v3", "mimo_v2_flash"

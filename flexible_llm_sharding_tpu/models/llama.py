@@ -965,6 +965,7 @@ def prefix_suffix_layer(
     stats = [] if moe_stats else None
     if return_kv:
         cfg.require_one_attention_shape("a KV cache (return_kv)", layer_fn=True)
+        cfg.require_single_visit("a KV cache (return_kv)")
     if is_linear(cfg, params["attn"]):
         prefix_out, suffix_out = _linear_prefix_suffix(
             params, cfg, prefix_h, suffix_h, prefix_len, log_decay,
@@ -1123,6 +1124,7 @@ def suffix_only_layer(
     kp/vp to rebuild the full decode-KV dict.
     """
     cfg.require_one_attention_shape("a cached prefix KV (suffix_only_layer)", layer_fn=True)
+    cfg.require_single_visit("a cached prefix KV (suffix_only_layer)")
     lp = kp.shape[0]
     s, ls, _ = suffix_h.shape
     eps = cfg.rms_norm_eps
@@ -1215,6 +1217,7 @@ def decode_step_layer(
     (``tp_mesh``) the kernel runs per head-shard via shard_map.
     """
     cfg.require_one_attention_shape("KV-cache decoding (decode_step_layer)", layer_fn=True)
+    cfg.require_single_visit("KV-cache decoding (decode_step_layer)")
     eps = cfg.rms_norm_eps
     (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
     sink = params["attn"].get("sink")
@@ -1333,6 +1336,62 @@ def final_norm(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     return x
 
 
+# ---------------------------------------------------------------------------
+# A looped stack's step end (Ouro): the exit gate and the rule that picks
+# each scored token's step. The final norm itself is ``final_norm``, over
+# every row between steps and over the scored rows at the last.
+# ---------------------------------------------------------------------------
+
+def exit_gate(params: Params, h: jax.Array) -> jax.Array:
+    """The probability of stopping at this step, one a row: ``sigmoid(w_g .
+    h + b_g)`` in float32, with h [..., D] the step's NORMED output and the
+    gate's leaves beside the final norm's scale (``params["gate"]``: kernel
+    [D, 1], bias [1])."""
+    with jax.named_scope("exit_gate"):
+        g = params["gate"]
+        z = _mm(h, g["kernel"]).astype(jnp.float32)[..., 0]
+        return jax.nn.sigmoid(z + g["bias"].astype(jnp.float32)[0])
+
+
+def exit_init(shape: tuple[int, ...], d: int, dtype) -> tuple:
+    """The exit state of ``shape`` scored rows before the first step:
+    ``(remaining, cum, expected, chosen)`` = (the probability of not having
+    stopped yet, 1; the cumulative exit probability, 0; the running sum of
+    step x probability, 0; the hidden state [*shape, 1, D] of the step a row
+    has been given, none yet)."""
+    return (
+        jnp.ones(shape, jnp.float32),
+        jnp.zeros(shape, jnp.float32),
+        jnp.zeros(shape, jnp.float32),
+        jnp.zeros((*shape, 1, d), dtype),
+    )
+
+
+def exit_step(
+    cfg: LlamaConfig, params: Params, state: tuple, h: jax.Array, step, last: bool
+) -> tuple:
+    """One step's end for the scored rows. h [..., 1, D]: their normed
+    output at step ``step`` (1-based, may be traced). With lambda the gate's
+    probability, step t < T stops with p_t = lambda_t * prod_{j<t}(1 -
+    lambda_j) and the last step takes what is left, p_T = prod_{j<T}(1 -
+    lambda_j). A row is given the FIRST step whose cumulative probability
+    reaches ``early_exit_threshold`` (the last step's always does). Returns
+    the new state; at a threshold >= 1 the caller reads the last step's h
+    and ``chosen`` stays unread."""
+    remaining, cum, expected, chosen = state
+    p = remaining if last else exit_gate(params, h[..., 0, :]) * remaining
+    q = jnp.float32(cfg.early_exit_threshold)
+    new_cum = cum + p
+    reached = jnp.ones_like(cum, bool) if last else new_cum >= q
+    given = jnp.logical_and(cum < q, reached)
+    return (
+        remaining - p,
+        new_cum,
+        expected + jnp.asarray(step, jnp.float32) * p,
+        jnp.where(given[..., None, None], h, chosen),
+    )
+
+
 def lm_head_scores_multi(
     params: Params, h: jax.Array, softcap: float | None = None
 ) -> jax.Array:
@@ -1385,6 +1444,7 @@ def forward_full(
     long/short table from the padded batch length (max position id + 1),
     so the default reproduces an HF forward on these exact ids.
     """
+    cfg.require_single_visit("the monolithic forward (forward_full, training)")
     b, l = ids.shape
     if total_len is None and cfg.rope_scaling_kind == "longrope":
         total_len = jnp.int32(l)
@@ -1626,6 +1686,15 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Params:
         ],
         "norm": {"scale": jnp.ones((cfg.hidden_size,), dtype)},
     }
+    if cfg.total_ut_steps > 1:  # a looped stack's exit gate, beside the norm
+        params["norm"]["gate"] = {
+            "kernel": (
+                jax.random.normal(
+                    jax.random.fold_in(rng, 7), (cfg.hidden_size, 1)
+                ) * 0.02
+            ).astype(dtype),
+            "bias": jnp.zeros((1,), dtype),
+        }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {
             "kernel": (

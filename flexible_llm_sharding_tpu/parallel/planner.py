@@ -15,24 +15,95 @@ Reproduces the reference's planning math exactly
 
 Prompt splitting for DP mode matches ``np.array_split(prompts, num_devices)``
 (``/root/reference/main.py:70``).
+
+A plan lists VISITS, not layers. For every model but a looped one they are
+the same thing: each entry of the execution list once, in order. A looped
+model (``LlamaConfig.total_ut_steps`` = T > 1) visits the embedding, then T
+times the decoder layers and the final norm (which closes every step), then
+the head: ``visit_order``. The shards are contiguous pieces of that order and
+still hold indices into the execution list, so a layer's index appears T
+times; what a shard's position means is ``shard_visit``'s to say, in one
+place, for the executor, its recompute path and the pipeline runner alike.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
+from typing import NamedTuple
 
 import numpy as np
 
 
+class ShardVisit(NamedTuple):
+    """What a shard's place in the order of visits means for a block of
+    prompts. The execution list is ``[embedding, decoder layers ..., final
+    norm, head]`` (``n_layers`` entries: the norm is ``n_layers - 2``, the
+    head ``n_layers - 1``), and the invariants are by VISIT:
+
+    - the FIRST visit is the embedding's (index 0, visited once): the block
+      has no activations yet, so nothing is fetched (``embeds``);
+    - prefix states live until the LAST DECODER VISIT is done (the last
+      step's visit of layer ``n_layers - 3``): a shard that starts after it
+      holds only the final norm and the head, which read the suffixes' last
+      tokens, and fetches no prefix (``needs_prefix`` false). In a looped
+      model the final norm of an earlier step norms every prefix and suffix
+      row for the next step, so the prefix lives through those step ends;
+    - the LAST visit is the head's (index ``n_layers - 1``, visited once):
+      nothing is stored after it and nothing is waited for at its shard's
+      end (``stores`` false).
+
+    ``step``: the loop step (0-based) of the shard's first layer; a shard
+    may run over a step's end, and whoever walks its segments counts the
+    norms it passes (``runtime/executor.apply_segments``)."""
+
+    step: int
+    embeds: bool
+    needs_prefix: bool
+    stores: bool
+
+
+def shard_visit(
+    layer_idxs, n_layers: int, step: int = 0, loop_steps: int = 1
+) -> ShardVisit:
+    """The role of one shard (see ``ShardVisit``). With ``loop_steps`` 1
+    (every model but a looped one; the pipeline runner, which refuses
+    those) the indices alone say it."""
+    first, last = layer_idxs[0], layer_idxs[-1]
+    return ShardVisit(
+        step=step,
+        embeds=first == 0,
+        needs_prefix=step < loop_steps - 1 or first <= n_layers - 3,
+        stores=last != n_layers - 1,
+    )
+
+
+def decoder_visits(layer_idxs, n_layers: int) -> int:
+    """How many of a shard's visits are decoder layers' (neither the
+    embedding's, the final norm's nor the head's)."""
+    return sum(0 < i < n_layers - 2 for i in layer_idxs)
+
+
+def visit_order(n_layers: int, loop_steps: int = 1) -> list[int]:
+    """Indices into the execution list in the order a batch visits them:
+    the embedding, ``loop_steps`` times (the decoder layers, the final
+    norm), the head. ``range(n_layers)`` for ``loop_steps`` 1."""
+    if loop_steps == 1:
+        return list(range(n_layers))
+    body = list(range(1, n_layers - 1))
+    return [0] + body * loop_steps + [n_layers - 1]
+
+
 @dataclasses.dataclass(frozen=True)
 class ShardPlan:
-    """One device's work: a list of shards, each a tuple of global layer indices."""
+    """One device's work: the order of visits cut into shards, each a tuple
+    of global layer indices (an index appears ``loop_steps`` times)."""
 
     shards: tuple[tuple[int, ...], ...]
     n_layers: int  # total layers in the model's execution list
     device_rank: int = 0
     num_devices: int = 1
+    loop_steps: int = 1  # LlamaConfig.total_ut_steps
 
     @property
     def num_local_layers(self) -> int:
@@ -41,6 +112,15 @@ class ShardPlan:
     def owns_layer(self, layer_idx: int) -> bool:
         return any(layer_idx in s for s in self.shards)
 
+    def visits(self) -> list[ShardVisit]:
+        """One ``ShardVisit`` per shard, in order: a shard's step is the
+        number of final-norm visits before it."""
+        out, step = [], 0
+        for s in self.shards:
+            out.append(shard_visit(s, self.n_layers, step, self.loop_steps))
+            step += sum(i == self.n_layers - 2 for i in s)
+        return out
+
 
 def _array_split_sizes(n: int, parts: int) -> list[int]:
     """Sizes produced by ``np.array_split(np.arange(n), parts)``."""
@@ -48,10 +128,15 @@ def _array_split_sizes(n: int, parts: int) -> list[int]:
     return [base + 1] * extra + [base] * (parts - extra)
 
 
-def _contiguous_shards(n_layers: int, num_shards: int) -> list[tuple[int, ...]]:
+def _contiguous_shards(
+    n_layers: int, num_shards: int, order: list[int] | None = None
+) -> list[tuple[int, ...]]:
+    """``order`` (default ``range(n_layers)``) cut into ``num_shards``
+    contiguous pieces of ``np.array_split`` sizes."""
+    order = list(range(n_layers)) if order is None else order
     out, start = [], 0
-    for size in _array_split_sizes(n_layers, num_shards):
-        out.append(tuple(range(start, start + size)))
+    for size in _array_split_sizes(len(order), num_shards):
+        out.append(tuple(order[start : start + size]))
         start += size
     return out
 
@@ -61,17 +146,24 @@ def plan_shards_dp(
     layer_num_per_shard: int,
     device_rank: int = 0,
     num_devices: int = 1,
+    loop_steps: int = 1,
 ) -> ShardPlan:
     """DP / single-device plan: contiguous shards, all streamed by this device
     (``/root/reference/utils.py:145-146``). ``device_rank``/``num_devices``
     identify the device within a DP group (used e.g. to tag per-rank disk
-    activation files, ``/root/reference/utils.py:172``)."""
-    num_shards = math.ceil(n_layers / layer_num_per_shard)
+    activation files, ``/root/reference/utils.py:172``). ``loop_steps``
+    (``LlamaConfig.total_ut_steps``): the shards cut ``visit_order``, the
+    same rule over a longer list; at the default one layer a shard every
+    step's shards are the same tuples, so its programs and its cache entries
+    are the step before's."""
+    order = visit_order(n_layers, loop_steps)
+    num_shards = math.ceil(len(order) / layer_num_per_shard)
     return ShardPlan(
-        shards=tuple(_contiguous_shards(n_layers, num_shards)),
+        shards=tuple(_contiguous_shards(n_layers, num_shards, order)),
         n_layers=n_layers,
         device_rank=device_rank,
         num_devices=num_devices,
+        loop_steps=loop_steps,
     )
 
 
@@ -127,6 +219,10 @@ def batch_ranges(num_prompts: int, num_batch: int) -> list[tuple[int, int]]:
 
 __all__ = [
     "ShardPlan",
+    "ShardVisit",
+    "shard_visit",
+    "decoder_visits",
+    "visit_order",
     "plan_shards_dp",
     "plan_shards_mp",
     "global_stage_order",

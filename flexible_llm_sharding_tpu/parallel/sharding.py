@@ -303,6 +303,7 @@ class TpPlacement:
 def check_tp_divisibility(cfg: LlamaConfig, tp_size: int) -> None:
     """TP constraints — fail loudly before XLA produces a cryptic error."""
     cfg.require_one_attention_shape("tensor parallelism")
+    cfg.require_single_visit("tensor parallelism")
     if cfg.num_attention_heads % tp_size:
         raise ValueError(
             f"num_attention_heads={cfg.num_attention_heads} not divisible by tp={tp_size}"

@@ -200,6 +200,11 @@ class ActivationStore:
         self.device_wait_s = 0.0
         self.link_bytes = self.device_bytes = 0
         self.trace_ids: dict = {}
+        # A looped model's exit state of each block between its shards
+        # (block id -> llama.exit_init's tuple of small device arrays, a
+        # few KB a block: always on the chip, never spilled, so a resumed
+        # disk pass has none; runtime/executor.LoopPlace reads and writes).
+        self.exit_state: dict = {}
         self._writer = None  # lazy single-thread pool for async disk writes
         self._write_futs: list = []
         self._store_gen = 0  # disk write/read generations (see set_shard)
@@ -544,6 +549,7 @@ class ActivationStore:
             self._spilled.clear()
             self._pending.clear()
             self._cpu_prompts = 0
+            self.exit_state.clear()
 
 
 __all__ = ["ActivationStore"]

@@ -878,6 +878,7 @@ class DecodeGenerator:
         self.cfg = cfg
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
         self.model_cfg.require_one_attention_shape("KV-cache decoding")
+        self.model_cfg.require_single_visit("KV-cache decoding")
         self.device = device
         self.dtype = _DTYPES[cfg.dtype]
         if tokenizer is None:

@@ -55,7 +55,13 @@ from flexible_llm_sharding_tpu.obs import events as obs_events
 from flexible_llm_sharding_tpu.obs import trace as obs_trace
 from flexible_llm_sharding_tpu.obs.registry import REGISTRY as _OBS_REGISTRY
 from flexible_llm_sharding_tpu.obs.registry import describe as _describe_gauges
-from flexible_llm_sharding_tpu.parallel.planner import ShardPlan, plan_shards_dp
+from flexible_llm_sharding_tpu.parallel.planner import (
+    ShardPlan,
+    ShardVisit,
+    decoder_visits,
+    plan_shards_dp,
+    visit_order,
+)
 from flexible_llm_sharding_tpu.runtime.activations import ActivationStore
 from flexible_llm_sharding_tpu.runtime.pressure import (
     HostOOMError,
@@ -205,6 +211,42 @@ def _norm_block(cfg: LlamaConfig, norm_params, suffix_h, suffix_eos):
         )
 
 
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
+def _loop_norm_block(
+    cfg: LlamaConfig, norm_params, prefix_h, suffix_h, suffix_eos, exit_state, step
+):
+    """A looped model's step end before the last: the final norm over EVERY
+    prefix and suffix row (its output feeds the next step; donated like a
+    decoder step's activations) and the exit gate on the scored rows (each
+    suffix's last real token). ``step`` int32 scalar, 1-based and traced:
+    one program serves every step. ``exit_state``: ``llama.exit_init``'s
+    tuple over [B, S]. -> (prefix_h, suffix_h, exit_state)."""
+    with jax.named_scope("loop_norm"):
+        prefix_h = llama.final_norm(norm_params, cfg, prefix_h)
+        suffix_h = llama.final_norm(norm_params, cfg, suffix_h)
+    scored = jnp.take_along_axis(suffix_h, suffix_eos[:, :, None, None], axis=2)
+    return prefix_h, suffix_h, llama.exit_step(
+        cfg, norm_params, exit_state, scored, step, last=False
+    )
+
+
+_exit_init = jax.jit(llama.exit_init, static_argnums=(0, 1, 2))
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _exit_block(cfg: LlamaConfig, norm_params, suffix_h, exit_state, real):
+    """A looped model's last step end, after ``_norm_block``: suffix_h
+    [B, S, 1, D] is the last step's normed output of the scored rows. ->
+    (what the head reads: each row's state at the step the exit rule gave
+    it, the last step's at a threshold >= 1; the gate's expected exit step
+    summed over the ``real`` [B, S] rows, float32 scalar)."""
+    _, _, expected, chosen = llama.exit_step(
+        cfg, norm_params, exit_state, suffix_h, cfg.total_ut_steps, last=True
+    )
+    out = suffix_h if cfg.early_exit_threshold >= 1 else chosen
+    return out, jnp.sum(jnp.where(real, expected, 0.0))
+
+
 @partial(jax.jit, static_argnums=(0,))
 def _head_block(cfg: LlamaConfig, head_params, suffix_h):
     """[B, S, 1, D] -> float32 scores [B, S, V] (``/root/reference/utils.py:287-290``);
@@ -220,8 +262,7 @@ def process_block(
     model_cfg: LlamaConfig,
     dtype,
     segments,
-    layer_idxs,
-    n_layers: int,
+    visit: ShardVisit,
     store,
     b: int,
     idxs,
@@ -235,12 +276,16 @@ def process_block(
     clock=None,
 ):
     """Run one shard over one block: fetch its activations (unless this shard
-    starts at the embed layer), apply the segments, scatter any head scores,
-    and store activations for the next shard. The per-block body shared by
-    the single-device executor and the MP pipeline runner — the subtle
-    invariants (prefix states end at the last decoder = index n_layers-3;
-    nothing is stored after the final layer; score rows truncate to the true
-    suffix count) live only here.
+    is the first visit, the embedding's), apply the segments, scatter any
+    head scores, and store activations for the next shard. The per-block
+    body shared by the single-device executor and the MP pipeline runner.
+    What the shard's place in the order of visits means (nothing fetched at
+    the first visit, prefix states dead after the last decoder visit,
+    nothing stored after the last visit) is ``visit``'s to say
+    (``parallel.planner.ShardVisit``, the one place that states those
+    invariants); that score rows truncate to the true suffix count lives
+    here. A looped model's exit state rides the store between a block's
+    shards (``store.exit_state``).
 
     ``fetched``: optional (prefix_h, suffix_h) override — already-on-device
     activations that REPLACE the store fetch (the executor's corruption
@@ -254,19 +299,19 @@ def process_block(
     Returns the block's suffix activations (device array) for optional
     synchronisation by the caller.
     """
-    first, last = layer_idxs[0], layer_idxs[-1]
     prefix_ids, suffix_ids, prefix_len, suffix_eos = meta
     ids = {} if clock is None else clock.span_ids()
-    if first == 0:
+    if visit.embeds:
         prefix_h, suffix_h = None, None  # produced by the embed segment
     elif fetched is not None:
         prefix_h, suffix_h = fetched
-        if first > n_layers - 3:  # norm/head shard: prefix is dead weight
+        if not visit.needs_prefix:  # norm/head shard: prefix is dead weight
             prefix_h = None
     else:
-        with_prefix = first <= n_layers - 3
         with obs_trace.timed("act_fetch", cat="sweep", block=b, **ids) as sp:
-            prefix_h, suffix_h = store.fetch(b, idxs, with_prefix=with_prefix)
+            prefix_h, suffix_h = store.fetch(
+                b, idxs, with_prefix=visit.needs_prefix
+            )
             # Host->HBM upload, or the chip-to-chip ICI hop in pipeline
             # mode. Under TpPlacement activations are replicated over the
             # tp mesh.
@@ -290,6 +335,7 @@ def process_block(
         use_pallas,
         tp_mesh,
         clock=clock,
+        loop=LoopPlace.of(model_cfg, visit, store.exit_state, b, idxs, toks),
     )
     if block_scores is not None:
         for row, i in enumerate(idxs):
@@ -299,7 +345,7 @@ def process_block(
             row_scores = block_scores[row, :s_true, None, :]
             row_scores.copy_to_host_async()
             scores[i] = row_scores
-    if last != n_layers - 1:
+    if visit.stores:
         with obs_trace.timed("act_store", cat="sweep", block=b, **ids) as sp:
             store.store(b, idxs, prefix_h, suffix_h)
         if clock is not None:
@@ -352,6 +398,7 @@ def apply_segments(
     use_pallas: bool = False,
     tp_mesh=None,
     clock: "SweepClock | None" = None,
+    loop: "LoopPlace | None" = None,
 ):
     """Run one shard's segments over a block.
 
@@ -364,7 +411,10 @@ def apply_segments(
     its ``expert_rows`` count what each decoder segment's expert layers are
     dispatched with, from the shapes; its ``moe_counts`` get the segment's
     device-resident int32 [2] expert counts where the model holds a share of
-    its experts. Nothing is read from the device here.
+    its experts. Nothing is read from the device here. ``loop`` (a looped
+    model only): where the shard stands among the loop's steps and the
+    block's exit state; a ``norm`` segment before the last step norms every
+    row and leaves the prefix alive, the last one is every model's.
     """
     block_scores = None
     moe_stats = clock is not None and model_cfg.moe_ep_size > 1
@@ -395,12 +445,60 @@ def apply_segments(
             )
             if moe_stats:
                 clock.moe_counts.extend(counts)
+        elif kind == "norm" and loop is not None:
+            prefix_h, suffix_h = loop.step_end(
+                model_cfg, params, prefix_h, suffix_h, suffix_eos, clock
+            )
         elif kind == "norm":
             suffix_h = _norm_block(model_cfg, params, suffix_h, suffix_eos)
             prefix_h = None
         else:  # head
             block_scores = _head_block(model_cfg, params, suffix_h)
     return prefix_h, suffix_h, block_scores
+
+
+class LoopPlace:
+    """Where one block stands in a looped model's steps while a shard's
+    segments are applied: ``step`` (0-based) counts the final norms the
+    block has passed; ``states`` (the activation store's ``exit_state``)
+    keeps the block's exit state between shards, as the store keeps its
+    activations; ``real``: the true suffix count of each prompt of the
+    block (rows past it are padding and count in no mean)."""
+
+    def __init__(self, step: int, states: dict, block: int, real: list[int]):
+        self.step, self.states, self.block, self.real = step, states, block, real
+
+    @classmethod
+    def of(cls, cfg, visit: ShardVisit, states: dict, block: int, idxs, toks):
+        """The block's place at the start of a shard; None for a model
+        visited once, whose ``norm`` segment is the plain one."""
+        if cfg.total_ut_steps == 1:
+            return None
+        return cls(visit.step, states, block, [toks[i].num_suffixes for i in idxs])
+
+    def step_end(self, cfg, norm_params, prefix_h, suffix_h, suffix_eos, clock):
+        """A ``norm`` segment: the step's end. -> (prefix_h, suffix_h) for
+        what follows: every row normed before the last step, the scored
+        rows' [B, S, 1, D] and no prefix at the last."""
+        self.step += 1
+        state = self.states.pop(self.block, None)
+        if state is None:
+            state = _exit_init(
+                suffix_h.shape[:2], suffix_h.shape[-1], suffix_h.dtype
+            )
+        if self.step < cfg.total_ut_steps:
+            prefix_h, suffix_h, self.states[self.block] = _loop_norm_block(
+                cfg, norm_params, prefix_h, suffix_h, suffix_eos, state,
+                np.int32(self.step),
+            )
+            return prefix_h, suffix_h
+        suffix_h = _norm_block(cfg, norm_params, suffix_h, suffix_eos)
+        real = np.arange(suffix_h.shape[1])[None, :] < np.asarray(self.real)[:, None]
+        suffix_h, expected = _exit_block(cfg, norm_params, suffix_h, state, real)
+        if clock is not None:
+            clock.exit_sums.append(expected)
+            clock.exit_rows += int(real.sum())
+        return None, suffix_h
 
 
 def _expert_rows(seg, act_size: int, tp_mesh) -> tuple[str, int]:
@@ -561,6 +659,13 @@ class SweepClock:
         # state one dispatch held: host counts from the shapes.
         self.linear_rows = {"kernel": 0, "xla": 0}
         self.linear_state_bytes = 0
+        # A looped model: decoder-layer visits the consumer took (a count of
+        # the plan's indices, on the host), and per block the gate's
+        # expected exit step summed over its real scored rows (device
+        # float32 scalars, read once in finish()) with those rows' count.
+        self.layer_visits = 0
+        self.exit_sums: list = []
+        self.exit_rows = 0
         self._sweep = obs_trace.sweep_span(self.sweep_id, mode="offline")
         self._head = obs_trace.timed(
             "sweep_head", cat="sweep", sweep_id=self.sweep_id
@@ -643,12 +748,18 @@ class SweepClock:
         if self.model is not None:
             rec.update(_model_account(self.model, self.moe_counts))
             rec.update(
+                loop_steps=self.model.total_ut_steps,
+                layer_visits=self.layer_visits,
                 expert_rows_grouped=self.expert_rows["grouped"],
                 expert_rows_dense=self.expert_rows["dense"],
                 linear_rows_kernel=self.linear_rows["kernel"],
                 linear_rows_xla=self.linear_rows["xla"],
                 linear_state_bytes=self.linear_state_bytes,
             )
+            if self.exit_sums:
+                rec["exit_step_mean"] = float(
+                    jnp.sum(jnp.stack(self.exit_sums))
+                ) / max(self.exit_rows, 1)
         with _SWEEP_LOG_LOCK:
             _SWEEP_LOG.append(rec)
         return rec
@@ -729,6 +840,20 @@ SWEEP_RECORD_HELP = {
     "not have to carry once seated.",
     "pin_hits": "Planned layers this sweep merged from the residency tier "
     "instead of uploading (a layer the sweep seated is not a hit).",
+    "visits_pinned": "Decoder-layer visits this sweep's source served from a "
+    "seat of the residency tier (nothing read, nothing uploaded); a looped "
+    "model visits a layer total_ut_steps times a sweep.",
+    "visits_streamed": "Decoder-layer visits served from an upload (a "
+    "streamed layer, or a planned one in the sweep that seats it): a "
+    "looped model's streamed layers cross the link once a step.",
+    "loop_steps": "Times the sweep visits the decoder stack (the model's "
+    "total_ut_steps; 1 for every model but a looped one).",
+    "layer_visits": "Decoder-layer visits the consumer took this sweep "
+    "(loop_steps x decoder layers for a whole pass).",
+    "exit_step_mean": "A looped model's exit gate: the expected exit step "
+    "(sum over steps of step x exit probability, steps from 1) averaged "
+    "over the sweep's real scored rows; summed on the device, read once at "
+    "the sweep's end.",
     "window_layers": "Decoder layers with local (sliding-window or chunked) "
     "attention in the model the sweep ran.",
     "full_layers": "Decoder layers with full causal attention.",
@@ -1819,6 +1944,9 @@ class ShardWeightSource:
             else frozenset()
         )
         self.pin_hits = 0  # pinned layers this source merged, not uploaded
+        # Decoder-layer visits served from a seat / from an upload (a looped
+        # plan visits a layer several times a pass).
+        self.visits_pinned = self.visits_streamed = 0
         self.produce_time = 0.0  # set BEFORE the producer thread starts
         # The producer's side of the sweep's account (see account()).
         self.upload_dispatch_s = 0.0
@@ -1918,6 +2046,8 @@ class ShardWeightSource:
                 else 0
             ),
             "pin_hits": self.pin_hits,
+            "visits_pinned": self.visits_pinned,
+            "visits_streamed": self.visits_streamed,
         }
 
     @property
@@ -1966,12 +2096,18 @@ class ShardWeightSource:
             # Count the sweep's saved link bytes ONCE per build (the put
             # below may retry; retries must not double-count).
             pinned_nbytes = 0
+            seated = set()
             for kind, idx, host in parts:
                 if kind == "pin":
                     self._residency.note_skip(idx)
                     self.pin_hits += 1
+                    seated.add(idx)
                 elif _on_pinned_host(host) is not None:
                     pinned_nbytes += _tree_nbytes(host)
+            n_names = len(self._loader.layer_names)
+            pinned = decoder_visits([i for i in layer_idxs if i in seated], n_names)
+            self.visits_pinned += pinned
+            self.visits_streamed += decoder_visits(layer_idxs, n_names) - pinned
 
             # The host->device put retries under the same policy as the
             # reads: a transfer that surfaces OSError/TimeoutError is
@@ -2074,11 +2210,13 @@ class ShardWeightSource:
         self._loader.trace_ids = {"sweep_id": self.sweep_id}
         cache = self._loader._host_cache
         evictions = cache.evictions
+        built = set()  # a looped plan lists a run once a step
         for idxs in self.shards:
             for in_set, run in _runs(idxs, self._pinned_idxs):
                 if self._stop.is_set() or cache.evictions != evictions:
                     return
-                if not in_set:
+                if not in_set and run not in built:
+                    built.add(run)
                     try:
                         self._loader.build_host_shard(run, upload=False)
                     except Exception:  # flscheck: disable=EXC-TAXONOMY: whatever a build raises, the sweep's own build of the run raises again at the shard's position, where the consumer takes it
@@ -2448,16 +2586,20 @@ class StreamingExecutor:
             self.model_cfg.num_hidden_layers, tie_word_embeddings=False
         )
         self.plan = plan or plan_shards_dp(
-            len(self.layer_names), cfg.layer_num_per_shard
+            len(self.layer_names), cfg.layer_num_per_shard,
+            loop_steps=self.model_cfg.total_ut_steps,
         )
-        # This executor streams every layer itself, in order; a plan that
-        # skips or reorders layers (an MP stage plan) needs the pipeline
+        # This executor streams every layer itself, in the order of visits
+        # (a looped model's stack once a step); a plan that skips or
+        # reorders layers (an MP stage plan) needs the pipeline
         # runner's cross-device activation handoff, which this class does not
         # do. Order matters: activations for shard k+1 only exist after
         # shard k ran, so `covered` is compared UNSORTED, and empty shards
         # (MP round-up padding) are rejected too.
         covered = [i for s in self.plan.shards for i in s]
-        if covered != list(range(len(self.layer_names))) or not all(self.plan.shards):
+        if covered != visit_order(
+            len(self.layer_names), self.model_cfg.total_ut_steps
+        ) or not all(self.plan.shards):
             raise ValueError(
                 "StreamingExecutor requires a plan covering all layers in "
                 "order with no empty shards (DP/single-device); use the MP "
@@ -2480,6 +2622,13 @@ class StreamingExecutor:
         # no sharding rule), so under TpPlacement the flash calls run inside
         # a shard_map over the heads axis (llama._flash_tp_*); the placement's
         # mesh rides into the jitted blocks as a static arg.
+        # A looped model whose exit rule reads the steps' gates (a threshold
+        # under 1): its exit state lives on the chip between shards and is
+        # neither spilled nor recomputed.
+        self._exit_state_needed = (
+            self.model_cfg.total_ut_steps > 1
+            and self.model_cfg.early_exit_threshold < 1
+        )
         self._use_pallas = cfg.pallas_enabled()
         self._tp_mesh = (
             device.mesh if hasattr(device, "segment_target") else None
@@ -2522,6 +2671,11 @@ class StreamingExecutor:
         shard k's outputs from the intact previous generation.
         """
         if not (self.cfg.resume and self.cfg.storage_location == "disk"):
+            return 0
+        if self._exit_state_needed:
+            # The marker counts visits, but the exit state of the steps
+            # before the crash was on the chip: the rule that gives each
+            # scored token its step cannot be resumed, so the pass restarts.
             return 0
         data = resume.read_marker(
             self._progress_path(store, sig), sig,
@@ -2845,6 +2999,7 @@ class StreamingExecutor:
         clock: SweepClock,
     ) -> None:
         n_layers = len(self.layer_names)
+        visits = self.plan.visits()
         total = (n_shards or len(self.plan.shards)) * max(len(blocks), 1)
         bar = metrics.progress_bar(total, desc="stream", unit="blk")
         it = enumerate(source)
@@ -2856,8 +3011,10 @@ class StreamingExecutor:
         # ping-pong guarantees the previous shard's own inputs are still
         # intact. Costs one extra shard's worth of HBM while streaming in
         # disk mode (comparable to prefetch_depth=1's queued shard).
-        heal_spills = store.location == "disk"
-        prev_shard = None  # (layer_idxs, segments) of the last shard run
+        heal_spills = store.location == "disk" and not self._exit_state_needed
+        prev_shard = None  # (visit, segments) of the last shard run
+        # A looped model: one span around each step's shards.
+        step_span = None
         # Correlation id for this full pass over the shards — the offline
         # equivalent of one serving sweep; every span below carries it so
         # the trace analyzer can group a pass's phases back together.
@@ -2897,6 +3054,20 @@ class StreamingExecutor:
                 # source yields only the resumed tail.
                 shard_idx = shard_i + (0 if skip else start_shard)
                 clock.shard_idx = shard_idx
+                visit = visits[shard_idx]
+                clock.layer_visits += decoder_visits(layer_idxs, n_layers)
+                # The embedding rides the first step's span, the head (whose
+                # shard starts after the last norm) the last step's.
+                step = min(visit.step, self.plan.loop_steps - 1)
+                if self.plan.loop_steps > 1 and (
+                    step_span is None or step_span[0] != step
+                ):
+                    if step_span is not None:
+                        step_span[1].__exit__(None, None, None)
+                    step_span = (step, obs_trace.span(
+                        "loop_step", cat="sweep", sweep_id=sweep_id, step=step,
+                    ))
+                    step_span[1].__enter__()
                 store.set_shard(shard_idx)
                 store.trace_ids = clock.span_ids()
                 with obs_trace.timed(
@@ -2905,22 +3076,21 @@ class StreamingExecutor:
                 ) as compute:
                     self._stream_shard(
                         store, toks, blocks, block_meta, scores,
-                        layer_idxs, segments, n_layers, prev_shard,
-                        bar, clock,
+                        visit, segments, prev_shard, bar, clock,
                     )
                     if on_shard_done is not None:
                         on_shard_done(shard_i)
                 clock.compute_s += compute.dur_s
-                prev_shard = (
-                    (layer_idxs, segments) if heal_spills else None
-                )
+                prev_shard = (visit, segments) if heal_spills else None
             clock.start_tail()
         finally:
+            if step_span is not None:
+                step_span[1].__exit__(None, None, None)
             bar.close()
 
     def _stream_shard(
-        self, store, toks, blocks, block_meta, scores, layer_idxs, segments,
-        n_layers, prev_shard, bar, clock,
+        self, store, toks, blocks, block_meta, scores, visit, segments,
+        prev_shard, bar, clock,
     ) -> None:
         """One shard's compute over every block — the body the traced
         ``compute`` span wraps in ``_stream``: its ``dispatch`` child is
@@ -2939,8 +3109,7 @@ class StreamingExecutor:
                             self.model_cfg,
                             self.dtype,
                             segments,
-                            layer_idxs,
-                            n_layers,
+                            visit,
                             store,
                             b,
                             idxs,
@@ -2971,8 +3140,7 @@ class StreamingExecutor:
                             "spill_recompute", block=b, sweep_id=sweep_id
                         )
                         fetched = self._recompute_block(
-                            prev_shard, store, b, idxs, block_meta[b],
-                            n_layers,
+                            prev_shard, store, b, idxs, block_meta[b], toks
                         )
                 bar.update(1)
             if not blocks:
@@ -2983,31 +3151,27 @@ class StreamingExecutor:
         # prefetch thread keeps uploading the next shard, and the
         # disk writer keeps writing, concurrently with this wait.
         # (blocks can be empty: num_batch > prompt count -> ex([]).)
-        if blocks and layer_idxs[-1] != n_layers - 1:
+        if blocks and visit.stores:
             with obs_trace.timed(
                 "device_wait", cat="sweep", at="shard_end", **ids
             ) as wait:
                 jax.block_until_ready(suffix_h)
             clock.device_wait_s += wait.dur_s
 
-    def _recompute_block(
-        self, prev_shard, store, b, idxs, meta, n_layers: int
-    ):
+    def _recompute_block(self, prev_shard, store, b, idxs, meta, toks):
         """Re-derive one block's activations by re-running the PREVIOUS
         shard: its inputs live in the other disk generation (the ping-pong
         that protects crash resume also protects this path — shard k-1's
         inputs at generation k%2 are untouched until shard k stores this
         very block). Returns (prefix_h, suffix_h) on device, ready to feed
         the current shard via ``process_block(fetched=...)``."""
-        prev_idxs, prev_segments = prev_shard
+        prev_visit, prev_segments = prev_shard
         prefix_ids, suffix_ids, prefix_len, suffix_eos = meta
-        first = prev_idxs[0]
-        if first == 0:
+        if prev_visit.embeds:
             prefix_h, suffix_h = None, None  # re-embed from token ids
         else:
-            with_prefix = first <= n_layers - 3
             prefix_h, suffix_h = store.fetch_recompute(
-                b, idxs, with_prefix=with_prefix
+                b, idxs, with_prefix=prev_visit.needs_prefix
             )
             act_target = getattr(self.device, "act", self.device)
             suffix_h = jax.device_put(suffix_h, act_target)
@@ -3025,6 +3189,10 @@ class StreamingExecutor:
             suffix_eos,
             self._use_pallas,
             self._tp_mesh,
+            # A looped model's step ends are re-run for their norms alone:
+            # the block's exit state already holds what the first run gave
+            # it (a pass whose exit rule reads that state does not heal).
+            loop=LoopPlace.of(self.model_cfg, prev_visit, {}, b, idxs, toks),
         )
         return prefix_h, suffix_h
 

@@ -320,6 +320,7 @@ class LongContextScorer:
         self.cfg = cfg
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
         self.model_cfg.require_one_attention_shape("the long-context scorer")
+        self.model_cfg.require_single_visit("the long-context scorer")
         devices = list(devices) if devices else None
         self.mesh = make_mesh(
             {"sp": len(devices)} if devices else None, devices=devices

@@ -274,7 +274,10 @@ def run_prompts(
         model_cfg.num_hidden_layers, tie_word_embeddings=False
     )
     n_exec_layers = len(layer_names)
-    plan = plan_shards_dp(n_exec_layers, cfg.layer_num_per_shard)
+    plan = plan_shards_dp(
+        n_exec_layers, cfg.layer_num_per_shard,
+        loop_steps=model_cfg.total_ut_steps,
+    )
     active = [rank for rank in range(n) if ranges[rank][0] < ranges[rank][1]]
     source = BroadcastShardSource(
         cfg.model_path,
@@ -313,6 +316,7 @@ def run_prompts(
                 cfg.layer_num_per_shard,
                 device_rank=rank,
                 num_devices=n,
+                loop_steps=model_cfg.total_ut_steps,
             ),
             tokenizer=tokenizer,
             weight_source_factory=lambda: source.view(slot),

@@ -39,6 +39,7 @@ from flexible_llm_sharding_tpu.obs import trace as _trace
 from flexible_llm_sharding_tpu.parallel.planner import (
     batch_ranges,
     global_stage_order,
+    shard_visit,
 )
 from flexible_llm_sharding_tpu.runtime import resume
 from flexible_llm_sharding_tpu.runtime.activations import ActivationStore
@@ -69,6 +70,7 @@ class PipelineRunner:
         self.devices = list(devices)
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
         self.model_cfg.require_one_attention_shape("the pipeline runner")
+        self.model_cfg.require_single_visit("the pipeline runner")
         self.dtype = _DTYPES[cfg.dtype]
         if tokenizer is None:
             from transformers import AutoTokenizer
@@ -256,8 +258,7 @@ class PipelineRunner:
                             self.model_cfg,
                             self.dtype,
                             segments,
-                            layer_idxs,
-                            n_layers,
+                            shard_visit(layer_idxs, n_layers),
                             store,
                             b,
                             idxs,

@@ -225,6 +225,7 @@ class ServeEngine:
         self.device = device
         self.model_cfg = LlamaConfig.from_pretrained(cfg.model_path)
         self.model_cfg.require_one_attention_shape("the serve engine")
+        self.model_cfg.require_single_visit("the serve engine")
         self.dtype = _DTYPES[cfg.dtype]
         if tokenizer is None:
             from transformers import AutoTokenizer

@@ -139,6 +139,7 @@ class StreamedTrainer:
         dtype=jnp.float32,
         pad_id: int | None = None,
     ):
+        cfg.require_single_visit("streamed training")
         # The tie rule must be the ONE llama.head_params applies in the
         # forward (absent/empty lm_head -> embedding.T), or the gradient
         # routing below would silently diverge from the head actually used.

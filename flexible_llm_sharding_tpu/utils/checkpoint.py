@@ -252,7 +252,10 @@ def key_to_layer(key: str) -> str:
     three dotted components (``model.layers.17.self_attn.q_proj.weight`` ->
     ``model.layers.17``; ``lm_head.weight`` -> ``lm_head``).
     """
-    return ".".join(re.sub(r"\.(weight|bias)$", "", key).split(".")[:3])
+    layer = ".".join(re.sub(r"\.(weight|bias)$", "", key).split(".")[:3])
+    # A looped model's exit gate (Ouro's ``model.early_exit_gate``) is read
+    # where the final norm is, at every step's end: it lives in the norm's file.
+    return "model.norm" if layer == "model.early_exit_gate" else layer
 
 
 def layer_names_for(num_hidden_layers: int, tie_word_embeddings: bool = False) -> list[str]:
@@ -432,7 +435,13 @@ def hf_layer_to_native(
     if layer_name == "model.embed_tokens":
         return {"embedding": sd["model.embed_tokens.weight"]}
     if layer_name == "model.norm":
-        return {"scale": sd["model.norm.weight"]}
+        out = {"scale": sd["model.norm.weight"]}
+        if "model.early_exit_gate.weight" in sd:  # Ouro: Linear(hidden, 1)
+            out["gate.kernel"] = np.ascontiguousarray(
+                sd["model.early_exit_gate.weight"].T
+            )
+            out["gate.bias"] = sd["model.early_exit_gate.bias"]
+        return out
     if layer_name == "lm_head":
         return {"kernel": np.ascontiguousarray(sd["lm_head.weight"].T)}
     moe = any(".block_sparse_moe." in k for k in sd)
@@ -443,6 +452,23 @@ def hf_layer_to_native(
     mla = f"{layer_name}.self_attn.kv_a_proj_with_mqa.weight" in sd  # deepseek
     out = {}
     consumed = set()
+    if f"{layer_name}.input_layernorm_2.weight" in sd:
+        # Ouro's four norms a layer, in the native sandwich slots (gemma2's):
+        # x + input_layernorm_2(Attn(input_layernorm(x))), then
+        # a + post_attention_layernorm_2(MLP(post_attention_layernorm(a))).
+        # The family's ``post_attention_layernorm`` is the MLP's INPUT norm,
+        # where the native slot of that name norms the attention's output.
+        sd = dict(sd)
+        for native_key, hf_sub in (
+            ("post_feedforward_layernorm.scale", "post_attention_layernorm_2"),
+            ("pre_feedforward_layernorm.scale", "post_attention_layernorm"),
+            ("post_attention_layernorm.scale", "input_layernorm_2"),
+        ):
+            out[native_key] = sd.pop(f"{layer_name}.{hf_sub}.weight")
+        # so that the generic map below finds its slot already filled
+        sd[f"{layer_name}.post_attention_layernorm.weight"] = out[
+            "post_attention_layernorm.scale"
+        ]
     for native_key, hf_sub, transpose in _LAYER_MAP:
         if (moe or ff or qmoe) and native_key.startswith("mlp."):
             continue  # Mixtral / llama4 / qwen3_moe expert layouts below

@@ -439,3 +439,50 @@ def test_sala_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, li
     assert ("flash_causal_attention" in text) != linear
     assert text.count("tpu_custom_call") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _ouro_cfg():
+    import json
+
+    from benchmark.families.ouro import weights
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "ouro-2.6b.json")) as f:
+        model = json.load(f)
+    model.pop("rehearsal")
+    return LlamaConfig.from_hf_config(weights.hf_config(model))
+
+
+@pytest.mark.parametrize("step", ["decoder", "loop_norm", "exit"])
+def test_ouro_steps_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, step):
+    """What a looped model's batch dispatches, at Ouro-2.6B's published widths
+    and the cell's longest bucket, one prompt a block: a decoder layer with
+    its four norms and the two flash kernels at 16 heads over 16 KV heads of
+    128; the step end before the last (the final norm over every row and the
+    gate on the scored rows: one program for every step, the step is traced);
+    the last step's exit."""
+    cfg = _ouro_cfg()
+    assert cfg.total_ut_steps == 4 and cfg.ffw_sandwich_norms and not cfg.norm_unit_offset
+    s = functools.partial(_sds, one_chip)
+    prefix, suffix = s((1, 3392, 2048)), s((1, 4, 64, 2048))
+    norm = {"scale": s((2048,)), "gate": {"kernel": s((2048, 1)), "bias": s((1,))}}
+    state = (s((1, 4), jnp.float32),) * 3 + (s((1, 4, 1, 2048)),)
+    if step == "decoder":
+        shapes = jax.eval_shape(
+            lambda: llama.init_layer_params(jax.random.PRNGKey(0), cfg, BF16)
+        )
+        assert "post_feedforward_layernorm" in shapes and shapes["attn"]["wk"].shape == (2048, 2048)
+        seg = {"layers": jax.tree.map(lambda x: s((1, *x.shape), x.dtype), shapes),
+               "sliding": None, "rope": None}
+        compiled, text = _compile(
+            executor._decoder_block, cfg, seg, prefix, suffix, s((1,), jnp.int32), True)
+        assert "flash_causal_attention" in text and "flash_prefix_shared_attention" in text
+        assert text.count("tpu_custom_call") >= 2
+    elif step == "loop_norm":
+        compiled, text = _compile(
+            executor._loop_norm_block, cfg, norm, prefix, suffix, s((1, 4), jnp.int32),
+            state, s((), jnp.int32))
+    else:
+        compiled, text = _compile(
+            executor._exit_block, cfg, norm, s((1, 4, 1, 2048)), state, s((1, 4), jnp.bool_))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
