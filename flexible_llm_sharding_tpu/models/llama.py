@@ -714,6 +714,78 @@ def _attn_kind(cfg: LlamaConfig, params: Params, sliding):
     return layer_kind(cfg, params["attn"], sliding)
 
 
+def attention_plan(
+    cfg: LlamaConfig, shape, sliding, lp: int, ls: int, use_pallas: bool,
+    tp_mesh, has_sink: bool,
+):
+    """What a layer's two attention ops are dispatched with at a prefix
+    bucket of ``lp`` and suffixes of ``ls`` rows: ``(flash, window, chunk,
+    sliding)``. ``shape``: the layer's (heads, kv heads, qk dim, v dim);
+    ``sliding``: its local toggle as ``_effective_window`` takes it.
+
+    The flash kernels carry the full family surface — custom scale
+    (query_pre_attn_scalar), softcap, sliding window / chunked masks, and
+    the traced per-layer local toggle; NoPE/temperature handling lives in
+    position_qk, OUTSIDE the attention op. Only shape eligibility gates
+    them (tiny head dims fall back to XLA attention; ragged head dims >= 64
+    like phi3's 96 pad to the lane multiple inside the kernels). Under
+    tensor parallelism (``tp_mesh``) the kernels run per head-shard via
+    shard_map, so eligibility is checked on PER-SHARD head counts; the
+    head-sharded kernels carry no sink yet (XLA op). Static facts only: the
+    traced layer functions and the host's count of the kernels' steps
+    (``runtime/executor._flash_steps``) read the same plan."""
+    n_q, n_kv, hd, vd = shape
+    window, chunk, sliding = _effective_window(cfg, sliding)
+    if (window is not None and lp + ls <= window) or (
+        chunk is not None and lp + ls <= chunk
+    ):
+        # Max query-key distance at these (static) bucket shapes is
+        # lp + ls - 1 < window (or every position sits in chunk 0): the
+        # local mask equals full causal, so drop it — keeping the flash
+        # kernels eligible (the common case for Mistral's 4096 window and
+        # Llama4's 8192 chunks under the 4096 token cap).
+        window = chunk = sliding = None
+    tp_size = tp_mesh.shape["tp"] if tp_mesh is not None else 1
+    flash = (
+        use_pallas
+        and pallas_attention.supports(
+            n_q // tp_size, n_kv // tp_size, hd, ls, lp, v_dim=vd
+        )
+        and not (has_sink and tp_mesh is not None)
+    )
+    return flash, window, chunk, sliding
+
+
+def layer_attention_plan(
+    cfg: LlamaConfig, layer: int, lp: int, ls: int, use_pallas: bool, tp_mesh=None
+):
+    """``attention_plan`` of decoder layer ``layer`` from the config alone,
+    for a host that counts what the layer's kernels do and holds no weights:
+    ``(flash, shape, window, chunk, local_on)``, or None for a
+    linear-attention layer. ``local_on`` is the python value of the layer's
+    local toggle as the kernels see it: a program that carries the toggle
+    traced (one scan over layers of both kinds and ONE shape: the executor
+    ships the pattern's flags with the segment) keeps the static window and
+    is told per layer; elsewhere the toggle folds into ``window``/``chunk``."""
+    if cfg.layer_linear is not None and cfg.layer_linear[layer]:
+        return None
+    local = layer_sliding_pattern(cfg)[layer]
+    if cfg.kv_lora_rank:
+        nh = cfg.num_attention_heads
+        shape, by_shape = (nh, nh, cfg.head_dim, cfg.v_dim), False
+    else:
+        shape = cfg.attn_shape(local)
+        by_shape = cfg.attn_shape(False) != cfg.attn_shape(True)
+    traced = cfg.layer_sliding is not None and not by_shape
+    # np.bool_ stands for the traced flag: no python bool, so
+    # _effective_window keeps the static window and hands the toggle back.
+    flash, window, chunk, sliding = attention_plan(
+        cfg, shape, np.bool_(local) if traced else local, lp, ls, use_pallas,
+        tp_mesh, cfg.attn_sink_local if local else cfg.attn_sink_global,
+    )
+    return flash, shape, window, chunk, sliding is None or bool(sliding)
+
+
 def _attention_scope(cfg: LlamaConfig, sliding):
     """The layer's ``attention`` scope, with the layer's kind inside it
     (``attention_window`` / ``attention_full``) where the kind is static: a
@@ -979,36 +1051,15 @@ def prefix_suffix_layer(
     (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
     sink = params["attn"].get("sink")
     rope_sliding = sliding  # rope base and scope survive the window shortcut
-    window, chunk, sliding = _effective_window(cfg, sliding)
-    if (window is not None and lp + ls <= window) or (
-        chunk is not None and lp + ls <= chunk
-    ):
-        # Max query-key distance at these (static) bucket shapes is
-        # lp + ls - 1 < window (or every position sits in chunk 0): the
-        # local mask equals full causal, so drop it — keeping the flash
-        # kernels eligible (the common case for Mistral's 4096 window and
-        # Llama4's 8192 chunks under the 4096 token cap).
-        window = chunk = sliding = None
-    # The flash kernels carry the full family surface — custom scale
-    # (query_pre_attn_scalar), softcap, sliding window / chunked masks, and
-    # the traced per-layer local toggle; NoPE/temperature handling lives in
-    # position_qk, OUTSIDE the attention op. Only shape eligibility gates
-    # them (tiny head dims / ragged buckets fall back to XLA attention;
-    # ragged head dims >= 64 like phi3's 96 pad to the lane multiple inside
-    # the kernels).
-    # Under tensor parallelism (``tp_mesh``) the kernels run per head-shard
-    # via shard_map, so eligibility is checked on PER-SHARD head counts.
-    tp_size = tp_mesh.shape["tp"] if tp_mesh is not None else 1
     # MLA (kv_lora_rank) rides the flash path too: the scoring kernels
     # carry q/k's head dim and V's own dim independently (QK^T over
     # head_dim, PV over v_dim) — positioned_qkv hands them per-head
     # decompressed K (nope + shared rope key) and V, so the EFFECTIVE kv
     # head count is the attention head count (GQA ratio 1: _attn_kind).
-    flash = use_pallas and pallas_attention.supports(
-        n_q // tp_size, n_kv // tp_size, hd, ls, lp, v_dim=vd
+    flash, window, chunk, sliding = attention_plan(
+        cfg, (n_q, n_kv, hd, vd), sliding, lp, ls, use_pallas, tp_mesh,
+        sink is not None,
     )
-    if sink is not None and tp_mesh is not None:
-        flash = False  # the head-sharded kernels carry no sink yet (XLA op)
 
     # --- prefix: causal self-attention, keep post-RoPE KV ---
     h = rms_norm(prefix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
@@ -1131,19 +1182,11 @@ def suffix_only_layer(
     (n_q, n_kv, hd, vd), sliding = _attn_kind(cfg, params, sliding)
     sink = params["attn"].get("sink")
     rope_sliding = sliding  # rope base and scope survive the window shortcut
-    window, chunk, sliding = _effective_window(cfg, sliding)
-    if (window is not None and lp + ls <= window) or (
-        chunk is not None and lp + ls <= chunk
-    ):
-        # Same shortcut as prefix_suffix_layer: at these bucket shapes the
-        # local mask equals full causal, so drop it (keeps flash eligible).
-        window = chunk = sliding = None
-    tp_size = tp_mesh.shape["tp"] if tp_mesh is not None else 1
-    flash = use_pallas and pallas_attention.supports(
-        n_q // tp_size, n_kv // tp_size, hd, ls, lp, v_dim=vd
+    # The same plan as prefix_suffix_layer's, from the same bucket shapes.
+    flash, window, chunk, sliding = attention_plan(
+        cfg, (n_q, n_kv, hd, vd), sliding, lp, ls, use_pallas, tp_mesh,
+        sink is not None,
     )
-    if sink is not None and tp_mesh is not None:
-        flash = False  # the head-sharded kernels carry no sink yet (XLA op)
 
     hs = rms_norm(suffix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
     pos_s = prefix_len + jnp.arange(ls)

@@ -23,6 +23,21 @@ GQA is handled by the KV index map (query head h reads KV head
 ``h * n_kv // n_q``), so KV heads are never replicated. Inputs keep the
 model dtype (bf16 on the MXU); softmax runs in fp32 VMEM accumulators.
 
+How blocks are chosen (:func:`flash_tiles`): by what the chip does fastest,
+not by what divides the bucket. A step of the online softmax has a fixed
+cost that a 64 x 64 block (all that divides a bucket of 1216 or 3392)
+never repays, so the two scoring kernels take 256 query rows x 512 keys
+under the diagonal, a suffix's 64 rows over up to 4096 prefix keys, and
+tiles near a binding window, a pure table of the call's static shapes; the
+wrappers zero-pad the length axes up to a whole number of tiles and slice
+the output back. That is exact: padded keys lie past ``valid_len``, where
+the loop bounds and the mask already stop, and padded query rows are cut
+off. K and V of a KV head stay whole in VMEM (the largest bucket, 4096 x
+256 bf16, K and V double-buffered: 8 MB beside a 0.5-1 MB score tile).
+:func:`causal_steps` / :func:`prefix_shared_steps` count the steps a call
+runs from the same tiles and bounds (the sweep record's ``flash_steps``).
+The decode kernel keeps divisor blocks (``_block``): no cell runs it yet.
+
 Model-family envelope (mirrors the XLA ops' full surface):
 
 - ``scale`` — custom attention scale (Gemma2's query_pre_attn_scalar).
@@ -44,8 +59,9 @@ Model-family envelope (mirrors the XLA ops' full surface):
 Shape eligibility is checked by :func:`supports` / :func:`supports_decode`;
 callers fall back to the XLA path otherwise. Ragged head dims >= 64 (phi3's
 96) are zero-padded to the lane multiple inside the scoring wrappers (exact;
-at most 2x lanes); tiny head dims, unbucketed lengths, and — for the decode
-kernel — any non-128-multiple head dim fall back to XLA.
+at most 2x lanes); tiny head dims, unbucketed lengths (the envelope callers
+are held to, though the wrappers' padding would carry any length), and — for
+the decode kernel — any non-128-multiple head dim fall back to XLA.
 """
 
 from __future__ import annotations
@@ -54,22 +70,77 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-_MAX_BLOCK_K = 512  # keys streamed through VMEM per flash step
-_MAX_BLOCK_Q = 128  # query rows per program
+_MAX_BLOCK_K = 512  # decode kernel: keys streamed through VMEM per flash step
+
+# The scoring kernels' tiles (flash_tiles), read off a sweep on a v5e (PERF.md
+# section 6, PR 34). A step of the online softmax costs ~0.35 us there
+# whatever is in it (a dynamic-trip loop with a carried (m, l, acc), two MXU
+# round trips, the cross-lane max and sum, the mask), so a step should carry
+# as large a score tile as pays. Under the diagonal that is 256 x 512 (the
+# fastest of 128-512 x 128-1024 for every head shape tried: wider key tiles
+# waste more of the diagonal block, taller ones spill). The prefix walk of a
+# suffix's 64 rows has no diagonal and kept gaining up to the whole prefix in
+# a step (64 x 4096).
+_TILE_Q = 256  # query rows per program
+_TILE_K = 512  # keys per flash step under a query tile of more than 128 rows
+_SMALL_Q_SCORES = 64 * 4096  # score-tile elements under a smaller query tile
 
 
 def _block(n: int, cap: int) -> int:
     """Largest power-of-two-ish tile <= cap that divides n (n % 64 == 0
-    callers guaranteed by supports(); fall back to n itself)."""
+    callers guaranteed by supports(); fall back to n itself). The decode
+    kernel's prefix walk only: the scoring kernels take ``flash_tiles``."""
     for b in (cap, 256, 128, 64):
         if b <= cap and n % b == 0:
             return b
     return n
+
+
+def _cdiv(a, b):
+    return -(-a // b)
+
+
+def _fit(n: int, cap: int, grain: int) -> int:
+    """The tile of a length-``n`` axis under ``cap``: as few tiles as the
+    cap allows, evenly sized, rounded up to ``grain`` (the axis is then
+    zero-padded to a whole number of tiles). A length of at most 64 is its
+    own tile (a block equal to the array's dim needs no alignment)."""
+    if n <= 64:
+        return n
+    tiles = _cdiv(n, cap)
+    return _cdiv(_cdiv(n, tiles), grain) * grain
+
+
+def flash_tiles(
+    lq: int, lk: int, hd: int, dv: int, window: int | None = None,
+    chunk: int | None = None,
+) -> tuple[int, int]:
+    """(query rows per program, keys per flash step) of the two scoring
+    kernels, from a call's static shapes alone: ``flash_causal_attention``
+    over ``lq`` queries and ``lk`` keys, and the prefix walk of
+    ``flash_prefix_shared_attention`` (``lq`` = one suffix's rows, ``lk`` =
+    the prefix bucket). ``hd`` / ``dv``: the q/k and the v head dims; the
+    sweep found ONE table fastest at 128 / 128 and at 192 / 128 (padded to
+    256), so nothing branches on them yet.
+
+    Full attention takes the tiles the sweep found fastest. A binding
+    ``window`` / ``chunk`` takes a query tile near it and a key tile of
+    twice that (a window-sized query tile sees under two windows of keys:
+    one key tile half the time, two the other half; a 512-key tile over a
+    window of 128 computes four times the scores the window needs). The
+    lengths need not divide: the wrappers zero-pad them up to the tile."""
+    local = window if window is not None else chunk
+    if local is not None and 2 * local <= _TILE_K:
+        near = max(128, 1 << (local - 1).bit_length())
+        return _fit(lq, near, 64), _fit(lk, 2 * near, 128)
+    bq = _fit(lq, _TILE_Q, 64)
+    return bq, _fit(lk, _TILE_K if bq > 128 else _SMALL_Q_SCORES // bq, 128)
 
 
 def supports(
@@ -92,6 +163,16 @@ def supports(
         and head_dim >= 64
         and v_dim >= 64
     )
+
+
+def _pad_dim(a, axis: int, mult: int):
+    """Zero-pad ``axis`` of ``a`` up to a multiple of ``mult``."""
+    p = (-a.shape[axis]) % mult
+    if not p:
+        return a
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (0, p)
+    return jnp.pad(a, pad)
 
 
 def _pad_head_dim(*arrays):
@@ -160,16 +241,61 @@ def _local_mask(mask, q_pos, k_pos, window, chunk, local_on):
     return mask & (jnp.logical_not(local_on) | in_local)
 
 
-def _local_start_block(first_q_pos, window, chunk, bk, local_on):
-    """First KV block that can contain a visible key for a q block whose
-    FIRST query sits at absolute position ``first_q_pos`` — blocks before it
-    are wholly outside the local region for every query in the block (later
-    queries only look further right). 0 when the layer's toggle is off."""
+def _kv_bounds(xp, q0, bq, bk, nk, plen, causal, window, chunk, local_on):
+    """The KV blocks ``[first, last)`` (of ``bk`` keys, ``nk`` of them, the
+    first ``plen`` keys real) that a q block of ``bq`` rows walks, its first
+    row at absolute position ``q0``: all that can hold a visible key. None
+    past the valid length (the wrappers' zero padding lies there), none
+    wholly above the diagonal (``causal``: the keys' positions are the rows'
+    own axis), none wholly before the earliest key a binding window/chunk
+    lets the FIRST row see (later rows only look further right).
+
+    One source for the three kernels' loop bounds (``xp`` = jnp, traced
+    scalars; the decode kernel's one query is a block of one row) and the
+    host's count of the scoring kernels' steps (``xp`` = numpy, ``q0`` an
+    array over q blocks): ``causal_steps`` / ``prefix_shared_steps``."""
+    last = xp.minimum(_cdiv(plen, bk), nk)
+    if causal:
+        last = xp.minimum(last, _cdiv(q0 + bq, bk))
     if window is not None:
-        first_vis = jnp.maximum(first_q_pos - window + 1, 0)
+        first = xp.maximum(q0 - window + 1, 0) // bk
+    elif chunk is not None:
+        first = (q0 // chunk) * chunk // bk
     else:
-        first_vis = (first_q_pos // chunk) * chunk
-    return jnp.where(local_on, first_vis // bk, 0)
+        return 0 * last, last
+    return xp.minimum(xp.where(local_on, first, 0), last), last
+
+
+def causal_steps(
+    lq: int, lk: int, hd: int, dv: int, valid_len: int,
+    window: int | None = None, chunk: int | None = None, local_on: bool = True,
+) -> int:
+    """Steps of the online softmax that ONE query head of a
+    ``flash_causal_attention`` call runs: the trips of its programs' loops,
+    by the wrapper's own tiles and the kernel's own bounds. A host count
+    (numpy over the q blocks): no step is run to count it."""
+    bq, bk = flash_tiles(lq, lk, hd, dv, window, chunk)
+    q0 = np.arange(_cdiv(lq, bq)) * bq
+    first, last = _kv_bounds(
+        np, q0, bq, bk, _cdiv(lk, bk), valid_len, True, window, chunk, local_on
+    )
+    return int((last - first).sum())
+
+
+def prefix_shared_steps(
+    ls: int, lp: int, hd: int, dv: int, prefix_len: int,
+    window: int | None = None, chunk: int | None = None, local_on: bool = True,
+) -> int:
+    """As ``causal_steps``, for one query head and ONE suffix of a
+    ``flash_prefix_shared_attention`` call: the prefix walk of each of its q
+    blocks and the one step over the suffix's own keys."""
+    bq, bkp = flash_tiles(ls, lp, hd, dv, window, chunk)
+    q0 = prefix_len + np.arange(_cdiv(ls, bq)) * bq
+    first, last = _kv_bounds(
+        np, q0, bq, bkp, _cdiv(lp, bkp), prefix_len, False, window, chunk,
+        local_on,
+    )
+    return int((last - first + 1).sum())
 
 
 # ---------------------------------------------------------------------------
@@ -197,26 +323,23 @@ def _causal_kernel(
     )
 
     def body(blk, carry):
-        m, l, acc = carry
-        start = blk * bk
+        start = pl.multiple_of(blk * bk, bk)
         kb = k_ref[0, pl.ds(start, bk), :]
         vb = v_ref[0, pl.ds(start, bk), :]
         kj = start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         mask = _local_mask(
             (kj <= qi) & (kj < plen), qi, kj, window, chunk, local_on
         )
-        return _online_block(q, kb, vb, mask, m, l, acc, scale, softcap)
+        return _online_block(q, kb, vb, mask, *carry, scale, softcap)
 
     # Causal: KV blocks wholly above this q block's diagonal contribute
     # nothing, and neither do blocks past the valid length (every key there
-    # has kj >= plen) — stop at whichever bound comes first. A binding local
-    # form also skips blocks wholly before the window/chunk.
-    causal_last = ((qb + 1) * bq + bk - 1) // bk
-    valid_last = (plen + bk - 1) // bk
-    last = jnp.minimum(jnp.minimum(causal_last, valid_last), lk // bk)
-    first = jnp.int32(0)
-    if window is not None or chunk is not None:
-        first = _local_start_block(qb * bq, window, chunk, bk, local_on)
+    # has kj >= plen, the wrapper's zero padding among them) — stop at
+    # whichever bound comes first. A binding local form also skips blocks
+    # wholly before the window/chunk.
+    first, last = _kv_bounds(
+        jnp, qb * bq, bq, bk, lk // bk, plen, True, window, chunk, local_on
+    )
     m, l, acc = jax.lax.fori_loop(first, last, body, (m, l, acc))
     o_ref[0] = _finish(l, acc, o_ref.dtype)
 
@@ -270,14 +393,21 @@ def flash_causal_attention(
     (q, k), _ = _pad_head_dim(q, k)
     (v,), dv_true = _pad_head_dim(v)
     hd, dv = q.shape[-1], v.shape[-1]
-    bq = _block(lq, _MAX_BLOCK_Q)
-    bk = _block(lk, _MAX_BLOCK_K)
-    grid = (n_q, lq // bq)
+    # Head-major, and the length axes zero-padded up to the tiles: padded
+    # keys sit at kj >= lk >= valid_len, which the loop bounds and the mask
+    # exclude (their V rows are zeros, not garbage: a masked p = 0 times a
+    # NaN would be a NaN in the PV matmul); padded query rows are sliced off.
+    bq, bk = flash_tiles(lq, lk, hd, dv, window, chunk)
+    q = _pad_dim(q.transpose(1, 0, 2), 1, bq)
+    k = _pad_dim(k.transpose(1, 0, 2), 1, bk)
+    v = _pad_dim(v.transpose(1, 0, 2), 1, bk)
+    lqp, lkp = q.shape[1], k.shape[1]
+    grid = (n_q, lqp // bq)
     kv_head = lambda h, qb, *_: (h * n_kv // n_q, 0, 0)
     prefetch = _prefetch(_flags(valid_len, local_on), sink)
 
     kernel = functools.partial(
-        _causal_kernel, scale=scale, lk=lk, bk=bk, window=window, chunk=chunk,
+        _causal_kernel, scale=scale, lk=lkp, bk=bk, window=window, chunk=chunk,
         softcap=softcap, has_sink=sink is not None,
     )
     # Named three times over. The TPU names the HLO instruction after the
@@ -296,22 +426,17 @@ def flash_causal_attention(
                 grid=grid,
                 in_specs=[
                     pl.BlockSpec((1, bq, hd), lambda h, qb, *_: (h, qb, 0)),
-                    pl.BlockSpec((1, lk, hd), kv_head),
-                    pl.BlockSpec((1, lk, dv), kv_head),
+                    pl.BlockSpec((1, lkp, hd), kv_head),
+                    pl.BlockSpec((1, lkp, dv), kv_head),
                 ],
                 out_specs=pl.BlockSpec((1, bq, dv), lambda h, qb, *_: (h, qb, 0)),
             ),
-            out_shape=jax.ShapeDtypeStruct((n_q, lq, dv), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((n_q, lqp, dv), q.dtype),
             interpret=interpret,
             name="flash_causal_attention",
             metadata={"kernel": "flash_causal_attention"},
-        )(
-            *prefetch,
-            q.transpose(1, 0, 2),
-            k.transpose(1, 0, 2),
-            v.transpose(1, 0, 2),
-        )
-    return out.transpose(1, 0, 2)[..., :dv_true]
+        )(*prefetch, q, k, v)
+    return out[:, :lq, :dv_true].transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -344,8 +469,7 @@ def _prefix_shared_kernel(
 
     # Prefix KV: visible iff the key is real (j < plen); no causality.
     def p_body(blk, carry):
-        m, l, acc = carry
-        start = blk * bkp
+        start = pl.multiple_of(blk * bkp, bkp)
         kb = kp_ref[0, pl.ds(start, bkp), :]
         vb = vp_ref[0, pl.ds(start, bkp), :]
         kj = start + jax.lax.broadcasted_iota(jnp.int32, (1, bkp), 1)
@@ -353,17 +477,17 @@ def _prefix_shared_kernel(
             jnp.broadcast_to(kj < plen, (bq, bkp)), q_abs, kj, window, chunk,
             local_on,
         )
-        return _online_block(q, kb, vb, mask, m, l, acc, scale, softcap)
+        return _online_block(q, kb, vb, mask, *carry, scale, softcap)
 
-    # Blocks past the real prefix are fully masked — skip them; with a
-    # binding local form, so are blocks wholly before the earliest visible
-    # key of this q block's FIRST query.
-    n_real = jnp.minimum((plen + bkp - 1) // bkp, lp // bkp)
-    first = jnp.int32(0)
-    if window is not None or chunk is not None:
-        first = _local_start_block(plen + qb * bq, window, chunk, bkp, local_on)
-        first = jnp.minimum(first, n_real)
-    m, l, acc = jax.lax.fori_loop(first, n_real, p_body, (m, l, acc))
+    # Blocks past the real prefix are fully masked (the wrapper's zero
+    # padding among them) — skip them; with a binding local form, so are
+    # blocks wholly before the earliest visible key of this q block's FIRST
+    # query.
+    first, last = _kv_bounds(
+        jnp, plen + qb * bq, bq, bkp, lp // bkp, plen, False, window, chunk,
+        local_on,
+    )
+    m, l, acc = jax.lax.fori_loop(first, last, p_body, (m, l, acc))
 
     # Own suffix KV: causal within the suffix (distance (plen+qi)-(plen+kj)
     # = qi-kj, so the window clause needs no plen; the chunk clause does).
@@ -407,16 +531,22 @@ def flash_prefix_shared_attention(
     (q, k_prefix, k_suffix), _ = _pad_head_dim(q, k_prefix, k_suffix)
     (v_prefix, v_suffix), dv_true = _pad_head_dim(v_prefix, v_suffix)
     hd, dv = q.shape[-1], v_prefix.shape[-1]
-    bq = _block(ls, _MAX_BLOCK_Q)
-    bkp = _block(lp, _MAX_BLOCK_K)
-    grid = (s, n_q, ls // bq)
+    # Head-major; the suffix rows and the prefix's length zero-padded up to
+    # the tiles, as in flash_causal_attention (padded prefix keys sit at
+    # kj >= lp >= prefix_len). A suffix's own keys stay whole: one step.
+    bq, bkp = flash_tiles(ls, lp, hd, dv, window, chunk)
+    q = _pad_dim(q.transpose(0, 2, 1, 3), 2, bq)
+    k_prefix = _pad_dim(k_prefix.transpose(1, 0, 2), 1, bkp)
+    v_prefix = _pad_dim(v_prefix.transpose(1, 0, 2), 1, bkp)
+    lsp, lpp = q.shape[2], k_prefix.shape[1]
+    grid = (s, n_q, lsp // bq)
     kv_head = lambda si, h, qb, *_: (h * n_kv // n_q, 0, 0)
     skv_head = lambda si, h, qb, *_: (si, h * n_kv // n_q, 0, 0)
     q_map = lambda si, h, qb, *_: (si, h, qb, 0)
     prefetch = _prefetch(_flags(prefix_len, local_on), sink)
 
     kernel = functools.partial(
-        _prefix_shared_kernel, scale=scale, lp=lp, bkp=bkp, window=window,
+        _prefix_shared_kernel, scale=scale, lp=lpp, bkp=bkp, window=window,
         chunk=chunk, softcap=softcap, has_sink=sink is not None,
     )
     with jax.named_scope("flash_prefix_shared_attention"):
@@ -427,26 +557,26 @@ def flash_prefix_shared_attention(
                 grid=grid,
                 in_specs=[
                     pl.BlockSpec((1, 1, bq, hd), q_map),
-                    pl.BlockSpec((1, lp, hd), kv_head),
-                    pl.BlockSpec((1, lp, dv), kv_head),
+                    pl.BlockSpec((1, lpp, hd), kv_head),
+                    pl.BlockSpec((1, lpp, dv), kv_head),
                     pl.BlockSpec((1, 1, ls, hd), skv_head),
                     pl.BlockSpec((1, 1, ls, dv), skv_head),
                 ],
                 out_specs=pl.BlockSpec((1, 1, bq, dv), q_map),
             ),
-            out_shape=jax.ShapeDtypeStruct((s, n_q, ls, dv), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((s, n_q, lsp, dv), q.dtype),
             interpret=interpret,
             name="flash_prefix_shared_attention",
             metadata={"kernel": "flash_prefix_shared_attention"},
         )(
             *prefetch,
-            q.transpose(0, 2, 1, 3),
-            k_prefix.transpose(1, 0, 2),
-            v_prefix.transpose(1, 0, 2),
+            q,
+            k_prefix,
+            v_prefix,
             k_suffix.transpose(0, 2, 1, 3),
             v_suffix.transpose(0, 2, 1, 3),
         )
-    return out.transpose(0, 2, 1, 3)[..., :dv_true]
+    return out[:, :, :ls, :dv_true].transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -497,12 +627,9 @@ def _decode_kernel(
         )
         return _online_block(q, kb, vb, mask, m, l, acc, scale, softcap)
 
-    n_real = jnp.minimum((plen + bkp - 1) // bkp, lp // bkp)
-    first = jnp.int32(0)
-    if window is not None or chunk is not None:
-        first = jnp.minimum(
-            _local_start_block(q_abs, window, chunk, bkp, local_on), n_real
-        )
+    first, n_real = _kv_bounds(
+        jnp, q_abs, 1, bkp, lp // bkp, plen, False, window, chunk, local_on
+    )
     m, l, acc = jax.lax.fori_loop(first, n_real, p_body, (m, l, acc))
 
     # Own suffix KV: keys j <= eos; absolute position plen + j.
@@ -545,15 +672,6 @@ def supports_decode(
         and head_dim % 128 == 0
         and (v_dim is None or v_dim % 128 == 0)
     )
-
-
-def _pad_dim(a, axis: int, mult: int):
-    p = (-a.shape[axis]) % mult
-    if not p:
-        return a
-    pad = [(0, 0)] * a.ndim
-    pad[axis] = (0, p)
-    return jnp.pad(a, pad)
 
 
 @functools.partial(
@@ -657,6 +775,9 @@ __all__ = [
     "flash_causal_attention",
     "flash_prefix_shared_attention",
     "flash_decode_attention",
+    "flash_tiles",
+    "causal_steps",
+    "prefix_shared_steps",
     "supports",
     "supports_decode",
 ]

@@ -24,6 +24,7 @@ TPU-first redesign (SURVEY.md §7):
 
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 import threading
@@ -55,6 +56,7 @@ from flexible_llm_sharding_tpu.obs import events as obs_events
 from flexible_llm_sharding_tpu.obs import trace as obs_trace
 from flexible_llm_sharding_tpu.obs.registry import REGISTRY as _OBS_REGISTRY
 from flexible_llm_sharding_tpu.obs.registry import describe as _describe_gauges
+from flexible_llm_sharding_tpu.ops import pallas_attention
 from flexible_llm_sharding_tpu.parallel.planner import (
     ShardPlan,
     ShardVisit,
@@ -533,6 +535,48 @@ def _linear_rows(
     )
 
 
+def _flash_steps(
+    model: LlamaConfig, layer_idxs, n_layers: int, block_shapes, use_pallas: bool,
+    tp_mesh,
+) -> int:
+    """Steps of the online softmax that the two scoring flash kernels run
+    when a shard's decoder layers (``layer_idxs``: indices into the
+    execution list) pass over a batch's blocks (``block_shapes``: per block
+    ``(Lp, S, Ls, the prompts' true prefix lengths)``): every query head's
+    loop trips, by the tiles the wrappers pick and the bounds the kernels
+    walk (``ops.pallas_attention.causal_steps`` / ``prefix_shared_steps``).
+    A host count from the shapes, as ``_expert_rows``; 0 where the layers
+    run the XLA ops."""
+    return sum(
+        _layer_flash_steps(model, i - 1, *block, use_pallas, tp_mesh)
+        for i in layer_idxs
+        if 0 < i < n_layers - 2
+        for block in block_shapes
+    )
+
+
+@functools.lru_cache(maxsize=4096)
+def _layer_flash_steps(
+    model: LlamaConfig, layer: int, lp: int, s: int, ls: int, prefix_lens,
+    use_pallas: bool, tp_mesh,
+) -> int:
+    """``_flash_steps`` of one decoder layer over one block: a causal call a
+    prompt and a prefix-shared call over its ``s`` suffixes (the bucket's:
+    padding suffixes run too). Cached: a sweep asks the same few (layer
+    kind, block) pairs once a layer visit."""
+    plan = llama.layer_attention_plan(model, layer, lp, ls, use_pallas, tp_mesh)
+    if plan is None or not plan[0]:
+        return 0
+    _, (n_q, _, hd, vd), window, chunk, local_on = plan
+    return n_q * sum(
+        pallas_attention.causal_steps(lp, lp, hd, vd, n, window, chunk, local_on)
+        + s * pallas_attention.prefix_shared_steps(
+            ls, lp, hd, vd, n, window, chunk, local_on
+        )
+        for n in prefix_lens
+    )
+
+
 # ---------------------------------------------------------------------------
 # Shard weight source (sync or prefetching)
 # ---------------------------------------------------------------------------
@@ -659,6 +703,10 @@ class SweepClock:
         # state one dispatch held: host counts from the shapes.
         self.linear_rows = {"kernel": 0, "xla": 0}
         self.linear_state_bytes = 0
+        # Steps of the online softmax the two scoring flash kernels run
+        # (``_flash_steps``): a host count from the shapes and the prompts'
+        # prefix lengths.
+        self.flash_steps = 0
         # A looped model: decoder-layer visits the consumer took (a count of
         # the plan's indices, on the host), and per block the gate's
         # expected exit step summed over its real scored rows (device
@@ -755,6 +803,7 @@ class SweepClock:
                 linear_rows_kernel=self.linear_rows["kernel"],
                 linear_rows_xla=self.linear_rows["xla"],
                 linear_state_bytes=self.linear_state_bytes,
+                flash_steps=self.flash_steps,
             )
             if self.exit_sums:
                 rec["exit_step_mean"] = float(
@@ -887,6 +936,11 @@ SWEEP_RECORD_HELP = {
     "attention layers held: prompts in the block x heads x qk dim x v dim x "
     "4 bytes (float32), inside the layer call; it never enters the "
     "activation store.",
+    "flash_steps": "Steps of the online softmax the two scoring flash "
+    "kernels ran this sweep (every query head's loop trips over key tiles, "
+    "a suffix's own keys one step): a host count from the shapes, the "
+    "prompts' prefix lengths and the tiles ops/pallas_attention.flash_tiles "
+    "picks; the kernels' device seconds over it is the cost of a step.",
 }
 _describe_gauges(
     "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}
@@ -3000,6 +3054,16 @@ class StreamingExecutor:
     ) -> None:
         n_layers = len(self.layer_names)
         visits = self.plan.visits()
+        # Per block (Lp, S, Ls, the prompts' true prefix lengths): what the
+        # flash kernels' step count is made from (``_flash_steps``).
+        block_shapes = [
+            (
+                *toks[idxs[0]].prefix_ids.shape,
+                *toks[idxs[0]].suffix_ids.shape,
+                tuple(toks[i].prefix_len for i in idxs),
+            )
+            for idxs in blocks
+        ]
         total = (n_shards or len(self.plan.shards)) * max(len(blocks), 1)
         bar = metrics.progress_bar(total, desc="stream", unit="blk")
         it = enumerate(source)
@@ -3056,6 +3120,10 @@ class StreamingExecutor:
                 clock.shard_idx = shard_idx
                 visit = visits[shard_idx]
                 clock.layer_visits += decoder_visits(layer_idxs, n_layers)
+                clock.flash_steps += _flash_steps(
+                    self.model_cfg, layer_idxs, n_layers, block_shapes,
+                    self._use_pallas, self._tp_mesh,
+                )
                 # The embedding rides the first step's span, the head (whose
                 # shard starts after the last norm) the last step's.
                 step = min(visit.step, self.plan.loop_steps - 1)

@@ -295,6 +295,10 @@ def test_shards_that_run_over_a_step_s_end(model_dir, layers_per_shard, use_pall
     assert len(log) == n0 + 1  # one record a batch, not one a step
     assert (log[-1]["loop_steps"], log[-1]["layer_visits"]) == (4, 12)
     assert log[-1]["full_layers"] == 3 and log[-1]["window_layers"] == 0
+    # The flash kernels' steps are counted a visit, not a layer: twelve equal
+    # shares however the visits fall into shards; none where the XLA ops run.
+    steps = log[-1]["flash_steps"]
+    assert (steps > 0) == use_pallas and steps % 12 == 0
 
 
 @pytest.mark.parametrize("q", [1.0, 0.5, 0.7, 0.05])

@@ -79,8 +79,11 @@ def _compile(fn, *args):
 
 # (n_q, n_kv, qk head dim, v head dim): Llama-3-8B, Llama-3-70B, MLA
 # (DeepSeek-V2/V3, Moonlight: qk 128+64 rope, v 128; kanana-2 the same at 32
-# heads), MiMo-V2-Flash's full and window layers (qk 192, v 128, no MLA).
+# heads), MiMo-V2-Flash's full and window layers (qk 192, v 128, no MLA),
+# Ouro-2.6B (plain multi-head) and MiniCPM-SALA's softmax layers (16:1).
 WIDTHS = {
+    "ouro": (16, 16, 128, 128),
+    "minicpm_sala": (32, 2, 128, 128),
     "llama3_8b": (32, 8, 128, 128),
     "llama3_70b": (64, 8, 128, 128),
     "mla": (16, 16, 192, 128),
@@ -91,8 +94,12 @@ WIDTHS = {
 
 
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
-@pytest.mark.parametrize("length", [512, 4096])
+@pytest.mark.parametrize("length", [512, 3392, 4096])
 def test_causal_kernel_compiles(one_chip, widths, length):
+    """512 and 4096 are whole tiles; 3392 (the long cells' largest bucket) is
+    zero-padded to 14 query tiles of 256 and 7 key tiles of 512 inside the
+    wrapper. K and V of a KV head stay whole in VMEM beside the 256 x 512
+    score tile: an overrun shows here, not first on the chip."""
     n_q, n_kv, hd, dv = WIDTHS[widths]
     s = functools.partial(_sds, one_chip)
     fn = functools.partial(pa.flash_causal_attention, interpret=False)
@@ -105,10 +112,11 @@ def test_causal_kernel_compiles(one_chip, widths, length):
 
 
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
-def test_prefix_shared_kernel_compiles(one_chip, widths):
+@pytest.mark.parametrize("lp", [3392, 4096])
+def test_prefix_shared_kernel_compiles(one_chip, widths, lp):
     n_q, n_kv, hd, dv = WIDTHS[widths]
     s = functools.partial(_sds, one_chip)
-    lp, ns, ls = 4096, 4, 64
+    ns, ls = 4, 64
     fn = functools.partial(pa.flash_prefix_shared_attention, interpret=False)
     _, text = _compile(
         jax.jit(fn),
@@ -176,12 +184,14 @@ def _mimo_cfg():
 
 
 @pytest.mark.parametrize("layer", [1, 5])
-def test_mimo_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, layer):
+@pytest.mark.parametrize("length", [3392, 4096])
+def test_mimo_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, layer, length):
     """One expert layer of each attention kind (layer 1 window, layer 5 full)
     as the benchmark's cell runs it: published widths, 16 of 256 experts
-    held, the longest prefix bucket, one prompt a block, the expert counts
-    out. Both kernels in the program, and the step beside four 1 GB shards
-    in flight and ~11 GB of pins inside the chip."""
+    held, the cell's longest prefix bucket (3392: the flash kernels pad it
+    to their tiles) and the longest the path admits (4096), one prompt a
+    block, the expert counts out. Both kernels in the program, and the step
+    beside four 1 GB shards in flight and ~11 GB of pins inside the chip."""
     cfg = _mimo_cfg()
     sliding = cfg.layer_sliding[layer]
     shapes = jax.eval_shape(
@@ -201,7 +211,7 @@ def test_mimo_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, la
     }
     compiled, text = _compile(
         executor._decoder_block,
-        cfg, seg, s((1, 3392, 4096)), s((1, 4, 64, 4096)), s((1,), jnp.int32), True,
+        cfg, seg, s((1, length, 4096)), s((1, 4, 64, 4096)), s((1,), jnp.int32), True,
         None, None, True,
     )
     assert text.count("tpu_custom_call") >= 2
@@ -411,14 +421,15 @@ def _sala_cfg():
     return LlamaConfig.from_hf_config(weights.hf_config(model))
 
 
-@pytest.mark.parametrize("linear", [True, False])
-def test_sala_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, linear):
+@pytest.mark.parametrize("linear,length", [(True, 3392), (False, 3392), (False, 4096)])
+def test_sala_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, linear, length):
     """A run of three linear layers and one softmax layer as the benchmark's
     cell runs them: published widths, the longest prefix bucket, one prompt a
     block. The linear run carries the lightning kernel (a prefix call and a
     suffix call) and no flash kernel; the softmax layer the two flash kernels
-    at 32 query heads over 2 KV heads. Both beside ~11 GB of pins and four
-    0.57 GB shards in flight inside the chip."""
+    at 32 query heads over 2 KV heads, also at the longest bucket the path
+    admits (4096). Both beside ~11 GB of pins and four 0.57 GB shards in
+    flight inside the chip."""
     cfg = _sala_cfg()
     k = 3 if linear else 1
     shapes = jax.eval_shape(
@@ -433,7 +444,7 @@ def test_sala_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, li
     }
     compiled, text = _compile(
         executor._decoder_block,
-        cfg, seg, s((1, 3392, 4096)), s((1, 4, 64, 4096)), s((1,), jnp.int32), True,
+        cfg, seg, s((1, length, 4096)), s((1, 4, 64, 4096)), s((1,), jnp.int32), True,
     )
     assert ("lightning_attention" in text) == linear
     assert ("flash_causal_attention" in text) != linear
@@ -453,18 +464,21 @@ def _ouro_cfg():
     return LlamaConfig.from_hf_config(weights.hf_config(model))
 
 
-@pytest.mark.parametrize("step", ["decoder", "loop_norm", "exit"])
-def test_ouro_steps_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, step):
+@pytest.mark.parametrize(
+    "step,length",
+    [("decoder", 3392), ("decoder", 4096), ("loop_norm", 3392), ("exit", 3392)],
+)
+def test_ouro_steps_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, step, length):
     """What a looped model's batch dispatches, at Ouro-2.6B's published widths
     and the cell's longest bucket, one prompt a block: a decoder layer with
     its four norms and the two flash kernels at 16 heads over 16 KV heads of
-    128; the step end before the last (the final norm over every row and the
+    128 (also at 4096, the longest bucket the path admits); the step end before the last (the final norm over every row and the
     gate on the scored rows: one program for every step, the step is traced);
     the last step's exit."""
     cfg = _ouro_cfg()
     assert cfg.total_ut_steps == 4 and cfg.ffw_sandwich_norms and not cfg.norm_unit_offset
     s = functools.partial(_sds, one_chip)
-    prefix, suffix = s((1, 3392, 2048)), s((1, 4, 64, 2048))
+    prefix, suffix = s((1, length, 2048)), s((1, 4, 64, 2048))
     norm = {"scale": s((2048,)), "gate": {"kernel": s((2048, 1)), "bias": s((1,))}}
     state = (s((1, 4), jnp.float32),) * 3 + (s((1, 4, 1, 2048)),)
     if step == "decoder":
