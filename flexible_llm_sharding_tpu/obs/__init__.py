@@ -12,7 +12,7 @@ hot-path modules can depend on it without cycles):
   counters register into, with Prometheus text exposition and an
   optional HTTP endpoint (the serve engine's ``--metrics_port``).
 - ``obs.report``   the trace analyzer behind ``cli trace-report``:
-  link utilization, compute/stream overlap efficiency, per-phase sweep
+  link utilization, why the device stood idle between shards, per-phase sweep
   breakdown, TTFT / per-token latency quantiles — plus the
   incident-bundle analyzer behind ``cli incidents``.
 - ``obs.events``   the black-box flight recorder's durable append-only

@@ -77,6 +77,8 @@ EVENT_KINDS = {
     "reread_heal": "warning",        # checksum mismatch healed by re-read
     "quarantine": "critical",        # on-disk corruption; path quarantined
     "spill_recompute": "warning",    # spill corrupt; block recomputed
+    # the sweep's account (runtime/executor.py)
+    "slow_sweep": "warning",         # a sweep's wall far over its peers'
     # resource pressure (runtime/pressure.py)
     "pressure_step": "warning",      # brownout ladder moved up or down
     "pressure_event": "error",       # hard resource event (OOM / ENOSPC)

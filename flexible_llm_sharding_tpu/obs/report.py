@@ -11,11 +11,17 @@ JSONL — both are auto-detected). Output:
   driven. ``upload_dispatch`` is the ``device_put`` CALL, which returns at
   the enqueue, and host builds (``shard_load``) move nothing over the
   link: neither counts.
-- **overlap efficiency**: ``1 - source_wait / shard_produce`` — the
-  fraction of weight-produce time hidden under compute, the stats
-  line's definition, computable from any run's trace after the fact.
-  Both terms are host time around asynchronous dispatch: what the link
-  and the device did is the sweep account's (``process_sweep_log``).
+- **idle between shards**: why the device stood idle from one shard to
+  the next, by the host's own stamps (``utils.intervals.idle_split``, the
+  sweep record's three fields computed again from the export): *drained*
+  (a shard-end ``device_wait`` returned and the next shard's first block
+  was not dispatched yet), *waiting for its own weights* (a shard launched
+  before its own ``upload`` had arrived) and *launched behind another
+  shard's upload* (its own weights were there, a transfer enqueued before
+  the launch was not done). Each in seconds and as a share of the sweeps'
+  wall. Needs the ``compute`` spans' ``launch_s`` attribute and the
+  ``upload`` spans (a one-pass scoring sweep; a serve engine's trace has
+  neither and the figure is left out).
 - **per-phase sweep breakdown**: total seconds per span name, plus the
   per-sweep phase profile (grouped by ``sweep_id``) showing where a
   sweep's wall goes.
@@ -33,14 +39,53 @@ import json
 import os
 import sys
 
-from flexible_llm_sharding_tpu.utils.intervals import union_seconds
+from flexible_llm_sharding_tpu.utils.intervals import idle_split, union_seconds
 
 # The span whose intervals are "the link carries bytes": one per streamed
-# shard, dispatch -> arrival. shard_produce (the producer's whole per-shard
-# wall) is overlap efficiency's produce denominator.
+# shard, dispatch -> arrival.
 UPLOAD_SPAN = "upload"
-PRODUCE_SPAN = "shard_produce"
-WAIT_SPAN = "source_wait"
+IDLE_KEYS = ("drained_s", "own_upload_wait_s", "behind_upload_s")
+
+
+def _idle_between_shards(spans: list[dict]) -> dict | None:
+    """The sweep record's three idle figures from an export's spans, summed
+    over the sweeps that carry what they need; None when none does."""
+    sweeps: dict[int, dict] = {}
+    for s in spans:
+        sid, name = s.get("sweep_id"), s["name"]
+        if sid is None or name not in ("sweep", "compute", "device_wait", UPLOAD_SPAN):
+            continue
+        d = sweeps.setdefault(
+            int(sid),
+            {"end": None, "rows": (), "compute": [], "wait": {}, "uploads": {}},
+        )
+        end = s["ts_s"] + s["dur_s"]
+        if name == "sweep":
+            d["end"] = end
+            d["rows"] = tuple(
+                float(x) for x in str(s.get("block_rows", "")).split(",") if x
+            )
+        elif name == "compute" and "launch_s" in s:
+            d["compute"].append((s["ts_s"], s.get("shard_idx"), s["launch_s"], end))
+        elif name == "device_wait" and s.get("at") == "shard_end":
+            d["wait"][s.get("shard_idx")] = (s["ts_s"], end)
+        elif name == UPLOAD_SPAN:
+            d["uploads"][s.get("shard_idx")] = (s["ts_s"], end)
+    totals, wall, n = dict.fromkeys(IDLE_KEYS, 0.0), 0.0, 0
+    for d in sweeps.values():
+        if not d["compute"] or d["end"] is None:
+            continue
+        shards = [
+            (idx, t0 + launch, *d["wait"].get(idx, (t1, None)))
+            for t0, idx, launch, t1 in sorted(d["compute"])
+        ]
+        for row in idle_split(shards, d["uploads"], d["end"], d["rows"]):
+            for key, sec in zip(IDLE_KEYS, row):
+                totals[key] += sec
+        n += 1
+    if not n:
+        return None
+    return {"sweeps": n, **{k: round(v, 6) for k, v in totals.items()}}
 
 
 def _bundle_manifest(path: str) -> tuple[str, dict] | None:
@@ -222,8 +267,6 @@ def analyze(events: list[dict]) -> dict:
             if s["name"] == UPLOAD_SPAN
         ]
     )
-    produce_s = by_name.get(PRODUCE_SPAN, {}).get("total_s", 0.0)
-    wait_s = by_name.get(WAIT_SPAN, {}).get("total_s", 0.0)
 
     # Per-sweep phase profile: spans correlated by sweep_id. The parent
     # "sweep" span is the per-sweep wall, not a phase — reported apart.
@@ -269,12 +312,9 @@ def analyze(events: list[dict]) -> dict:
             ]
         ),
     }
-    if produce_s > 0:
-        report["overlap_efficiency"] = round(
-            max(0.0, min(1.0, (produce_s - wait_s) / produce_s)), 4
-        )
-        report["source_wait_s"] = round(wait_s, 6)
-        report["produce_s"] = round(produce_s, 6)
+    idle = _idle_between_shards(spans)
+    if idle is not None:
+        report["idle_between_shards"] = idle
     drops = [e.get("trace_drops") for e in events if e["name"] == "trace_meta"]
     if drops and drops[-1] is not None:
         report["trace_drops"] = int(drops[-1])
@@ -282,7 +322,7 @@ def analyze(events: list[dict]) -> dict:
     for name in (
         "reread_heal", "quarantine", "spill_recompute", "io_retry",
         "engine_recovery", "wave_abort", "watchdog_stall", "wave_admit",
-        "request_finish", "hostcache_hit", "hostcache_miss",
+        "request_finish", "hostcache_hit", "hostcache_miss", "slow_sweep",
     ):
         n = sum(1 for e in events if e["name"] == name)
         if n:
@@ -310,12 +350,20 @@ def format_report(report: dict) -> str:
             f"link utilization: not timed (no `{UPLOAD_SPAN}` spans in "
             "this trace)"
         )
-    if "overlap_efficiency" in report:
+    idle = report.get("idle_between_shards")
+    if idle:
+        wall = max(report.get("sweep_wall_s", 0.0), 1e-9)
         lines.append(
-            f"compute/stream overlap efficiency: "
-            f"{report['overlap_efficiency']:.1%} "
-            f"(source_wait {report['source_wait_s']:.3f}s of "
-            f"{report['produce_s']:.3f}s produce)"
+            "device idle between shards, by the host's stamps: "
+            + ", ".join(
+                f"{label} {idle[key]:.3f}s ({idle[key] / wall:.1%})"
+                for key, label in (
+                    ("drained_s", "drained"),
+                    ("own_upload_wait_s", "waiting for own weights"),
+                    ("behind_upload_s", "launched behind another shard's upload"),
+                )
+            )
+            + f" of {wall:.3f}s sweep wall"
         )
     if report.get("sweeps"):
         lines.append(
@@ -463,7 +511,7 @@ def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(
         prog="flexible-llm-sharding-tpu trace-report",
         description="Analyze a --trace recording: link utilization, "
-        "compute/stream overlap efficiency, per-phase sweep breakdown, "
+        "why the device stood idle between shards, per-phase sweep breakdown, "
         "TTFT and per-token latency quantiles.",
     )
     p.add_argument("--trace", type=str, required=True,

@@ -25,7 +25,9 @@ TPU-first redesign (SURVEY.md §7):
 from __future__ import annotations
 
 import functools
+import gc
 import itertools
+import logging
 import os
 import threading
 import time
@@ -79,7 +81,7 @@ from flexible_llm_sharding_tpu.runtime.tokenization import (
 )
 from flexible_llm_sharding_tpu.runtime import resume
 from flexible_llm_sharding_tpu.utils import checkpoint, metrics
-from flexible_llm_sharding_tpu.utils.intervals import union_seconds
+from flexible_llm_sharding_tpu.utils.intervals import idle_split, union_seconds
 
 Params = dict[str, Any]
 
@@ -643,6 +645,49 @@ def process_sweep_log() -> list[dict]:
         return [dict(r) for r in _SWEEP_LOG]
 
 
+# The sweeps that stalled (SweepClock.finish marks them ``slow``), newest
+# last: each one's record with its per-shard table, which every other sweep
+# drops. ``_SLOW_SWEEPS_SEEN`` counts them over the process's life.
+_SLOW_SWEEPS: deque = deque(maxlen=8)  # guarded by: _SWEEP_LOG_LOCK
+_SLOW_SWEEPS_SEEN = [0]  # guarded by: _SWEEP_LOG_LOCK
+# A sweep is slow over SLOW_X times the median wall of the log's preceding
+# records of the same plan AND SLOW_OVER_S seconds over it, once the log
+# holds SLOW_MIN_PEERS of them (a compiling first sweep is never slow).
+SLOW_X, SLOW_OVER_S, SLOW_MIN_PEERS = 1.5, 0.5, 5
+_LOG = logging.getLogger(__name__)
+
+
+def process_slow_sweeps() -> list[dict]:
+    """The last sweeps marked ``slow`` (at most 8), oldest first: the
+    sweep's record, the median record it was held against (``median``),
+    the phase and shard with the largest excess (``worst_phase``,
+    ``worst_shard``) and its per-shard table (``shards``)."""
+    with _SWEEP_LOG_LOCK:
+        return [dict(r) for r in _SLOW_SWEEPS]
+
+
+# Python's collector, as one hook sees it: [seconds inside generation-2
+# collections, their count, the running one's start]. Registered by the
+# process's first SweepClock; a sweep reads the first two before and after.
+_GC_SEEN = [0.0, 0, 0.0]
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _GC_SEEN[2] = time.perf_counter()
+    else:
+        _GC_SEEN[0] += time.perf_counter() - _GC_SEEN[2]
+        _GC_SEEN[1] += 1
+
+
+def _gc_seen() -> tuple[float, int]:
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    return _GC_SEEN[0], _GC_SEEN[1]
+
+
 def stream_stats() -> dict[str, float]:
     """The process-wide stream counters as ONE registry source — shared
     by the process registry here and the serve engine's per-engine
@@ -656,9 +701,29 @@ def stream_stats() -> dict[str, float]:
     }
     with _SWEEP_LOG_LOCK:
         last = _SWEEP_LOG[-1] if _SWEEP_LOG else None
+        out["slow_sweeps"] = _SLOW_SWEEPS_SEEN[0]
     if last is not None:
         out.update({f"last_sweep_{k}": v for k, v in last.items()})
     return out
+
+
+class ShardStamps:
+    """One shard on the consumer's clock (``perf_counter``): ``t_take``
+    (the ``source_wait`` span's end: the shard is the consumer's),
+    ``t_launch`` (its first block's steps are enqueued: one read of the
+    clock, the only one the timeline adds), ``t_wait`` / ``t_ready`` (the
+    shard-end ``device_wait`` span's two ends; None for a shard that has
+    no such wait) and ``t_end`` (the ``compute`` span's end)."""
+
+    __slots__ = ("shard_idx", "source_wait_s", "t_take", "t_launch",
+                 "t_wait", "t_ready", "t_end")
+
+    def __init__(self, shard_idx: int, source_wait_s: float, t_take: float):
+        self.shard_idx, self.source_wait_s, self.t_take = (
+            shard_idx, source_wait_s, t_take
+        )
+        self.t_launch = self.t_wait = self.t_ready = None
+        self.t_end = t_take
 
 
 class SweepClock:
@@ -682,11 +747,24 @@ class SweepClock:
     shard's dispatch: the source's close, the scores' fetch, the store's
     clear). The producer's side comes from the source's own account
     (``ShardWeightSource.account``). One thread opens, drives and finishes
-    a clock: its profiler annotations nest on that thread."""
+    a clock: its profiler annotations nest on that thread.
+
+    Beside the sums the clock keeps the sweep's timeline shard by shard
+    (``ShardStamps``, one a shard the consumer takes): lined up with the
+    source's uploads in ``finish()`` they say why the device stood idle
+    between shards (``utils.intervals.idle_split``: ``drained_s``,
+    ``own_upload_wait_s``, ``behind_upload_s``), and a sweep that stalls
+    keeps them as its per-shard table (``process_slow_sweeps``); every
+    other sweep drops them with its clock."""
 
     def __init__(self):
         self.sweep_id = obs_trace.new_sweep_id()
         self.shard_idx = -1  # the shard the consumer is on
+        self.shards: list[ShardStamps] = []  # one a shard taken, in order
+        # Rows of each block a shard is dispatched over (the pass's blocks,
+        # in order): what idle_split weighs a block's device time by.
+        self.block_rows: tuple[int, ...] = ()
+        self._gc0 = _gc_seen()
         self.source_wait_s = self.compute_s = self.device_wait_s = 0.0
         self.act_fetch_s = self.act_store_s = 0.0
         self.head_s = 0.0
@@ -732,6 +810,36 @@ class SweepClock:
 
     def span_ids(self) -> dict:
         return {"sweep_id": self.sweep_id, "shard_idx": self.shard_idx}
+
+    def set_block_rows(self, rows) -> None:
+        """The rows of each block the pass's shards are dispatched over."""
+        self.block_rows = tuple(rows)
+        self._sweep.attrs["block_rows"] = ",".join(map(str, self.block_rows))
+
+    def take(self, shard_idx: int, wait) -> None:
+        """The consumer has shard ``shard_idx``; ``wait`` is the
+        ``source_wait`` span that ended with it."""
+        self.shard_idx = shard_idx
+        self.source_wait_s += wait.dur_s
+        self.shards.append(
+            ShardStamps(shard_idx, wait.dur_s, wait.t0 + wait.dur_s)
+        )
+
+    def launched(self) -> float:
+        """The current shard's first block is dispatched."""
+        t = self.shards[-1].t_launch = time.perf_counter()
+        return t
+
+    def shard_end_wait(self, wait) -> None:
+        """``wait``: the ``device_wait`` span at the current shard's end."""
+        self.device_wait_s += wait.dur_s
+        stamps = self.shards[-1]
+        stamps.t_wait, stamps.t_ready = wait.t0, wait.t0 + wait.dur_s
+
+    def shard_done(self, compute) -> None:
+        """``compute``: the current shard's ``compute`` span, ended."""
+        self.compute_s += compute.dur_s
+        self.shards[-1].t_end = compute.t0 + compute.dur_s
 
     def end_head(self) -> None:
         """The consumer reaches its first wait for a shard."""
@@ -790,9 +898,11 @@ class SweepClock:
             "act_bytes": act_bytes,
             "act_device_bytes": act_device_bytes,
         }
+        gc_s, gc_n = _gc_seen()
+        rec.update(gc_s=gc_s - self._gc0[0], gc_collections=gc_n - self._gc0[1])
         account = getattr(source, "account", None)
         if account is not None:  # a shared (broadcast) source keeps none
-            rec.update(account(sweep.t0, sweep.t0 + sweep.dur_s))
+            rec.update(account(sweep.t0, sweep.t0 + sweep.dur_s, self))
         if self.model is not None:
             rec.update(_model_account(self.model, self.moe_counts))
             rec.update(
@@ -810,8 +920,110 @@ class SweepClock:
                     jnp.sum(jnp.stack(self.exit_sums))
                 ) / max(self.exit_rows, 1)
         with _SWEEP_LOG_LOCK:
+            peers = sorted(
+                (
+                    r for r in _SWEEP_LOG
+                    if r.get("uploads") == rec.get("uploads")
+                    and r.get("layer_visits") == rec.get("layer_visits")
+                ),
+                key=lambda r: r["wall_s"],
+            )
+            median = peers[len(peers) // 2] if len(peers) >= SLOW_MIN_PEERS else None
+            rec["slow"] = int(
+                median is not None
+                and rec["wall_s"]
+                > max(SLOW_X * median["wall_s"], median["wall_s"] + SLOW_OVER_S)
+            )
             _SWEEP_LOG.append(rec)
+        if rec["slow"]:
+            _keep_slow_sweep(rec, median, self, source, sweep.t0 + sweep.dur_s)
         return rec
+
+
+def _keep_slow_sweep(
+    rec: dict, median: dict, clock: "SweepClock", source, t_end: float
+) -> None:
+    """A sweep stalled: keep its record with its per-shard table (the last
+    8: ``process_slow_sweeps``), count it, and say once, in the journal and
+    the log, which phase and which shard hold the largest excess over the
+    median record ``median``'s share."""
+    phases = ("head_s", "source_wait_s", "dispatch_s", "device_wait_s", "tail_s")
+    worst = max(phases, key=lambda k: rec[k] - median[k])
+    table = (
+        source.shard_table(clock, t_end)
+        if hasattr(source, "shard_table")
+        else _shard_table(clock, t_end, {}, {})  # a shared source times no upload
+    )
+    key = worst if worst in ("source_wait_s", "dispatch_s", "device_wait_s") else None
+    row = max(table, key=lambda r: r[key], default=None) if key else None
+    kept = dict(
+        rec,
+        median={k: median[k] for k in ("sweep_id", "wall_s", *phases)},
+        worst_phase=worst,
+        worst_phase_excess_s=rec[worst] - median[worst],
+        worst_shard=row["shard_idx"] if row else -1,
+        worst_shard_s=row[key] if row else 0.0,
+        # of that shard's seconds, what the host's stamps put down to uploads
+        worst_shard_upload_s=(
+            row["own_upload_wait_s"] + row["behind_upload_s"] if row else 0.0
+        ),
+        shards=table,
+    )
+    with _SWEEP_LOG_LOCK:
+        _SLOW_SWEEPS.append(kept)
+        _SLOW_SWEEPS_SEEN[0] += 1
+    said = {
+        k: kept[k] for k in ("sweep_id", "wall_s", "worst_phase",
+                             "worst_phase_excess_s", "worst_shard",
+                             "worst_shard_s", "worst_shard_upload_s", "gc_s")
+    }
+    obs_trace.instant("slow_sweep", cat="sweep", **said)
+    obs_events.emit("slow_sweep", median_wall_s=median["wall_s"], **said)
+    _LOG.warning(
+        "slow sweep %d: %.3f s against a median of %.3f s; %s is %.3f s over "
+        "the median sweep's, most of it in shard %d (%.3f s, of which %.3f s "
+        "launched ahead of an upload's arrival); gc %.3f s "
+        "(process_slow_sweeps() has the per-shard table)",
+        rec["sweep_id"], rec["wall_s"], median["wall_s"], worst,
+        kept["worst_phase_excess_s"], kept["worst_shard"],
+        kept["worst_shard_s"], kept["worst_shard_upload_s"], rec["gc_s"],
+    )
+
+
+def _shard_table(
+    clock: "SweepClock", t_end: float, uploads: dict, produced: dict
+) -> list[dict]:
+    """A sweep's timeline as one flat dict a shard: the consumer's stamps
+    (``clock.shards``) as durations, the idle split against ``uploads``
+    (``shard_idx -> (t_enqueue, t_done)``) and the producer's seconds for
+    that shard (``produced``: ``shard_idx -> (shard_load_s,
+    upload_dispatch_s)``). ``t_end``: the sweep's end, which bounds the
+    last shard's waits where it has no shard-end wait of its own."""
+    shards = clock.shards
+    split = idle_split(
+        [
+            # the last block is dispatched where the shard-end wait begins
+            (s.shard_idx, s.t_launch, s.t_wait or s.t_end, s.t_ready)
+            for s in shards
+        ],
+        uploads, t_end, clock.block_rows,
+    )
+    table = []
+    for s, (drained, own, behind) in zip(shards, split):
+        wait = s.t_ready - s.t_wait if s.t_ready is not None else 0.0
+        load, put = produced.get(s.shard_idx, (0.0, 0.0))
+        table.append({
+            "shard_idx": s.shard_idx,
+            "source_wait_s": s.source_wait_s,
+            "dispatch_s": s.t_end - s.t_take - wait,
+            "device_wait_s": wait,
+            "drained_s": drained,
+            "own_upload_wait_s": own,
+            "behind_upload_s": behind,
+            "shard_load_s": load,
+            "upload_dispatch_s": put,
+        })
+    return table
 
 
 def _model_account(model: LlamaConfig, moe_counts: list) -> dict:
@@ -856,6 +1068,33 @@ SWEEP_RECORD_HELP = {
     "device- or link-bound (see upload_busy_s), not host-bound.",
     "tail_s": "Consumer: after the last shard's dispatch (source close, "
     "scores to the host, store clear).",
+    "drained_s": "Device idle, by the host's stamps: from each shard-end wait's "
+    "return (the device's last result is on the host, nothing is enqueued "
+    "behind it) to the next shard's first block dispatched; holds the "
+    "source_wait, the store's bookkeeping and that block's host dispatch. "
+    "Summed over drained_shards boundaries.",
+    "drained_shards": "Shard boundaries counted in drained_s (a shard with a "
+    "wait for the device at its end, followed by one that launched).",
+    "own_upload_wait_s": "Device idle, by the host's stamps: shards whose "
+    "first steps were enqueued before their OWN weight upload had arrived, "
+    "from the launch to that arrival (no later than the shard-end wait's "
+    "return); the link's honest turn. 0 for a shard served from the "
+    "residency tier.",
+    "behind_upload_s": "Device idle, by the host's stamps: shards whose own "
+    "weights had arrived but whose launches queued behind ANOTHER shard's "
+    "upload, enqueued before the shard's last block was dispatched and "
+    "arrived before the shard was done: from the first launch (or the own "
+    "arrival, or that enqueue if later, less the blocks dispatched before "
+    "it) to that upload's arrival; what an order of dispatch costs, not the "
+    "link.",
+    "launches_behind_upload": "Shards whose behind_upload_s share is over 1 ms.",
+    "gc_s": "Seconds inside Python's generation-2 collections that ended "
+    "during the sweep, on any thread (one gc.callbacks hook a process).",
+    "gc_collections": "Generation-2 collections that ended during the sweep.",
+    "slow": "1 when the sweep's wall_s is over 1.5 x the median of the log's "
+    "preceding records of the same plan (same uploads and layer_visits, at "
+    "least five) and 0.5 s over it: its per-shard table is then in "
+    "process_slow_sweeps(); 0 otherwise.",
     "act_fetch_s": "Consumer: inside the activation store's fetches "
     "(host->device for a block not kept on the chip), waits included.",
     "act_store_s": "Consumer: inside the activation store's stores "
@@ -944,6 +1183,11 @@ SWEEP_RECORD_HELP = {
 }
 _describe_gauges(
     "stream", {f"last_sweep_{k}": v for k, v in SWEEP_RECORD_HELP.items()}
+)
+_describe_gauges(
+    "stream",
+    {"slow_sweeps": "Sweeps marked slow since the process started (see "
+     "last_sweep_slow); the last 8 keep their per-shard table."},
 )
 
 
@@ -1882,7 +2126,9 @@ class _UploadWatcher:
                 if missed:
                     self.misses += 1
                 else:
-                    self.intervals.append((sp.t0, sp.t0 + sp.dur_s))
+                    self.intervals.append(
+                        (sp.t0, sp.t0 + sp.dur_s, attrs.get("shard_idx", -1))
+                    )
 
     def close(self, timeout_s: float = 10.0) -> None:
         """Finish the queued waits and retire the thread (bounded: a wait
@@ -1897,8 +2143,9 @@ class _UploadWatcher:
             except Empty:  # the thread may take the last item under us
                 break
 
-    def snapshot(self) -> tuple[list[tuple[float, float]], int]:
-        """The completed uploads' intervals and the count of misses."""
+    def snapshot(self) -> tuple[list[tuple[float, float, int]], int]:
+        """The completed uploads' ``(t_dispatch, t_done, shard_idx)`` and the
+        count of misses."""
         with self._lock:
             return list(self.intervals), self.misses
 
@@ -2007,6 +2254,9 @@ class ShardWeightSource:
         self.producer_blocked_s = 0.0
         self.upload_bytes = 0
         self.upload_pinned_bytes = 0  # of upload_bytes, from pinned_host
+        # shard_idx -> (shard_load_s, upload_dispatch_s): the producer's
+        # seconds by shard, for a slow sweep's table (shard_table()).
+        self._produced: dict[int, tuple[float, float]] = {}
         # The completion thread exists where an account reads it: a
         # one-pass source, closed when its sweep's record is written. A
         # cycling source (the serve engine's, which keeps no account and
@@ -2074,22 +2324,59 @@ class ShardWeightSource:
             if self._watcher is not None:
                 self._watcher.close(max(0.0, deadline - time.monotonic()))
 
-    def account(self, t_lo: float, t_hi: float) -> dict:
+    def shard_table(
+        self, clock: "SweepClock", t_end: float, intervals=None
+    ) -> list[dict]:
+        """The consumer's per-shard stamps (``clock.shards``) lined up with
+        this source's uploads (``intervals``: the watcher's snapshot, taken
+        here unless given) and builds: ``_shard_table``'s rows. Read after
+        ``close()``."""
+        if intervals is None:
+            intervals = self._watcher.snapshot()[0] if self._watcher else []
+        uploads = {idx: (a, b) for a, b, idx in intervals}
+        return _shard_table(clock, t_end, uploads, self._produced)
+
+    def account(
+        self, t_lo: float, t_hi: float, clock: "SweepClock | None" = None
+    ) -> dict:
         """The producer's side of a sweep's account over ``[t_lo, t_hi]``
         (``perf_counter``): host build, upload dispatch and blocked-on-
         queue seconds, and the link as the completion thread saw it —
         ``upload_busy_s`` is the UNION of the ``upload`` intervals inside
         the window, ``upload_bytes`` the host bytes handed to
         ``device_put`` for streamed parts (the streamed-bytes counter's
-        delta; pinned layers upload nothing). Read after ``close()``."""
+        delta; pinned layers upload nothing). A one-pass source (one that
+        times its uploads) also lines the consumer's per-shard stamps
+        (``clock.shards``) up with them: why the device stood idle between shards
+        (``drained_s``, ``own_upload_wait_s``, ``behind_upload_s``). Read
+        after ``close()``."""
         intervals, misses = (
             self._watcher.snapshot() if self._watcher is not None else ([], 0)
         )
+        idle = {}
+        if self._watcher is not None and clock is not None:
+            shards = clock.shards
+            table = self.shard_table(clock, t_hi, intervals)
+            idle = {
+                "drained_s": sum(r["drained_s"] for r in table),
+                "drained_shards": sum(
+                    a.t_ready is not None and b.t_launch is not None
+                    for a, b in zip(shards, shards[1:])
+                ),
+                "own_upload_wait_s": sum(r["own_upload_wait_s"] for r in table),
+                "behind_upload_s": sum(r["behind_upload_s"] for r in table),
+                "launches_behind_upload": sum(
+                    r["behind_upload_s"] > 1e-3 for r in table
+                ),
+            }
         return {
+            **idle,
             "host_build_s": self._loader.build_time,
             "upload_dispatch_s": self.upload_dispatch_s,
             "producer_blocked_s": self.producer_blocked_s,
-            "upload_busy_s": union_seconds(intervals, t_lo, t_hi),
+            "upload_busy_s": union_seconds(
+                [(a, b) for a, b, _ in intervals], t_lo, t_hi
+            ),
             "upload_bytes": self.upload_bytes,
             "upload_pinned_bytes": self.upload_pinned_bytes,
             "uploads": len(intervals),
@@ -2130,7 +2417,7 @@ class ShardWeightSource:
         # produce_time covers the producer's WHOLE per-shard wall — host
         # file->numpy load (load_time counts just that part) plus the
         # device placement dispatch — the denominator of the stats line's
-        # and the trace report's overlap_efficiency (source_wait_s over
+        # overlap_efficiency (source_wait_s over
         # produce_wall_s compares like with like; load_time alone
         # under-counts what overlap must hide on a slow host->HBM link).
         # Host time around asynchronous dispatch, not link or device time:
@@ -2142,11 +2429,13 @@ class ShardWeightSource:
         self._loader.trace_ids = ids
         with obs_trace.timed("shard_produce", cat="stream", **attrs) as produce:
             bytes_before = self._loader.bytes_loaded
+            build_before = self._loader.build_time
             parts = _split_parts(
                 self._loader, layer_idxs, self._pinned_idxs, self._residency,
                 (device,),
             )
             nbytes = self._loader.bytes_loaded - bytes_before
+            load_s = self._loader.build_time - build_before
             # Count the sweep's saved link bytes ONCE per build (the put
             # below may retry; retries must not double-count).
             pinned_nbytes = 0
@@ -2198,6 +2487,7 @@ class ShardWeightSource:
                     dict(attrs, bytes=nbytes),
                 )
         self.upload_dispatch_s += dispatch.dur_s
+        self._produced[ids["shard_idx"]] = (load_s, dispatch.dur_s)
         self.upload_bytes += nbytes
         self.upload_pinned_bytes += pinned_nbytes
         self.produce_time += produce.dur_s
@@ -3064,6 +3354,11 @@ class StreamingExecutor:
             )
             for idxs in blocks
         ]
+        # Rows a block puts through a layer (prompts x (Lp + S x Ls)); the
+        # sweep's span carries them for the trace report.
+        clock.set_block_rows(
+            len(lens) * (lp + s * ls) for lp, s, ls, lens in block_shapes
+        )
         total = (n_shards or len(self.plan.shards)) * max(len(blocks), 1)
         bar = metrics.progress_bar(total, desc="stream", unit="blk")
         it = enumerate(source)
@@ -3108,16 +3403,14 @@ class StreamingExecutor:
                         wait.drop()
                         del segments
                         continue
-                # Driver time blocked on the weight source — the exact
-                # NOT-hidden load time (prefetch hides the rest); the
-                # numerator of overlap_efficiency (cli stats line, trace
-                # report).
-                clock.source_wait_s += wait.dur_s
                 # Global shard index: shared sources yield every shard
                 # from 0 (skip consumed the resumed prefix); an own
                 # source yields only the resumed tail.
                 shard_idx = shard_i + (0 if skip else start_shard)
-                clock.shard_idx = shard_idx
+                # Driver time blocked on the weight source — the exact
+                # NOT-hidden load time (prefetch hides the rest); the
+                # numerator of overlap_efficiency (cli stats line).
+                clock.take(shard_idx, wait)
                 visit = visits[shard_idx]
                 clock.layer_visits += decoder_visits(layer_idxs, n_layers)
                 clock.flash_steps += _flash_steps(
@@ -3144,11 +3437,11 @@ class StreamingExecutor:
                 ) as compute:
                     self._stream_shard(
                         store, toks, blocks, block_meta, scores,
-                        visit, segments, prev_shard, bar, clock,
+                        visit, segments, prev_shard, bar, clock, compute,
                     )
                     if on_shard_done is not None:
                         on_shard_done(shard_i)
-                clock.compute_s += compute.dur_s
+                clock.shard_done(compute)
                 prev_shard = (visit, segments) if heal_spills else None
             clock.start_tail()
         finally:
@@ -3158,10 +3451,10 @@ class StreamingExecutor:
 
     def _stream_shard(
         self, store, toks, blocks, block_meta, scores, visit, segments,
-        prev_shard, bar, clock,
+        prev_shard, bar, clock, compute,
     ) -> None:
         """One shard's compute over every block — the body the traced
-        ``compute`` span wraps in ``_stream``: its ``dispatch`` child is
+        ``compute`` span (``compute``) wraps in ``_stream``: its ``dispatch`` child is
         the consumer's pass over the blocks (inside it, the activation
         store's own ``device_wait`` where it resolves a block's copy), its
         ``device_wait`` child the wait for the device at the shard's end
@@ -3210,6 +3503,10 @@ class StreamingExecutor:
                         fetched = self._recompute_block(
                             prev_shard, store, b, idxs, block_meta[b], toks
                         )
+                if b == 0:
+                    # The shard's first steps are enqueued: seconds from the
+                    # compute span's start, for the timeline and the trace.
+                    compute.attrs["launch_s"] = clock.launched() - compute.t0
                 bar.update(1)
             if not blocks:
                 bar.update(1)
@@ -3224,7 +3521,7 @@ class StreamingExecutor:
                 "device_wait", cat="sweep", at="shard_end", **ids
             ) as wait:
                 jax.block_until_ready(suffix_h)
-            clock.device_wait_s += wait.dur_s
+            clock.shard_end_wait(wait)
 
     def _recompute_block(self, prev_shard, store, b, idxs, meta, toks):
         """Re-derive one block's activations by re-running the PREVIOUS
@@ -3270,6 +3567,7 @@ __all__ = [
     "ShardWeightSource",
     "BroadcastShardSource",
     "process_host_casts",
+    "process_slow_sweeps",
     "process_sweep_log",
     "process_tied_head_requants",
     "SweepClock",

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Repo entry point for the trace analyzer (same CLI as
 ``python -m flexible_llm_sharding_tpu.cli trace-report``): link
-utilization, compute/stream overlap efficiency, per-phase sweep
+utilization, why the device stood idle between shards, per-phase sweep
 breakdown, and TTFT / per-token latency quantiles from a ``--trace``
 recording (Chrome trace-event JSON or JSONL). ``--trace`` also accepts
 an incident-bundle directory (obs/incident.py, docs/incidents.md) —

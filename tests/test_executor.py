@@ -507,6 +507,202 @@ def test_completion_thread_keeps_bounded_state():
     w.close()  # idempotent, and an emptied queue is no error
 
 
+# ---------------------------------------------------------------------------
+# Why the device stood idle, shard by shard; the collector; a slow sweep
+# ---------------------------------------------------------------------------
+
+IDLE = ("drained_s", "own_upload_wait_s", "behind_upload_s")
+NEW_FIELDS = IDLE + ("drained_shards", "launches_behind_upload", "gc_s",
+                     "gc_collections", "slow")
+
+
+@pytest.mark.parametrize(
+    "case, shards, uploads, want",
+    [
+        # Launched after its own upload's arrival and before a later
+        # shard's (enqueued before the launch): the overlap, to that
+        # upload's end, is behind_upload_s; own_upload_wait_s 0.
+        ("behind a later shard's upload",
+         [(3, 10.0, 10.02, 10.5)], {3: (8.0, 9.0), 6: (9.9, 10.2)},
+         [(0.0, 0.0, 0.2)]),
+        # Launched before its own upload had arrived: the reverse.
+        ("waiting for its own weights",
+         [(3, 10.0, 10.02, 10.5)], {3: (9.8, 10.3)},
+         [(0.0, 0.3, 0.0)]),
+        # Both: the own upload first, then the one enqueued behind it.
+        ("its own, then another's",
+         [(3, 10.0, 10.02, 10.5)], {3: (9.8, 10.1), 4: (9.9, 10.25)},
+         [(0.0, 0.1, 0.15)]),
+        # Enqueued between the shard's first and last launch: the later
+        # blocks queue behind it, from the enqueue on.
+        ("enqueued between two of its launches",
+         [(3, 10.0, 10.05, 10.5)], {3: (8.0, 9.0), 6: (10.01, 10.3)},
+         [(0.0, 0.0, 0.29)]),
+        # The same with the blocks' rows known (1 : 3): the block dispatched
+        # before the enqueue still runs, for a third of the 0.2 s the later
+        # one takes after the arrival; the idle starts where it ends.
+        ("enqueued between two launches, the early block's time taken off",
+         [(3, 10.0, 10.05, 10.5)], {3: (8.0, 9.0), 6: (10.01, 10.3)},
+         [(0.0, 0.0, round(10.3 - (10.0 + 0.2 / 3), 9))]),
+        # An upload enqueued AFTER the last launch holds nothing back, nor
+        # does one that arrives after the shard was done (the shard did not
+        # wait for it); the own upload's wait ends with the shard's.
+        ("enqueued after the last launch, or arrived after the shard's end",
+         [(3, 10.0, 10.02, 10.5), (4, 10.6, 10.62, 10.7)],
+         {4: (10.05, 11.0), 5: (10.55, 12.0)},
+         [(0.0, 0.0, 0.0), (0.1, 0.1, 0.0)]),
+        # A shard served from the residency tier has no upload: 0 in both,
+        # whatever the link does later.
+        ("a pinned shard",
+         [(0, 1.0, 1.01, 1.2), (1, 1.25, 1.26, 1.4)], {7: (1.3, 1.5)},
+         [(0.0, 0.0, 0.0), (0.05, 0.0, 0.0)]),
+        # drained_s is the sum of the boundaries; a shard with no shard-end
+        # wait (t_ready None) is accounted with the next one, the sweep's
+        # last up to the sweep's end (20.0), and starts no boundary.
+        ("boundaries, and a shard without a shard-end wait",
+         [(0, 1.0, 1.1, 2.0), (1, 2.25, 2.3, None), (2, 3.0, 3.1, 3.5),
+          (3, 4.0, 4.1, None)],
+         {1: (2.0, 3.25), 3: (3.9, 25.0)},
+         [(0.0, 0.0, 0.0), (0.25, 1.0, 0.0), (0.0, 0.0, 0.25),
+          (0.5, 16.0, 0.0)]),
+        # A shard that launched nothing (no block) reads zeros.
+        ("nothing launched", [(0, None, 4.0, None), (1, 5.0, 5.1, 5.5)], {},
+         [(0.0, 0.0, 0.0), (0.0, 0.0, 0.0)]),
+    ],
+)
+def test_idle_split_on_synthetic_stamps(case, shards, uploads, want):
+    from flexible_llm_sharding_tpu.utils.intervals import idle_split
+
+    rows = (1, 3) if "rows known" in case or "taken off" in case else ()
+    got = idle_split(shards, uploads, t_end=20.0, block_rows=rows)
+    assert [tuple(round(x, 9) for x in row) for row in got] == want, case
+
+
+def test_streamed_sweep_accounts_for_the_idle_between_shards(model_dir):
+    path, _ = model_dir
+    n0 = len(executor_mod.process_sweep_log())
+    orchestration.run_prompts(
+        _account_cfg(path), list(PROMPTS), tokenizer=FakeTokenizer(),
+        devices=ONE_CHIP(),
+    )
+    (rec,) = executor_mod.process_sweep_log()[n0:]
+    _assert_phases_partition(rec)  # the five phases still partition the wall
+    assert all(rec[k] >= 0.0 for k in IDLE)
+    # Disjoint stretches of the consumer's timeline: never more than it.
+    assert sum(rec[k] for k in IDLE) <= rec["wall_s"]
+    # 7 shards: every one but the last (the head stores nothing) ends on a
+    # wait for the device, so six boundaries are counted.
+    assert rec["drained_shards"] == 6 and rec["drained_s"] > 0.0
+    assert 0 <= rec["launches_behind_upload"] <= 7
+    assert rec["slow"] == 0 and rec["gc_s"] >= 0.0
+
+
+def test_a_broadcast_sources_record_omits_the_idle_fields(model_dir):
+    path, _ = model_dir
+    n0 = len(executor_mod.process_sweep_log())
+    orchestration.run_prompts(
+        _account_cfg(path, data_parallel=True), list(PROMPTS),
+        tokenizer=FakeTokenizer(), devices=jax.devices()[:2],
+    )
+    recs = executor_mod.process_sweep_log()[n0:]
+    assert len(recs) == 2  # one a rank
+    for rec in recs:
+        _assert_phases_partition(rec, slack_s=0.05)
+        assert "uploads" not in rec  # the shared source keeps no account
+        for key in IDLE + ("drained_shards", "launches_behind_upload"):
+            assert key not in rec, key  # omitted, not written as zeros
+        assert rec["slow"] == 0 and rec["gc_collections"] >= 0
+
+
+def test_a_planted_collection_shows_in_the_sweeps_record(model_dir, monkeypatch):
+    import gc
+
+    path, _ = model_dir
+    orig_block = executor_mod.process_block
+    planted = {"n": 0}
+
+    def collecting(*a, **k):
+        if not planted["n"]:
+            planted["n"] = 1
+            gc.collect()  # a full (generation-2) collection
+        return orig_block(*a, **k)
+
+    monkeypatch.setattr(executor_mod, "process_block", collecting)
+    orchestration.run_prompts(
+        _account_cfg(path), list(PROMPTS), tokenizer=FakeTokenizer(),
+        devices=ONE_CHIP(),
+    )
+    rec = executor_mod.process_sweep_log()[-1]
+    assert rec["gc_collections"] >= 1 and rec["gc_s"] > 0.0
+    assert gc.callbacks.count(executor_mod._gc_hook) == 1  # once a process
+
+
+def test_a_stalled_sweep_keeps_its_timeline(model_dir, monkeypatch, caplog):
+    """Six sweeps of one plan; in the sixth the load of shard 3 sleeps. It
+    alone is marked slow, its per-shard table lands in
+    process_slow_sweeps() with that shard and the consumer's wait for it
+    named, the counter moves by one, and one warning says so."""
+    from flexible_llm_sharding_tpu.obs.registry import REGISTRY
+
+    path, _ = model_dir
+    with executor_mod._SWEEP_LOG_LOCK:
+        executor_mod._SWEEP_LOG.clear()  # peers are this test's own sweeps
+    seen0 = REGISTRY.collect()["stream"]["slow_sweeps"]
+    kept0 = len(executor_mod.process_slow_sweeps())
+
+    def sweep():
+        orchestration.run_prompts(
+            _account_cfg(path, host_cache_gb=0), list(PROMPTS),
+            tokenizer=FakeTokenizer(), devices=ONE_CHIP(),
+        )
+        return executor_mod.process_sweep_log()[-1]
+
+    before = [sweep() for _ in range(5)]
+    assert [r["slow"] for r in before] == [0] * 5
+    assert len(executor_mod.process_slow_sweeps()) == kept0
+
+    orig = executor_mod._HostShardLoader._build_host_shard
+
+    def sleepy(self, layer_idxs, *a, **k):
+        if self.trace_ids.get("shard_idx") == 3:
+            time.sleep(1.0)
+        return orig(self, layer_idxs, *a, **k)
+
+    monkeypatch.setattr(executor_mod._HostShardLoader, "_build_host_shard", sleepy)
+    with caplog.at_level("WARNING", logger=executor_mod.__name__):
+        rec = sweep()
+    assert rec["slow"] == 1
+    assert REGISTRY.collect()["stream"]["slow_sweeps"] == seen0 + 1
+    assert "fls_stream_slow_sweeps " in REGISTRY.prometheus_text()
+    slow = executor_mod.process_slow_sweeps()
+    assert len(slow) == kept0 + 1
+    kept = slow[-1]
+    assert kept["sweep_id"] == rec["sweep_id"] and kept["slow"] == 1
+    assert kept["worst_phase"] == "source_wait_s" and kept["worst_shard"] == 3
+    assert kept["worst_phase_excess_s"] > 0.9
+    assert [r["shard_idx"] for r in kept["shards"]] == list(range(7))
+    (row,) = [r for r in kept["shards"] if r["shard_idx"] == 3]
+    assert row["source_wait_s"] > 0.9 and row["shard_load_s"] > 0.9
+    assert set(row) == {
+        "shard_idx", "source_wait_s", "dispatch_s", "device_wait_s",
+        "drained_s", "own_upload_wait_s", "behind_upload_s", "shard_load_s",
+        "upload_dispatch_s",
+    }
+    warnings = [r for r in caplog.records if "slow sweep" in r.getMessage()]
+    assert len(warnings) == 1
+    assert "source_wait_s" in warnings[0].getMessage()
+    assert "shard 3" in warnings[0].getMessage()
+    # The log stays flat dictionaries: no table rides a record.
+    for r in executor_mod.process_sweep_log():
+        assert not any(isinstance(v, (list, dict)) for v in r.values())
+
+
+@pytest.mark.parametrize("field", NEW_FIELDS)
+def test_each_new_record_field_has_its_help_line(field):
+    text = executor_mod.SWEEP_RECORD_HELP[field]
+    assert len(text) > 20 and "\n" not in text
+
+
 def test_store_waits_for_the_device_are_device_wait_spans(model_dir):
     from flexible_llm_sharding_tpu.obs import trace as obs_trace
 

@@ -421,22 +421,37 @@ def test_serving_metrics_prometheus_has_full_counter_family():
 
 def test_analyzer_derives_utilization_overlap_and_quantiles():
     # Synthetic timeline: 2 produce spans (0.2s each, waits 0.1s total),
+    # two shards whose launches, shard-end waits and uploads are known,
     # serve latency instants with known quantiles.
     evs = [
         {"name": "shard_produce", "cat": "stream", "ts_s": 0.0, "dur_s": 0.2},
         {"name": "shard_load", "cat": "stream", "ts_s": 0.0, "dur_s": 0.15},
         {"name": "upload_dispatch", "cat": "stream", "ts_s": 0.15,
          "dur_s": 0.01},
-        {"name": "upload", "cat": "stream", "ts_s": 0.15, "dur_s": 0.1},
+        {"name": "upload", "cat": "stream", "ts_s": 0.15, "dur_s": 0.1,
+         "sweep_id": 1, "shard_idx": 0},
         {"name": "shard_produce", "cat": "stream", "ts_s": 0.5, "dur_s": 0.2},
         {"name": "shard_load", "cat": "stream", "ts_s": 0.5, "dur_s": 0.2},
         # Two uploads in flight at once count once (the union).
-        {"name": "upload", "cat": "stream", "ts_s": 0.2, "dur_s": 0.1},
-        {"name": "upload", "cat": "stream", "ts_s": 0.7, "dur_s": 0.25},
+        {"name": "upload", "cat": "stream", "ts_s": 0.2, "dur_s": 0.1,
+         "sweep_id": 1, "shard_idx": 1},
+        {"name": "upload", "cat": "stream", "ts_s": 0.7, "dur_s": 0.25,
+         "sweep_id": 1, "shard_idx": 2},
         {"name": "source_wait", "cat": "sweep", "ts_s": 0.0, "dur_s": 0.1,
          "sweep_id": 1},
+        # Shard 0 launches at 0.22, 0.03 s before its own upload arrives
+        # (0.25), and behind shard 1's (enqueued at 0.2, done at 0.3).
         {"name": "compute", "cat": "sweep", "ts_s": 0.2, "dur_s": 0.3,
-         "sweep_id": 1, "shard_idx": 0},
+         "sweep_id": 1, "shard_idx": 0, "launch_s": 0.02},
+        {"name": "device_wait", "cat": "sweep", "ts_s": 0.4, "dur_s": 0.1,
+         "sweep_id": 1, "shard_idx": 0, "at": "shard_end"},
+        # Shard 1 launches 0.05 s after that wait's return, its weights
+        # long there, behind shard 2's upload (0.7 -> 0.95), which arrives
+        # before its own shard-end wait returns at 0.97.
+        {"name": "compute", "cat": "sweep", "ts_s": 0.52, "dur_s": 0.46,
+         "sweep_id": 1, "shard_idx": 1, "launch_s": 0.23},
+        {"name": "device_wait", "cat": "sweep", "ts_s": 0.8, "dur_s": 0.17,
+         "sweep_id": 1, "shard_idx": 1, "at": "shard_end"},
         {"name": "sweep", "cat": "sweep", "ts_s": 0.0, "dur_s": 1.0,
          "sweep_id": 1},
     ] + [
@@ -449,10 +464,18 @@ def test_analyzer_derives_utilization_overlap_and_quantiles():
     # [0.7,0.95]; host builds and the dispatch calls carry nothing.
     assert rep["stream_busy_s"] == pytest.approx(0.4)
     assert rep["link_utilization"] == pytest.approx(0.4)
-    # overlap = 1 - wait/produce = 1 - 0.1/0.4.
-    assert rep["overlap_efficiency"] == pytest.approx(0.75)
+    # The sweep record's three idle figures, from the export alone.
+    idle = rep["idle_between_shards"]
+    assert idle["sweeps"] == 1
+    assert idle["own_upload_wait_s"] == pytest.approx(0.03)
+    assert idle["behind_upload_s"] == pytest.approx(0.05 + 0.2)
+    assert idle["drained_s"] == pytest.approx(0.25)
+    assert "overlap_efficiency" not in rep
+    assert "launched behind another shard's upload 0.250s (25.0%)" in (
+        obs_report.format_report(rep)
+    )
     assert rep["sweeps"] == 1
-    assert rep["sweep_phase_s"]["compute"] == pytest.approx(0.3)
+    assert rep["sweep_phase_s"]["compute"] == pytest.approx(0.3 + 0.46)
     assert rep["sweep_wall_s"] == pytest.approx(1.0)
     q = rep["ttft_s"]
     assert q["count"] == 4 and q["p50"] == 0.3 and q["max"] == 0.4
@@ -493,7 +516,9 @@ def test_analyzer_roundtrips_both_export_formats(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_executor_run_produces_sweep_timeline(model, process_tracer):
-    from flexible_llm_sharding_tpu.runtime.executor import StreamingExecutor
+    from flexible_llm_sharding_tpu.runtime.executor import (
+        StreamingExecutor, process_sweep_log,
+    )
 
     ex = StreamingExecutor(_fw(model), tokenizer=FakeTokenizer())
     ex(list(PROMPTS))
@@ -510,7 +535,12 @@ def test_executor_run_produces_sweep_timeline(model, process_tracer):
     rep = obs_report.analyze(spans)
     assert rep["sweeps"] == 1
     assert 0.0 <= rep["link_utilization"] <= 1.0
-    assert "overlap_efficiency" in rep
+    # The record's three idle figures again, from the ring's spans (which
+    # round to the microsecond).
+    last = process_sweep_log()[-1]
+    idle = rep["idle_between_shards"]
+    for key in ("drained_s", "own_upload_wait_s", "behind_upload_s"):
+        assert idle[key] == pytest.approx(last[key], abs=1e-4), key
 
 
 def test_serve_run_traces_waves_and_exposes_metrics(model, process_tracer):
@@ -738,3 +768,63 @@ def test_last_sweep_gauges_on_the_stream_source(model):
         if ln.startswith("# HELP fls_stream_last_sweep_dispatch_s ")
     ]
     assert "device_wait_s" in line and "\n" not in line
+
+
+@pytest.mark.parametrize(
+    "field",
+    ["drained_s", "drained_shards", "own_upload_wait_s", "behind_upload_s",
+     "launches_behind_upload", "gc_s", "gc_collections", "slow"],
+)
+def test_idle_fields_are_gauges_with_their_definition(model, field):
+    from flexible_llm_sharding_tpu.obs.registry import REGISTRY
+    from flexible_llm_sharding_tpu.runtime import executor, orchestration
+
+    if not executor.process_sweep_log():
+        orchestration.run_prompts(
+            _fw(model), list(PROMPTS), tokenizer=FakeTokenizer(),
+            devices=ONE_CHIP(),
+        )
+    text = REGISTRY.prometheus_text()
+    assert f"# HELP fls_stream_last_sweep_{field} " in text
+    assert f"\nfls_stream_last_sweep_{field} " in text
+    # The counter of slow sweeps is there from the first scrape on.
+    assert "# HELP fls_stream_slow_sweeps " in text
+    assert "\nfls_stream_slow_sweeps " in text
+
+
+def test_slow_sweep_lands_in_the_journal_and_the_ring(process_tracer, tmp_path):
+    """The event's path, driven on a record by hand: one journal line, one
+    instant on the ring, both naming the phase and the shard."""
+    import types
+
+    from flexible_llm_sharding_tpu.obs import events as obs_events
+    from flexible_llm_sharding_tpu.runtime import executor
+
+    obs_events.reset_journal()
+    obs_events.JOURNAL.configure(str(tmp_path))
+    try:
+        phases = ("head_s", "source_wait_s", "dispatch_s", "device_wait_s", "tail_s")
+        median = dict.fromkeys(phases, 0.1) | {"sweep_id": 7, "wall_s": 0.5}
+        rec = dict(median, sweep_id=9, wall_s=3.5, device_wait_s=3.1, gc_s=0.25,
+                   slow=1)
+        stamps = []
+        for k, wait_s in enumerate((0.02, 3.0, 0.08)):
+            s = executor.ShardStamps(k, 0.0, 10.0 * k)
+            s.t_launch, s.t_wait = 10.0 * k + 0.01, 10.0 * k + 0.02
+            s.t_ready = s.t_end = s.t_wait + wait_s
+            stamps.append(s)
+        n0 = executor.stream_stats()["slow_sweeps"]
+        clock = types.SimpleNamespace(shards=stamps, block_rows=())
+        executor._keep_slow_sweep(rec, median, clock, source=None, t_end=24.0)
+        assert executor.stream_stats()["slow_sweeps"] == n0 + 1
+        kept = executor.process_slow_sweeps()[-1]
+        assert (kept["worst_phase"], kept["worst_shard"]) == ("device_wait_s", 1)
+        assert kept["worst_shard_s"] == pytest.approx(3.0)
+        (event,) = [e for e in obs_events.JOURNAL.tail() if e["kind"] == "slow_sweep"]
+        assert event["severity"] == "warning" and event["sweep_id"] == 9
+        assert event["worst_phase"] == "device_wait_s" and event["worst_shard"] == 1
+        assert event["gc_s"] == 0.25 and event["median_wall_s"] == 0.5
+        (inst,) = [s for s in process_tracer.snapshot() if s["name"] == "slow_sweep"]
+        assert inst["worst_shard"] == 1 and "dur_s" not in inst
+    finally:
+        obs_events.reset_journal()
