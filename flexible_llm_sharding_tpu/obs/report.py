@@ -18,7 +18,8 @@ JSONL — both are auto-detected). Output:
   was not dispatched yet), *waiting for its own weights* (a shard launched
   before its own ``upload`` had arrived) and *launched behind another
   shard's upload* (its own weights were there, a transfer enqueued before
-  the launch was not done). Each in seconds and as a share of the sweeps'
+  the launch was not done; an upload is enqueued where its
+  ``upload_dispatch`` span ends). Each in seconds and as a share of the sweeps'
   wall. Needs the ``compute`` spans' ``launch_s`` attribute and the
   ``upload`` spans (a one-pass scoring sweep; a serve engine's trace has
   neither and the figure is left out).
@@ -53,11 +54,14 @@ def _idle_between_shards(spans: list[dict]) -> dict | None:
     sweeps: dict[int, dict] = {}
     for s in spans:
         sid, name = s.get("sweep_id"), s["name"]
-        if sid is None or name not in ("sweep", "compute", "device_wait", UPLOAD_SPAN):
+        if sid is None or name not in (
+            "sweep", "compute", "device_wait", "upload_dispatch", UPLOAD_SPAN
+        ):
             continue
         d = sweeps.setdefault(
             int(sid),
-            {"end": None, "rows": (), "compute": [], "wait": {}, "uploads": {}},
+            {"end": None, "rows": (), "compute": [], "wait": {}, "uploads": {},
+             "enqueued": {}},
         )
         end = s["ts_s"] + s["dur_s"]
         if name == "sweep":
@@ -69,6 +73,8 @@ def _idle_between_shards(spans: list[dict]) -> dict | None:
             d["compute"].append((s["ts_s"], s.get("shard_idx"), s["launch_s"], end))
         elif name == "device_wait" and s.get("at") == "shard_end":
             d["wait"][s.get("shard_idx")] = (s["ts_s"], end)
+        elif name == "upload_dispatch":
+            d["enqueued"][s.get("shard_idx")] = end
         elif name == UPLOAD_SPAN:
             d["uploads"][s.get("shard_idx")] = (s["ts_s"], end)
     totals, wall, n = dict.fromkeys(IDLE_KEYS, 0.0), 0.0, 0
@@ -79,7 +85,13 @@ def _idle_between_shards(spans: list[dict]) -> dict | None:
             (idx, t0 + launch, *d["wait"].get(idx, (t1, None)))
             for t0, idx, launch, t1 in sorted(d["compute"])
         ]
-        for row in idle_split(shards, d["uploads"], d["end"], d["rows"]):
+        # An upload is enqueued where its device_put call returned (the
+        # record's rule: ShardWeightSource.shard_table).
+        uploads = {
+            idx: (d["enqueued"].get(idx, t0), t1)
+            for idx, (t0, t1) in d["uploads"].items()
+        }
+        for row in idle_split(shards, uploads, d["end"], d["rows"]):
             for key, sec in zip(IDLE_KEYS, row):
                 totals[key] += sec
         n += 1

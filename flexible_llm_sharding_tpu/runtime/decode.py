@@ -1232,6 +1232,8 @@ class DecodeGenerator:
                             tok_hist[b].append(pick(dist, b))
                     if layer_idxs[-1] != n_layers - 1:
                         kv_store.put(("h", b), (ph, sh))
+                if closer is not None:
+                    closer.dispatched()  # the next upload goes out behind these steps
 
             def stream_pass(embed_ids, decoders_fn, head_fn, skip_block=None):
                 """One full-model walk (shards x blocks x segments) shared
@@ -1293,6 +1295,8 @@ class DecodeGenerator:
                                 )
                         if layer_idxs[-1] != n_layers - 1:
                             kv_store.put(("x", b), x)
+                    if closer is not None:
+                        closer.dispatched()
 
             # Traced wrapper: every full-model decode walk is one "sweep"
             # span (the offline counterpart of a serving sweep), so the

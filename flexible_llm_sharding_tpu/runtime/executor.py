@@ -755,7 +755,12 @@ class SweepClock:
     between shards (``utils.intervals.idle_split``: ``drained_s``,
     ``own_upload_wait_s``, ``behind_upload_s``), and a sweep that stalls
     keeps them as its per-shard table (``process_slow_sweeps``); every
-    other sweep drops them with its clock."""
+    other sweep drops them with its clock. ``t_wait`` (the shard-end
+    wait's start) is also where the consumer has just handed the shard's
+    upload slot back (``ShardWeightSource.dispatched``): an upload ordered
+    by that signal (the record's ``uploads_ordered``) is enqueued after it,
+    and an upload's enqueue is dated where its ``device_put`` call
+    RETURNED."""
 
     def __init__(self):
         self.sweep_id = obs_trace.new_sweep_id()
@@ -997,8 +1002,10 @@ def _shard_table(
     (``clock.shards``) as durations, the idle split against ``uploads``
     (``shard_idx -> (t_enqueue, t_done)``) and the producer's seconds for
     that shard (``produced``: ``shard_idx -> (shard_load_s,
-    upload_dispatch_s)``). ``t_end``: the sweep's end, which bounds the
-    last shard's waits where it has no shard-end wait of its own."""
+    upload_dispatch_s, upload_ordered)``). An upload's ``t_enqueue`` is
+    where its ``device_put`` call returned. ``t_end``: the sweep's end,
+    which bounds the last shard's waits where it has no shard-end wait of
+    its own."""
     shards = clock.shards
     split = idle_split(
         [
@@ -1011,7 +1018,7 @@ def _shard_table(
     table = []
     for s, (drained, own, behind) in zip(shards, split):
         wait = s.t_ready - s.t_wait if s.t_ready is not None else 0.0
-        load, put = produced.get(s.shard_idx, (0.0, 0.0))
+        load, put, ordered = produced.get(s.shard_idx, (0.0, 0.0, 0))
         table.append({
             "shard_idx": s.shard_idx,
             "source_wait_s": s.source_wait_s,
@@ -1022,6 +1029,7 @@ def _shard_table(
             "behind_upload_s": behind,
             "shard_load_s": load,
             "upload_dispatch_s": put,
+            "upload_ordered": ordered,
         })
     return table
 
@@ -1082,12 +1090,18 @@ SWEEP_RECORD_HELP = {
     "residency tier.",
     "behind_upload_s": "Device idle, by the host's stamps: shards whose own "
     "weights had arrived but whose launches queued behind ANOTHER shard's "
-    "upload, enqueued before the shard's last block was dispatched and "
-    "arrived before the shard was done: from the first launch (or the own "
-    "arrival, or that enqueue if later, less the blocks dispatched before "
-    "it) to that upload's arrival; what an order of dispatch costs, not the "
-    "link.",
+    "upload, enqueued (its device_put call returned) before the shard's "
+    "last block was dispatched and arrived before the shard was done: from "
+    "the first launch (or the own arrival, or that enqueue if later, less "
+    "the blocks dispatched before it) to that upload's arrival. With the "
+    "uploads ordered (uploads_ordered) that upload went out behind an "
+    "EARLIER shard's steps and is the link's turn, not an accident of order.",
     "launches_behind_upload": "Shards whose behind_upload_s share is over 1 ms.",
+    "uploads_ordered": "Weight uploads whose device_put was enqueued on a "
+    "slot the consumer returned with dispatched(), that is behind the last "
+    "block of the shard prefetch_depth + 1 before it (against uploads; the "
+    "first prefetch_depth + 1 builds of a source use the slots it starts "
+    "with, and a consumer that never says dispatched orders none).",
     "gc_s": "Seconds inside Python's generation-2 collections that ended "
     "during the sweep, on any thread (one gc.callbacks hook a process).",
     "gc_collections": "Generation-2 collections that ended during the sweep.",
@@ -1111,7 +1125,13 @@ SWEEP_RECORD_HELP = {
     "upload_dispatch_s": "Producer: inside jax.device_put calls, which "
     "return at the enqueue; not a transfer time.",
     "producer_blocked_s": "Producer: holding a built shard while the "
-    "prefetch queue was full.",
+    "prefetch queue was full, or a shard's host side built and waiting for "
+    "its upload slot (the consumer's dispatched()).",
+    "link_wait_s": "Consumer, inside source_wait_s: a shard in hand, waiting "
+    "for the newest weight upload to its device to arrive before taking it "
+    "(steps dispatched under an upload in flight would wait for the next "
+    "upload too): the link's turn where the link binds. Where it is the "
+    "larger part of drained_s, the boundaries wait for the link, not the host.",
     "upload_busy_s": "Union of the weight uploads' intervals (dispatch to "
     "arrival) inside the sweep: the time the link carried weights.",
     "upload_bytes": "Host bytes handed to device_put for streamed layers "
@@ -2160,6 +2180,32 @@ class ShardWeightSource:
     compute of shard t (the reference serializes these,
     ``/root/reference/utils.py:228-233``).
 
+    How far the thread leads is a count of upload slots: ``prefetch_depth``
+    + 1 shards built (placed on the device) and not yet DISPATCHED by the
+    consumer, the queue's places plus the one in the producer's hand. A
+    build takes its slot after its host side is done (the cache hit, the
+    ``pinned_host`` tree) and just before its ``device_put``; the consumer
+    hands the slot back with ``dispatched()`` once the last block of the
+    shard it holds is enqueued, so the next upload goes out BEHIND that
+    shard's steps and runs beside them. (On the chip a program launched
+    after a transfer's enqueue waits for the transfer; launched before it,
+    both run side by side.) A consumer that never calls ``dispatched()``
+    returns the slot when it asks for the next shard: the same lead, the
+    same bytes in HBM, no order promised.
+
+    The other half of the order: a shard is handed to the consumer only
+    once the newest upload to its device has ARRIVED (``_await_upload``,
+    inside the consumer's ``next()``; ``link_wait_s``). "Dispatched" on the
+    host is not "issued" on the chip: a launch, when the runtime issues it,
+    waits for every transfer enqueued by then, and while it waits the
+    launches behind it are not issued either, so they also wait for the
+    uploads enqueued MEANWHILE. Steps dispatched under an upload still in
+    flight would therefore wait for the next upload too, the one their own
+    "dispatched" lets out (read on the chip: every second streamed shard
+    took an upload and a half longer). Dispatched with the link idle they
+    are issued at once, and the upload their signal lets out runs beside
+    them: a streamed shard costs the larger of its upload and its steps.
+
     ``cycle=True`` loops the shard list endlessly instead of stopping after
     one pass — the online serving loop's weight stream, where the number of
     full-model sweeps is open-ended (requests keep arriving) and a
@@ -2254,9 +2300,18 @@ class ShardWeightSource:
         self.producer_blocked_s = 0.0
         self.upload_bytes = 0
         self.upload_pinned_bytes = 0  # of upload_bytes, from pinned_host
-        # shard_idx -> (shard_load_s, upload_dispatch_s): the producer's
-        # seconds by shard, for a slow sweep's table (shard_table()).
-        self._produced: dict[int, tuple[float, float]] = {}
+        # shard_idx -> (shard_load_s, upload_dispatch_s, upload_ordered): the
+        # producer's seconds by shard and whether its device_put went out on
+        # a slot dispatched() returned, for a slow sweep's table
+        # (shard_table()); the second also dates the enqueue's END.
+        self._produced: dict[int, tuple[float, float, int]] = {}
+        # Uploads whose device_put went out on a slot that dispatched()
+        # returned (against the account's ``uploads``).
+        self.uploads_ordered = 0
+        # The newest upload, (device, arrays), until a consumer has seen it
+        # arrive, and the consumer's seconds waiting for that (_await_upload).
+        self._in_flight: tuple | None = None
+        self.link_wait_s = 0.0
         # The completion thread exists where an account reads it: a
         # one-pass source, closed when its sweep's record is written. A
         # cycling source (the serve engine's, which keeps no account and
@@ -2264,6 +2319,16 @@ class ShardWeightSource:
         # per-upload state, no second join on the watchdog's recovery path.
         self._watcher = None if cycle else _UploadWatcher()
         self._q: Queue = Queue(maxsize=max(1, prefetch_depth))
+        # Upload slots (see the class docstring), and for each free one,
+        # oldest first, whether dispatched() returned it. The consumer
+        # appends before it releases and the producer pops after it has
+        # acquired, so a flag is there for every slot taken.
+        # prefetch_depth 0 builds in the consumer's next(): no thread, no slots.
+        self._slots = (
+            threading.Semaphore(prefetch_depth + 1) if prefetch_depth >= 1 else None
+        )
+        self._slot_ordered: deque = deque([False] * (prefetch_depth + 1))
+        self._holding = False  # consumer's thread: a shard taken, slot not returned
         self._close_lock = threading.Lock()  # close() may race abort()/close()
         self._thread: threading.Thread | None = None
         if prefetch_depth >= 1:
@@ -2313,6 +2378,7 @@ class ShardWeightSource:
                     self._q.get_nowait()
                 except Empty:
                     break
+            self._in_flight = None  # a closed source holds no array
             # Retire the loader's native readahead pool promptly — a source
             # is created per executor call and sits in a reference cycle
             # (producer thread target holds self), so GC alone would strand
@@ -2333,7 +2399,14 @@ class ShardWeightSource:
         ``close()``."""
         if intervals is None:
             intervals = self._watcher.snapshot()[0] if self._watcher else []
-        uploads = {idx: (a, b) for a, b, idx in intervals}
+        # An upload counts as enqueued where its device_put call RETURNED
+        # (a run of layers goes in leaf by leaf, for milliseconds: a block
+        # launched meanwhile queues behind the leaves already in, not behind
+        # the upload). The link's own interval still opens at the call.
+        uploads = {
+            idx: (a + self._produced[idx][1] if idx in self._produced else a, b)
+            for a, b, idx in intervals
+        }
         return _shard_table(clock, t_end, uploads, self._produced)
 
     def account(
@@ -2348,8 +2421,9 @@ class ShardWeightSource:
         delta; pinned layers upload nothing). A one-pass source (one that
         times its uploads) also lines the consumer's per-shard stamps
         (``clock.shards``) up with them: why the device stood idle between shards
-        (``drained_s``, ``own_upload_wait_s``, ``behind_upload_s``). Read
-        after ``close()``."""
+        (``drained_s``, ``own_upload_wait_s``, ``behind_upload_s``).
+        ``uploads_ordered`` counts the uploads enqueued on a slot that
+        ``dispatched()`` returned. Read after ``close()``."""
         intervals, misses = (
             self._watcher.snapshot() if self._watcher is not None else ([], 0)
         )
@@ -2374,12 +2448,14 @@ class ShardWeightSource:
             "host_build_s": self._loader.build_time,
             "upload_dispatch_s": self.upload_dispatch_s,
             "producer_blocked_s": self.producer_blocked_s,
+            "link_wait_s": self.link_wait_s,
             "upload_busy_s": union_seconds(
                 [(a, b) for a, b, _ in intervals], t_lo, t_hi
             ),
             "upload_bytes": self.upload_bytes,
             "upload_pinned_bytes": self.upload_pinned_bytes,
             "uploads": len(intervals),
+            "uploads_ordered": self.uploads_ordered,
             "upload_misses": misses,
             "pinned_bytes": (
                 self._residency.max_pinned_device_bytes()
@@ -2467,30 +2543,46 @@ class ShardWeightSource:
                     parts, device, self._loader.np_dtype, self._residency
                 )
 
+            # The host side is done; only the device_put waits for its
+            # slot, once a shard and outside the retried region.
+            blocked_before = self.producer_blocked_s
+            ordered = self._slots is not None and self._take_slot(shard_i)
             # upload_dispatch is the CALL (device_put returns at the
             # enqueue), not the transfer: the completion thread's upload
             # span, opened at this dispatch, ends where the bytes arrived.
-            with obs_trace.timed(
-                "upload_dispatch", cat="stream", **attrs
-            ) as dispatch:
-                out = retry_call(
-                    put,
-                    policy=self._retry,
-                    label="device_put",
-                    recorder=self._recorder,
-                    wrap=ShardLoadError,
-                    abort=self._stop.is_set,
-                )
-            if nbytes and self._watcher is not None:
-                self._watcher.watch(
-                    [seg for _, seg in out], dispatch.t0,
-                    dict(attrs, bytes=nbytes),
-                )
+            try:
+                with obs_trace.timed(
+                    "upload_dispatch", cat="stream", **attrs
+                ) as dispatch:
+                    out = retry_call(
+                        put,
+                        policy=self._retry,
+                        label="device_put",
+                        recorder=self._recorder,
+                        wrap=ShardLoadError,
+                        abort=self._stop.is_set,
+                    )
+            except BaseException:
+                if self._slots is not None:
+                    # Nothing was built: the consumer takes a fault in this
+                    # shard's place, which holds no slot.
+                    self._free_slot(False)
+                raise
+            if nbytes:
+                arrays = [seg for _, seg in out]
+                self._in_flight = (device, arrays)
+                if self._watcher is not None:
+                    self._watcher.watch(arrays, dispatch.t0, dict(attrs, bytes=nbytes))
         self.upload_dispatch_s += dispatch.dur_s
-        self._produced[ids["shard_idx"]] = (load_s, dispatch.dur_s)
+        ordered = int(bool(nbytes and ordered))
+        self._produced[ids["shard_idx"]] = (load_s, dispatch.dur_s, ordered)
         self.upload_bytes += nbytes
         self.upload_pinned_bytes += pinned_nbytes
-        self.produce_time += produce.dur_s
+        self.uploads_ordered += ordered
+        # The wait for a slot is the consumer's pace, not the producer's work.
+        self.produce_time += produce.dur_s - (
+            self.producer_blocked_s - blocked_before
+        )
         return out
 
     def _span_ids(self, shard_i: int) -> dict:
@@ -2498,6 +2590,62 @@ class ShardWeightSource:
             "sweep_id": self.sweep_id,
             "shard_idx": self._first_shard_idx + shard_i,
         }
+
+    # -- upload slots ------------------------------------------------------
+    def dispatched(self) -> None:
+        """Consumer: the last block of the shard it holds is enqueued on the
+        device. Hands that shard's upload slot back, so the producer's next
+        ``device_put`` lands behind the shard's steps (and before the
+        consumer's wait at the shard's end, which the upload then runs
+        beside). Once a shard; a second call, or one with no shard held
+        (``prefetch_depth`` 0: no thread, no slots), does nothing."""
+        self._return_slot(True)
+
+    def _await_upload(self, device) -> None:
+        """Consumer, a shard for ``device`` in hand: wait until the newest
+        upload to that device has arrived, so that the shard's steps are
+        issued the moment they are dispatched (see the class docstring). An
+        upload to another chip (a pipeline's next stage) holds nothing back
+        here."""
+        in_flight = self._in_flight
+        if in_flight is None or in_flight[0] is not device:
+            return
+        with obs_trace.timed(
+            "link_wait", cat="stream", sweep_id=self.sweep_id
+        ) as wait:
+            try:
+                jax.block_until_ready(in_flight[1])
+            except RuntimeError:  # deleted under us: nothing left to wait for
+                pass
+        self.link_wait_s += wait.dur_s
+        if self._in_flight is in_flight:  # seen to arrive: hold it no longer
+            self._in_flight = None
+
+    def _return_slot(self, ordered: bool) -> None:
+        if self._holding:
+            self._holding = False
+            self._free_slot(ordered)
+
+    def _free_slot(self, ordered: bool) -> None:
+        self._slot_ordered.append(ordered)  # before the release: see __init__
+        self._slots.release()
+
+    def _take_slot(self, shard_i: int) -> bool:
+        """Producer: wait for an upload slot; True when ``dispatched()``
+        returned the one taken. The wait polls ``_stop`` as ``_put`` does
+        (``close()`` and ``abort()`` never hang on it) and counts as
+        ``producer_blocked``."""
+        if not self._slots.acquire(blocking=False):
+            with obs_trace.timed(
+                "producer_blocked", cat="stream", **self._span_ids(shard_i)
+            ) as blocked:
+                while not self._slots.acquire(timeout=0.2):
+                    if self._stop.is_set():
+                        break
+            self.producer_blocked_s += blocked.dur_s
+            if self._stop.is_set():
+                raise SourceClosed("ShardWeightSource closed while streaming")
+        return self._slot_ordered.popleft()
 
     # -- prefetch thread ---------------------------------------------------
     def _put(self, item, shard_i: int = 0) -> bool:
@@ -2513,8 +2661,9 @@ class ShardWeightSource:
             return True
         except Full:
             pass
-        # The queue is full: the prefetch depth holds the producer (and
-        # with it the next upload) back until the consumer takes a shard.
+        # The queue is full: a built shard waits in the producer's hand
+        # until the consumer takes one. (What holds the next UPLOAD back is
+        # its slot, which returns at "dispatched": _take_slot.)
         with obs_trace.timed(
             "producer_blocked", cat="stream", **self._span_ids(shard_i)
         ) as blocked:
@@ -2628,10 +2777,15 @@ class ShardWeightSource:
                     return
         else:
             while True:
-                for idxs in self.shards:
+                for idxs, dev in zip(self.shards, self.shard_devices):
+                    # A consumer that did not say "dispatched" for the shard
+                    # it held hands its slot back by asking for the next.
+                    self._return_slot(False)
                     item = self._get()
                     if isinstance(item, _ShardFault):
                         _reraise_from_producer(item.error)
+                    self._await_upload(dev)
+                    self._holding = True
                     yield idxs, item
                 if not self.cycle:
                     return
@@ -2816,6 +2970,10 @@ class _BroadcastView:
     def host_casts(self) -> int:
         """Shared loader total of host-side cast fallbacks."""
         return self._parent._loader.host_casts
+
+    def dispatched(self) -> None:
+        """The consumer's "last block enqueued" (``ShardWeightSource.
+        dispatched``): a shared source's bound is its queues' alone."""
 
     def __iter__(self):
         q = self._parent._queues[self._rank]
@@ -3106,6 +3264,19 @@ class StreamingExecutor:
             if (self._residency is not None and own_source)
             else None
         )
+        # Per-block device-resident metadata, uploaded once, BEFORE the source
+        # exists: its thread starts enqueuing weight uploads at once, and
+        # transfers run in order on the link.
+        block_meta = {}
+        for b, idxs in enumerate(blocks):
+            block_meta[b] = (
+                jnp.asarray(np.stack([toks[i].prefix_ids for i in idxs])),
+                jnp.asarray(np.stack([toks[i].suffix_ids for i in idxs])),
+                jnp.asarray(
+                    np.array([toks[i].prefix_len for i in idxs], dtype=np.int32)
+                ),
+                jnp.asarray(np.stack([toks[i].suffix_eos for i in idxs])),
+            )
         if self.weight_source_factory is not None:
             # Shared (DP broadcast) source: it streams EVERY shard to every
             # chip — a resuming rank cannot slice the stream, so it consumes
@@ -3150,17 +3321,6 @@ class StreamingExecutor:
         scores: dict[int, np.ndarray] = ScoreSink(
             max_device=self.cfg.score_sink_max_device
         )
-        # Per-block device-resident metadata, uploaded once.
-        block_meta = {}
-        for b, idxs in enumerate(blocks):
-            block_meta[b] = (
-                jnp.asarray(np.stack([toks[i].prefix_ids for i in idxs])),
-                jnp.asarray(np.stack([toks[i].suffix_ids for i in idxs])),
-                jnp.asarray(
-                    np.array([toks[i].prefix_len for i in idxs], dtype=np.int32)
-                ),
-                jnp.asarray(np.stack([toks[i].suffix_eos for i in idxs])),
-            )
 
         def on_shard_done(local_idx: int) -> None:
             if resumable:
@@ -3437,7 +3597,7 @@ class StreamingExecutor:
                 ) as compute:
                     self._stream_shard(
                         store, toks, blocks, block_meta, scores,
-                        visit, segments, prev_shard, bar, clock, compute,
+                        visit, segments, prev_shard, bar, clock, compute, source,
                     )
                     if on_shard_done is not None:
                         on_shard_done(shard_i)
@@ -3451,7 +3611,7 @@ class StreamingExecutor:
 
     def _stream_shard(
         self, store, toks, blocks, block_meta, scores, visit, segments,
-        prev_shard, bar, clock, compute,
+        prev_shard, bar, clock, compute, source,
     ) -> None:
         """One shard's compute over every block — the body the traced
         ``compute`` span (``compute``) wraps in ``_stream``: its ``dispatch`` child is
@@ -3510,11 +3670,15 @@ class StreamingExecutor:
                 bar.update(1)
             if not blocks:
                 bar.update(1)
+        # The shard's last block is enqueued: the producer's next upload may
+        # go out now, behind these steps and beside them.
+        source.dispatched()
         # Every store path is async now (cpu: copy_to_host_async +
         # depth-1 finalize; disk: writer thread), so block once per
-        # shard to keep compute_wall_s a device-time measure — the
-        # prefetch thread keeps uploading the next shard, and the
-        # disk writer keeps writing, concurrently with this wait.
+        # shard to keep compute_wall_s a device-time measure. The
+        # prefetch thread enqueues its next upload on the signal above,
+        # so that upload (and the disk writer's writes) run concurrently
+        # with this wait.
         # (blocks can be empty: num_batch > prompt count -> ex([]).)
         if blocks and visit.stores:
             with obs_trace.timed(

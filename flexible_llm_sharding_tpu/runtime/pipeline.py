@@ -269,6 +269,7 @@ class PipelineRunner:
                             use_pallas=self._use_pallas,
                         )
                         bar.update(1)
+                source.dispatched()  # the next upload goes out behind this stage's steps
                 self.recorder.record(
                     "stage_dispatch",
                     time.perf_counter() - t_stage,

@@ -1542,6 +1542,10 @@ class ServeEngine:
                             self._decode_shard(
                                 wave, shard_pos, layer_idxs, segments
                             )
+                if self._source is not None:  # streamed: set and cleared on this thread
+                    # Every wave's steps for this shard are enqueued: the
+                    # next upload may go out behind them.
+                    self._source.dispatched()
             # Back at the boundary: the next shard-0 admission is NOW.
             self._sweep_pos = 0
 

@@ -35,7 +35,11 @@ def idle_split(
     with the next one, the sweep's last up to ``t_end``; a shard that
     launched nothing has ``t_launch`` None and reads zeros). ``uploads``:
     ``shard_idx -> (t_enqueue, t_done)`` of the weight uploads seen to
-    completion. ``block_rows``: the rows of each block a shard is dispatched
+    completion, ``t_enqueue`` where the upload's ``device_put`` call
+    RETURNED: an upload of many leaves goes in leaf by leaf for
+    milliseconds, and a block launched during the call queues behind the
+    leaves already in, not behind the upload, so it counts as launched
+    before it. ``block_rows``: the rows of each block a shard is dispatched
     over, in order (what a block's device time is taken to grow with).
     Per shard, in order, ``(drained_s, own_upload_wait_s,
     behind_upload_s)``:

@@ -513,7 +513,7 @@ def test_completion_thread_keeps_bounded_state():
 
 IDLE = ("drained_s", "own_upload_wait_s", "behind_upload_s")
 NEW_FIELDS = IDLE + ("drained_shards", "launches_behind_upload", "gc_s",
-                     "gc_collections", "slow")
+                     "gc_collections", "slow", "uploads_ordered", "link_wait_s")
 
 
 @pytest.mark.parametrize(
@@ -568,12 +568,29 @@ NEW_FIELDS = IDLE + ("drained_shards", "launches_behind_upload", "gc_s",
         # A shard that launched nothing (no block) reads zeros.
         ("nothing launched", [(0, None, 4.0, None), (1, 5.0, 5.1, 5.5)], {},
          [(0.0, 0.0, 0.0), (0.0, 0.0, 0.0)]),
+        # A many-leaf upload: its device_put call runs from 10.01 to 10.06,
+        # leaf by leaf. Dated by the call's START every block after the
+        # first would count as queued behind it (0.29 s, the case above);
+        # dated by its END (what shard_table passes) the shard's last
+        # launch, 10.05, comes before the enqueue and nothing waited.
+        ("a many-leaf upload, dated where its call returned",
+         [(3, 10.0, 10.05, 10.5)], {3: (8.0, 9.0), 6: (10.06, 10.3)},
+         [(0.0, 0.0, 0.0)]),
+        # The same call ending between the second launch and the last of
+        # four (rows 1 : 1 : 1 : 1, launches at 10.0, 10.02, 10.04, 10.06):
+        # the two blocks launched by 10.03 run, half of the 0.2 s the two
+        # later ones take after the arrival; the idle starts where they end.
+        ("a many-leaf upload ending between two launches",
+         [(3, 10.0, 10.06, 10.5)], {3: (8.0, 9.0), 6: (10.03, 10.3)},
+         [(0.0, 0.0, round(10.3 - (10.0 + 0.2), 9))]),
     ],
 )
 def test_idle_split_on_synthetic_stamps(case, shards, uploads, want):
     from flexible_llm_sharding_tpu.utils.intervals import idle_split
 
     rows = (1, 3) if "rows known" in case or "taken off" in case else ()
+    if case == "a many-leaf upload ending between two launches":
+        rows = (1, 1, 1, 1)
     got = idle_split(shards, uploads, t_end=20.0, block_rows=rows)
     assert [tuple(round(x, 9) for x in row) for row in got] == want, case
 
@@ -686,7 +703,7 @@ def test_a_stalled_sweep_keeps_its_timeline(model_dir, monkeypatch, caplog):
     assert set(row) == {
         "shard_idx", "source_wait_s", "dispatch_s", "device_wait_s",
         "drained_s", "own_upload_wait_s", "behind_upload_s", "shard_load_s",
-        "upload_dispatch_s",
+        "upload_dispatch_s", "upload_ordered",
     }
     warnings = [r for r in caplog.records if "slow sweep" in r.getMessage()]
     assert len(warnings) == 1
@@ -896,11 +913,12 @@ _RESIDENT = (0, 1, 2, 3, 9, 10)
 
 @pytest.mark.parametrize("depth", [1, 2, 3])
 def test_producer_holds_depth_plus_two_streamed_shards(monkeypatch, deep_dir, depth):
-    """The queue counts shards, resident ones too: with the consumer at the
-    head no streamed layer is on its way yet; the queue's places, the shard
-    in the producer's hand and the one at the consumer make at most depth +
-    2 streamed shards alive (what residency.in_flight_bytes reserves), that
-    bound is reached, and shards arrive in order."""
+    """The slots count shards, resident ones too: with the consumer at the
+    head no streamed layer is on its way yet; depth + 1 shards built and not
+    dispatched (the queue's places and the one in the producer's hand) and
+    the one whose steps are running make at most depth + 2 streamed shards
+    alive (what residency.in_flight_bytes reserves), that bound is reached,
+    and shards arrive in order."""
     source = _tracked_source(monkeypatch, deep_dir, depth, _RESIDENT)
     try:
         _wait_for(lambda: source._q.full())
@@ -910,7 +928,8 @@ def test_producer_holds_depth_plus_two_streamed_shards(monkeypatch, deep_dir, de
         got, peak = [], 0
         for idxs, segs in source:
             got.append(idxs)
-            time.sleep(0.01)  # let the producer use the place a take freed
+            source.dispatched()
+            time.sleep(0.01)  # let the producer use the slot that returned
             peak = max(peak, _Buffer.alive)
             assert _Buffer.alive <= depth + 2
         del segs
@@ -956,6 +975,343 @@ def test_cycling_source_keeps_its_bound_over_sweeps(monkeypatch, deep_dir):
     finally:
         source.close()
     assert _Buffer.alive == 0
+
+
+# ---------------------------------------------------------------------------
+# The order of enqueue: an upload's slot comes back at "dispatched" (PR 36)
+# ---------------------------------------------------------------------------
+
+def _log_puts(monkeypatch, fail=None):
+    """Log every ``_assemble_parts`` call (a build's device_put) as ("put", n),
+    n the shard's position (calls come in shard order, a retried one again
+    with the same n). ``fail(n, attempt)`` True makes that attempt raise."""
+    events, lock = [], threading.Lock()
+    orig = executor_mod._assemble_parts
+    state = {"n": -1, "attempt": 0, "parts": None}
+
+    def logged(parts, *a, **k):
+        with lock:
+            if parts is not state["parts"]:  # a retry passes the same list
+                state.update(n=state["n"] + 1, attempt=0, parts=parts)
+            state["attempt"] += 1
+            n, attempt = state["n"], state["attempt"]
+            events.append(("put", n))
+        if fail is not None and fail(n, attempt):
+            raise OSError(f"planted: shard {n} attempt {attempt}")
+        return orig(parts, *a, **k)
+
+    monkeypatch.setattr(executor_mod, "_assemble_parts", logged)
+
+    def say(*event):
+        with lock:
+            events.append(event)
+
+    def puts():
+        with lock:
+            return len({e[1] for e in events if e[0] == "put"})
+
+    return events, say, puts
+
+
+def _most_built_not_dispatched(events):
+    most = held = 0
+    seen = set()
+    for kind, n in events:
+        if kind == "put" and n not in seen:
+            seen.add(n)
+            held += 1
+        elif kind == "dispatched":
+            held -= 1
+        most = max(most, held)
+    return most
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_an_upload_goes_out_behind_the_dispatch_it_was_waiting_for(
+    monkeypatch, deep_dir, depth
+):
+    """For every shard k the device_put of shard k + depth + 1 begins after
+    k's last block is dispatched (never before: the consumer dawdles first)
+    and before k's shard-end wait returns (the consumer does not take the
+    next shard until it has seen that put begin); shards built and not
+    dispatched never exceed depth + 1."""
+    events, say, puts = _log_puts(monkeypatch)
+    source = _tracked_source(monkeypatch, deep_dir, depth, ())
+    n = len(source.shards)
+    try:
+        for k, (idxs, segs) in enumerate(source):
+            time.sleep(0.02)  # the blocks' dispatch: the producer has its time
+            assert puts() == min(k + depth + 1, n)  # nothing past its slots
+            say("dispatched", k)
+            source.dispatched()
+            source.dispatched()  # a second call returns nothing
+            _wait_for(lambda: puts() == min(k + depth + 2, n), timeout_s=5.0)
+        del segs
+    finally:
+        source.close()
+    first_put = {}
+    for i, (kind, j) in enumerate(events):
+        if kind == "put":
+            first_put.setdefault(j, i)
+    for k in range(n - depth - 1):
+        assert events.index(("dispatched", k)) < first_put[k + depth + 1]
+    assert _most_built_not_dispatched(events) == depth + 1
+    acct = source.account(0.0, time.perf_counter())
+    assert acct["producer_blocked_s"] > 0  # the waits for a slot are counted
+
+
+@pytest.mark.parametrize("how", ["close", "abort_then_close"])
+def test_stopping_a_source_whose_producer_waits_for_a_slot(monkeypatch, deep_dir, how):
+    built = []
+    orig = executor_mod._split_parts
+
+    def logged(loader, layer_idxs, *a, **k):
+        out = orig(loader, layer_idxs, *a, **k)
+        built.append(layer_idxs)
+        return out
+
+    monkeypatch.setattr(executor_mod, "_split_parts", logged)
+    events, say, puts = _log_puts(monkeypatch)
+    source = _tracked_source(monkeypatch, deep_dir, 2, ())
+    it = iter(source)
+    idxs, segs = next(it)  # held and never dispatched: no slot comes back
+    # The host side of the fourth build is done and its device_put waits.
+    _wait_for(lambda: len(built) == 4)
+    time.sleep(0.05)
+    assert puts() == 3 and source._thread.is_alive()
+    t0 = time.monotonic()
+    if how == "abort_then_close":
+        source.abort()
+    source.close()
+    assert time.monotonic() - t0 < 1.0
+    assert source._thread is None and puts() == 3
+    del it, segs
+    import gc
+
+    gc.collect()
+    assert _Buffer.alive == 0
+
+
+@pytest.mark.parametrize("cycle", [False, True], ids=["one_sweep", "cycling_two"])
+def test_a_consumer_that_never_says_dispatched_is_served_all_the_same(
+    monkeypatch, deep_dir, cycle
+):
+    """The slot of a shard comes back when the consumer asks for the next:
+    no deadlock, the same bound, no upload counted as ordered."""
+    source = _tracked_source(monkeypatch, deep_dir, 2, (), cycle=cycle)
+    want = source.shards * (2 if cycle else 1)
+    got = []
+    try:
+        it = iter(source)
+        for _ in want:
+            idxs, segs = next(it)
+            got.append(idxs)
+            assert _Buffer.alive <= 4
+        if not cycle:
+            with pytest.raises(StopIteration):
+                next(it)
+        del segs
+    finally:
+        source.close()
+    assert got == want
+    assert source.uploads_ordered == 0
+
+
+def test_a_retried_device_put_takes_one_slot(monkeypatch, deep_dir):
+    from flexible_llm_sharding_tpu.faults.retry import RetryPolicy
+
+    events, say, puts = _log_puts(
+        monkeypatch, fail=lambda n, attempt: n == 4 and attempt <= 3
+    )
+    source = _tracked_source(
+        monkeypatch, deep_dir, 2, (),
+        retry_policy=RetryPolicy(max_attempts=5, base_delay_s=0.001),
+    )
+    try:
+        for idxs, segs in source:
+            source.dispatched()
+        del segs
+    finally:
+        source.close()
+    assert [e for e in events if e == ("put", 4)] == [("put", 4)] * 4
+    # Every build took one slot and every shard returned one.
+    assert len(source._slot_ordered) == 3
+    assert source._slots.acquire(blocking=False)
+    assert source._slots.acquire(blocking=False)
+    assert source._slots.acquire(blocking=False)
+    assert not source._slots.acquire(blocking=False)
+
+
+def test_a_device_put_that_fails_for_good_gives_its_slot_back(monkeypatch, deep_dir):
+    """The consumer takes a fault in that shard's place, which holds no
+    slot: the producer still gets depth + 1 builds past it."""
+    from flexible_llm_sharding_tpu.faults.retry import RetryPolicy
+
+    events, say, puts = _log_puts(monkeypatch, fail=lambda n, attempt: n == 4)
+    source = _tracked_source(
+        monkeypatch, deep_dir, 2, (),
+        retry_policy=RetryPolicy(max_attempts=2, base_delay_s=0.001),
+    )
+    try:
+        it = iter(source)
+        for _ in range(4):
+            idxs, segs = next(it)
+            source.dispatched()
+        with pytest.raises(executor_mod.ShardLoadError):
+            next(it)
+        # Shards 0-3 came and went, 4 failed: 5, 6 and 7 are built.
+        _wait_for(lambda: _Buffer.made == 7, timeout_s=5.0)
+        time.sleep(0.05)
+        assert _Buffer.made == 7
+        del segs
+    finally:
+        source.close()
+
+
+def test_a_streamed_tails_uploads_are_all_ordered(monkeypatch, deep_dir):
+    """Embedding, norm, head and the first two layers seated; the six layers
+    behind them streamed. The seating sweep uploads all eleven files, the
+    first depth + 1 on the slots the source starts with; the next sweep's
+    six uploads all go out on a slot that "dispatched" returned, each after
+    the last block of the shard three before it was dispatched."""
+    from flexible_llm_sharding_tpu.runtime import hostcache, residency
+
+    path, names = deep_dir
+    size = residency.layer_stream_bytes(path, names, False)
+    budget = size[0] + size[1] + size[2] + size[9] + size[10] + 16
+    residency.reset_process_tier()
+    hostcache.reset_process_cache()
+    events, lock = [], threading.Lock()
+    orig_put, orig_block = executor_mod._assemble_parts, executor_mod.process_block
+
+    def put(parts, *a, **k):
+        with lock:
+            events.append(("put", sum(e[0] == "put" for e in events)))
+        return orig_put(parts, *a, **k)
+
+    def block(cfg, dtype, segments, visit, store, b, *a, clock=None, **k):
+        out = orig_block(cfg, dtype, segments, visit, store, b, *a, clock=clock, **k)
+        with lock:
+            events.append(("block", clock.shard_idx, b))
+        return out
+
+    try:
+        ex = StreamingExecutor(
+            _account_cfg(path, hbm_pin_gb=budget / 1e9), tokenizer=FakeTokenizer()
+        )
+        ex(list(PROMPTS))  # seats the pins
+        seating = executor_mod.process_sweep_log()[-1]
+        with monkeypatch.context() as m:
+            m.setattr(executor_mod, "_assemble_parts", put)
+            m.setattr(executor_mod, "process_block", block)
+            ex(list(PROMPTS))
+        rec = executor_mod.process_sweep_log()[-1]
+    finally:
+        residency.reset_process_tier()
+        hostcache.reset_process_cache()
+    assert (seating["uploads"], seating["uploads_ordered"]) == (11, 8)
+    assert rec["pin_hits"] == 5
+    assert (rec["uploads"], rec["uploads_ordered"]) == (6, 6)
+    last_block = {}
+    for i, e in enumerate(events):
+        if e[0] == "block":
+            last_block[e[1]] = i
+    for k in range(11 - 3):
+        assert last_block[k] < events.index(("put", k + 3)), k
+
+
+@pytest.mark.parametrize("where", ["its_own_device", "another_device"])
+def test_a_shard_is_handed_over_once_the_newest_upload_to_its_device_arrived(
+    monkeypatch, deep_dir, where
+):
+    """Steps dispatched under an upload in flight would wait for the next
+    upload too: next() returns a shard for a device only when the newest
+    upload to THAT device has arrived (link_wait_s counts the wait); an
+    upload to another chip holds nothing back."""
+    arrived = threading.Event()
+    waited = []
+
+    def fake_wait(arrays):
+        waited.append(arrays)
+        assert arrived.wait(10.0)
+        return arrays
+
+    monkeypatch.setattr(executor_mod.jax, "block_until_ready", fake_wait)
+    d0, d1 = jax.devices()[:2]
+    n = len(deep_dir[1])
+    source = _tracked_source(
+        monkeypatch, deep_dir, 2, (),
+        # the first shard alone is for d0; or every shard (device=d0)
+        devices=[d0] + [d1] * (n - 1) if where == "another_device" else None,
+    )
+    got = []
+    taker = threading.Thread(target=lambda: got.append(next(iter(source))), daemon=True)
+    try:
+        _wait_for(lambda: _Buffer.made == 3)  # the three the slots allow are placed
+        taker.start()
+        taker.join(0.3)
+        if where == "its_own_device":
+            assert taker.is_alive() and not got and len(waited) == 1
+            arrived.set()
+            taker.join(5.0)
+            assert source.link_wait_s >= 0.25
+        else:
+            assert not waited and source.link_wait_s == 0.0
+        assert not taker.is_alive() and got[0][0] == source.shards[0]
+        if where == "its_own_device":
+            assert source._in_flight is None  # seen to arrive: held no longer
+    finally:
+        arrived.set()
+        source.close()
+    assert source._in_flight is None
+    got.clear()
+    waited.clear()
+    import gc
+
+    gc.collect()
+    assert _Buffer.alive == 0
+
+
+@pytest.fixture(scope="module")
+def serial_scores(model_dir):
+    """The schedule with no thread and no slots: prefetch_depth 0 builds each
+    shard in the consumer's next(), as before this mechanism existed."""
+    path, _ = model_dir
+    return StreamingExecutor(
+        _account_cfg(path, prefetch_depth=0), tokenizer=FakeTokenizer()
+    )(list(PROMPTS))
+
+
+@pytest.mark.parametrize("depth", [0, 1, 2])
+def test_scores_are_bit_identical_at_every_prefetch_depth(model_dir, serial_scores, depth):
+    path, _ = model_dir
+    got = StreamingExecutor(
+        _account_cfg(path, prefetch_depth=depth), tokenizer=FakeTokenizer()
+    )(list(PROMPTS))
+    rec = executor_mod.process_sweep_log()[-1]
+    assert rec["uploads"] == 7
+    assert rec["uploads_ordered"] == (max(0, 7 - depth - 1) if depth else 0)
+    for a, b in zip(got, serial_scores):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_shard_table_dates_an_upload_where_its_call_returned():
+    """The watcher's interval opens where the device_put call STARTS (the link
+    may carry from there on); for the idle split the upload is enqueued where
+    the call returned, 50 ms later here: shard 3's last launch, 20 ms into
+    the call, is before it, and nothing waited."""
+    from types import SimpleNamespace
+
+    stamps = executor_mod.ShardStamps(3, 0.0, 9.99)
+    stamps.t_launch, stamps.t_wait, stamps.t_ready, stamps.t_end = 10.0, 10.03, 10.5, 10.5
+    clock = SimpleNamespace(shards=[stamps], block_rows=())
+    source = SimpleNamespace(_watcher=None, _produced={6: (0.001, 0.05, 1)})
+    intervals = [(8.0, 9.0, 3), (10.01, 10.3, 6)]
+    (row,) = executor_mod.ShardWeightSource.shard_table(source, clock, 20.0, intervals)
+    assert row["behind_upload_s"] == 0.0 and row["upload_ordered"] == 0
+    source._produced[6] = (0.001, 0.001, 1)  # one leaf: enqueued at 10.011
+    (row,) = executor_mod.ShardWeightSource.shard_table(source, clock, 20.0, intervals)
+    assert row["behind_upload_s"] == pytest.approx(10.3 - 10.011)
 
 
 def test_runs_are_the_parts_split_parts_builds():
