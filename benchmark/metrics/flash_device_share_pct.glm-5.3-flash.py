@@ -1,0 +1,13 @@
+"""The latent layers' flash attention kernels' share of the device's busy time
+in the traced batches (the ``pallas:flash_*`` ops over the union of all op
+intervals)."""
+
+from benchmark.families.glm5_next_text import readers
+
+
+def read(run):
+    kernel_s = readers.flash_kernel_s(run)
+    tr = run.get("trace")
+    if not kernel_s or not tr or not tr.get("busy_s"):
+        return None
+    return 100.0 * kernel_s / tr["busy_s"]

@@ -461,6 +461,27 @@ class LlamaConfig:
     # published value) reads the last step. 1 = every other model.
     total_ut_steps: int = 1
     early_exit_threshold: float = 1.0
+    # Which linear attention ``layer_linear`` layers run: ``lightning`` (a
+    # decayed sum, one rate a head: ops/lightning_attention.py) or ``kda``
+    # (GLM-5.3's delta-rule state with a decay per key channel and token,
+    # behind a causal depthwise convolution of ``linear_conv_size`` taps on
+    # q, k and v; the decay's log is ``linear_gate_lower_bound * sigmoid(.)``,
+    # which bounds it from below: ops/kda_attention.py).
+    linear_kind: str = "lightning"
+    linear_conv_size: int = 0
+    linear_gate_lower_bound: float = 0.0
+    # Manifold-constrained hyper-connections (mHC): the residual between
+    # layers is ``hc_mult`` streams of ``hidden_size``, a row ``hc_mult *
+    # hidden_size`` wide; each sublayer reads one mix of them and writes back
+    # through a Sinkhorn-normalised (``hc_sinkhorn_iters`` rounds) stream-
+    # mixing matrix (models/llama.py ``_hc_pre`` / ``_hc_post``). 1 = the
+    # plain ``x + y`` residual of every other model.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    # SwiGLU clamp: ``silu(min(gate, L)) * clip(up, -L, L)`` in every MLP
+    # and expert. None = unclamped.
+    swiglu_limit: float | None = None
 
     def attn_shape(self, sliding: bool = False, linear: bool = False) -> tuple[int, int, int, int]:
         """(heads, kv heads, qk head dim, v head dim) of a layer kind."""
@@ -778,6 +799,107 @@ class LlamaConfig:
         kwargs["total_ut_steps"] = int(d.get("total_ut_steps", 4))
         kwargs["early_exit_threshold"] = float(d.get("early_exit_threshold", 1.0))
 
+    @staticmethod
+    def _apply_glm5_next(kwargs: dict[str, Any], d: dict[str, Any]) -> None:
+        """GLM-5.3 (``glm5_next_text``): ``layer_types`` lists each layer's
+        mixer, ``linear_attention`` (KDA: ``linear_attn_config``) or
+        ``deepseek_sparse_attention`` (latent attention with NO rotary part,
+        ``qk_rope_head_dim`` 0, LoRA'd queries; dense over every earlier key
+        while the prompt has at most ``index_topk`` tokens, refused past
+        that: the indexer that selects keys there is not built);
+        ``mlp_layer_types`` / ``first_k_dense_replace`` the dense and the
+        expert layers (the DeepSeek router, one shared expert); ``hc_*`` the
+        four-stream residual; ``swiglu_limit`` the clamp. Width convention
+        as deepseek_v3; the held share of the experts from ``ep_size`` /
+        ``ep_rank`` as mimo_v2_flash."""
+        n = int(d.get("num_hidden_layers", 32))
+        kinds = list(d.get("layer_types") or ["deepseek_sparse_attention"] * n)[:n]
+        unknown = sorted(set(kinds) - {"linear_attention", "deepseek_sparse_attention"})
+        if len(kinds) != n or unknown:
+            raise ValueError(
+                f"glm5_next_text layer_types has {len(kinds)} entries for {n} "
+                f"layers (unknown kinds: {unknown})"
+            )
+        if not d.get("mla_use_nope", True) or int(d.get("qk_rope_head_dim") or 0):
+            raise NotImplementedError(
+                "glm5_next_text with a rotary part in its latent attention "
+                "(mla_use_nope false / qk_rope_head_dim > 0) is not supported"
+            )
+        kwargs["kv_lora_rank"] = int(d.get("kv_lora_rank", 512))
+        qlr = d.get("q_lora_rank")
+        kwargs["q_lora_rank"] = int(qlr) if qlr else None
+        kwargs["qk_nope_head_dim"] = int(d.get("qk_nope_head_dim", 256))
+        kwargs["qk_rope_head_dim"] = 0
+        kwargs["v_head_dim"] = int(d.get("v_head_dim", 256))
+        kwargs["explicit_head_dim"] = None
+        kwargs["query_pre_attn_scalar"] = float(kwargs["qk_nope_head_dim"])
+        kwargs["sliding_window"] = None
+        if d.get("attention_bias"):
+            raise NotImplementedError("glm5_next_text with attention_bias")
+        linear = tuple(k == "linear_attention" for k in kinds)
+        if any(linear):
+            la = d.get("linear_attn_config") or {}
+            lh, ld = int(la.get("num_heads", 64)), int(la.get("head_dim", 128))
+            kwargs["layer_linear"] = linear
+            kwargs["linear_attn_shape"] = (lh, lh, ld, ld)
+            kwargs["linear_kind"] = "kda"
+            kwargs["linear_conv_size"] = int(la.get("short_conv_kernel_size", 4))
+            kwargs["linear_gate_lower_bound"] = float(la.get("gate_lower_bound", -5))
+            kwargs["linear_output_norm"] = kwargs["linear_output_gate"] = True
+            if not -88.0 / 16 < kwargs["linear_gate_lower_bound"] < 0:
+                raise NotImplementedError(
+                    "glm5_next_text gate_lower_bound "
+                    f"{kwargs['linear_gate_lower_bound']}: the chunked form "
+                    "holds exp(+-cumsum g) over 16 rows in float32 only for "
+                    "a bound in (-5.5, 0)"
+                )
+        # Past index_topk tokens the indexer picks a query's keys; up to
+        # there top-k of fewer keys is all of them.
+        kwargs["sparse_attn_from"] = int(d.get("index_topk", 2048)) + 1
+        if d.get("mhc"):
+            kwargs["hc_mult"] = int(d.get("hc_mult", 4))
+            kwargs["hc_sinkhorn_iters"] = int(d.get("hc_sinkhorn_iters", 20))
+            kwargs["hc_eps"] = float(d.get("hc_eps", 1e-6))
+        lim = d.get("swiglu_limit")
+        kwargs["swiglu_limit"] = None if lim is None else float(lim)
+        n_routed = int(d.get("n_routed_experts") or 0)
+        kwargs["num_local_experts"] = n_routed
+        if not n_routed:
+            return
+        if d.get("scoring_func", "sigmoid") != "sigmoid":
+            raise NotImplementedError(
+                f"glm5_next_text scoring_func {d.get('scoring_func')!r} (sigmoid is supported)"
+            )
+        kwargs["intermediate_size_mlp"] = int(d.get("intermediate_size", 12288))
+        kwargs["intermediate_size"] = int(d.get("moe_intermediate_size", 2048))
+        kwargs["num_experts_per_tok"] = int(d.get("num_experts_per_tok", 8))
+        kwargs["moe_norm_topk_prob"] = bool(d.get("norm_topk_prob", True))
+        kwargs["moe_n_group"] = int(d.get("n_group") or 1)
+        kwargs["moe_topk_group"] = int(d.get("topk_group") or 1)
+        rsf = d.get("routed_scaling_factor")
+        kwargs["moe_routed_scaling_factor"] = 1.0 if rsf is None else float(rsf)
+        nse = d.get("n_shared_experts")
+        kwargs["n_shared_experts"] = 1 if nse is None else int(nse)
+        mlps = d.get("mlp_layer_types")
+        if mlps:
+            moe = tuple(t == "sparse" for t in list(mlps)[:n])
+        else:
+            first = int(d.get("first_k_dense_replace", 0))
+            moe = tuple(i >= first for i in range(n))
+        if len(moe) != n:
+            raise ValueError(
+                f"glm5_next_text mlp_layer_types has {len(moe)} entries for {n} layers"
+            )
+        if not all(moe):
+            kwargs["moe_layer_pattern"] = moe
+        ep, rank = int(d.get("ep_size") or 1), int(d.get("ep_rank") or 0)
+        if n_routed % ep or not 0 <= rank < ep:
+            raise ValueError(
+                f"glm5_next_text: {n_routed} experts do not split over ep_size {ep} "
+                f"(ep_rank {rank})"
+            )
+        kwargs["moe_ep_size"], kwargs["moe_ep_rank"] = ep, rank
+
     @classmethod
     def from_hf_config(cls, d: dict[str, Any]) -> "LlamaConfig":
         known = {f.name for f in dataclasses.fields(cls)}
@@ -1034,6 +1156,9 @@ class LlamaConfig:
         elif model_type == "ouro":
             if not native:
                 cls._apply_ouro(kwargs, d)
+        elif model_type == "glm5_next_text":
+            if not native:
+                cls._apply_glm5_next(kwargs, d)
         elif model_type in ("mistral", "mixtral", "phi3"):
             # sliding_window flows through by field name (may be null);
             # mixtral's num_local_experts/num_experts_per_tok likewise.
@@ -1047,16 +1172,17 @@ class LlamaConfig:
                 f"model_type {model_type!r} is not supported "
                 "(llama, mistral, phi3, qwen2, qwen3, qwen3_moe, mixtral, gemma, "
                 "gemma2, gemma3_text, llama4_text, deepseek_v3, mimo_v2_flash, "
-                "minicpm_sala, ouro are)"
+                "minicpm_sala, ouro, glm5_next_text are)"
             )
         if model_type not in (
-            "mixtral", "llama4_text", "qwen3_moe", "deepseek_v3", "mimo_v2_flash"
+            "mixtral", "llama4_text", "qwen3_moe", "deepseek_v3", "mimo_v2_flash",
+            "glm5_next_text",
         ):
             # A stray num_local_experts key in a dense export must not flip
             # the model into MoE mode (same stray-key defence as
             # sliding_window above).
             kwargs["num_local_experts"] = 0
-        if d.get("head_dim") and model_type != "deepseek_v3":
+        if d.get("head_dim") and model_type not in ("deepseek_v3", "glm5_next_text"):
             # deepseek's top-level head_dim is the ROTARY dim, not a
             # projection width; the MLA head_dim property derives
             # qk_nope + qk_rope itself.

@@ -56,6 +56,7 @@ from flexible_llm_sharding_tpu.ops import (
 )
 from flexible_llm_sharding_tpu.ops import (
     grouped_matmul,
+    kda_attention,
     lightning_attention,
     pallas_attention,
 )
@@ -125,6 +126,8 @@ def is_linear(cfg: LlamaConfig, attn: Params) -> bool:
     once a kind, not once a layer."""
     if cfg.layer_linear is None:
         return False
+    if cfg.linear_kind == "kda":  # only that kind has a decay's weights
+        return "A_log" in attn
     widths = lambda shape: (shape[1] * shape[2], shape[1] * shape[3])
     linear, full = cfg.attn_shape(linear=True), cfg.attn_shape()
     if widths(linear) != widths(full):
@@ -140,8 +143,8 @@ def layer_log_decay(cfg: LlamaConfig) -> np.ndarray | None:
     ``2^(-8 (n + 1) / H) * (1 - l / (L - 1) + 1e-5)`` a token. No tensor of a
     checkpoint: a function of the layer's index among all layers, which is
     why it reaches a layer as an argument."""
-    if cfg.layer_linear is None:
-        return None
+    if cfg.layer_linear is None or cfg.linear_kind != "lightning":
+        return None  # a KDA layer's decay is its weights' and its input's
     h, n = cfg.linear_attn_shape[0], cfg.num_hidden_layers
     slopes = 2.0 ** (-8.0 * (np.arange(h) + 1) / h)
     factor = 1.0 - np.arange(n) / max(n - 1, 1) + 1e-5
@@ -209,6 +212,8 @@ def _qkv_mla(attn: Params, cfg: LlamaConfig, x: jax.Array, positions, total_len=
         rms_norm(c_kv, attn["kv_a_norm"], eps, False), attn["kv_b"]
     ).reshape(*x.shape[:-1], nh, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
+    if not dr:  # no rotary part at all (GLM-5.3's ``mla_use_nope``)
+        return q, k_nope, v
 
     cos, sin = rope_cos_sin(
         positions, dr, cfg.rope_theta, cfg.rope_scaling_spec, total_len=total_len
@@ -251,9 +256,19 @@ _ACT = {
 assert set(_ACT) == set(SUPPORTED_ACTIVATIONS)  # config validates against this
 
 
+def _glu(act, gate: jax.Array, up, limit: float | None) -> jax.Array:
+    """``act(gate) * up()``, and under a ``swiglu_limit`` L ``act(min(gate,
+    L)) * clip(up(), -L, L)``. ``up`` is a thunk, called after the gate's
+    activation: the order every unclamped model's program was traced in."""
+    if limit is None:
+        return act(gate) * up()
+    with jax.named_scope("swiglu_clamp"):
+        return act(jnp.minimum(gate, limit)) * jnp.clip(up(), -limit, limit)
+
+
 @jax.named_scope("mlp")
-def _dense_mlp(mlp: Params, x: jax.Array, act) -> jax.Array:
-    h = act(_lin(x, mlp, "gate", "bgate")) * _lin(x, mlp, "up", "bup")
+def _dense_mlp(mlp: Params, x: jax.Array, act, limit: float | None = None) -> jax.Array:
+    h = _glu(act, _lin(x, mlp, "gate", "bgate"), lambda: _lin(x, mlp, "up", "bup"), limit)
     return _lin(h, mlp, "down", "bdown")
 
 
@@ -345,6 +360,7 @@ def _routed_experts(
     held: range,
     act,
     use_pallas: bool = False,
+    limit: float | None = None,
 ) -> jax.Array:
     """The routed experts' part of an MoE layer, computing a row only in the
     experts its router chose: rows ``x [R, D]`` with the router's choices
@@ -392,7 +408,9 @@ def _routed_experts(
     grouped = grouped_matmul.for_groups(
         group_sizes, use_pallas, _PRECISION if x.dtype == jnp.float32 else None
     )
-    y = act(grouped(xs, gate.astype(x.dtype))) * grouped(xs, up.astype(x.dtype))
+    y = _glu(
+        act, grouped(xs, gate.astype(x.dtype)), lambda: grouped(xs, up.astype(x.dtype)), limit
+    )
     y = grouped(y * ws[:, None], down.astype(x.dtype), jnp.float32)
     y = y[inv.reshape(k, r)]  # [k, R, D]
     y = jnp.where(on_held[..., None], y, 0.0)
@@ -467,22 +485,27 @@ def _deepseek_moe_mlp(
                 stats.append(
                     jnp.stack([hits.sum(), jnp.asarray(top_idx.size)]).astype(jnp.int32)
                 )
-    act = _ACT[cfg.hidden_act]
+    act, limit = _ACT[cfg.hidden_act], cfg.swiglu_limit
     with jax.named_scope("moe_experts"):
         if grouped:
             d = x.shape[-1]
             routed = _routed_experts(
                 x.reshape(-1, d), top_idx.reshape(-1, k), top_w.reshape(-1, k),
-                mlp["gate"], mlp["up"], mlp["down"], ids, act, use_pallas,
+                mlp["gate"], mlp["up"], mlp["down"], ids, act, use_pallas, limit,
             ).reshape(x.shape)
         else:
             combine = jnp.sum(
                 jax.nn.one_hot(top_idx, e, dtype=jnp.float32) * top_w[..., None],
                 axis=-2,
             ).astype(x.dtype)[..., ids.start : ids.stop]  # [..., L, held]
-            h = act(
-                jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION)
-            ) * jnp.einsum("...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION)
+            h = _glu(
+                act,
+                jnp.einsum("...ld,edf->...lef", x, mlp["gate"].astype(x.dtype), precision=_PRECISION),
+                lambda: jnp.einsum(
+                    "...ld,edf->...lef", x, mlp["up"].astype(x.dtype), precision=_PRECISION
+                ),
+                limit,
+            )
             c = combine[..., None]
             h = jnp.where(c != 0, h * c, jnp.zeros_like(h))
             routed = jnp.einsum(
@@ -492,7 +515,7 @@ def _deepseek_moe_mlp(
         return routed
     with jax.named_scope("moe_shared_experts"):
         shared = _mm(
-            act(_mm(x, mlp["shared_gate"])) * _mm(x, mlp["shared_up"]),
+            _glu(act, _mm(x, mlp["shared_gate"]), lambda: _mm(x, mlp["shared_up"]), limit),
             mlp["shared_down"],
         )
     return routed + shared
@@ -511,7 +534,10 @@ def _mlp(
     if "router" in mlp:
         assert cfg is not None and cfg.num_local_experts > 0
         return _moe_mlp(mlp, cfg, x)
-    return _dense_mlp(mlp, x, _ACT[cfg.hidden_act if cfg is not None else "silu"])
+    return _dense_mlp(
+        mlp, x, _ACT[cfg.hidden_act if cfg is not None else "silu"],
+        None if cfg is None else cfg.swiglu_limit,
+    )
 
 
 def _gate_heads(attn: Params, cfg: LlamaConfig, o: jax.Array, h: jax.Array) -> jax.Array:
@@ -526,6 +552,9 @@ def _gate_heads(attn: Params, cfg: LlamaConfig, o: jax.Array, h: jax.Array) -> j
     if "wg" in attn:
         with jax.named_scope("output_gate"):
             o = o * jax.nn.sigmoid(_mm(h, attn["wg"])).reshape(o.shape)
+    elif "wg_a" in attn:  # the gate through a narrow waist (KDA)
+        with jax.named_scope("output_gate"):
+            o = o * jax.nn.sigmoid(_mm(_mm(h, attn["wg_a"]), attn["wg_b"])).reshape(o.shape)
     return o
 
 
@@ -537,13 +566,85 @@ def _scaled(cfg: LlamaConfig, y: jax.Array) -> jax.Array:
     return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
 
 
+def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``m`` float32 [n, n, ...], positive: ``iters`` rounds of (every row
+    over its sum + eps, then every column over its sum + eps), towards a
+    doubly stochastic matrix. The matrix axes lead so that whatever trails
+    (the rows of a block) lies along lanes."""
+    with jax.named_scope("sinkhorn"):
+        for _ in range(iters):
+            m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+            m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+        return m
+
+
+def _hc_pre(hc: Params, cfg: LlamaConfig, x: jax.Array):
+    """What a sublayer reads of the ``hc_mult`` residual streams, and how its
+    output goes back (mHC). x [..., n * D] -> (u [..., D], (h_post float32
+    [n, R], h_res float32 [n, n, R]) for ``_hc_post``; R the rows). From the
+    whole row, RMS-normed with no scale, one projection ``phi`` [n * D, 2 n +
+    n * n] gives the three mixes' logits: ``h_pre = sigmoid(a_pre p + b)``,
+    ``h_post = 2 sigmoid(a_post q + b)``, ``h_res = sinkhorn(exp(a_res R +
+    b))``, all float32; ``u = sum_i h_pre[i] X[i]``."""
+    n, f32 = cfg.hc_mult, jnp.float32
+    with jax.named_scope("hc_pre"):
+        rows = x.reshape(-1, x.shape[-1])
+        x32 = rows.astype(f32)
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + cfg.hc_eps)
+        logits = jnp.matmul(
+            x32 * inv, hc["phi"].astype(f32), precision=_PRECISION
+        ).T  # [2 n + n n, R]: the rows along lanes from here on
+        a, b = hc["a"].astype(f32), hc["b"].astype(f32)[:, None]
+        h_pre = jax.nn.sigmoid(a[0] * logits[:n] + b[:n])
+        h_post = 2.0 * jax.nn.sigmoid(a[1] * logits[n : 2 * n] + b[n : 2 * n])
+        h_res = sinkhorn(
+            jnp.exp(a[2] * logits[2 * n :] + b[2 * n :]).reshape(n, n, -1),
+            cfg.hc_sinkhorn_iters, cfg.hc_eps,
+        )
+        # Elementwise sums over the n streams (no dot: n is 4, R thousands).
+        streams = x32.reshape(-1, n, x.shape[-1] // n)
+        u = sum(h_pre[i][:, None] * streams[:, i] for i in range(n))
+        return u.astype(x.dtype).reshape(*x.shape[:-1], -1), (h_post, h_res)
+
+
+def _hc_post(cfg: LlamaConfig, x: jax.Array, y: jax.Array, mix) -> jax.Array:
+    """``X'[i] = sum_j h_res[i, j] X[j] + h_post[i] y`` in float32, rounded
+    once. x [..., n * D], y [..., D]."""
+    h_post, h_res = mix
+    n, f32 = cfg.hc_mult, jnp.float32
+    with jax.named_scope("hc_post"):
+        streams = x.reshape(-1, n, x.shape[-1] // n).astype(f32)
+        y32 = y.reshape(-1, y.shape[-1]).astype(f32)
+        out = [
+            sum(h_res[i, j][:, None] * streams[:, j] for j in range(n))
+            + h_post[i][:, None] * y32
+            for i in range(n)
+        ]
+        return jnp.stack(out, axis=1).astype(x.dtype).reshape(x.shape)
+
+
+def _sublayer_input(params: Params, cfg: LlamaConfig, x: jax.Array, hc: str, norm: str):
+    """(h, mix): a sublayer's normed input and, for a model with ``hc_mult``
+    residual streams, how its output is written back (``params[hc]``: the
+    sublayer's mHC weights); ``mix`` None = the plain ``x + y``."""
+    mix = None
+    if cfg.hc_mult > 1:
+        x, mix = _hc_pre(params[hc], cfg, x)
+    return rms_norm(x, params[norm]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset), mix
+
+
+def _attn_input(params: Params, cfg: LlamaConfig, x: jax.Array):
+    """``_sublayer_input`` of the attention sublayer."""
+    return _sublayer_input(params, cfg, x, "hc_attn", "input_layernorm")
+
+
 def _residual_attn(
-    params: Params, cfg: LlamaConfig, x: jax.Array, attn_out, h=None
+    params: Params, cfg: LlamaConfig, x: jax.Array, attn_out, h=None, mix=None
 ) -> jax.Array:
     """Residual add of the attention sublayer. Gemma2's sandwich layout
     (``ffw_sandwich_norms``) norms the sublayer OUTPUT before the add.
     ``h``: the layer's normed input, which an output gate reads
-    (``_gate_heads``)."""
+    (``_gate_heads``). ``mix``: ``_attn_input``'s, for ``hc_mult`` streams."""
     y = _out_proj(params["attn"], _gate_heads(params["attn"], cfg, attn_out, h))
     if cfg.ffw_sandwich_norms:
         y = rms_norm(
@@ -552,6 +653,8 @@ def _residual_attn(
             cfg.rms_norm_eps,
             cfg.norm_unit_offset,
         )
+    if mix is not None:
+        return _hc_post(cfg, x, y, mix)
     return x + _scaled(cfg, y)
 
 
@@ -570,7 +673,7 @@ def _residual_mlp(
         if cfg.ffw_sandwich_norms
         else "post_attention_layernorm"
     )
-    h = rms_norm(x, params[pre]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    h, mix = _sublayer_input(params, cfg, x, "hc_mlp", pre)
     y = _mlp(params["mlp"], h, cfg, stats, grouped, use_pallas)
     if cfg.ffw_sandwich_norms:
         y = rms_norm(
@@ -579,6 +682,8 @@ def _residual_mlp(
             cfg.rms_norm_eps,
             cfg.norm_unit_offset,
         )
+    if mix is not None:
+        return _hc_post(cfg, x, y, mix)
     return x + _scaled(cfg, y)
 
 
@@ -835,6 +940,8 @@ def embed(
         x = x * jnp.asarray(cfg.hidden_size**0.5, dtype)
     if cfg is not None and cfg.embed_multiplier is not None:
         x = x * jnp.asarray(cfg.embed_multiplier, dtype)  # MiniCPM's scale_emb
+    if cfg is not None and cfg.hc_mult > 1:  # every residual stream starts as e
+        x = jnp.tile(x, cfg.hc_mult)
     return x
 
 
@@ -855,7 +962,11 @@ def decoder_layer(
     ``total_len`` is longrope's real-length selector). A linear-attention
     layer (``is_linear``) takes ``log_decay`` [heads] and no mask: it is
     causal over x's L rows (x: [B, L, D])."""
-    h = rms_norm(x, params["input_layernorm"]["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    h, mix = _attn_input(params, cfg, x)
+    if is_linear(cfg, params["attn"]) and cfg.linear_kind == "kda":
+        attn_out, _, _ = _kda_mixer(params["attn"], cfg, h, None, None, None, False)
+        x = _residual_attn(params, cfg, x, attn_out, h, mix)
+        return _residual_mlp(params, cfg, x)
     q, k, v = positioned_qkv(params, cfg, h, positions, sliding, rope_on, total_len)
     if is_linear(cfg, params["attn"]):
         with _linear_attention_scope():
@@ -866,7 +977,7 @@ def decoder_layer(
             q, k, v, mask, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
             sink=params["attn"].get("sink"),
         )
-    x = _residual_attn(params, cfg, x, attn_out, h)
+    x = _residual_attn(params, cfg, x, attn_out, h, mix)
     return _residual_mlp(params, cfg, x)
 
 
@@ -937,11 +1048,91 @@ def linear_uses_kernel(cfg: LlamaConfig, lp: int, ls: int, use_pallas: bool, tp_
     bucket ``ls``) prompt runs the Pallas kernel or the XLA op: one choice for
     both of its calls, from the shapes (the sweep's record counts by it)."""
     _, _, d, vd = cfg.attn_shape(linear=True)
+    op = kda_attention if cfg.linear_kind == "kda" else lightning_attention
     return (
         use_pallas and tp_mesh is None
-        and lightning_attention.supports(d, vd, lp)
-        and lightning_attention.supports(d, vd, ls)
+        and op.supports(d, vd, lp) and op.supports(d, vd, ls)
     )
+
+
+def _causal_conv(x: jax.Array, taps: jax.Array, tail: jax.Array | None) -> jax.Array:
+    """Causal depthwise convolution over the rows: ``y_t = sum_j taps[j] *
+    x_{t - (K - 1) + j}`` (the last tap on the row itself), float32. x
+    [..., L, C]; taps [K, C]; tail [..., K - 1, C]: the rows before x's first
+    (None = zeros: a sequence's start)."""
+    kk = taps.shape[0]
+    x32 = x.astype(jnp.float32)
+    if tail is None:
+        tail = jnp.zeros((*x.shape[:-2], kk - 1, x.shape[-1]), jnp.float32)
+    padded = jnp.concatenate([tail.astype(jnp.float32), x32], axis=-2)
+    length = x.shape[-2]
+    w = taps.astype(jnp.float32)
+    return sum(
+        w[j] * jax.lax.slice_in_dim(padded, j, j + length, axis=-2) for j in range(kk)
+    )
+
+
+def _kda_mixer(attn: Params, cfg: LlamaConfig, h: jax.Array, live, tail, state, kernel: bool):
+    """A KDA layer's heads over rows ``h`` [N, L, D] (normed input): q, k, v
+    projections through a causal short convolution and SiLU, q and k
+    L2-normalised a head (q over sqrt(d) besides), a log-decay ``g =
+    gate_lower_bound * sigmoid(exp(A_log) * (W_f2 W_f1 h + dt_bias))`` a key
+    channel and ``beta = sigmoid(W_b h)`` a head, then the delta rule
+    (``ops/kda_attention.py``). ``live`` bool [L] (None = all): rows past a
+    right-padded sequence's end get ``g = 0``, ``beta = 0`` and leave the
+    state alone. ``tail`` [N, K - 1, 3 H d]: the pre-convolution rows before
+    the first (None = a sequence's start); ``state`` float32 [H, d, dv] the N
+    sequences start from (None = zeros). -> (o [N, L, H, dv], the
+    pre-convolution rows [N, L, 3 H d], the final states [N, H, d, dv])."""
+    nh, _, d, dv = cfg.attn_shape(linear=True)
+    f32 = jnp.float32
+    lead = h.shape[:-1]
+    with jax.named_scope("kda_conv"):
+        pre = jnp.concatenate([_mm(h, attn[w]) for w in ("wq", "wk", "wv")], axis=-1)
+        taps = jnp.concatenate([attn[w] for w in ("conv_q", "conv_k", "conv_v")], axis=-1)
+        qkv = jax.nn.silu(_causal_conv(pre, taps, tail))
+        q, k, v = jnp.split(qkv, [nh * d, 2 * nh * d], axis=-1)
+        q, k = q.reshape(*lead, nh, d), k.reshape(*lead, nh, d)
+        unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+        q, k = unit(q) * d**-0.5, unit(k)
+    with jax.named_scope("kda_gate"):
+        f = _mm(_mm(h, attn["f_a"]), attn["f_b"]).astype(f32) + attn["dt_bias"].astype(f32)
+        rate = jnp.exp(attn["A_log"].astype(f32))[:, None]
+        g = cfg.linear_gate_lower_bound * jax.nn.sigmoid(rate * f.reshape(*lead, nh, d))
+        beta = jax.nn.sigmoid(_mm(h, attn["wb"]).astype(f32))  # [N, L, H]
+        if live is not None:
+            g = jnp.where(live[:, None, None], g, 0.0)
+            beta = jnp.where(live[:, None], beta, 0.0)
+    op = kda_attention.kda_attention if kernel else kda_attention.kda_attention_xla
+    with _linear_attention_scope(), jax.named_scope("kda_attention"):
+        o, final = op(
+            q.astype(h.dtype), k.astype(h.dtype), v.reshape(*lead, nh, dv).astype(h.dtype),
+            g, beta, state,
+        )
+    return o, pre, final
+
+
+def _kda_prefix_suffix(params, cfg, prefix_h, suffix_h, prefix_len, kernel: bool):
+    """``_linear_prefix_suffix`` for a KDA layer. The prefix hands its
+    suffixes TWO things at the dynamic ``prefix_len`` inside its right-padded
+    bucket: the state (rows past ``prefix_len`` neither decay it nor write to
+    it, so the bucket's last is the one at ``prefix_len``) and the last
+    ``linear_conv_size - 1`` pre-convolution rows of q, k and v before
+    ``prefix_len`` (zeros where the prefix is shorter than that)."""
+    lp, kk = prefix_h.shape[0], cfg.linear_conv_size
+    h, mix = _attn_input(params, cfg, prefix_h)
+    live = jnp.arange(lp) < prefix_len
+    o, pre, state = _kda_mixer(params["attn"], cfg, h[None], live, None, None, kernel)
+    prefix_out = _residual_attn(params, cfg, prefix_h, o[0], h, mix)
+    # Row prefix_len - (K - 1) + j of the prefix is row prefix_len + j of the
+    # prefix with K - 1 rows of zeros before it.
+    tail = jax.lax.dynamic_slice_in_dim(
+        jnp.pad(pre[0], ((kk - 1, 0), (0, 0))), prefix_len, kk - 1, axis=0
+    )
+    hs, mix = _attn_input(params, cfg, suffix_h)
+    tails = jnp.broadcast_to(tail, (suffix_h.shape[0], *tail.shape))
+    os_, _, _ = _kda_mixer(params["attn"], cfg, hs, None, tails, state[0], kernel)
+    return prefix_out, _residual_attn(params, cfg, suffix_h, os_, hs, mix)
 
 
 def _linear_prefix_suffix(
@@ -949,6 +1140,8 @@ def _linear_prefix_suffix(
 ):
     """The attention half of ``prefix_suffix_layer`` for a linear-attention
     layer: (prefix, suffixes) residual streams as they enter the MLP half."""
+    if cfg.linear_kind == "kda":
+        return _kda_prefix_suffix(params, cfg, prefix_h, suffix_h, prefix_len, kernel)
     lp, ls = prefix_h.shape[0], suffix_h.shape[1]
     eps = cfg.rms_norm_eps
     op = (
@@ -1033,7 +1226,6 @@ def prefix_suffix_layer(
     """
     lp, _ = prefix_h.shape
     s, ls, _ = suffix_h.shape
-    eps = cfg.rms_norm_eps
     stats = [] if moe_stats else None
     if return_kv:
         cfg.require_one_attention_shape("a KV cache (return_kv)", layer_fn=True)
@@ -1062,7 +1254,7 @@ def prefix_suffix_layer(
     )
 
     # --- prefix: causal self-attention, keep post-RoPE KV ---
-    h = rms_norm(prefix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
+    h, mix = _attn_input(params, cfg, prefix_h)
     q, k, v = positioned_qkv(
         params, cfg, h, jnp.arange(lp), rope_sliding, rope_on, total_len
     )
@@ -1097,13 +1289,13 @@ def prefix_suffix_layer(
                 q, k, v, mask, scale=cfg.attn_scale,
                 softcap=cfg.attn_logit_softcap, sink=sink,
             )
-    prefix_out = _residual_attn(params, cfg, prefix_h, attn_out, h)
+    prefix_out = _residual_attn(params, cfg, prefix_h, attn_out, h, mix)
     if not attn_only:
         prefix_out = _residual_mlp(params, cfg, prefix_out, stats)
 
     # --- suffixes: batched attention over [shared prefix KV ; own causal KV],
     # prefix KV never expanded across suffixes (ops.prefix_shared_attention) ---
-    hs = rms_norm(suffix_h, params["input_layernorm"]["scale"], eps, cfg.norm_unit_offset)
+    hs, mix = _attn_input(params, cfg, suffix_h)
     pos_s = prefix_len + jnp.arange(ls)
     qs, ks, vs = positioned_qkv(
         params, cfg, hs, pos_s, rope_sliding, rope_on, total_len
@@ -1134,7 +1326,7 @@ def prefix_suffix_layer(
                 chunk=chunk,
                 sink=sink,
             )
-    suffix_out = _residual_attn(params, cfg, suffix_h, attn_s, hs)
+    suffix_out = _residual_attn(params, cfg, suffix_h, attn_s, hs, mix)
     if not attn_only:
         suffix_out = _residual_mlp(params, cfg, suffix_out, stats)
     out = (prefix_out, suffix_out)
@@ -1372,7 +1564,11 @@ def select_eos_and_norm(
 
 def final_norm(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     """``model.norm`` and, where the model has one, muP's logit divisor
-    (``hidden_size / dim_model_base``) on what the head reads."""
+    (``hidden_size / dim_model_base``) on what the head reads. A model with
+    ``hc_mult`` residual streams norms their sum."""
+    if cfg.hc_mult > 1:
+        streams = x.reshape(*x.shape[:-1], cfg.hc_mult, -1).astype(jnp.float32)
+        x = jnp.sum(streams, axis=-2).astype(x.dtype)
     x = rms_norm(x, params["scale"], cfg.rms_norm_eps, cfg.norm_unit_offset)
     if cfg.logit_divisor is not None:
         x = x / jnp.asarray(cfg.logit_divisor, x.dtype)

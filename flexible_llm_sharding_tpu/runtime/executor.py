@@ -434,15 +434,20 @@ def apply_segments(
         elif kind == "decoders":
             if clock is not None:
                 body, n = _expert_rows(
-                    params, prefix_h.size + suffix_h.size, tp_mesh
+                    params, (prefix_h.size + suffix_h.size) // model_cfg.hc_mult,
+                    tp_mesh,
                 )
                 clock.expert_rows[body] += n
                 body, n, state = _linear_rows(
                     model_cfg, params, prefix_h.shape, suffix_h.shape,
                     use_pallas, tp_mesh,
                 )
-                clock.linear_rows[body] += n
-                clock.linear_state_bytes = max(clock.linear_state_bytes, state)
+                if model_cfg.linear_kind == "kda":
+                    clock.kda_rows[body] += n
+                    clock.kda_state_bytes = max(clock.kda_state_bytes, state)
+                else:
+                    clock.linear_rows[body] += n
+                    clock.linear_state_bytes = max(clock.linear_state_bytes, state)
             prefix_h, suffix_h, *counts = _decoder_block(
                 model_cfg, params, prefix_h, suffix_h, prefix_len, use_pallas,
                 tp_mesh, total_len, moe_stats,
@@ -786,6 +791,10 @@ class SweepClock:
         # state one dispatch held: host counts from the shapes.
         self.linear_rows = {"kernel": 0, "xla": 0}
         self.linear_state_bytes = 0
+        # The same two counts for delta-rule (KDA) layers, whose rows run
+        # another kernel (ops/kda_attention.py).
+        self.kda_rows = {"kernel": 0, "xla": 0}
+        self.kda_state_bytes = 0
         # Steps of the online softmax the two scoring flash kernels run
         # (``_flash_steps``): a host count from the shapes and the prompts'
         # prefix lengths.
@@ -918,6 +927,9 @@ class SweepClock:
                 linear_rows_kernel=self.linear_rows["kernel"],
                 linear_rows_xla=self.linear_rows["xla"],
                 linear_state_bytes=self.linear_state_bytes,
+                kda_rows_kernel=self.kda_rows["kernel"],
+                kda_rows_xla=self.kda_rows["xla"],
+                kda_state_bytes=self.kda_state_bytes,
                 flash_steps=self.flash_steps,
             )
             if self.exit_sums:
@@ -1040,11 +1052,19 @@ def _model_account(model: LlamaConfig, moe_counts: list) -> dict:
     end) how many of the router's assignments landed on a held expert."""
     sliding = llama.layer_sliding_pattern(model)
     linear = sum(model.layer_linear or ())
+    kda = model.linear_kind == "kda"
+    heads, _, d, _ = model.attn_shape(linear=True)
     rec = {
         "window_layers": sum(sliding),
         "full_layers": len(sliding) - sum(sliding) - linear,
         "linear_layers": linear,
         "softmax_layers": len(sliding) - linear,
+        "kda_layers": linear if kda else 0,
+        # What a KDA layer's prefix hands each prompt's suffixes beside the
+        # state: the last (taps - 1) pre-convolution rows of q, k and v, in
+        # the compute dtype's two bytes.
+        "conv_tail_bytes": (model.linear_conv_size - 1) * 3 * heads * d * 2 * bool(kda and linear),
+        "hc_streams": model.hc_mult,
         "experts_held": len(model.held_experts),
         "router_width": model.num_local_experts,
     }
@@ -1169,6 +1189,16 @@ SWEEP_RECORD_HELP = {
     "recurrent state in place of keys and values).",
     "softmax_layers": "Decoder layers with softmax attention, window and "
     "full together.",
+    "kda_layers": "Of linear_layers, those that keep a delta-rule state with "
+    "a decay per key channel behind a short convolution (KDA, "
+    "ops/kda_attention.py); 0 for a lightning-attention model.",
+    "conv_tail_bytes": "What a KDA layer's prefix hands a prompt's suffixes "
+    "beside the state: the last (taps - 1) pre-convolution rows of q, k and "
+    "v at 2 bytes an element, a prompt and layer; inside the layer call, as "
+    "the state; 0 for a model without such layers.",
+    "hc_streams": "Residual streams between layers (hc_mult: a block's row "
+    "is hc_streams x hidden_size wide in the activation store); 1 for the "
+    "plain residual.",
     "experts_held": "Routed experts this process holds of each expert layer "
     "(all of them unless the model's config gives it a share).",
     "router_width": "Experts the router scores (0 for a dense model).",
@@ -1195,6 +1225,15 @@ SWEEP_RECORD_HELP = {
     "attention layers held: prompts in the block x heads x qk dim x v dim x "
     "4 bytes (float32), inside the layer call; it never enters the "
     "activation store.",
+    "kda_rows_kernel": "Rows x KDA layers dispatched with the Pallas kernel "
+    "(ops/kda_attention.py: kda_chunk); padding rows included, counted on "
+    "the host from the shapes. A KDA model reads 0 in linear_rows_*.",
+    "kda_rows_xla": "Rows x KDA layers dispatched with the XLA op of the same "
+    "mathematics (use_pallas off, a tensor-parallel mesh, or shapes the "
+    "kernel does not take); 0 in a scoring sweep on one chip.",
+    "kda_state_bytes": "The most delta-rule state one dispatch of KDA layers "
+    "held: prompts in the block x heads x qk dim x v dim x 4 bytes "
+    "(float32), inside the layer call.",
     "flash_steps": "Steps of the online softmax the two scoring flash "
     "kernels ran this sweep (every query head's loop trips over key tiles, "
     "a suffix's own keys one step): a host count from the shapes, the "
@@ -1763,9 +1802,12 @@ class _HostShardLoader:
             # passed (a verify failure raised out of the build above), so
             # cached trees are verified-clean by construction. Consumers
             # treat cached segments as immutable (_place only reads).
+            base = self._cache_key_base
             put = cache.put(
                 cache_key, segments, nbytes=shard_bytes, guard=guard,
                 evict=streamed,
+                # this model's other shards: the sweep's cycle (hostcache.put)
+                spare=lambda key: key[: len(base)] == base,
             )
             if put and pin:
                 self._ask_pinned(cache_key, segments)
@@ -2702,11 +2744,11 @@ class ShardWeightSource:
             return
         self._loader.trace_ids = {"sweep_id": self.sweep_id}
         cache = self._loader._host_cache
-        evictions = cache.evictions
+        full = cache.evictions + cache.scan_refusals
         built = set()  # a looped plan lists a run once a step
         for idxs in self.shards:
             for in_set, run in _runs(idxs, self._pinned_idxs):
-                if self._stop.is_set() or cache.evictions != evictions:
+                if self._stop.is_set() or cache.evictions + cache.scan_refusals != full:
                     return
                 if not in_set and run not in built:
                     built.add(run)
@@ -3216,13 +3258,18 @@ class StreamingExecutor:
             from flexible_llm_sharding_tpu.runtime import residency
 
             location = "cpu"
-            device_budget = residency.activation_budget_bytes(
+            tied = self.model_cfg.tie_word_embeddings
+            width = self.model_cfg.hidden_size * self.model_cfg.hc_mult
+            device_budget = residency.make_room_for_activations(
                 self.device,
                 self._residency,
-                residency.in_flight_bytes(
-                    self.cfg, self.layer_names,
-                    self.model_cfg.tie_word_embeddings,
-                ),
+                residency.in_flight_bytes(self.cfg, self.layer_names, tied),
+                # one generation of the pass's blocks: every prompt's prefix
+                # bucket and its suffixes' rows, a residual's width each
+                sum(
+                    t.prefix_ids.size + t.suffix_ids.size for t in toks
+                ) * width * self._np_dtype.itemsize,
+                tied,
             )
         store = ActivationStore(
             location,

@@ -144,6 +144,9 @@ class HostShardCache:
         self.misses = 0
         self.evictions = 0
         self.invalidations = 0
+        # put() calls that found the cache full of entries they were told
+        # to spare (a cyclic scan larger than the budget: see put()).
+        self.scan_refusals = 0
         # pinned_host copies (see pin()): requests by key, newest wins, and
         # the one thread that works them off while there are any.
         self._pin_requests: dict = {}  # guarded by: _lock
@@ -223,6 +226,7 @@ class HostShardCache:
         nbytes: int | None = None,
         guard: tuple | None = None,
         evict: bool = True,
+        spare=None,
     ) -> bool:
         """Insert one shard's host tree, guarded by the backing files'
         stats — pass ``guard`` captured via :func:`stat_guard` BEFORE the
@@ -231,7 +235,15 @@ class HostShardCache:
         False (uncached) when any path can't be stat'ed or the entry
         alone exceeds the budget. ``evict=False`` is for a tree that is
         read once (a layer the residency tier keeps from then on): it is
-        cached where there is room and pushes nothing out."""
+        cached where there is room and pushes nothing out. ``spare`` (a
+        predicate over keys): entries this tree must not push out; where
+        the least recently used entry is one of them the tree stays
+        uncached (``scan_refusals``). A loader spares its own model's
+        shards: a sweep reads them in a cycle, and a cycle longer than the
+        budget under plain LRU evicts each shard just before its next use
+        (every build a miss, sweep after sweep: 13.9 GB of streamed layers
+        over a budget of 11 GB read 0 hits, PERF.md PR 37); sparing them
+        keeps the first shards of the cycle and reads only the rest again."""
         if guard is None:
             guard = stat_guard(paths)
             if guard is None:
@@ -247,6 +259,9 @@ class HostShardCache:
                 return False
             while self.bytes + nbytes > self.budget_bytes and self._entries:
                 oldest = next(iter(self._entries))
+                if spare is not None and spare(oldest):
+                    self.scan_refusals += 1
+                    return False
                 self._drop(oldest)
                 self.evictions += 1
             self._entries[key] = (segments, int(nbytes), tuple(guard))
@@ -389,6 +404,7 @@ class HostShardCache:
                 "hits": self.hits,
                 "misses": self.misses,
                 "evictions": self.evictions,
+                "scan_refusals": self.scan_refusals,
                 "invalidations": self.invalidations,
                 "entries": len(self._entries),
                 "bytes": self.bytes,

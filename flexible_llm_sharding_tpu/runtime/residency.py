@@ -73,6 +73,14 @@ ACTIVATION_HEADROOM_FRACTION = 0.35
 # with large shards then pins less instead of failing to allocate where,
 # unpinned, it ran.
 SCRATCH_HEADROOM_FRACTION = 0.05
+# What a pass leaves for the programs it is about to compile when it sizes
+# the pins to its blocks (``make_room_for_activations``): a compiled step
+# lives on the chip too, and at the seating pass none is there yet. A model
+# of three layer kinds over 16 block shapes holds 48 decoder programs of
+# 20-30 MB of generated code each (AOT for the v5e; on the chip the process
+# held 0.99 GB beside its pins after a window: PERF.md, PR 37); the other
+# cells' programs take 0.10-0.22 GB (PR 32).
+PROGRAM_HEADROOM_FRACTION = 0.10
 
 
 def layer_stream_bytes(
@@ -331,6 +339,50 @@ def activation_budget_bytes(device, tier, in_flight_bytes: int) -> int:
     return int(max(0.0, limit - in_use - pins - in_flight_bytes) // 2)
 
 
+def make_room_for_activations(
+    device, tier, in_flight_bytes: int, need_bytes: int, tied_embeddings: bool
+) -> int:
+    """``activation_budget_bytes`` for a pass whose blocks take
+    ``need_bytes`` a generation, after giving up pins for them where that
+    makes them fit. ONE decision, at a process's seating pass, when the
+    blocks' sizes are first known and nothing is seated yet: an AUTO-sized
+    tier is re-planned to what the chip has free less the shards in flight,
+    less twice the need (the store takes half of what is left,
+    ``activation_budget_bytes``), less ``PROGRAM_HEADROOM_FRACTION`` of the
+    chip for the programs this pass is about to compile. A layer that
+    streams costs its bytes over the link once a sweep; a generation of
+    blocks that does not fit crosses it twice a SHARD, and the host copies
+    it (a residual of several streams, ``LlamaConfig.hc_mult``: 0.71 GB a
+    generation against a rest of 0.3 GB: PERF.md, PR 37). The tier keeps the
+    difference (``activation_reserve_bytes``) off every later auto budget. A
+    pass that fits as planned, an explicit ``hbm_pin_gb``, a brownout and a
+    tier with anything seated (the plan stands: a source froze its pin set
+    on it) change nothing, and what does not fit goes the ``cpu`` way as
+    before."""
+    budget = activation_budget_bytes(device, tier, in_flight_bytes)
+    if tier is None or budget >= need_bytes:
+        return budget
+    limit, in_use = _chip_account(device)
+    pins = int(
+        limit - in_use - in_flight_bytes - 2 * need_bytes
+        - PROGRAM_HEADROOM_FRACTION * limit
+    )
+    with _PROCESS_LOCK:
+        fixed = (
+            tier is not _PROCESS_TIER or _PROCESS_BUDGET_EXPLICIT
+            or tier.pressure_demoted or pins >= tier.plan.budget_bytes
+            or tier.pinned_device_bytes(device)
+        )
+    if fixed:
+        return budget
+    plan = plan_residency(tier.model_path, tier.layer_names, max(pins, 0), tied_embeddings)
+    with _PROCESS_LOCK:
+        if tier is _PROCESS_TIER and not _PROCESS_BUDGET_EXPLICIT:
+            tier.activation_reserve_bytes += tier.plan.budget_bytes - plan.budget_bytes
+            tier._install_plan(plan)
+    return activation_budget_bytes(device, tier, in_flight_bytes)
+
+
 def placement_key(device) -> tuple:
     """Stable identity of a placement target, so pins survive the target
     OBJECT being rebuilt (a NamedSharding recreated per scorer instance
@@ -454,6 +506,10 @@ class DeviceResidencyTier:
         # plan. Public so tier_for can read it without a tier method.
         self.pressure_demoted = False  # guarded by: _lock
         self._saved_plan: ResidencyPlan | None = None  # guarded by: _lock
+        # Bytes an auto budget leaves out of the pins for a pass's
+        # activations (make_room_for_activations): tier_for takes them off
+        # every later auto budget, so the plan does not grow back.
+        self.activation_reserve_bytes = 0  # guarded by: _PROCESS_LOCK
 
     # -- membership --------------------------------------------------------
 
@@ -744,6 +800,11 @@ def tier_for(
         bool(tied_embeddings),
     )
     global _PROCESS_TIER, _PROCESS_TIER_KEY, _PROCESS_BUDGET_EXPLICIT
+    if not explicit:
+        with _PROCESS_LOCK:
+            if _PROCESS_TIER is not None and _PROCESS_TIER_KEY == key:
+                # What a pass's activations were given stays theirs.
+                budget -= _PROCESS_TIER.activation_reserve_bytes
     # Planning stats every layer file on disk, so it never runs under
     # _PROCESS_LOCK (a wedged filesystem would stall process_tier() and
     # every source construction in the process): decide under the lock,
@@ -906,9 +967,11 @@ def plan_report(model_path: str, budget_bytes: int) -> dict:
 
 __all__ = [
     "ACTIVATION_HEADROOM_FRACTION",
+    "PROGRAM_HEADROOM_FRACTION",
     "DeviceResidencyTier",
     "ResidencyPlan",
     "activation_budget_bytes",
+    "make_room_for_activations",
     "auto_pin_budget_bytes",
     "in_flight_bytes",
     "layer_stream_bytes",

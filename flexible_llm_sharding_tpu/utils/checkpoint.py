@@ -533,6 +533,43 @@ def hf_layer_to_native(
     if gate_key in sd:  # MiniCPM-SALA: the output gate's kernel, both kinds
         consumed.add(gate_key)
         out["attn.wg"] = np.ascontiguousarray(sd[gate_key].T)
+    if f"{layer_name}.self_attn.A_log" in sd:
+        # GLM-5.3's KDA layers (names assumed, as the benchmark's
+        # configuration file says): the short convolutions' taps [C, 1, K] ->
+        # [K, C], the decay's and the output gate's two-matrix waists, the
+        # per-head decay rate and its bias, beta's projection.
+        for native_key, hf_sub, how in (
+            ("attn.conv_q", "q_conv1d.weight", "taps"),
+            ("attn.conv_k", "k_conv1d.weight", "taps"),
+            ("attn.conv_v", "v_conv1d.weight", "taps"),
+            ("attn.f_a", "f_a_proj.weight", "t"),
+            ("attn.f_b", "f_b_proj.weight", "t"),
+            ("attn.wg_a", "g_a_proj.weight", "t"),
+            ("attn.wg_b", "g_b_proj.weight", "t"),
+            ("attn.wb", "b_proj.weight", "t"),
+            ("attn.A_log", "A_log", ""),
+            ("attn.dt_bias", "dt_bias", ""),
+        ):
+            key = f"{layer_name}.self_attn.{hf_sub}"
+            consumed.add(key)
+            w = sd[key]
+            if how == "taps":
+                w = w.reshape(w.shape[0], -1)
+            out[native_key] = np.ascontiguousarray(w.T) if how else w
+    for sub in ("attn", "mlp"):
+        # mHC, one set a sublayer: the mixes' projection, bias and scalars.
+        key = f"{layer_name}.{sub}_hc.fn.weight"
+        if key in sd:
+            out[f"hc_{sub}.phi"] = np.ascontiguousarray(sd[key].T)
+            out[f"hc_{sub}.b"] = sd[f"{layer_name}.{sub}_hc.base"]
+            out[f"hc_{sub}.a"] = sd[f"{layer_name}.{sub}_hc.scale"]
+            consumed.update(
+                (key, f"{layer_name}.{sub}_hc.base", f"{layer_name}.{sub}_hc.scale")
+            )
+    # The sparse layers' indexer ranks keys only past index_topk tokens,
+    # where the model is refused (runtime/tokenization.check_dense_len): its
+    # tensors are left out of the layer files.
+    consumed.update(k for k in sd if k.startswith(f"{layer_name}.self_attn.indexer."))
     for native_key, hf_sub in _LAYER_MAP_OPTIONAL:
         if mla and native_key in ("attn.bq", "attn.bk", "attn.bv"):
             continue  # HF MLA projections are bias-free (q_a/kv_a aside)

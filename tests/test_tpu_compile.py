@@ -500,3 +500,66 @@ def test_ouro_steps_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, step, leng
         compiled, text = _compile(
             executor._exit_block, cfg, norm, s((1, 4, 1, 2048)), state, s((1, 4), jnp.bool_))
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# --- GLM-5.3-Flash: the KDA kernel and the three layer kinds' steps -----------
+
+@pytest.mark.parametrize("n,length", [(1, 1856), (4, 64)])
+def test_kda_kernel_compiles(one_chip, n, length):
+    """The cell's calls: the longest prefix bucket alone (29 chunks of 64
+    rows, the float32 solve inside), four suffixes from one state."""
+    from flexible_llm_sharding_tpu.ops import kda_attention as ka
+
+    s = functools.partial(_sds, one_chip)
+    f = jax.jit(functools.partial(ka.kda_attention, interpret=False))
+    _, text = _compile(
+        f, s((n, length, 64, 128)), s((n, length, 64, 128)), s((n, length, 64, 128)),
+        s((n, length, 64, 128), jnp.float32), s((n, length, 64), jnp.float32),
+        s((64, 128, 128), jnp.float32),
+    )
+    assert "tpu_custom_call" in text and "kda_chunk" in text
+
+
+def _glm_model():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "glm-5.3-flash.json")) as f:
+        model = json.load(f)
+    model.pop("rehearsal")
+    return model
+
+
+@pytest.mark.parametrize("layer,kind", [(0, "kda_dense"), (3, "latent_moe"), (4, "kda_moe")])
+def test_glm_decoder_block_at_the_cell_s_shapes(one_chip, lowering_sees_tpu, layer, kind):
+    """One layer of each kind as the benchmark's cell runs it: published
+    widths, a row 4 x 4096 wide between layers, the longest prefix bucket, one
+    prompt a block. A KDA layer carries the KDA kernel (a prefix call and a
+    suffix call) and no flash kernel, a latent layer the two flash kernels at
+    64 heads of 256 / 256 with no rotary; an expert layer the grouped matmuls
+    over 36 held experts of 288 routed. Beside the tier's pins and four 2.14 GB
+    shards in flight inside the chip."""
+    from benchmark.families.glm5_next_text import weights
+
+    model = _glm_model()
+    cfg = LlamaConfig.from_hf_config(weights.hf_config(model))
+    assert weights.layer_kind(model, layer) == kind and cfg.hc_mult == 4
+    s = functools.partial(_sds, one_chip)
+    seg = {
+        "layers": weights.unflatten({
+            k: s((1, *shape)) for k, shape, _ in weights.tensor_specs(model, f"model.layers.{layer}")
+        }),
+        "sliding": None, "rope": None, "index": s((1,), jnp.int32),
+    }
+    compiled, text = _compile(
+        executor._decoder_block,
+        cfg, seg, s((1, 1856, 16384)), s((1, 4, 64, 16384)), s((1,), jnp.int32), True,
+        None, None, True,
+    )
+    # By the kernels' own op lines: the text's table of Python function names
+    # outlives a compilation, so a bare name may be an earlier program's.
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert any("kda_chunk" in c for c in calls) == kind.startswith("kda")
+    assert any("flash_causal_attention" in c for c in calls) == kind.startswith("latent")
+    assert len(calls) >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
