@@ -81,10 +81,12 @@ def _idle_between_shards(spans: list[dict]) -> dict | None:
     for d in sweeps.values():
         if not d["compute"] or d["end"] is None:
             continue
-        shards = [
-            (idx, t0 + launch, *d["wait"].get(idx, (t1, None)))
-            for t0, idx, launch, t1 in sorted(d["compute"])
-        ]
+        shards = []
+        for t0, idx, launch, t1 in sorted(d["compute"]):
+            # A shard's wait lies in the next shard's span where it lagged:
+            # its last block went out where its own span ended.
+            t_wait, t_ready = d["wait"].get(idx, (t1, None))
+            shards.append((idx, t0 + launch, min(t_wait, t1), t_ready))
         # An upload is enqueued where its device_put call returned (the
         # record's rule: ShardWeightSource.shard_table).
         uploads = {

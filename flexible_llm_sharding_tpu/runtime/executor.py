@@ -717,8 +717,10 @@ class ShardStamps:
     (the ``source_wait`` span's end: the shard is the consumer's),
     ``t_launch`` (its first block's steps are enqueued: one read of the
     clock, the only one the timeline adds), ``t_wait`` / ``t_ready`` (the
-    shard-end ``device_wait`` span's two ends; None for a shard that has
-    no such wait) and ``t_end`` (the ``compute`` span's end)."""
+    shard-end ``device_wait`` span's two ends, which wait for THIS shard's
+    results; None for a shard that has no such wait; where the wait lagged
+    one shard, both lie inside the next shard's ``compute`` span, after
+    ``t_end``) and ``t_end`` (the ``compute`` span's end)."""
 
     __slots__ = ("shard_idx", "source_wait_s", "t_take", "t_launch",
                  "t_wait", "t_ready", "t_end")
@@ -760,12 +762,14 @@ class SweepClock:
     between shards (``utils.intervals.idle_split``: ``drained_s``,
     ``own_upload_wait_s``, ``behind_upload_s``), and a sweep that stalls
     keeps them as its per-shard table (``process_slow_sweeps``); every
-    other sweep drops them with its clock. ``t_wait`` (the shard-end
-    wait's start) is also where the consumer has just handed the shard's
-    upload slot back (``ShardWeightSource.dispatched``): an upload ordered
-    by that signal (the record's ``uploads_ordered``) is enqueued after it,
-    and an upload's enqueue is dated where its ``device_put`` call
-    RETURNED."""
+    other sweep drops them with its clock. The shard's last block is
+    dispatched at the earlier of ``t_wait`` (the shard-end wait's start)
+    and ``t_end``, which is also where the consumer has just handed the
+    shard's upload slot back (``ShardWeightSource.dispatched``): an upload
+    ordered by that signal (the record's ``uploads_ordered``) is enqueued
+    after it, and an upload's enqueue is dated where its ``device_put``
+    call RETURNED. ``waits_deferred`` counts the shards whose end was
+    waited for one shard later (``StreamingExecutor._stream_shard``)."""
 
     def __init__(self):
         self.sweep_id = obs_trace.new_sweep_id()
@@ -776,6 +780,7 @@ class SweepClock:
         self.block_rows: tuple[int, ...] = ()
         self._gc0 = _gc_seen()
         self.source_wait_s = self.compute_s = self.device_wait_s = 0.0
+        self.waits_deferred = 0
         self.act_fetch_s = self.act_store_s = 0.0
         self.head_s = 0.0
         # Device-resident int32 [2] counts, one per decoder segment and
@@ -844,10 +849,17 @@ class SweepClock:
         t = self.shards[-1].t_launch = time.perf_counter()
         return t
 
-    def shard_end_wait(self, wait) -> None:
-        """``wait``: the ``device_wait`` span at the current shard's end."""
+    def wait_for_shard(self, result, stamps: ShardStamps) -> None:
+        """Block on ``result``, the last result of the shard ``stamps``
+        stands for (the current one, or the one before where its wait
+        lagged): the ``device_wait`` span at that shard's end, named by its
+        index, and its ``t_wait`` / ``t_ready``."""
+        with obs_trace.timed(
+            "device_wait", cat="sweep", at="shard_end", sweep_id=self.sweep_id,
+            shard_idx=stamps.shard_idx,
+        ) as wait:
+            jax.block_until_ready(result)
         self.device_wait_s += wait.dur_s
-        stamps = self.shards[-1]
         stamps.t_wait, stamps.t_ready = wait.t0, wait.t0 + wait.dur_s
 
     def shard_done(self, compute) -> None:
@@ -906,6 +918,7 @@ class SweepClock:
             "dispatch_s": self.compute_s - device_wait_s,
             "device_wait_s": device_wait_s,
             "tail_s": tail.dur_s if tail is not None else 0.0,
+            "waits_deferred": self.waits_deferred,
             "act_fetch_s": self.act_fetch_s,
             "act_store_s": self.act_store_s,
             "act_wait_s": act_wait_s,
@@ -1021,20 +1034,25 @@ def _shard_table(
     shards = clock.shards
     split = idle_split(
         [
-            # the last block is dispatched where the shard-end wait begins
-            (s.shard_idx, s.t_launch, s.t_wait or s.t_end, s.t_ready)
+            # the last block is dispatched where the shard-end wait begins,
+            # or, where that wait lagged into the next shard, where the
+            # shard's compute span ends
+            (s.shard_idx, s.t_launch, min(s.t_wait or s.t_end, s.t_end), s.t_ready)
             for s in shards
         ],
         uploads, t_end, clock.block_rows,
     )
     table = []
+    lagged_in = 0.0  # the shard before's wait, where it lagged into this one
     for s, (drained, own, behind) in zip(shards, split):
         wait = s.t_ready - s.t_wait if s.t_ready is not None else 0.0
+        lagged = s.t_wait is not None and s.t_wait > s.t_end
         load, put, ordered = produced.get(s.shard_idx, (0.0, 0.0, 0))
         table.append({
             "shard_idx": s.shard_idx,
             "source_wait_s": s.source_wait_s,
-            "dispatch_s": s.t_end - s.t_take - wait,
+            # the span's time less the waits for the device inside it
+            "dispatch_s": s.t_end - s.t_take - lagged_in - (0.0 if lagged else wait),
             "device_wait_s": wait,
             "drained_s": drained,
             "own_upload_wait_s": own,
@@ -1043,6 +1061,7 @@ def _shard_table(
             "upload_dispatch_s": put,
             "upload_ordered": ordered,
         })
+        lagged_in = wait if lagged else 0.0
     return table
 
 
@@ -1092,15 +1111,24 @@ SWEEP_RECORD_HELP = {
     "(dispatching steps, copying activations); waits for the device are "
     "NOT in here but in device_wait_s.",
     "device_wait_s": "Consumer: blocked on the device's results, at each "
-    "shard's end and inside the activation store; near wall_s the sweep is "
-    "device- or link-bound (see upload_busy_s), not host-bound.",
+    "shard's end (or the next shard's, where the wait lagged: "
+    "waits_deferred) and inside the activation store; near wall_s the sweep "
+    "is device- or link-bound (see upload_busy_s), not host-bound.",
     "tail_s": "Consumer: after the last shard's dispatch (source close, "
     "scores to the host, store clear).",
     "drained_s": "Device idle, by the host's stamps: from each shard-end wait's "
     "return (the device's last result is on the host, nothing is enqueued "
     "behind it) to the next shard's first block dispatched; holds the "
     "source_wait, the store's bookkeeping and that block's host dispatch. "
-    "Summed over drained_shards boundaries.",
+    "Summed over drained_shards boundaries; 0 at a boundary whose wait "
+    "lagged (waits_deferred): the next shard was launched before it returned.",
+    "waits_deferred": "Shards whose end was waited for one shard later: their "
+    "last result blocked on inside the next shard's dispatch, before its "
+    "last block, so the device had the next shard queued while the host "
+    "woke. Only a shard that uploaded nothing, kept its blocks on the chip "
+    "and is followed by two builds that upload nothing (no upload goes out "
+    "behind queued launches), never the last; a pass whose every layer is "
+    "seated defers every shard-end wait it has.",
     "drained_shards": "Shard boundaries counted in drained_s (a shard with a "
     "wait for the device at its end, followed by one that launched).",
     "own_upload_wait_s": "Device idle, by the host's stamps: shards whose "
@@ -2371,6 +2399,12 @@ class ShardWeightSource:
         )
         self._slot_ordered: deque = deque([False] * (prefetch_depth + 1))
         self._holding = False  # consumer's thread: a shard taken, slot not returned
+        # For wait_may_lag: how far ahead a returned slot builds, the position
+        # of the shard the consumer holds, and the positions whose build
+        # uploads (the producer adds one before its put).
+        self._depth = max(0, prefetch_depth)
+        self._held: int | None = None
+        self._uploaded: set[int] = set()
         self._close_lock = threading.Lock()  # close() may race abort()/close()
         self._thread: threading.Thread | None = None
         if prefetch_depth >= 1:
@@ -2554,6 +2588,9 @@ class ShardWeightSource:
             )
             nbytes = self._loader.bytes_loaded - bytes_before
             load_s = self._loader.build_time - build_before
+            if any(kind != "pin" for kind, _, _ in parts):
+                # Said before the put below seats anything (wait_may_lag).
+                self._uploaded.add(shard_i)
             # Count the sweep's saved link bytes ONCE per build (the put
             # below may retry; retries must not double-count).
             pinned_nbytes = 0
@@ -2642,6 +2679,27 @@ class ShardWeightSource:
         beside). Once a shard; a second call, or one with no shard held
         (``prefetch_depth`` 0: no thread, no slots), does nothing."""
         self._return_slot(True)
+
+    def wait_may_lag(self) -> bool:
+        """Consumer, after ``dispatched()``: True when its wait for the
+        shard it holds may lag one shard (blocked on at the next shard's
+        dispatch, so the device has this shard queued while the host takes
+        and dispatches the next). Only where no upload can go out behind
+        launches queued meanwhile, and the host still holds streamed
+        buffers back: the shard uploaded nothing, it is not the last, and
+        neither the build that this ``dispatched()`` lets out nor the one
+        the next shard's lets out (``prefetch_depth`` + 1 and + 2 ahead)
+        uploads anything: every layer of theirs seated, or past the end of
+        the plan. A cycling source says False."""
+        i, n = self._held, len(self.shards)
+        if self.cycle or i is None or i in self._uploaded or i + 1 >= n:
+            return False
+        # A build that uploads is in _uploaded before its put seats a layer,
+        # and reads an unseated one until then: no race with the producer.
+        ahead = (i + self._depth + 1, i + self._depth + 2)
+        return not any(
+            j in self._uploaded or self._to_read(j) for j in ahead if j < n
+        )
 
     def _await_upload(self, device) -> None:
         """Consumer, a shard for ``device`` in hand: wait until the newest
@@ -2814,12 +2872,16 @@ class ShardWeightSource:
                         return
                     if i + 1 < len(self.shards):
                         self._loader.warm(self._to_read(i + 1))
-                    yield idxs, self._build_shard(idxs, dev, i)
+                    item = self._build_shard(idxs, dev, i)
+                    self._held = i
+                    yield idxs, item
                 if not self.cycle:
                     return
         else:
             while True:
-                for idxs, dev in zip(self.shards, self.shard_devices):
+                for i, (idxs, dev) in enumerate(
+                    zip(self.shards, self.shard_devices)
+                ):
                     # A consumer that did not say "dispatched" for the shard
                     # it held hands its slot back by asking for the next.
                     self._return_slot(False)
@@ -2827,7 +2889,7 @@ class ShardWeightSource:
                     if isinstance(item, _ShardFault):
                         _reraise_from_producer(item.error)
                     self._await_upload(dev)
-                    self._holding = True
+                    self._holding, self._held = True, i
                     yield idxs, item
                 if not self.cycle:
                     return
@@ -3016,6 +3078,11 @@ class _BroadcastView:
     def dispatched(self) -> None:
         """The consumer's "last block enqueued" (``ShardWeightSource.
         dispatched``): a shared source's bound is its queues' alone."""
+
+    def wait_may_lag(self) -> bool:
+        """``ShardWeightSource.wait_may_lag``: a shared source keeps every
+        shard-end wait (it cannot tell what its producer uploads next)."""
+        return False
 
     def __iter__(self):
         q = self._parent._queues[self._rank]
@@ -3579,6 +3646,8 @@ class StreamingExecutor:
         # disk mode (comparable to prefetch_depth=1's queued shard).
         heal_spills = store.location == "disk" and not self._exit_state_needed
         prev_shard = None  # (visit, segments) of the last shard run
+        # (last result, stamps) of a shard whose end-wait lags into the next
+        lagging = None
         # A looped model: one span around each step's shards.
         step_span = None
         # Correlation id for this full pass over the shards — the offline
@@ -3642,9 +3711,10 @@ class StreamingExecutor:
                     "compute", cat="sweep", sweep_id=sweep_id,
                     shard_idx=shard_idx,
                 ) as compute:
-                    self._stream_shard(
+                    lagging = self._stream_shard(
                         store, toks, blocks, block_meta, scores,
                         visit, segments, prev_shard, bar, clock, compute, source,
+                        lagging,
                     )
                     if on_shard_done is not None:
                         on_shard_done(shard_i)
@@ -3658,18 +3728,28 @@ class StreamingExecutor:
 
     def _stream_shard(
         self, store, toks, blocks, block_meta, scores, visit, segments,
-        prev_shard, bar, clock, compute, source,
-    ) -> None:
+        prev_shard, bar, clock, compute, source, lagging,
+    ):
         """One shard's compute over every block — the body the traced
         ``compute`` span (``compute``) wraps in ``_stream``: its ``dispatch`` child is
         the consumer's pass over the blocks (inside it, the activation
         store's own ``device_wait`` where it resolves a block's copy), its
         ``device_wait`` child the wait for the device at the shard's end
-        (the spill-corruption recompute path lives here)."""
+        (the spill-corruption recompute path lives here).
+
+        ``lagging``: ``(last result, stamps)`` of the shard before, whose
+        end-wait lagged into this shard, or None. It is waited for inside
+        the dispatch, before the last block: that block's step takes the
+        result's buffers (a decoder step donates its activations). Returns
+        this shard's own pair where its wait lags in turn, else None."""
         sweep_id = clock.sweep_id
         ids = clock.span_ids()
+        link_bytes = store.link_bytes
         with obs_trace.span("dispatch", cat="sweep", **ids):
             for b, idxs in enumerate(blocks):
+                if lagging is not None and b == len(blocks) - 1:
+                    clock.wait_for_shard(*lagging)
+                    lagging = None
                 fetched = None
                 while True:
                     try:
@@ -3720,19 +3800,28 @@ class StreamingExecutor:
         # The shard's last block is enqueued: the producer's next upload may
         # go out now, behind these steps and beside them.
         source.dispatched()
-        # Every store path is async now (cpu: copy_to_host_async +
-        # depth-1 finalize; disk: writer thread), so block once per
-        # shard to keep compute_wall_s a device-time measure. The
-        # prefetch thread enqueues its next upload on the signal above,
-        # so that upload (and the disk writer's writes) run concurrently
-        # with this wait.
-        # (blocks can be empty: num_batch > prompt count -> ex([]).)
-        if blocks and visit.stores:
-            with obs_trace.timed(
-                "device_wait", cat="sweep", at="shard_end", **ids
-            ) as wait:
-                jax.block_until_ready(suffix_h)
-            clock.shard_end_wait(wait)
+        # The wait for the shard's results at its end (blocks can be empty:
+        # num_batch > prompt count -> ex([])) is the host's brake: it holds
+        # a streamed shard's buffers to one shard in flight, it puts the
+        # next shard's launches onto an empty device queue (an upload the
+        # signal above lets out runs beside these steps, and the next shard
+        # is taken only once it arrived, so nothing is launched under an
+        # upload), and a disk pass's progress marker (on_shard_done) never
+        # runs ahead of the device. Where none of that is needed it lags
+        # one shard, so the device has this shard queued while the host
+        # takes and dispatches the next: the blocks stayed on the chip (the
+        # store sent none over the link, which a disk pass, resumed from its
+        # marker and healing spills from the shard before, always does) and
+        # the source says no upload can go out behind the launches queued
+        # meanwhile (wait_may_lag).
+        if not (blocks and visit.stores):
+            return None
+        stamps = clock.shards[-1]
+        if store.link_bytes == link_bytes and source.wait_may_lag():
+            clock.waits_deferred += 1
+            return suffix_h, stamps
+        clock.wait_for_shard(suffix_h, stamps)
+        return None
 
     def _recompute_block(self, prev_shard, store, b, idxs, meta, toks):
         """Re-derive one block's activations by re-running the PREVIOUS

@@ -513,7 +513,8 @@ def test_completion_thread_keeps_bounded_state():
 
 IDLE = ("drained_s", "own_upload_wait_s", "behind_upload_s")
 NEW_FIELDS = IDLE + ("drained_shards", "launches_behind_upload", "gc_s",
-                     "gc_collections", "slow", "uploads_ordered", "link_wait_s")
+                     "gc_collections", "slow", "uploads_ordered", "link_wait_s",
+                     "waits_deferred")
 
 
 @pytest.mark.parametrize(
@@ -583,6 +584,13 @@ NEW_FIELDS = IDLE + ("drained_shards", "launches_behind_upload", "gc_s",
         ("a many-leaf upload ending between two launches",
          [(3, 10.0, 10.06, 10.5)], {3: (8.0, 9.0), 6: (10.03, 10.3)},
          [(0.0, 0.0, round(10.3 - (10.0 + 0.2), 9))]),
+        # A wait that lagged: shard 0's end is waited for from 1.12 to 1.30,
+        # inside shard 1, whose first block was launched at 1.10, before
+        # that wait returned: the boundary did not drain. Shard 1's own wait
+        # returns at 1.40 and shard 2 launches at 1.45.
+        ("a wait that lagged into the next shard",
+         [(0, 1.0, 1.05, 1.30), (1, 1.10, 1.32, 1.40), (2, 1.45, 1.5, 1.6)],
+         {}, [(0.0, 0.0, 0.0), (0.0, 0.0, 0.0), (0.05, 0.0, 0.0)]),
     ],
 )
 def test_idle_split_on_synthetic_stamps(case, shards, uploads, want):
@@ -1220,6 +1228,194 @@ def test_a_streamed_tails_uploads_are_all_ordered(monkeypatch, deep_dir):
         assert last_block[k] < events.index(("put", k + 3)), k
 
 
+# ---------------------------------------------------------------------------
+# A seated shard's end waited for one shard later (wait_may_lag)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def fresh_tier():
+    """The residency tier and the host cache are the process's: this test's
+    seats must not serve the next test."""
+    from flexible_llm_sharding_tpu.runtime import hostcache, residency
+
+    residency.reset_process_tier()
+    hostcache.reset_process_cache()
+    yield residency
+    residency.reset_process_tier()
+    hostcache.reset_process_cache()
+
+
+def _keep_every_wait(monkeypatch):
+    monkeypatch.setattr(
+        executor_mod.ShardWeightSource, "wait_may_lag", lambda self: False
+    )
+
+
+def test_a_seated_pass_waits_for_each_shard_one_shard_later(
+    monkeypatch, deep_dir, fresh_tier
+):
+    """Every layer seated (the second pass; the first seats them, each of
+    its shards uploading, and keeps every wait): each shard's end is waited
+    for inside the next shard's dispatch, after its first launch, so no
+    boundary drains; the span and the stamps of that wait name the shard
+    waited on; the scores are bit for bit those of the same pass with every
+    wait kept."""
+    from flexible_llm_sharding_tpu.obs import trace as obs_trace
+
+    path, _ = deep_dir
+    ex = StreamingExecutor(
+        _account_cfg(path, hbm_pin_gb=1.0, storage_location="tpu"),
+        tokenizer=FakeTokenizer(),
+    )
+    n = len(ex.plan.shards)
+    assert len(make_blocks(ex._tokenize(list(PROMPTS)), 2)) > 1
+    ex(list(PROMPTS))
+    seating = executor_mod.process_sweep_log()[-1]
+    tracer = obs_trace.TRACER
+    tracer.clear()
+    tracer.enable()
+    try:
+        lagged = ex(list(PROMPTS))
+        spans = tracer.snapshot()
+    finally:
+        tracer.disable()
+        tracer.clear()
+    rec = executor_mod.process_sweep_log()[-1]
+    _keep_every_wait(monkeypatch)
+    kept = ex(list(PROMPTS))
+    rec_kept = executor_mod.process_sweep_log()[-1]
+
+    assert (seating["uploads"], seating["waits_deferred"]) == (n, 0)
+    # the head stores nothing and has no wait; every other shard's lags
+    assert (rec["uploads"], rec["waits_deferred"]) == (0, n - 1)
+    assert rec_kept["waits_deferred"] == 0 and rec_kept["drained_s"] > 0.0
+    assert rec["drained_shards"] == n - 1 and rec["drained_s"] == 0.0
+    _assert_phases_partition(rec)
+    for a, b in zip(lagged, kept):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    compute = {s["shard_idx"]: s for s in spans if s["name"] == "compute"}
+    ends = [
+        s for s in spans if s["name"] == "device_wait" and s["at"] == "shard_end"
+    ]
+    assert sorted(s["shard_idx"] for s in ends) == list(range(n - 1))
+    for w in ends:
+        nxt = compute[w["shard_idx"] + 1]
+        assert nxt["ts_s"] + nxt["launch_s"] <= w["ts_s"]
+        assert w["ts_s"] + w["dur_s"] <= nxt["ts_s"] + nxt["dur_s"] + 1e-6
+    assert sum(s["dur_s"] for s in ends) == pytest.approx(
+        rec["device_wait_s"], abs=1e-4
+    )
+
+
+@pytest.mark.parametrize(
+    "seated_layers, lagging",
+    # the third: two streamed layers, so shard 6 follows the last shard
+    # that lets an upload out (5, whose "dispatched" lets out shard 8's)
+    [(2, {9}), (5, {0, 1, 9}), (6, {0, 1, 2, 6, 9})],
+    ids=["head_of_three", "head_of_six", "two_streamed"],
+)
+def test_a_wait_lags_only_where_no_upload_can_go_out_behind_it(
+    monkeypatch, deep_dir, fresh_tier, seated_layers, lagging
+):
+    """The embedding, the first ``seated_layers`` decoder layers, the norm
+    and the head seated, the layers between them streamed, prefetch depth
+    2 (shard k's "dispatched" lets out the build of shard k + 3). In the
+    second pass a shard's end is waited for one shard later only where it
+    uploaded nothing and neither its own "dispatched" nor the next shard's
+    lets an upload out: every shard that uploaded, every shard that lets an
+    upload out and the shard before it keep the wait at their own end; no
+    block of a shard that lets an upload out is dispatched while an earlier
+    shard's wait is pending; every upload is still ordered, each put of
+    shard k + 3 after the last block of shard k."""
+    residency = fresh_tier
+    path, names = deep_dir
+    size = residency.layer_stream_bytes(path, names, False)
+    budget = sum(size[i] for i in (*range(seated_layers + 1), 9, 10)) + 16
+    streamed = set(range(seated_layers + 1, 9))
+    releasing = {s - 3 for s in streamed}
+    events, lock = [], threading.Lock()
+    orig_put, orig_block = executor_mod._assemble_parts, executor_mod.process_block
+    orig_wait = executor_mod.SweepClock.wait_for_shard
+
+    def put(parts, *a, **k):
+        with lock:
+            events.append(("put", sum(e[0] == "put" for e in events)))
+        return orig_put(parts, *a, **k)
+
+    def block(cfg, dtype, segments, visit, store, b, *a, clock=None, **k):
+        out = orig_block(cfg, dtype, segments, visit, store, b, *a, clock=clock, **k)
+        with lock:
+            events.append(("block", clock.shard_idx, b))
+        return out
+
+    def wait(clock, result, stamps):
+        orig_wait(clock, result, stamps)
+        with lock:  # the shard waited on, and the shard the consumer is on
+            events.append(("wait", stamps.shard_idx, clock.shard_idx))
+
+    ex = StreamingExecutor(
+        _account_cfg(path, hbm_pin_gb=budget / 1e9, storage_location="tpu"),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))  # seats the pins
+    with monkeypatch.context() as m:
+        m.setattr(executor_mod, "_assemble_parts", put)
+        m.setattr(executor_mod, "process_block", block)
+        m.setattr(executor_mod.SweepClock, "wait_for_shard", wait)
+        ex(list(PROMPTS))
+    rec = executor_mod.process_sweep_log()[-1]
+    assert rec["pin_hits"] == 11 - len(streamed)
+    assert rec["uploads"] == rec["uploads_ordered"] == len(streamed)
+    assert rec["waits_deferred"] == len(lagging)
+    waits = [e for e in events if e[0] == "wait"]
+    assert sorted(e[1] for e in waits) == list(range(10))  # the head has none
+    assert {k for _, k, on in waits if on == k + 1} == lagging
+    assert all(on == k for _, k, on in waits if k not in lagging)
+    assert not lagging & (streamed | releasing | {r - 1 for r in releasing})
+    waited_at = {e[1]: i for i, e in enumerate(events) if e[0] == "wait"}
+    for i, e in enumerate(events):
+        if e[0] == "block" and e[1] in releasing:
+            assert all(waited_at[k] < i for k in range(e[1])), e
+    last_block = {}
+    for i, e in enumerate(events):
+        if e[0] == "block":
+            last_block[e[1]] = i
+    for k in range(11 - 3):
+        assert last_block[k] < events.index(("put", k + 3)), k
+
+
+@pytest.mark.parametrize("where", ["cpu", "disk", "kept_on_the_chip"])
+def test_a_wait_lags_only_where_the_blocks_stay_on_the_chip(
+    monkeypatch, deep_dir, fresh_tier, tmp_path, where
+):
+    """Every layer seated: a ``cpu`` store (no room on the chip: every block
+    crosses the link) and a resumable ``disk`` pass keep every wait; with
+    nobody saying where and room on the chip for every block (the chip's
+    default) every wait but the head's lags."""
+    from flexible_llm_sharding_tpu.utils import metrics
+
+    path, _ = deep_dir
+    if where == "kept_on_the_chip":
+        stats = {"bytes_limit": 1e12, "bytes_in_use": 0.0}
+        monkeypatch.setattr(metrics, "device_memory_stats", lambda device=None: stats)
+    ex = StreamingExecutor(
+        _account_cfg(
+            path, hbm_pin_gb=1.0, disk_folder=str(tmp_path),
+            storage_location=None if where == "kept_on_the_chip" else where,
+        ),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))
+    ex(list(PROMPTS))
+    rec = executor_mod.process_sweep_log()[-1]
+    assert rec["uploads"] == 0
+    if where == "kept_on_the_chip":
+        assert rec["act_bytes"] == 0
+        assert rec["waits_deferred"] == len(ex.plan.shards) - 1
+    else:
+        assert rec["act_bytes"] > 0 and rec["waits_deferred"] == 0
+
+
 @pytest.mark.parametrize("where", ["its_own_device", "another_device"])
 def test_a_shard_is_handed_over_once_the_newest_upload_to_its_device_arrived(
     monkeypatch, deep_dir, where
@@ -1312,6 +1508,42 @@ def test_shard_table_dates_an_upload_where_its_call_returned():
     source._produced[6] = (0.001, 0.001, 1)  # one leaf: enqueued at 10.011
     (row,) = executor_mod.ShardWeightSource.shard_table(source, clock, 20.0, intervals)
     assert row["behind_upload_s"] == pytest.approx(10.3 - 10.011)
+
+
+def test_shard_table_puts_a_lagged_wait_on_the_shard_it_waited_on():
+    """Shard 0's end, waited for from 1.12 to 1.30 inside shard 1's compute
+    span (1.06 -> 1.40): the table gives those 0.18 s to shard 0 as its
+    device_wait_s, takes them off shard 1's dispatch_s, dates shard 0's
+    last launch at its span's end (1.05, before shard 4's upload was
+    enqueued at 1.08: nothing queued behind it; dated at the wait's start
+    it would read 0.01 s behind that upload) and reads no drain at the
+    boundary, shard 1 having launched at 1.10."""
+    from types import SimpleNamespace
+
+    s0 = executor_mod.ShardStamps(0, 0.0, 0.99)
+    s0.t_launch, s0.t_end, s0.t_wait, s0.t_ready = 1.0, 1.05, 1.12, 1.30
+    s1 = executor_mod.ShardStamps(1, 0.01, 1.06)
+    s1.t_launch, s1.t_wait, s1.t_ready, s1.t_end = 1.10, 1.32, 1.40, 1.40
+    clock = SimpleNamespace(shards=[s0, s1], block_rows=())
+    source = SimpleNamespace(_watcher=None, _produced={4: (0.001, 0.001, 1)})
+    rows = executor_mod.ShardWeightSource.shard_table(
+        source, clock, 2.0, [(1.079, 1.09, 4)]
+    )
+    got = [
+        {k: round(r[k], 9) for k in ("dispatch_s", "device_wait_s", "drained_s",
+                                      "behind_upload_s")}
+        for r in rows
+    ]
+    assert got == [
+        {"dispatch_s": 0.06, "device_wait_s": 0.18, "drained_s": 0.0,
+         "behind_upload_s": 0.0},
+        {"dispatch_s": 0.08, "device_wait_s": 0.08, "drained_s": 0.0,
+         "behind_upload_s": 0.0},
+    ]
+    # The two spans' seconds, whole: one wait each, none counted twice.
+    assert sum(r["dispatch_s"] + r["device_wait_s"] for r in rows) == pytest.approx(
+        (1.05 - 0.99) + (1.40 - 1.06)
+    )
 
 
 def test_runs_are_the_parts_split_parts_builds():

@@ -486,6 +486,32 @@ def test_analyzer_derives_utilization_overlap_and_quantiles():
     assert "link utilization: not timed" in obs_report.format_report(bare)
 
 
+def test_analyzer_reads_a_lagged_wait_as_the_record_does():
+    """Shard 0's shard-end wait, named by its index, lies inside shard 1's
+    compute span (its end waited for one shard later): shard 0's last
+    launch is its own span's end, before shard 4's upload was enqueued, and
+    shard 1 launched before that wait returned: nothing drained, nothing
+    queued behind the upload."""
+    evs = [
+        {"name": "upload_dispatch", "cat": "stream", "ts_s": 1.079, "dur_s": 0.001,
+         "sweep_id": 1, "shard_idx": 4},
+        {"name": "upload", "cat": "stream", "ts_s": 1.079, "dur_s": 0.011,
+         "sweep_id": 1, "shard_idx": 4},
+        {"name": "compute", "cat": "sweep", "ts_s": 0.99, "dur_s": 0.06,
+         "sweep_id": 1, "shard_idx": 0, "launch_s": 0.01},
+        {"name": "compute", "cat": "sweep", "ts_s": 1.06, "dur_s": 0.34,
+         "sweep_id": 1, "shard_idx": 1, "launch_s": 0.04},
+        {"name": "device_wait", "cat": "sweep", "ts_s": 1.12, "dur_s": 0.18,
+         "sweep_id": 1, "shard_idx": 0, "at": "shard_end"},
+        {"name": "device_wait", "cat": "sweep", "ts_s": 1.32, "dur_s": 0.08,
+         "sweep_id": 1, "shard_idx": 1, "at": "shard_end"},
+        {"name": "sweep", "cat": "sweep", "ts_s": 0.9, "dur_s": 0.6, "sweep_id": 1},
+    ]
+    idle = obs_report.analyze(evs)["idle_between_shards"]
+    assert idle == {"sweeps": 1, "drained_s": 0.0, "own_upload_wait_s": 0.0,
+                    "behind_upload_s": 0.0}
+
+
 def test_analyzer_roundtrips_both_export_formats(tmp_path):
     t = Tracer()
     time.sleep(0.05)  # real spans start well after tracer construction

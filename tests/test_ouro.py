@@ -286,13 +286,14 @@ def test_shards_that_run_over_a_step_s_end(model_dir, layers_per_shard, use_pall
     model, d = model_dir
     tok = tr.WordIdTokenizer(int(model["vocab_size"]))
     prompts = _prompts(model)
-    n0 = len(executor.process_sweep_log())
+    # by sweep id: the log keeps the last 256 records, and may be full
+    seen = max((r["sweep_id"] for r in executor.process_sweep_log()), default=-1)
     got = _score(d, prompts, tok, use_pallas=use_pallas, layer_num_per_shard=layers_per_shard,
                  hbm_pin_gb=0)
     for g, w in zip(got, _reference_logp(model, prompts, tok)):
         np.testing.assert_allclose(np.log(np.asarray(g)[:, 0, :]), w, atol=2e-5)
     log = executor.process_sweep_log()
-    assert len(log) == n0 + 1  # one record a batch, not one a step
+    assert [r["sweep_id"] > seen for r in log].count(True) == 1  # one a batch, not one a step
     assert (log[-1]["loop_steps"], log[-1]["layer_visits"]) == (4, 12)
     assert log[-1]["full_layers"] == 3 and log[-1]["window_layers"] == 0
     # The flash kernels' steps are counted a visit, not a layer: twelve equal
@@ -327,6 +328,36 @@ def test_the_threshold_picks_each_scored_token_s_step(model_dir, tmp_path, q):
     p = np.concatenate([lam[:-1] * left[:-1], left[-1:]])
     expected = (p * np.arange(1, 5)[:, None]).sum(0).mean()
     assert executor.process_sweep_log()[-1]["exit_step_mean"] == pytest.approx(expected, rel=1e-5)
+
+
+def test_a_seated_loop_waits_for_each_shard_one_shard_later(model_dir, monkeypatch):
+    """The whole model seated, prefetch depth 2 as on the chip: 18 shards
+    (the embedding, 4 steps of 3 layers and the norm, the head). Every
+    shard's end but the head's (which has no wait) is waited for one shard
+    later, inside the next shard's dispatch; the seating sweep does so
+    where steps 2-4 read the seats and the build two and three shards ahead
+    uploads nothing (shards 5-12, 15 and 16). The scores and the exit
+    gate's mean are bit for bit those of the same pass with every wait
+    kept."""
+    model, d = model_dir
+    tok = tr.WordIdTokenizer(int(model["vocab_size"]))
+    prompts = _prompts(model)
+
+    def sweep():
+        got = _score(d, prompts, tok, use_pallas=False, hbm_pin_gb=1.0,
+                     storage_location="tpu", prefetch_depth=2)
+        return got, executor.process_sweep_log()[-1]
+
+    _, seating = sweep()
+    lagged, rec = sweep()
+    monkeypatch.setattr(executor.ShardWeightSource, "wait_may_lag", lambda self: False)
+    kept, rec_kept = sweep()
+    assert seating["waits_deferred"] == 10
+    assert (rec["visits_pinned"], rec["uploads"]) == (12, 0)
+    assert rec["waits_deferred"] == 18 - 1 and rec_kept["waits_deferred"] == 0
+    assert rec["exit_step_mean"] == rec_kept["exit_step_mean"]
+    for a, b in zip(lagged, kept):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
 
 
 @pytest.mark.parametrize("part", reference.PARTS)
