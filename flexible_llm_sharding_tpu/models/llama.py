@@ -486,6 +486,38 @@ def _routed_experts(
     return jnp.sum(y, axis=0).astype(x.dtype)
 
 
+def _top_k(x: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """What ``jax.lax.top_k(x, k)`` returns, without a sort: the k largest
+    entries of ``x``'s last axis in descending order, of equal ones the lower
+    index first, and their int32 indices. k rounds of: the row's max, the
+    first index that holds it, that one entry masked to -inf. The chip
+    compiles an exact ``top_k`` as a sort of the whole axis; these are 2k
+    reductions over it. For finite ``x`` (a -inf entry could tie the mask)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    vals, idx = [], []
+    for _ in range(k):
+        v = jnp.max(x, axis=-1, keepdims=True)
+        i = jnp.min(jnp.where(x == v, iota, x.shape[-1]), axis=-1, keepdims=True)
+        vals.append(v)
+        idx.append(i)
+        x = jnp.where(iota == i, -jnp.inf, x)
+    return jnp.concatenate(vals, axis=-1), jnp.concatenate(idx, axis=-1)
+
+
+def _top_groups(scores: jax.Array, k: int) -> jax.Array:
+    """The set ``jax.lax.top_k(scores, k)`` picks from the last axis, as a
+    bool mask and in no order: an entry is picked when fewer than k entries
+    come before it, one coming before another when it is larger, or equal
+    at a lower index."""
+    g = scores.shape[-1]
+    lower = jax.lax.broadcasted_iota(jnp.int32, (g, g), 0) < jax.lax.broadcasted_iota(
+        jnp.int32, (g, g), 1
+    )  # [i, j]: i < j
+    si, sj = scores[..., :, None], scores[..., None, :]
+    before = (si > sj) | ((si == sj) & lower)
+    return jnp.sum(before, axis=-2, dtype=jnp.int32) < k
+
+
 def _deepseek_moe_mlp(
     mlp: Params, cfg: LlamaConfig, x: jax.Array, stats: list | None = None,
     grouped: bool = False, use_pallas: bool = False, limits=None,
@@ -494,7 +526,8 @@ def _deepseek_moe_mlp(
     """DeepSeek-V3 MoE (DeepseekV3MoE/TopkRouter): fp32 sigmoid scores;
     SELECTION adds a trained correction bias and is group-limited (experts
     partition into n_group groups, each scored by its top-2 sum, only the
-    best topk_group groups stay eligible) — the combine WEIGHTS come from
+    best topk_group groups stay eligible; every selection ``top_k``'s, made
+    without a sort: ``_top_k``, ``_top_groups``) — the combine WEIGHTS come from
     the unbiased scores, renormalised (+1e-20) iff norm_topk_prob and
     scaled by routed_scaling_factor. A shared expert
     (n_shared_experts x the routed width) adds where the weights have one
@@ -533,16 +566,10 @@ def _deepseek_moe_mlp(
         if g > 1:
             with jax.named_scope("moe_group_limit"):
                 by_group = choice.reshape(*choice.shape[:-1], g, e // g)
-                top2, _ = jax.lax.top_k(by_group, 2)
-                group_scores = top2.sum(axis=-1)  # [..., L, G]
-                _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
-                gmask = jnp.sum(
-                    jax.nn.one_hot(gidx, g, dtype=choice.dtype), axis=-2
-                )  # [..., L, G]
-                choice = jnp.where(
-                    jnp.repeat(gmask, e // g, axis=-1) > 0, choice, 0.0
-                )
-        _, top_idx = jax.lax.top_k(choice, k)
+                top2, _ = _top_k(by_group, 2)
+                keep = _top_groups(top2.sum(axis=-1), cfg.moe_topk_group)  # [..., L, G]
+                choice = jnp.where(jnp.repeat(keep, e // g, axis=-1), choice, 0.0)
+        _, top_idx = _top_k(choice, k)
         top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
         if cfg.moe_norm_topk_prob:
             top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
