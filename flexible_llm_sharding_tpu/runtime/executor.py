@@ -659,6 +659,15 @@ def process_sweep_log() -> list[dict]:
         return [dict(r) for r in _SWEEP_LOG]
 
 
+def _last_head_record() -> dict:
+    """The newest record of a sweep that released a seated head's uploads
+    (it has ``head_queued_s``), or {}: what a source plans its lead on."""
+    with _SWEEP_LOG_LOCK:
+        return next(
+            (dict(r) for r in reversed(_SWEEP_LOG) if "head_queued_s" in r), {}
+        )
+
+
 # The sweeps that stalled (SweepClock.finish marks them ``slow``), newest
 # last: each one's record with its per-shard table, which every other sweep
 # drops. ``_SLOW_SWEEPS_SEEN`` counts them over the process's life.
@@ -1154,7 +1163,9 @@ SWEEP_RECORD_HELP = {
     "woke. Only a shard that uploaded nothing, kept its blocks on the chip "
     "and is followed by two builds that upload nothing (no upload goes out "
     "behind queued launches), never the last; a pass whose every layer is "
-    "seated defers every shard-end wait it has.",
+    "seated defers every shard-end wait it has. Also counted: the shards of "
+    "a seated head before its last, whose streamed run is held behind it "
+    "(lead_planned): not waited for at all, the last one's wait covers them.",
     "drained_shards": "Shard boundaries counted in drained_s (a shard with a "
     "wait for the device at its end, followed by one that launched).",
     "own_upload_wait_s": "Device idle, by the host's stamps: shards whose "
@@ -1176,6 +1187,17 @@ SWEEP_RECORD_HELP = {
     "block of the shard prefetch_depth + 1 before it (against uploads; the "
     "first prefetch_depth + 1 builds of a source use the slots it starts "
     "with, and a consumer that never says dispatched orders none).",
+    "lead_planned": "Uploads the seated head's release let out at once: "
+    "where the sweep opens with seated shards holding a decoder layer, the "
+    "streamed run behind them waits for the head's last shard to be "
+    "dispatched, then leads by one upload, or by two or three while the "
+    "head's work still queued (the previous sweep's head_queued_s) outlasts "
+    "them; 0 without such a head.",
+    "uploads_led": "Uploads let out under lead_planned before the consumer "
+    "took the run's first shard.",
+    "head_queued_s": "The wait at the seated head's last shard, which starts "
+    "where its dispatch released the run's uploads: the head's device work "
+    "still queued then (the next sweep's lead is planned on it).",
     "gc_s": "Seconds inside Python's generation-2 collections that ended "
     "during the sweep, on any thread (one gc.callbacks hook a process).",
     "gc_collections": "Generation-2 collections that ended during the sweep.",
@@ -2284,6 +2306,22 @@ class _UploadWatcher:
             return list(self.intervals), self.misses
 
 
+def lead_uploads(queued_s: float, upload_s: Sequence[float], cap: int) -> int:
+    """How many of a streamed run's first uploads go out together once the
+    seated head before the run is dispatched: the first always; each
+    further one only while the device work queued ahead of them
+    (``queued_s``) is expected to outlast all of them (``upload_s``: each
+    one's seconds, in order: they cross the link one after another); never
+    more than ``cap``."""
+    lead, total = 1, upload_s[0] if upload_s else 0.0
+    for seconds in upload_s[1:cap]:
+        total += seconds
+        if total > queued_s:
+            break
+        lead += 1
+    return lead
+
+
 class ShardWeightSource:
     """Loads shard weights disk -> host -> HBM, optionally prefetching ahead.
 
@@ -2320,6 +2358,24 @@ class ShardWeightSource:
     are issued at once, and the upload their signal lets out runs beside
     them: a streamed shard costs the larger of its upload and its steps.
 
+    Where the sweep opens with a seated head (shards ``0 .. h-1`` upload
+    nothing and hold a decoder layer; shard ``h`` starts a run of shards
+    that upload) and the consumer passes ``lead`` (the executor's one-chip
+    scoring pass, where its blocks stay on the chip), the link starts at the
+    head's end: the run's uploads are held until the consumer has
+    dispatched shard ``h - 1``, so the head's shards need no wait of their
+    own (``in_head``: the host runs ahead of the device through the head,
+    and the last head shard's wait covers them all). The release lets
+    ``lead_planned`` uploads out at once (``lead_uploads``: one, and a
+    second or third only while the head's device work still queued is
+    expected to outlast them, read off the previous sweep's record:
+    ``head_queued_s``, and its link rate), and the consumer takes shard
+    ``h`` once they are all enqueued and arrived; each later upload of the
+    run goes out when the shard ``lead_planned`` before it is dispatched.
+    Every other consumer (serving's cycling source, the pipeline, KV
+    decode, DP's broadcast) and a plan with no seated head keep the slots'
+    order alone.
+
     ``cycle=True`` loops the shard list endlessly instead of stopping after
     one pass — the online serving loop's weight stream, where the number of
     full-model sweeps is open-ended (requests keep arriving) and a
@@ -2353,7 +2409,11 @@ class ShardWeightSource:
         residency=None,
         sweep_id: int = 0,
         first_shard_idx: int = 0,
+        lead: dict | None = None,
     ):
+        # lead: the previous sweep's record ({} where there is none) where the
+        # consumer lets the link start at a seated head (the class
+        # docstring); None keeps the slots' order alone.
         # residency: a runtime.residency.DeviceResidencyTier (or None) —
         # pinned layers are subtracted from every shard build (their bytes
         # never cross the link) and merged back as resident segments at
@@ -2451,6 +2511,18 @@ class ShardWeightSource:
         self._depth = max(0, prefetch_depth)
         self._held: int | None = None
         self._uploaded: set[int] = set()
+        # The seated head's lead (the class docstring): the head's length h
+        # and the end of the run of uploads behind it (None: no lead), the
+        # highest positions the consumer has dispatched and the producer has
+        # passed, and the lead, set at the release.
+        self._lead_seen = lead or {}
+        self._head = self._run_end = None
+        if lead is not None and prefetch_depth >= 1 and devices is None and not cycle:
+            self._head, self._run_end = self._lead_head()
+        self._lead_cv = threading.Condition()
+        self._lead: int | None = None  # guarded by: _lead_cv
+        self._dispatched_pos = self._passed_pos = -1  # guarded by: _lead_cv
+        self.uploads_led = self.lead_planned = 0
         self._close_lock = threading.Lock()  # close() may race abort()/close()
         self._thread: threading.Thread | None = None
         if prefetch_depth >= 1:
@@ -2545,7 +2617,10 @@ class ShardWeightSource:
         (``clock.shards``) up with them: why the device stood idle between shards
         (``drained_s``, ``own_upload_wait_s``, ``behind_upload_s``).
         ``uploads_ordered`` counts the uploads enqueued on a slot that
-        ``dispatched()`` returned. Read after ``close()``."""
+        ``dispatched()`` returned; ``lead_planned`` / ``uploads_led`` what
+        the seated head's release allowed and let out, and
+        ``head_queued_s`` (a sweep that released) what its last shard's wait
+        read. Read after ``close()``."""
         intervals, misses = (
             self._watcher.snapshot() if self._watcher is not None else ([], 0)
         )
@@ -2578,6 +2653,9 @@ class ShardWeightSource:
             "upload_pinned_bytes": self.upload_pinned_bytes,
             "uploads": len(intervals),
             "uploads_ordered": self.uploads_ordered,
+            "uploads_led": self.uploads_led,
+            "lead_planned": self.lead_planned,
+            **self._head_queued(clock),
             "upload_misses": misses,
             "pinned_bytes": (
                 self._residency.max_pinned_device_bytes()
@@ -2588,6 +2666,19 @@ class ShardWeightSource:
             "visits_pinned": self.visits_pinned,
             "visits_streamed": self.visits_streamed,
         }
+
+    def _head_queued(self, clock: "SweepClock | None") -> dict:
+        """``head_queued_s``: the wait at the seated head's last shard,
+        which begins where its "dispatched" released the lead: the head's
+        device work still queued then (what the next sweep's lead is
+        planned on). Empty where nothing was released."""
+        if not self.lead_planned or clock is None:
+            return {}
+        last = self._first_shard_idx + self._head - 1
+        for s in clock.shards:
+            if s.shard_idx == last and s.t_ready is not None:
+                return {"head_queued_s": s.t_ready - s.t_wait}
+        return {}
 
     @property
     def load_time(self) -> float:
@@ -2672,6 +2763,11 @@ class ShardWeightSource:
             # slot, once a shard and outside the retried region.
             blocked_before = self.producer_blocked_s
             ordered = self._slots is not None and self._take_slot(shard_i)
+            held = bool(nbytes) and self._head is not None and (
+                self._head <= shard_i < self._run_end
+            )
+            if held:  # an upload of the run behind the seated head
+                lead = self._await_release(shard_i)
             # upload_dispatch is the CALL (device_put returns at the
             # enqueue), not the transfer: the completion thread's upload
             # span, opened at this dispatch, ends where the bytes arrived.
@@ -2699,6 +2795,8 @@ class ShardWeightSource:
                 if self._watcher is not None:
                     self._watcher.watch(arrays, dispatch.t0, dict(attrs, bytes=nbytes))
         self.upload_dispatch_s += dispatch.dur_s
+        if held and shard_i < self._head + lead:
+            self.uploads_led += 1
         ordered = int(bool(nbytes and ordered))
         self._produced[ids["shard_idx"]] = (load_s, dispatch.dur_s, ordered)
         self.upload_bytes += nbytes
@@ -2736,10 +2834,16 @@ class ShardWeightSource:
         neither the build that this ``dispatched()`` lets out nor the one
         the next shard's lets out (``prefetch_depth`` + 1 and + 2 ahead)
         uploads anything: every layer of theirs seated, or past the end of
-        the plan. A cycling source says False."""
+        the plan. In a seated head whose run of uploads is held
+        (``in_head``) every shard but the last says True, and the last
+        False. A cycling source says False."""
         i, n = self._held, len(self.shards)
         if self.cycle or i is None or i in self._uploaded or i + 1 >= n:
             return False
+        if self._head is not None and i < self._head:
+            # The run's uploads wait for the head's last shard, which keeps
+            # its own wait: its "dispatched" lets them out.
+            return self.in_head()
         # A build that uploads is in _uploaded before its put seats a layer,
         # and reads an unseated one until then: no race with the producer.
         ahead = (i + self._depth + 1, i + self._depth + 2)
@@ -2771,6 +2875,8 @@ class ShardWeightSource:
         if self._holding:
             self._holding = False
             self._free_slot(ordered)
+            if self._head is not None:
+                self._note_dispatched(self._held)
 
     def _free_slot(self, ordered: bool) -> None:
         self._slot_ordered.append(ordered)  # before the release: see __init__
@@ -2792,6 +2898,105 @@ class ShardWeightSource:
             if self._stop.is_set():
                 raise SourceClosed("ShardWeightSource closed while streaming")
         return self._slot_ordered.popleft()
+
+    # -- the seated head's lead (the class docstring) -----------------------
+    def in_head(self) -> bool:
+        """Consumer: True while it holds a shard of the seated head before
+        the head's last, whose wait covers this one (the run's uploads are
+        held until that last shard is dispatched)."""
+        return self._head is not None and self._held is not None and (
+            self._held < self._head - 1
+        )
+
+    def _lead_head(self) -> tuple[int | None, int | None]:
+        """``(h, r)``: shards ``0 .. h-1`` upload nothing and hold a decoder
+        layer, ``h .. r-1`` each upload; ``(None, None)`` where the plan
+        opens otherwise."""
+        reads = [bool(self._to_read(j)) for j in range(len(self.shards))]
+        if True not in reads:
+            return None, None
+        h = reads.index(True)
+        n_names = len(self._loader.layer_names)
+        if not any(decoder_visits(self.shards[j], n_names) for j in range(h)):
+            return None, None
+        r = h
+        while r < len(reads) and reads[r]:
+            r += 1
+        return h, r
+
+    def _plan_lead(self) -> int:
+        """``lead_uploads`` over the run's first builds: the device work
+        still queued at the previous sweep's release (``head_queued_s``)
+        against each build's bytes at the link rate that sweep measured;
+        one where the record has neither."""
+        seen = self._lead_seen
+        queued, busy = seen.get("head_queued_s"), seen.get("upload_busy_s")
+        if queued is None or not busy or not seen.get("upload_bytes"):
+            return 1
+        from flexible_llm_sharding_tpu.runtime.residency import layer_stream_bytes
+
+        rate, loader = seen["upload_bytes"] / busy, self._loader
+        cap = min(self._depth + 1, self._run_end - self._head)
+        seconds = [
+            sum(layer_stream_bytes(
+                loader.model_path,
+                [loader.layer_names[i] for i in self._to_read(j)],
+                loader.tied,
+            ).values()) / rate
+            for j in range(self._head, self._head + cap)
+        ]
+        return lead_uploads(queued, seconds, cap)
+
+    def _note_dispatched(self, i: int) -> None:
+        """Consumer: shard ``i`` is dispatched; the head's last one releases
+        the run's first ``lead_planned`` uploads."""
+        with self._lead_cv:
+            self._dispatched_pos = i
+            if i == self._head - 1:
+                self._lead = self.lead_planned = self._plan_lead()
+            self._lead_cv.notify_all()
+
+    def _await_release(self, shard_i: int) -> int:
+        """Producer, an upload of the run behind the seated head: wait until
+        the head's last shard is dispatched and, past the lead, the shard
+        ``lead`` positions before this one; returns the lead. Polls
+        ``_stop`` and counts as ``producer_blocked``, as ``_take_slot``
+        does."""
+
+        def let_out(lead, dispatched):
+            return lead is not None and dispatched >= shard_i - lead
+
+        with self._lead_cv:
+            lead, dispatched = self._lead, self._dispatched_pos
+        if not let_out(lead, dispatched):
+            with obs_trace.timed(
+                "producer_blocked", cat="stream", **self._span_ids(shard_i)
+            ) as blocked, self._lead_cv:
+                while not let_out(self._lead, self._dispatched_pos):
+                    if self._stop.is_set():
+                        raise SourceClosed("ShardWeightSource closed while streaming")
+                    self._lead_cv.wait(0.2)
+                lead = self._lead
+            self.producer_blocked_s += blocked.dur_s
+        return lead
+
+    def _passed(self, shard_i: int) -> None:
+        """Producer: the build of ``shard_i`` is placed, or failed."""
+        if self._head is not None:
+            with self._lead_cv:
+                self._passed_pos = shard_i
+                self._lead_cv.notify_all()
+
+    def _await_lead_out(self) -> None:
+        """Consumer, about to take the run's first shard: wait until every
+        upload of the lead is enqueued (or failed), so that the arrival it
+        waits for next is the newest of them."""
+        with self._lead_cv:
+            while not (
+                self._passed_pos >= self._head + self._lead - 1
+                or self._stop.is_set()
+            ):
+                self._lead_cv.wait(0.2)
 
     # -- prefetch thread ---------------------------------------------------
     def _put(self, item, shard_i: int = 0) -> bool:
@@ -2887,9 +3092,11 @@ class ShardWeightSource:
                     # fails only the in-flight wave and keeps consuming
                     # (offline consumers raise and close(), which stops this
                     # loop via _stop on the next iteration).
+                    self._passed(i)
                     if not self._put(_ShardFault(e), i):
                         return
                     continue
+                self._passed(i)
                 if not self._put(item, i):
                     return
             if not self.cycle:
@@ -2934,6 +3141,8 @@ class ShardWeightSource:
                     item = self._get()
                     if isinstance(item, _ShardFault):
                         _reraise_from_producer(item.error)
+                    if i == self._head:
+                        self._await_lead_out()
                     self._await_upload(dev)
                     self._holding, self._held = True, i
                     yield idxs, item
@@ -3474,6 +3683,15 @@ class StreamingExecutor:
                 residency=self._residency,
                 sweep_id=clock.sweep_id,
                 first_shard_idx=start_shard,
+                # The link may start at a seated head where the host can run
+                # ahead of the device through it: one chip, blocks kept on
+                # it, no progress marker to keep behind the device.
+                lead=(
+                    _last_head_record()
+                    if self._tp_mesh is None and not resumable
+                    and (location == "tpu" or device_budget > 0)
+                    else None
+                ),
             )
             skip = 0
             # Baseline taken BEFORE the source's prefetch producer starts
@@ -3868,6 +4086,11 @@ class StreamingExecutor:
         stamps = clock.shards[-1]
         if store.link_bytes == link_bytes and source.wait_may_lag():
             clock.waits_deferred += 1
+            if source.in_head():
+                # The seated head's last shard waits for all of it: the
+                # host runs ahead, so the device still has the head queued
+                # when the uploads held behind it go out.
+                return None
             return suffix_h, stamps
         clock.wait_for_shard(suffix_h, stamps)
         return None

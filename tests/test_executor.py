@@ -1307,32 +1307,20 @@ def test_a_seated_pass_waits_for_each_shard_one_shard_later(
     )
 
 
-@pytest.mark.parametrize(
-    "seated_layers, lagging",
-    # the third: two streamed layers, so shard 6 follows the last shard
-    # that lets an upload out (5, whose "dispatched" lets out shard 8's)
-    [(2, {9}), (5, {0, 1, 9}), (6, {0, 1, 2, 6, 9})],
-    ids=["head_of_three", "head_of_six", "two_streamed"],
-)
-def test_a_wait_lags_only_where_no_upload_can_go_out_behind_it(
-    monkeypatch, deep_dir, fresh_tier, seated_layers, lagging
-):
-    """The embedding, the first ``seated_layers`` decoder layers, the norm
-    and the head seated, the layers between them streamed, prefetch depth
-    2 (shard k's "dispatched" lets out the build of shard k + 3). In the
-    second pass a shard's end is waited for one shard later only where it
-    uploaded nothing and neither its own "dispatched" nor the next shard's
-    lets an upload out: every shard that uploaded, every shard that lets an
-    upload out and the shard before it keep the wait at their own end; no
-    block of a shard that lets an upload out is dispatched while an earlier
-    shard's wait is pending; every upload is still ordered, each put of
-    shard k + 3 after the last block of shard k."""
-    residency = fresh_tier
+def _seated_budget(residency, deep_dir, seated):
+    """A tier budget that seats exactly the layers ``seated`` (indices into
+    the deep toy's 11 files)."""
     path, names = deep_dir
     size = residency.layer_stream_bytes(path, names, False)
-    budget = sum(size[i] for i in (*range(seated_layers + 1), 9, 10)) + 16
-    streamed = set(range(seated_layers + 1, 9))
-    releasing = {s - 3 for s in streamed}
+    return (sum(size[i] for i in seated) + 16) / 1e9
+
+
+def _logged_pass(monkeypatch, ex, lead=True):
+    """One pass of ``ex`` with its builds' puts ("put", n: n the shard's
+    position, every build puts once), blocks ("block", shard, b) and
+    shard-end waits ("wait", shard waited on, shard the consumer is on)
+    logged in order; ``lead`` False turns the seated head's lead off (the
+    slots' order alone). Returns (scores, record, events)."""
     events, lock = [], threading.Lock()
     orig_put, orig_block = executor_mod._assemble_parts, executor_mod.process_block
     orig_wait = executor_mod.SweepClock.wait_for_shard
@@ -1350,38 +1338,234 @@ def test_a_wait_lags_only_where_no_upload_can_go_out_behind_it(
 
     def wait(clock, result, stamps):
         orig_wait(clock, result, stamps)
-        with lock:  # the shard waited on, and the shard the consumer is on
+        with lock:
             events.append(("wait", stamps.shard_idx, clock.shard_idx))
 
-    ex = StreamingExecutor(
-        _account_cfg(path, hbm_pin_gb=budget / 1e9, storage_location="tpu"),
-        tokenizer=FakeTokenizer(),
-    )
-    ex(list(PROMPTS))  # seats the pins
     with monkeypatch.context() as m:
         m.setattr(executor_mod, "_assemble_parts", put)
         m.setattr(executor_mod, "process_block", block)
         m.setattr(executor_mod.SweepClock, "wait_for_shard", wait)
-        ex(list(PROMPTS))
-    rec = executor_mod.process_sweep_log()[-1]
-    assert rec["pin_hits"] == 11 - len(streamed)
-    assert rec["uploads"] == rec["uploads_ordered"] == len(streamed)
-    assert rec["waits_deferred"] == len(lagging)
-    waits = [e for e in events if e[0] == "wait"]
-    assert sorted(e[1] for e in waits) == list(range(10))  # the head has none
-    assert {k for _, k, on in waits if on == k + 1} == lagging
-    assert all(on == k for _, k, on in waits if k not in lagging)
-    assert not lagging & (streamed | releasing | {r - 1 for r in releasing})
-    waited_at = {e[1]: i for i, e in enumerate(events) if e[0] == "wait"}
-    for i, e in enumerate(events):
-        if e[0] == "block" and e[1] in releasing:
-            assert all(waited_at[k] < i for k in range(e[1])), e
-    last_block = {}
+        if not lead:
+            m.setattr(
+                executor_mod.ShardWeightSource, "_lead_head", lambda self: (None, None)
+            )
+        scores = ex(list(PROMPTS))
+    return scores, executor_mod.process_sweep_log()[-1], events
+
+
+def _last_blocks(events) -> dict:
+    return {e[1]: i for i, e in enumerate(events) if e[0] == "block"}
+
+
+@pytest.mark.parametrize(
+    "seated_layers, lagging",
+    # the third: two streamed layers, so shard 6 follows the last shard
+    # that lets an upload out (5, whose "dispatched" lets out shard 8's)
+    [(2, {9}), (5, {0, 1, 9}), (6, {0, 1, 2, 6, 9})],
+    ids=["head_of_three", "head_of_six", "two_streamed"],
+)
+def test_a_wait_lags_only_where_no_upload_can_go_out_behind_it(
+    monkeypatch, deep_dir, fresh_tier, seated_layers, lagging
+):
+    """The embedding, the first ``seated_layers`` decoder layers, the norm
+    and the head seated, the layers between them streamed, prefetch depth
+    2. With the slots' order alone (the seated head's lead off: shard k's
+    "dispatched" lets out the build of shard k + 3) a shard's end is waited
+    for one shard later only where it uploaded nothing and neither its own
+    "dispatched" nor the next shard's lets an upload out: every shard that
+    uploaded, every shard that lets an upload out and the shard before it
+    keep the wait at their own end; no block of a shard that lets an upload
+    out is dispatched while an earlier shard's wait is pending; every upload
+    is still ordered, each put of shard k + 3 after the last block of shard
+    k. With the lead (the pass's own order: the run's uploads held until
+    the head's last shard is dispatched, then one by one) the head's shards
+    before its last wait for nothing, its last shard waits at its own end,
+    where the uploads go out, and so do the streamed ones; only the norm
+    lags; each block of a shard that lets an upload out comes after every
+    wait that shard's predecessors have; each put of shard k + 1 comes
+    after the last block of shard k."""
+    path, _ = deep_dir
+    head = seated_layers + 1
+    executor_mod._SWEEP_LOG.clear()  # no earlier sweep's head to plan a lead on
+    streamed = set(range(head, 9))
+    ex = StreamingExecutor(
+        _account_cfg(
+            path, storage_location="tpu",
+            hbm_pin_gb=_seated_budget(fresh_tier, deep_dir, (*range(head), 9, 10)),
+        ),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))  # seats the pins
+    for lead, releasing, lagged, covered in [
+        (False, {s - 3 for s in streamed}, lagging, set()),
+        (True, {s - 1 for s in streamed}, {9}, set(range(head - 1))),
+    ]:
+        _, rec, events = _logged_pass(monkeypatch, ex, lead=lead)
+        assert rec["pin_hits"] == 11 - len(streamed)
+        assert rec["uploads"] == rec["uploads_ordered"] == len(streamed)
+        assert rec["waits_deferred"] == len(lagged) + len(covered)
+        waits = [e for e in events if e[0] == "wait"]
+        # the head has none; a shard the head's last one covers has none
+        assert sorted(e[1] for e in waits) == sorted(set(range(10)) - covered)
+        assert {k for _, k, on in waits if on == k + 1} == lagged
+        assert all(on == k for _, k, on in waits if k not in lagged)
+        assert not lagged & (streamed | releasing | {r - 1 for r in releasing})
+        waited_at = {e[1]: i for i, e in enumerate(events) if e[0] == "wait"}
+        for i, e in enumerate(events):
+            if e[0] == "block" and e[1] in releasing:
+                assert all(
+                    waited_at[k] < i for k in range(e[1]) if k not in covered
+                ), e
+        last_block = _last_blocks(events)
+        for k in range(11 - 3):
+            assert last_block[k] < events.index(("put", k + 3)), k
+        for k in releasing if lead else ():
+            assert last_block[k] < events.index(("put", k + 1)), k
+
+
+def test_the_link_starts_at_the_seated_heads_end(monkeypatch, deep_dir, fresh_tier):
+    """The embedding and the first two decoder layers seated (the head:
+    shards 0-2), six streamed, the norm and the head seated; blocks kept on
+    the chip. The run's first upload goes out after the last block of the
+    head's last shard, the lead is one (no sweep before this one released a
+    head) and the record says so; the scores are bit for bit those of the
+    same pass with the lead turned off, which reads no lead."""
+    path, _ = deep_dir
+    executor_mod._SWEEP_LOG.clear()
+    ex = StreamingExecutor(
+        _account_cfg(
+            path, storage_location="tpu",
+            hbm_pin_gb=_seated_budget(fresh_tier, deep_dir, (0, 1, 2, 9, 10)),
+        ),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))  # seats the pins
+    seating = executor_mod.process_sweep_log()[-1]
+    led, rec, events = _logged_pass(monkeypatch, ex)
+    off, rec_off, _ = _logged_pass(monkeypatch, ex, lead=False)
+    assert (seating["lead_planned"], seating["uploads_led"]) == (0, 0)
+    assert (rec["lead_planned"], rec["uploads_led"]) == (1, 1)
+    assert rec["head_queued_s"] >= 0.0
+    assert (rec_off["lead_planned"], rec_off["uploads_led"]) == (0, 0)
+    assert "head_queued_s" not in rec_off
+    first_put = events.index(("put", 3))
+    assert max(i for i, e in enumerate(events) if e[0] == "block" and e[1] < 3) < first_put
+    for a, b in zip(led, off):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize(
+    "queued_s, upload_s, cap, want",
+    [
+        (0.0, [0.092] * 6, 3, 1),  # nothing queued: the first alone
+        (0.1, [0.092] * 6, 3, 1),  # two would take 0.184 s
+        (0.2, [0.084] * 6, 3, 2),
+        (0.5, [0.084] * 6, 3, 3),  # the slots bound it
+        (9.0, [0.084] * 2, 3, 2),  # a run of two
+        (9.0, [0.084] * 6, 1, 1),
+        (9.0, [], 3, 1),
+    ],
+)
+def test_lead_uploads(queued_s, upload_s, cap, want):
+    assert executor_mod.lead_uploads(queued_s, upload_s, cap) == want
+
+
+@pytest.mark.parametrize(
+    "estimate, want",
+    [(None, 1), (0.0, 1), (1e9, 3)],
+    ids=["no_sweep_before", "nothing_queued", "queued_past_every_slot"],
+)
+def test_the_lead_follows_the_work_queued_and_stays_in_the_slots(
+    monkeypatch, deep_dir, fresh_tier, estimate, want
+):
+    """The head of three, a run of six streamed. With no earlier sweep that
+    released a head, or one whose head's last wait read nothing queued, the
+    lead is one; with the queued work past any run of uploads it is the
+    slots' three (prefetch depth 2), never more. The lead's uploads are all
+    put after the head's last block and before the run's first block; each
+    later one after the last block of the shard ``lead`` before it, and
+    every block of the head before the first of them."""
+    path, _ = deep_dir
+    ex = StreamingExecutor(
+        _account_cfg(
+            path, storage_location="tpu",
+            hbm_pin_gb=_seated_budget(fresh_tier, deep_dir, (0, 1, 2, 9, 10)),
+        ),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))  # seats the pins
+    seen = {} if estimate is None else {
+        "head_queued_s": estimate, "upload_busy_s": 1.0, "upload_bytes": 10**9,
+    }
+    monkeypatch.setattr(executor_mod, "_last_head_record", lambda: dict(seen))
+    _, rec, events = _logged_pass(monkeypatch, ex)
+    assert (rec["lead_planned"], rec["uploads_led"]) == (want, want)
+    assert rec["uploads"] == 6
+    last_block = _last_blocks(events)
+    first_block = {}
     for i, e in enumerate(events):
         if e[0] == "block":
-            last_block[e[1]] = i
-    for k in range(11 - 3):
-        assert last_block[k] < events.index(("put", k + 3)), k
+            first_block.setdefault(e[1], i)
+    puts = {e[1]: i for i, e in enumerate(events) if e[0] == "put"}
+    for j in range(3, 3 + want):
+        assert last_block[2] < puts[j] < first_block[3], j
+    for j in range(3 + want, 9):
+        assert last_block[j - want] < puts[j], j
+
+
+@pytest.mark.parametrize(
+    "case", ["first_decoder_streamed", "cpu", "disk", "cycle", "per_shard_devices"]
+)
+def test_no_seated_head_or_another_consumer_keeps_the_slots_order(
+    monkeypatch, deep_dir, fresh_tier, tmp_path, case
+):
+    """No lead where the plan's first decoder layer is streamed (only the
+    embedding ahead of it), in a ``cpu``-store pass and in a resumable
+    ``disk`` pass: the records read no lead and every wait falls where the
+    slots' order alone puts it. A cycling source (serving) and one with a
+    device a shard (the pipeline) find no head whatever they are given."""
+    path, names = deep_dir
+    if case in ("cycle", "per_shard_devices"):
+        n = len(names)
+        kw = {"cycle": True} if case == "cycle" else {
+            "devices": [jax.devices()[0]] * n
+        }
+        source = _tracked_source(
+            monkeypatch, deep_dir, 2, (0, 1, 2, 9, 10), lead={}, **kw
+        )
+        try:
+            it = iter(source)
+            for _ in range(n):
+                next(it)
+                source.dispatched()
+            assert source._head is None and source.lead_planned == 0
+        finally:
+            source.close()
+        # the same plan and pins, one sweep, one chip: it has the head
+        control = _tracked_source(monkeypatch, deep_dir, 2, (0, 1, 2, 9, 10), lead={})
+        control.close()
+        assert (control._head, control._run_end) == (3, 9)
+        return
+    # the tier seats decoder layers from the first: to stream the first,
+    # seat none of them
+    seated = (0, 9, 10) if case == "first_decoder_streamed" else (0, 1, 2, 9, 10)
+    ex = StreamingExecutor(
+        _account_cfg(
+            path, disk_folder=str(tmp_path),
+            storage_location="tpu" if case == "first_decoder_streamed" else case,
+            hbm_pin_gb=_seated_budget(fresh_tier, deep_dir, seated),
+        ),
+        tokenizer=FakeTokenizer(),
+    )
+    ex(list(PROMPTS))  # seats the pins
+    _, rec, events = _logged_pass(monkeypatch, ex)
+    _, rec_off, events_off = _logged_pass(monkeypatch, ex, lead=False)
+    assert (rec["lead_planned"], rec["uploads_led"]) == (0, 0)
+    assert "head_queued_s" not in rec
+    assert rec["uploads"] == rec_off["uploads"] == 11 - len(seated)
+    assert rec["waits_deferred"] == rec_off["waits_deferred"]
+    waits = [e for e in events if e[0] == "wait"]
+    assert waits == [e for e in events_off if e[0] == "wait"]
 
 
 @pytest.mark.parametrize("where", ["cpu", "disk", "kept_on_the_chip"])
