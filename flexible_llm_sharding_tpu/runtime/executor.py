@@ -24,6 +24,7 @@ TPU-first redesign (SURVEY.md §7):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import gc
 import itertools
@@ -782,8 +783,8 @@ class SweepClock:
     keeps them as its per-shard table (``process_slow_sweeps``); every
     other sweep drops them with its clock. The shard's last block is
     dispatched at the earlier of ``t_wait`` (the shard-end wait's start)
-    and ``t_end``, which is also where the consumer has just handed the
-    shard's upload slot back (``ShardWeightSource.dispatched``): an upload
+    and ``t_end``, which is also where the consumer has just let go of the
+    shard (``ShardWeightSource.dispatched``): an upload
     ordered by that signal (the record's ``uploads_ordered``) is enqueued
     after it, and an upload's enqueue is dated where its ``device_put``
     call RETURNED. ``waits_deferred`` counts the shards whose end was
@@ -1157,15 +1158,15 @@ SWEEP_RECORD_HELP = {
     "source_wait, the store's bookkeeping and that block's host dispatch. "
     "Summed over drained_shards boundaries; 0 at a boundary whose wait "
     "lagged (waits_deferred): the next shard was launched before it returned.",
-    "waits_deferred": "Shards whose end was waited for one shard later: their "
+    "waits_deferred": "Shards whose end was not waited for at the end: where "
+    "the source answered lag or skip (ShardWeightSource.end_wait). Lag: the "
     "last result blocked on inside the next shard's dispatch, before its "
     "last block, so the device had the next shard queued while the host "
-    "woke. Only a shard that uploaded nothing, kept its blocks on the chip "
+    "woke; only a shard that uploaded nothing, kept its blocks on the chip "
     "and is followed by two builds that upload nothing (no upload goes out "
     "behind queued launches), never the last; a pass whose every layer is "
-    "seated defers every shard-end wait it has. Also counted: the shards of "
-    "a seated head before its last, whose streamed run is held behind it "
-    "(lead_planned): not waited for at all, the last one's wait covers them.",
+    "seated lags every shard-end wait it has. Skip: a shard of a seated "
+    "head before its last, whose wait covers it (lead_planned).",
     "drained_shards": "Shard boundaries counted in drained_s (a shard with a "
     "wait for the device at its end, followed by one that launched).",
     "own_upload_wait_s": "Device idle, by the host's stamps: shards whose "
@@ -1182,17 +1183,19 @@ SWEEP_RECORD_HELP = {
     "uploads ordered (uploads_ordered) that upload went out behind an "
     "EARLIER shard's steps and is the link's turn, not an accident of order.",
     "launches_behind_upload": "Shards whose behind_upload_s share is over 1 ms.",
-    "uploads_ordered": "Weight uploads whose device_put was enqueued on a "
-    "slot the consumer returned with dispatched(), that is behind the last "
-    "block of the shard prefetch_depth + 1 before it (against uploads; the "
-    "first prefetch_depth + 1 builds of a source use the slots it starts "
-    "with, and a consumer that never says dispatched orders none).",
-    "lead_planned": "Uploads the seated head's release let out at once: "
-    "where the sweep opens with seated shards holding a decoder layer, the "
-    "streamed run behind them waits for the head's last shard to be "
-    "dispatched, then leads by one upload, or by two or three while the "
-    "head's work still queued (the previous sweep's head_queued_s) outlasts "
-    "them; 0 without such a head.",
+    "uploads_ordered": "Weight uploads whose device_put went out on a place "
+    "the consumer gave back with dispatched() (the source's one gate: a "
+    "free place, of prefetch_depth + 1, and behind a seated head the head's "
+    "release), that is behind the last block of an earlier shard (against "
+    "uploads; the first prefetch_depth + 1 builds of a source use the "
+    "places it starts with, and a consumer that never says dispatched "
+    "orders none).",
+    "lead_planned": "The lead the gate gave the streamed run behind a seated "
+    "head (seated shards that hold a decoder layer open the sweep): its "
+    "uploads go out once the head's last shard is dispatched, one, or two "
+    "or three while the head's work still queued (the previous sweep's "
+    "head_queued_s) outlasts them, and each later one once the shard lead "
+    "before it is; 0 without such a head.",
     "uploads_led": "Uploads let out under lead_planned before the consumer "
     "took the run's first shard.",
     "head_queued_s": "The wait at the seated head's last shard, which starts "
@@ -1221,8 +1224,8 @@ SWEEP_RECORD_HELP = {
     "upload_dispatch_s": "Producer: inside jax.device_put calls, which "
     "return at the enqueue; not a transfer time.",
     "producer_blocked_s": "Producer: holding a built shard while the "
-    "prefetch queue was full, or a shard's host side built and waiting for "
-    "its upload slot (the consumer's dispatched()).",
+    "prefetch queue was full, or a shard's host side built and waiting at "
+    "its upload's gate (a place the consumer's dispatched() gives back).",
     "link_wait_s": "Consumer, inside source_wait_s: a shard in hand, waiting "
     "for the newest weight upload to its device to arrive before taking it "
     "(steps dispatched under an upload in flight would wait for the next "
@@ -2332,49 +2335,48 @@ class ShardWeightSource:
     compute of shard t (the reference serializes these,
     ``/root/reference/utils.py:228-233``).
 
-    How far the thread leads is a count of upload slots: ``prefetch_depth``
-    + 1 shards built (placed on the device) and not yet DISPATCHED by the
-    consumer, the queue's places plus the one in the producer's hand. A
-    build takes its slot after its host side is done (the cache hit, the
-    ``pinned_host`` tree) and just before its ``device_put``; the consumer
-    hands the slot back with ``dispatched()`` once the last block of the
-    shard it holds is enqueued, so the next upload goes out BEHIND that
-    shard's steps and runs beside them. (On the chip a program launched
-    after a transfer's enqueue waits for the transfer; launched before it,
-    both run side by side.) A consumer that never calls ``dispatched()``
-    returns the slot when it asks for the next shard: the same lead, the
-    same bytes in HBM, no order promised.
+    One rule releases the uploads, held in one place (``_gate``). The
+    source has ``prefetch_depth`` + 1 places: a build takes one once its
+    host side is done (the cache hit, the ``pinned_host`` tree), just before
+    its ``device_put``, and the consumer gives its shard's place back by
+    letting go of the shard: ``dispatched()`` once the shard's last block is
+    enqueued, or, having said nothing, asking for the next shard (a build
+    that fails for good gives its place back itself: the consumer takes a
+    fault there and holds nothing). Build ``j``'s ``device_put`` goes out
+    when a place is free and, for an upload of the run ``h .. r-1`` behind a
+    seated head (shards ``0 .. h-1`` upload nothing and hold a decoder
+    layer) where the consumer passed ``lead`` (the executor's one-chip
+    scoring pass, whose blocks stay on the chip), once the consumer has let
+    go of shard ``max(h - 1, j - lead)``; ``lead`` is planned as it lets go
+    of shard ``h - 1`` (``lead_uploads``: one, and a second or third only
+    while the head's device work still queued is expected to outlast them,
+    read off the previous sweep's record: ``head_queued_s``, and its link
+    rate). So an upload goes out BEHIND the steps just dispatched and runs
+    beside them (on the chip a program launched after a transfer's enqueue
+    waits for it; launched before, both run side by side), and the link
+    starts at the head's end. Every other consumer (serving's cycling
+    source, the pipeline, KV decode, DP's broadcast) and a plan with no
+    seated head have the places alone; a consumer that never says
+    "dispatched" keeps the same lead and the same bytes in HBM, with no
+    order promised.
 
-    The other half of the order: a shard is handed to the consumer only
-    once the newest upload to its device has ARRIVED (``_await_upload``,
-    inside the consumer's ``next()``; ``link_wait_s``). "Dispatched" on the
-    host is not "issued" on the chip: a launch, when the runtime issues it,
-    waits for every transfer enqueued by then, and while it waits the
-    launches behind it are not issued either, so they also wait for the
-    uploads enqueued MEANWHILE. Steps dispatched under an upload still in
-    flight would therefore wait for the next upload too, the one their own
-    "dispatched" lets out (read on the chip: every second streamed shard
-    took an upload and a half longer). Dispatched with the link idle they
-    are issued at once, and the upload their signal lets out runs beside
-    them: a streamed shard costs the larger of its upload and its steps.
+    A shard is handed to the consumer only once the newest upload to its
+    device has ARRIVED (``_await_upload``, inside the consumer's ``next()``;
+    ``link_wait_s``), and the run's first shard only once the lead's uploads
+    are all enqueued. "Dispatched" on the host is not "issued" on the chip:
+    a launch, when the runtime issues it, waits for every transfer enqueued
+    by then, and while it waits the launches behind it are not issued
+    either, so they also wait for the uploads enqueued MEANWHILE. Steps
+    dispatched under an upload still in flight would therefore wait for the
+    next upload too, the one their own "dispatched" lets out (read on the
+    chip: every second streamed shard took an upload and a half longer).
+    Dispatched with the link idle they are issued at once, and the upload
+    their signal lets out runs beside them: a streamed shard costs the
+    larger of its upload and its steps.
 
-    Where the sweep opens with a seated head (shards ``0 .. h-1`` upload
-    nothing and hold a decoder layer; shard ``h`` starts a run of shards
-    that upload) and the consumer passes ``lead`` (the executor's one-chip
-    scoring pass, where its blocks stay on the chip), the link starts at the
-    head's end: the run's uploads are held until the consumer has
-    dispatched shard ``h - 1``, so the head's shards need no wait of their
-    own (``in_head``: the host runs ahead of the device through the head,
-    and the last head shard's wait covers them all). The release lets
-    ``lead_planned`` uploads out at once (``lead_uploads``: one, and a
-    second or third only while the head's device work still queued is
-    expected to outlast them, read off the previous sweep's record:
-    ``head_queued_s``, and its link rate), and the consumer takes shard
-    ``h`` once they are all enqueued and arrived; each later upload of the
-    run goes out when the shard ``lead_planned`` before it is dispatched.
-    Every other consumer (serving's cycling source, the pipeline, KV
-    decode, DP's broadcast) and a plan with no seated head keep the slots'
-    order alone.
+    At a shard's end the consumer asks one question, ``end_wait()``: wait
+    for the shard now, let the wait lag one shard, or skip it (a head shard
+    before the head's last, whose wait covers it).
 
     ``cycle=True`` loops the shard list endlessly instead of stopping after
     one pass — the online serving loop's weight stream, where the number of
@@ -2413,7 +2415,7 @@ class ShardWeightSource:
     ):
         # lead: the previous sweep's record ({} where there is none) where the
         # consumer lets the link start at a seated head (the class
-        # docstring); None keeps the slots' order alone.
+        # docstring); None: the places alone.
         # residency: a runtime.residency.DeviceResidencyTier (or None) —
         # pinned layers are subtracted from every shard build (their bytes
         # never cross the link) and merged back as resident segments at
@@ -2478,10 +2480,10 @@ class ShardWeightSource:
         self.upload_pinned_bytes = 0  # of upload_bytes, from pinned_host
         # shard_idx -> (shard_load_s, upload_dispatch_s, upload_ordered): the
         # producer's seconds by shard and whether its device_put went out on
-        # a slot dispatched() returned, for a slow sweep's table
+        # a place dispatched() returned, for a slow sweep's table
         # (shard_table()); the second also dates the enqueue's END.
         self._produced: dict[int, tuple[float, float, int]] = {}
-        # Uploads whose device_put went out on a slot that dispatched()
+        # Uploads whose device_put went out on a place that dispatched()
         # returned (against the account's ``uploads``).
         self.uploads_ordered = 0
         # The newest upload, (device, arrays), until a consumer has seen it
@@ -2495,34 +2497,28 @@ class ShardWeightSource:
         # per-upload state, no second join on the watchdog's recovery path.
         self._watcher = None if cycle else _UploadWatcher()
         self._q: Queue = Queue(maxsize=max(1, prefetch_depth))
-        # Upload slots (see the class docstring), and for each free one,
-        # oldest first, whether dispatched() returned it. The consumer
-        # appends before it releases and the producer pops after it has
-        # acquired, so a flag is there for every slot taken.
-        # prefetch_depth 0 builds in the consumer's next(): no thread, no slots.
-        self._slots = (
-            threading.Semaphore(prefetch_depth + 1) if prefetch_depth >= 1 else None
-        )
-        self._slot_ordered: deque = deque([False] * (prefetch_depth + 1))
-        self._holding = False  # consumer's thread: a shard taken, slot not returned
-        # For wait_may_lag: how far ahead a returned slot builds, the position
-        # of the shard the consumer holds, and the positions whose build
-        # uploads (the producer adds one before its put).
+        # The release rule (the class docstring). Plan facts: the places
+        # beyond the one in hand, and the run of uploads behind a seated head
+        # (None: no lead). Under _cv: the free places, oldest first, each
+        # True where dispatched() returned it; the shard the consumer let go
+        # of last and the one the producer put last; the lead, planned as
+        # the head's last shard is let go of. prefetch_depth 0 builds in the
+        # consumer's next(): no thread, no places.
         self._depth = max(0, prefetch_depth)
+        self._lead_seen = lead or {}
+        self._run: range | None = None
+        if lead is not None and prefetch_depth >= 1 and devices is None and not cycle:
+            self._run = self._run_behind_head()
+        self._cv = threading.Condition()
+        self._places: deque = deque([False] * (self._depth + 1))  # guarded by: _cv
+        self._released = self._passed = -1  # guarded by: _cv
+        self._lead: int | None = None  # guarded by: _cv
+        self.uploads_led = self.lead_planned = 0
+        # For end_wait: the shard the consumer holds (counted from the
+        # first: a one-pass source's position) and the positions whose
+        # build uploads (the producer adds one before its put).
         self._held: int | None = None
         self._uploaded: set[int] = set()
-        # The seated head's lead (the class docstring): the head's length h
-        # and the end of the run of uploads behind it (None: no lead), the
-        # highest positions the consumer has dispatched and the producer has
-        # passed, and the lead, set at the release.
-        self._lead_seen = lead or {}
-        self._head = self._run_end = None
-        if lead is not None and prefetch_depth >= 1 and devices is None and not cycle:
-            self._head, self._run_end = self._lead_head()
-        self._lead_cv = threading.Condition()
-        self._lead: int | None = None  # guarded by: _lead_cv
-        self._dispatched_pos = self._passed_pos = -1  # guarded by: _lead_cv
-        self.uploads_led = self.lead_planned = 0
         self._close_lock = threading.Lock()  # close() may race abort()/close()
         self._thread: threading.Thread | None = None
         if prefetch_depth >= 1:
@@ -2616,7 +2612,7 @@ class ShardWeightSource:
         times its uploads) also lines the consumer's per-shard stamps
         (``clock.shards``) up with them: why the device stood idle between shards
         (``drained_s``, ``own_upload_wait_s``, ``behind_upload_s``).
-        ``uploads_ordered`` counts the uploads enqueued on a slot that
+        ``uploads_ordered`` counts the uploads enqueued on a place that
         ``dispatched()`` returned; ``lead_planned`` / ``uploads_led`` what
         the seated head's release allowed and let out, and
         ``head_queued_s`` (a sweep that released) what its last shard's wait
@@ -2674,7 +2670,7 @@ class ShardWeightSource:
         planned on). Empty where nothing was released."""
         if not self.lead_planned or clock is None:
             return {}
-        last = self._first_shard_idx + self._head - 1
+        last = self._first_shard_idx + self._run.start - 1
         for s in clock.shards:
             if s.shard_idx == last and s.t_ready is not None:
                 return {"head_queued_s": s.t_ready - s.t_wait}
@@ -2726,7 +2722,7 @@ class ShardWeightSource:
             nbytes = self._loader.bytes_loaded - bytes_before
             load_s = self._loader.build_time - build_before
             if any(kind != "pin" for kind, _, _ in parts):
-                # Said before the put below seats anything (wait_may_lag).
+                # Said before the put below seats anything (end_wait).
                 self._uploaded.add(shard_i)
             # Count the sweep's saved link bytes ONCE per build (the put
             # below may retry; retries must not double-count).
@@ -2760,14 +2756,20 @@ class ShardWeightSource:
                 )
 
             # The host side is done; only the device_put waits for its
-            # slot, once a shard and outside the retried region.
+            # gate, once a shard and outside the retried region.
             blocked_before = self.producer_blocked_s
-            ordered = self._slots is not None and self._take_slot(shard_i)
-            held = bool(nbytes) and self._head is not None and (
-                self._head <= shard_i < self._run_end
-            )
-            if held:  # an upload of the run behind the seated head
-                lead = self._await_release(shard_i)
+            behind_head = bool(nbytes) and self._run is not None and shard_i in self._run
+            ordered = led = False
+            if self._depth:
+
+                def take(timeout):  # the oldest free place, once the gate opens
+                    if self._cv.wait_for(lambda: self._gate(shard_i, behind_head), timeout):
+                        return self._places.popleft()
+                    return None
+
+                with self._cv:
+                    ordered = self._wait(take, shard_i)
+                    led = behind_head and shard_i < self._run.start + self._lead
             # upload_dispatch is the CALL (device_put returns at the
             # enqueue), not the transfer: the completion thread's upload
             # span, opened at this dispatch, ends where the bytes arrived.
@@ -2784,10 +2786,9 @@ class ShardWeightSource:
                         abort=self._stop.is_set,
                     )
             except BaseException:
-                if self._slots is not None:
-                    # Nothing was built: the consumer takes a fault in this
-                    # shard's place, which holds no slot.
-                    self._free_slot(False)
+                # Nothing was built: the consumer takes a fault in this
+                # shard's place and holds nothing, so the place comes back here.
+                self._release(failed=True)
                 raise
             if nbytes:
                 arrays = [seg for _, seg in out]
@@ -2795,14 +2796,13 @@ class ShardWeightSource:
                 if self._watcher is not None:
                     self._watcher.watch(arrays, dispatch.t0, dict(attrs, bytes=nbytes))
         self.upload_dispatch_s += dispatch.dur_s
-        if held and shard_i < self._head + lead:
-            self.uploads_led += 1
+        self.uploads_led += led
         ordered = int(bool(nbytes and ordered))
         self._produced[ids["shard_idx"]] = (load_s, dispatch.dur_s, ordered)
         self.upload_bytes += nbytes
         self.upload_pinned_bytes += pinned_nbytes
         self.uploads_ordered += ordered
-        # The wait for a slot is the consumer's pace, not the producer's work.
+        # The wait at the gate is the consumer's pace, not the producer's work.
         self.produce_time += produce.dur_s - (
             self.producer_blocked_s - blocked_before
         )
@@ -2814,42 +2814,134 @@ class ShardWeightSource:
             "shard_idx": self._first_shard_idx + shard_i,
         }
 
-    # -- upload slots ------------------------------------------------------
+    # -- the release rule (the class docstring) ----------------------------
     def dispatched(self) -> None:
         """Consumer: the last block of the shard it holds is enqueued on the
-        device. Hands that shard's upload slot back, so the producer's next
-        ``device_put`` lands behind the shard's steps (and before the
-        consumer's wait at the shard's end, which the upload then runs
-        beside). Once a shard; a second call, or one with no shard held
-        (``prefetch_depth`` 0: no thread, no slots), does nothing."""
-        self._return_slot(True)
+        device. Lets go of that shard, so the producer's next ``device_put``
+        lands behind the shard's steps (and before the consumer's wait at the
+        shard's end, which the upload then runs beside). Once a shard; a
+        second call, or one with no shard held, does nothing."""
+        self._release(dispatched=True)
 
-    def wait_may_lag(self) -> bool:
-        """Consumer, after ``dispatched()``: True when its wait for the
-        shard it holds may lag one shard (blocked on at the next shard's
-        dispatch, so the device has this shard queued while the host takes
-        and dispatches the next). Only where no upload can go out behind
-        launches queued meanwhile, and the host still holds streamed
-        buffers back: the shard uploaded nothing, it is not the last, and
-        neither the build that this ``dispatched()`` lets out nor the one
-        the next shard's lets out (``prefetch_depth`` + 1 and + 2 ahead)
-        uploads anything: every layer of theirs seated, or past the end of
-        the plan. In a seated head whose run of uploads is held
-        (``in_head``) every shard but the last says True, and the last
-        False. A cycling source says False."""
+    def end_wait(self) -> str:
+        """Consumer, after ``dispatched()``: what to do about its wait for
+        the shard it holds. "skip": a shard of the seated head before the
+        head's last, whose wait covers it (the run's uploads wait for that
+        last shard). "lag": the wait may come one shard later (blocked on at
+        the next shard's dispatch, so the device has this shard queued while
+        the host takes and dispatches the next), which is only where no
+        upload can go out behind launches queued meanwhile and the host
+        still holds streamed buffers back: the shard uploaded nothing, it is
+        not the last, and neither the build that this ``dispatched()`` lets
+        out nor the one the next shard's lets out (``prefetch_depth`` + 1
+        and + 2 ahead) uploads anything: every layer of theirs seated, or
+        past the end of the plan. "wait" otherwise: the head's last shard,
+        and every shard of a cycling source."""
         i, n = self._held, len(self.shards)
         if self.cycle or i is None or i in self._uploaded or i + 1 >= n:
-            return False
-        if self._head is not None and i < self._head:
-            # The run's uploads wait for the head's last shard, which keeps
-            # its own wait: its "dispatched" lets them out.
-            return self.in_head()
+            return "wait"
+        if self._run is not None and i < self._run.start:
+            return "skip" if i < self._run.start - 1 else "wait"
         # A build that uploads is in _uploaded before its put seats a layer,
         # and reads an unseated one until then: no race with the producer.
         ahead = (i + self._depth + 1, i + self._depth + 2)
-        return not any(
-            j in self._uploaded or self._to_read(j) for j in ahead if j < n
+        if any(j in self._uploaded or self._to_read(j) for j in ahead if j < n):
+            return "wait"
+        return "lag"
+
+    def _gate(self, j: int, behind_head: bool) -> bool:
+        """Under ``_cv``: build ``j``'s ``device_put`` may go out. A place is
+        free and, for an upload of the run ``h .. r-1`` behind the seated
+        head (``behind_head``), the consumer has let go of shard ``max(h - 1,
+        j - lead)`` (the lead is planned as it lets go of ``h - 1``)."""
+        # flscheck: holds=_cv: asked only inside the producer's wait_for
+        if not self._places:
+            return False
+        return not behind_head or (
+            self._lead is not None and self._released >= j - self._lead
         )
+
+    def _release(self, dispatched: bool = False, failed: bool = False) -> None:
+        """A place comes back: the consumer lets go of the shard it holds
+        (once; letting go of the seated head's last shard plans the lead),
+        or (``failed``) the producer gives back the place of a build that
+        failed for good."""
+        if not self._depth:
+            return
+        with self._cv:
+            if not failed:
+                if self._held is None or self._released >= self._held:
+                    return
+                self._released = self._held
+                if self._run is not None and self._released == self._run.start - 1:
+                    self._lead = self.lead_planned = self._plan_lead()
+            self._places.append(dispatched)
+            self._cv.notify_all()
+
+    def _run_behind_head(self) -> range | None:
+        """Shards ``h .. r-1`` that each upload, behind a head ``0 .. h-1``
+        that uploads nothing and holds a decoder layer; None where the plan
+        opens otherwise."""
+        reads = [bool(self._to_read(j)) for j in range(len(self.shards))]
+        if True not in reads:
+            return None
+        h = reads.index(True)
+        n_names = len(self._loader.layer_names)
+        if not any(decoder_visits(self.shards[j], n_names) for j in range(h)):
+            return None
+        r = h
+        while r < len(reads) and reads[r]:
+            r += 1
+        return range(h, r)
+
+    def _plan_lead(self) -> int:
+        """``lead_uploads`` over the run's first builds: the device work
+        still queued at the previous sweep's release (``head_queued_s``)
+        against each build's bytes at the link rate that sweep measured;
+        one where the record has neither."""
+        seen = self._lead_seen
+        queued, busy = seen.get("head_queued_s"), seen.get("upload_busy_s")
+        if queued is None or not busy or not seen.get("upload_bytes"):
+            return 1
+        from flexible_llm_sharding_tpu.runtime.residency import layer_stream_bytes
+
+        rate, loader = seen["upload_bytes"] / busy, self._loader
+        cap = min(self._depth + 1, len(self._run))
+        seconds = [
+            sum(layer_stream_bytes(
+                loader.model_path,
+                [loader.layer_names[i] for i in self._to_read(j)],
+                loader.tied,
+            ).values()) / rate
+            for j in self._run[:cap]
+        ]
+        return lead_uploads(queued, seconds, cap)
+
+    def _wait(self, attempt: Callable[[float], Any], shard_i: int | None = None):
+        """Every blocking wait of the source: ``attempt(timeout)`` until it
+        returns something other than None, which is returned (``timeout``
+        is 0 the first time, 0.2 s after that). ``close()`` and ``abort()``
+        end it within 0.2 s: it raises ``SourceClosed``. A wait of the
+        producer's (``shard_i`` given) that does not pass at once is its
+        ``producer_blocked`` span and adds to ``producer_blocked_s``."""
+        if self._stop.is_set():
+            raise SourceClosed("ShardWeightSource closed while streaming")
+        got = attempt(0)
+        if got is None:
+            with (
+                obs_trace.timed(
+                    "producer_blocked", cat="stream", **self._span_ids(shard_i)
+                )
+                if shard_i is not None
+                else contextlib.nullcontext()
+            ) as blocked:
+                while got is None and not self._stop.is_set():
+                    got = attempt(0.2)
+            if shard_i is not None:
+                self.producer_blocked_s += blocked.dur_s
+            if got is None:
+                raise SourceClosed("ShardWeightSource closed while streaming")
+        return got
 
     def _await_upload(self, device) -> None:
         """Consumer, a shard for ``device`` in hand: wait until the newest
@@ -2871,165 +2963,29 @@ class ShardWeightSource:
         if self._in_flight is in_flight:  # seen to arrive: hold it no longer
             self._in_flight = None
 
-    def _return_slot(self, ordered: bool) -> None:
-        if self._holding:
-            self._holding = False
-            self._free_slot(ordered)
-            if self._head is not None:
-                self._note_dispatched(self._held)
-
-    def _free_slot(self, ordered: bool) -> None:
-        self._slot_ordered.append(ordered)  # before the release: see __init__
-        self._slots.release()
-
-    def _take_slot(self, shard_i: int) -> bool:
-        """Producer: wait for an upload slot; True when ``dispatched()``
-        returned the one taken. The wait polls ``_stop`` as ``_put`` does
-        (``close()`` and ``abort()`` never hang on it) and counts as
-        ``producer_blocked``."""
-        if not self._slots.acquire(blocking=False):
-            with obs_trace.timed(
-                "producer_blocked", cat="stream", **self._span_ids(shard_i)
-            ) as blocked:
-                while not self._slots.acquire(timeout=0.2):
-                    if self._stop.is_set():
-                        break
-            self.producer_blocked_s += blocked.dur_s
-            if self._stop.is_set():
-                raise SourceClosed("ShardWeightSource closed while streaming")
-        return self._slot_ordered.popleft()
-
-    # -- the seated head's lead (the class docstring) -----------------------
-    def in_head(self) -> bool:
-        """Consumer: True while it holds a shard of the seated head before
-        the head's last, whose wait covers this one (the run's uploads are
-        held until that last shard is dispatched)."""
-        return self._head is not None and self._held is not None and (
-            self._held < self._head - 1
-        )
-
-    def _lead_head(self) -> tuple[int | None, int | None]:
-        """``(h, r)``: shards ``0 .. h-1`` upload nothing and hold a decoder
-        layer, ``h .. r-1`` each upload; ``(None, None)`` where the plan
-        opens otherwise."""
-        reads = [bool(self._to_read(j)) for j in range(len(self.shards))]
-        if True not in reads:
-            return None, None
-        h = reads.index(True)
-        n_names = len(self._loader.layer_names)
-        if not any(decoder_visits(self.shards[j], n_names) for j in range(h)):
-            return None, None
-        r = h
-        while r < len(reads) and reads[r]:
-            r += 1
-        return h, r
-
-    def _plan_lead(self) -> int:
-        """``lead_uploads`` over the run's first builds: the device work
-        still queued at the previous sweep's release (``head_queued_s``)
-        against each build's bytes at the link rate that sweep measured;
-        one where the record has neither."""
-        seen = self._lead_seen
-        queued, busy = seen.get("head_queued_s"), seen.get("upload_busy_s")
-        if queued is None or not busy or not seen.get("upload_bytes"):
-            return 1
-        from flexible_llm_sharding_tpu.runtime.residency import layer_stream_bytes
-
-        rate, loader = seen["upload_bytes"] / busy, self._loader
-        cap = min(self._depth + 1, self._run_end - self._head)
-        seconds = [
-            sum(layer_stream_bytes(
-                loader.model_path,
-                [loader.layer_names[i] for i in self._to_read(j)],
-                loader.tied,
-            ).values()) / rate
-            for j in range(self._head, self._head + cap)
-        ]
-        return lead_uploads(queued, seconds, cap)
-
-    def _note_dispatched(self, i: int) -> None:
-        """Consumer: shard ``i`` is dispatched; the head's last one releases
-        the run's first ``lead_planned`` uploads."""
-        with self._lead_cv:
-            self._dispatched_pos = i
-            if i == self._head - 1:
-                self._lead = self.lead_planned = self._plan_lead()
-            self._lead_cv.notify_all()
-
-    def _await_release(self, shard_i: int) -> int:
-        """Producer, an upload of the run behind the seated head: wait until
-        the head's last shard is dispatched and, past the lead, the shard
-        ``lead`` positions before this one; returns the lead. Polls
-        ``_stop`` and counts as ``producer_blocked``, as ``_take_slot``
-        does."""
-
-        def let_out(lead, dispatched):
-            return lead is not None and dispatched >= shard_i - lead
-
-        with self._lead_cv:
-            lead, dispatched = self._lead, self._dispatched_pos
-        if not let_out(lead, dispatched):
-            with obs_trace.timed(
-                "producer_blocked", cat="stream", **self._span_ids(shard_i)
-            ) as blocked, self._lead_cv:
-                while not let_out(self._lead, self._dispatched_pos):
-                    if self._stop.is_set():
-                        raise SourceClosed("ShardWeightSource closed while streaming")
-                    self._lead_cv.wait(0.2)
-                lead = self._lead
-            self.producer_blocked_s += blocked.dur_s
-        return lead
-
-    def _passed(self, shard_i: int) -> None:
-        """Producer: the build of ``shard_i`` is placed, or failed."""
-        if self._head is not None:
-            with self._lead_cv:
-                self._passed_pos = shard_i
-                self._lead_cv.notify_all()
-
-    def _await_lead_out(self) -> None:
-        """Consumer, about to take the run's first shard: wait until every
-        upload of the lead is enqueued (or failed), so that the arrival it
-        waits for next is the newest of them."""
-        with self._lead_cv:
-            while not (
-                self._passed_pos >= self._head + self._lead - 1
-                or self._stop.is_set()
-            ):
-                self._lead_cv.wait(0.2)
-
     # -- prefetch thread ---------------------------------------------------
     def _put(self, item, shard_i: int = 0) -> bool:
-        # Stop is re-checked BEFORE every put attempt, including the
-        # first: close()/abort() may fire between building the item and
-        # queueing it, and a put landing in the just-drained queue would
-        # strand a shard's HBM buffers (or an error nobody consumes)
-        # while close() joins this thread.
-        if self._stop.is_set():
-            return False
+        """Producer: queue a build (or its fault), waiting while the queue is
+        full; False once the source is closed. Stop is checked BEFORE every
+        attempt, including the first: close()/abort() may fire between
+        building the item and queueing it, and a put landing in the
+        just-drained queue would strand a shard's HBM buffers (or an error
+        nobody consumes) while close() joins this thread."""
+        with self._cv:
+            self._passed = shard_i
+            self._cv.notify_all()
+
+        def put(timeout):
+            try:
+                self._q.put(item, timeout=timeout)
+                return True
+            except Full:
+                return None
+
         try:
-            self._q.put_nowait(item)
-            return True
-        except Full:
-            pass
-        # The queue is full: a built shard waits in the producer's hand
-        # until the consumer takes one. (What holds the next UPLOAD back is
-        # its slot, which returns at "dispatched": _take_slot.)
-        with obs_trace.timed(
-            "producer_blocked", cat="stream", **self._span_ids(shard_i)
-        ) as blocked:
-            queued = None
-            while queued is None:
-                if self._stop.is_set():
-                    queued = False
-                else:
-                    try:
-                        self._q.put(item, timeout=0.2)
-                        queued = True
-                    except Full:
-                        pass
-        self.producer_blocked_s += blocked.dur_s
-        return queued
+            return self._wait(put, shard_i)
+        except SourceClosed:
+            return False
 
     def _build_streamed_first(self) -> None:
         """Producer, before the first shard of a sweep that will seat layers
@@ -3092,28 +3048,26 @@ class ShardWeightSource:
                     # fails only the in-flight wave and keeps consuming
                     # (offline consumers raise and close(), which stops this
                     # loop via _stop on the next iteration).
-                    self._passed(i)
                     if not self._put(_ShardFault(e), i):
                         return
                     continue
-                self._passed(i)
                 if not self._put(item, i):
                     return
             if not self.cycle:
                 return
 
     def _get(self):
-        """Queue get that close()/abort() can unblock: a consumer must never
-        hang forever on a queue whose producer died or whose source a
-        watchdog aborted."""
-        while True:
+        """Consumer: the next queued item. A closed source raises
+        ``SourceClosed``: a consumer must never hang forever on a queue whose
+        producer died or whose source a watchdog aborted."""
+
+        def get(timeout):
             try:
-                return self._q.get(timeout=0.2)
+                return self._q.get(timeout=timeout)
             except Empty:
-                if self._stop.is_set():
-                    raise SourceClosed(
-                        "ShardWeightSource closed while streaming"
-                    ) from None
+                return None
+
+        return self._wait(get)
 
     def __iter__(self):
         if self._thread is None:
@@ -3131,20 +3085,27 @@ class ShardWeightSource:
                 if not self.cycle:
                     return
         else:
+            taken = itertools.count()
             while True:
                 for i, (idxs, dev) in enumerate(
                     zip(self.shards, self.shard_devices)
                 ):
                     # A consumer that did not say "dispatched" for the shard
-                    # it held hands its slot back by asking for the next.
-                    self._return_slot(False)
+                    # it held lets it go by asking for the next.
+                    self._release()
                     item = self._get()
                     if isinstance(item, _ShardFault):
                         _reraise_from_producer(item.error)
-                    if i == self._head:
-                        self._await_lead_out()
+                    if self._run is not None and i == self._run.start:
+                        # The lead's uploads are all enqueued (or failed), so
+                        # the arrival waited for next is the newest of them.
+                        with self._cv:
+                            last = i + self._lead - 1
+                            self._wait(lambda t: self._cv.wait_for(
+                                lambda: self._passed >= last, t
+                            ) or None)
                     self._await_upload(dev)
-                    self._holding, self._held = True, i
+                    self._held = next(taken)
                     yield idxs, item
                 if not self.cycle:
                     return
@@ -3336,10 +3297,10 @@ class _BroadcastView:
         """The consumer's "last block enqueued" (``ShardWeightSource.
         dispatched``): a shared source's bound is its queues' alone."""
 
-    def wait_may_lag(self) -> bool:
-        """``ShardWeightSource.wait_may_lag``: a shared source keeps every
+    def end_wait(self) -> str:
+        """``ShardWeightSource.end_wait``: a shared source keeps every
         shard-end wait (it cannot tell what its producer uploads next)."""
-        return False
+        return "wait"
 
     def __iter__(self):
         q = self._parent._queues[self._rank]
@@ -4080,20 +4041,18 @@ class StreamingExecutor:
         # store sent none over the link, which a disk pass, resumed from its
         # marker and healing spills from the shard before, always does) and
         # the source says no upload can go out behind the launches queued
-        # meanwhile (wait_may_lag).
+        # meanwhile. A shard of a seated head before its last is not waited
+        # for at all: the host runs ahead, so the device still has the head
+        # queued when the uploads held behind it go out (end_wait).
         if not (blocks and visit.stores):
             return None
         stamps = clock.shards[-1]
-        if store.link_bytes == link_bytes and source.wait_may_lag():
-            clock.waits_deferred += 1
-            if source.in_head():
-                # The seated head's last shard waits for all of it: the
-                # host runs ahead, so the device still has the head queued
-                # when the uploads held behind it go out.
-                return None
-            return suffix_h, stamps
-        clock.wait_for_shard(suffix_h, stamps)
-        return None
+        end = source.end_wait() if store.link_bytes == link_bytes else "wait"
+        if end == "wait":
+            clock.wait_for_shard(suffix_h, stamps)
+            return None
+        clock.waits_deferred += 1
+        return (suffix_h, stamps) if end == "lag" else None
 
     def _recompute_block(self, prev_shard, store, b, idxs, meta, toks):
         """Re-derive one block's activations by re-running the PREVIOUS

@@ -1142,12 +1142,10 @@ def test_a_retried_device_put_takes_one_slot(monkeypatch, deep_dir):
     finally:
         source.close()
     assert [e for e in events if e == ("put", 4)] == [("put", 4)] * 4
-    # Every build took one slot and every shard returned one.
-    assert len(source._slot_ordered) == 3
-    assert source._slots.acquire(blocking=False)
-    assert source._slots.acquire(blocking=False)
-    assert source._slots.acquire(blocking=False)
-    assert not source._slots.acquire(blocking=False)
+    # Every build took one place and every shard gave one back: the source
+    # holds its three again, each given back by a "dispatched".
+    assert list(source._places) == [True] * 3
+    assert source._released == len(source.shards) - 1
 
 
 def test_a_device_put_that_fails_for_good_gives_its_slot_back(monkeypatch, deep_dir):
@@ -1229,7 +1227,240 @@ def test_a_streamed_tails_uploads_are_all_ordered(monkeypatch, deep_dir):
 
 
 # ---------------------------------------------------------------------------
-# A seated shard's end waited for one shard later (wait_may_lag)
+# The release rule pinned: after which of the consumer's releases each
+# build's device_put goes out, and what each shard's end does
+# ---------------------------------------------------------------------------
+
+def _settled_producer(monkeypatch, source_box):
+    """``settle()``: return once the producer of ``source_box[0]`` is gone
+    or blocked on its own pacing: inside a wait whose innermost frame with
+    a ``self`` in the executor's module is the source's, entered after the
+    call (a wait that began later evaluated its condition after whatever
+    the consumer did before; a loader's wait for its readahead is not
+    one)."""
+    import sys
+
+    me, here = threading.current_thread(), executor_mod.__file__
+    state = {"entered": 0, "inside": False}
+    orig = threading.Condition.wait
+
+    def pacing(frame) -> bool:
+        while frame is not None:
+            if frame.f_code.co_filename == here and "self" in frame.f_locals:
+                return frame.f_locals["self"] is source_box[0]
+            frame = frame.f_back
+        return False
+
+    def wait(cond, timeout=None):
+        if threading.current_thread() is me or not pacing(sys._getframe(1)):
+            return orig(cond, timeout)
+        state["entered"] += 1
+        state["inside"] = True
+        try:
+            return orig(cond, timeout)
+        finally:
+            state["inside"] = False
+
+    monkeypatch.setattr(threading.Condition, "wait", wait)
+
+    def settle():
+        since = state["entered"]
+        thread = source_box[0]._thread
+        _wait_for(
+            lambda: thread is None
+            or not thread.is_alive()
+            or (state["inside"] and state["entered"] > since)
+        )
+
+    return settle
+
+
+def _paced_run(monkeypatch, deep_dir, depth, pinned, takes, dispatch=True,
+               fail=None, **kw):
+    """Drive a source as the executor does, one step at a time, letting the
+    producer settle after each: take a shard, run ``_stream_shard`` over one
+    block (which says "dispatched" and then does what the source answers at
+    the shard's end). Returns, by build in order, the consumer's step after
+    which its device_put went out ("-": none yet; "nK" asking for take K,
+    "dK" its "dispatched"), each take's end ("wait", "lag", "skip"; "-"
+    where nothing is dispatched, "fault" for a build that failed) and the
+    source's ordered uploads, led uploads and planned lead."""
+    from types import SimpleNamespace
+
+    from flexible_llm_sharding_tpu.faults.retry import RetryPolicy
+
+    events, say, _ = _log_puts(monkeypatch, fail=fail)
+    box = [None]
+    settle = _settled_producer(monkeypatch, box)
+    monkeypatch.setattr(executor_mod, "process_block", lambda *a, **k: "suffix_h")
+    source = box[0] = _tracked_source(
+        monkeypatch, deep_dir, depth, pinned,
+        retry_policy=RetryPolicy(max_attempts=1), **kw,
+    )
+    said = source.dispatched
+    clock = SimpleNamespace(
+        sweep_id=0, waits_deferred=0, shards=["stamps"],
+        span_ids=lambda: {}, launched=lambda: 0.0,
+        wait_for_shard=lambda result, stamps: None,
+    )
+    fake = SimpleNamespace(
+        model_cfg=None, dtype=None, device=None, _use_pallas=False, _tp_mesh=None
+    )
+    ends, lagging, segs = [], None, None
+    try:
+        it = iter(source)
+        settle()
+        for k in range(takes):
+            say("next", k)
+            try:
+                _, segs = next(it)
+            except executor_mod.ShardLoadError:
+                settle()
+                ends.append("fault")
+                break
+            settle()
+            if not dispatch:
+                ends.append("-")
+                continue
+            source.dispatched = lambda k=k: (say("dispatched", k), said())
+            deferred = clock.waits_deferred
+            lagging = StreamingExecutor._stream_shard(
+                fake, SimpleNamespace(link_bytes=0), None, [(0,)], [None], None,
+                SimpleNamespace(stores=True), segs, None,
+                SimpleNamespace(update=lambda n: None), clock,
+                SimpleNamespace(attrs={}, t0=0.0), source, lagging,
+            )
+            ends.append(
+                "lag" if lagging else "skip" if clock.waits_deferred > deferred
+                else "wait"
+            )
+            settle()
+        del segs
+    finally:
+        source.close()
+    after, puts = "-", []
+    for kind, n in events:
+        if kind == "put" and n == len(puts):
+            puts.append(after)
+        elif kind != "put":
+            after = f"{kind[0]}{n}"
+    return (
+        " ".join(puts), " ".join(ends),
+        (source.uploads_ordered, source.uploads_led, source.lead_planned),
+    )
+
+
+_HEAD = (0, 1, 2, 9, 10)  # the embedding, layers 0-1, the norm and the head
+_LEAD_OF_THREE = {"head_queued_s": 1e9, "upload_busy_s": 1.0, "upload_bytes": 10**9}
+
+
+_WAITS = "wait " * 9  # the shards 0-8 of a plan whose every one waits
+
+
+@pytest.mark.parametrize(
+    "depth, pinned, kw, puts, ends, counts",
+    [
+        pytest.param(
+            2, (), {},
+            "- - - d0 d1 d2 d3 d4 d5 d6 d7",
+            _WAITS + "wait wait", (8, 0, 0),
+            id="no_seated_layer",
+        ),
+        pytest.param(
+            1, _HEAD, {"lead": {}},
+            "- - d0 d2 d3 d4 d5 d6 d7 d7 d8",
+            "skip skip wait wait wait wait wait wait wait lag wait", (6, 1, 1),
+            id="seated_head_depth_1",
+        ),
+        pytest.param(
+            2, _HEAD, {"lead": {}},
+            "- - - d2 d3 d4 d5 d6 d7 d7 d7",
+            "skip skip wait wait wait wait wait wait wait lag wait", (6, 1, 1),
+            id="seated_head_depth_2",
+        ),
+        pytest.param(
+            2, _HEAD, {"lead": dict(_LEAD_OF_THREE)},
+            "- - - d2 d2 d2 d3 d4 d5 d6 d7",
+            "skip skip wait wait wait wait wait wait wait lag wait", (6, 3, 3),
+            id="seated_head_lead_of_three",
+        ),
+        pytest.param(
+            2, _HEAD, {},
+            "- - - d0 d1 d2 d3 d4 d5 d6 d7",
+            _WAITS + "lag wait", (6, 0, 0),
+            id="seated_head_no_lead_asked",
+        ),
+        pytest.param(
+            2, (0, 1, 2, 5, 6, 9, 10), {"lead": {}},
+            "- - - d2 d3 d3 d3 d4 d5 d6 d7",
+            "skip skip wait wait wait wait lag wait wait lag wait", (4, 1, 1),
+            id="head_and_a_later_stretch",
+        ),
+        pytest.param(
+            2, tuple(range(11)), {"lead": {}},
+            "- - - d0 d1 d2 d3 d4 d5 d6 d7",
+            "lag " * 10 + "wait", (0, 0, 0),
+            id="every_layer_seated",
+        ),
+        pytest.param(
+            2, _HEAD, {"lead": {}, "cycle": True, "takes": 22},
+            "- - - " + " ".join(f"d{k}" for k in range(22)),
+            " ".join(["wait"] * 22), (12, 0, 0),
+            id="cycling_two_sweeps",
+        ),
+        pytest.param(
+            2, _HEAD, {"lead": {}, "devices": "a_shard"},
+            "- - - d0 d1 d2 d3 d4 d5 d6 d7",
+            _WAITS + "lag wait", (6, 0, 0),
+            id="a_device_a_shard",
+        ),
+        pytest.param(
+            0, _HEAD, {"lead": {}},
+            " ".join(f"n{k}" for k in range(11)),
+            "lag wait wait wait wait wait wait wait wait lag wait", (0, 0, 0),
+            id="prefetch_depth_0",
+        ),
+        pytest.param(
+            2, (), {"fail": 4},
+            "- - - d0 d1 n2 n3 n4",
+            "wait wait wait wait fault", (3, 0, 0),
+            id="a_build_fails_for_good",
+        ),
+        pytest.param(
+            2, _HEAD, {"lead": {}, "fail": 4},
+            "- - - d2 d3",
+            "skip skip wait wait fault", (1, 1, 1),
+            id="a_run_build_fails_for_good",
+        ),
+        pytest.param(
+            2, (), {"dispatch": False},
+            "- - - n1 n2 n3 n4 n5 n6 n7 n8",
+            " ".join(["-"] * 11), (0, 0, 0),
+            id="never_dispatched",
+        ),
+    ],
+)
+def test_the_release_rule_decides_as_pinned(
+    monkeypatch, deep_dir, depth, pinned, kw, puts, ends, counts
+):
+    """For each plan, the consumer's step after which each build's
+    device_put goes out, what each shard's end does, and the ordered and
+    led uploads and the planned lead. The tables are literal: a change to
+    the release rule or to the question at a shard's end changes them."""
+    kw = dict(kw)
+    n = len(deep_dir[1])
+    if kw.pop("devices", None):
+        kw["devices"] = [jax.devices()[0]] * n
+    fail = kw.pop("fail", None)
+    got = _paced_run(
+        monkeypatch, deep_dir, depth, pinned, kw.pop("takes", n),
+        fail=None if fail is None else lambda j, attempt: j == fail, **kw,
+    )
+    assert got == (puts, ends, counts)
+
+
+# ---------------------------------------------------------------------------
+# A seated shard's end waited for one shard later (end_wait says "lag")
 # ---------------------------------------------------------------------------
 
 @pytest.fixture
@@ -1247,7 +1478,7 @@ def fresh_tier():
 
 def _keep_every_wait(monkeypatch):
     monkeypatch.setattr(
-        executor_mod.ShardWeightSource, "wait_may_lag", lambda self: False
+        executor_mod.ShardWeightSource, "end_wait", lambda self: "wait"
     )
 
 
@@ -1347,7 +1578,7 @@ def _logged_pass(monkeypatch, ex, lead=True):
         m.setattr(executor_mod.SweepClock, "wait_for_shard", wait)
         if not lead:
             m.setattr(
-                executor_mod.ShardWeightSource, "_lead_head", lambda self: (None, None)
+                executor_mod.ShardWeightSource, "_run_behind_head", lambda self: None
             )
         scores = ex(list(PROMPTS))
     return scores, executor_mod.process_sweep_log()[-1], events
@@ -1538,13 +1769,13 @@ def test_no_seated_head_or_another_consumer_keeps_the_slots_order(
             for _ in range(n):
                 next(it)
                 source.dispatched()
-            assert source._head is None and source.lead_planned == 0
+            assert source._run is None and source.lead_planned == 0
         finally:
             source.close()
         # the same plan and pins, one sweep, one chip: it has the head
         control = _tracked_source(monkeypatch, deep_dir, 2, (0, 1, 2, 9, 10), lead={})
         control.close()
-        assert (control._head, control._run_end) == (3, 9)
+        assert control._run == range(3, 9)
         return
     # the tier seats decoder layers from the first: to stream the first,
     # seat none of them

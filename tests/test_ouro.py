@@ -350,7 +350,7 @@ def test_a_seated_loop_waits_for_each_shard_one_shard_later(model_dir, monkeypat
 
     _, seating = sweep()
     lagged, rec = sweep()
-    monkeypatch.setattr(executor.ShardWeightSource, "wait_may_lag", lambda self: False)
+    monkeypatch.setattr(executor.ShardWeightSource, "end_wait", lambda self: "wait")
     kept, rec_kept = sweep()
     assert seating["waits_deferred"] == 10
     assert (rec["visits_pinned"], rec["uploads"]) == (12, 0)
